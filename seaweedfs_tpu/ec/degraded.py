@@ -596,9 +596,7 @@ class DegradedReadEngine:
                     self._c["host_dispatches"] += 1
             else:
                 from ..ops.pipeline import PipelinedMatmul
-                pm = PipelinedMatmul(
-                    rows, max_width=max(sub.shape[1], 1 << 20),
-                    codec=codec)
+                pm = PipelinedMatmul(rows, max_width=None, codec=codec)
                 out = None
                 for _meta, _d, o in pm.stream([(None, sub)]):
                     out = o
@@ -669,8 +667,9 @@ class DegradedReadEngine:
                     self._c["host_dispatches"] += 1
             else:
                 from ..ops.pipeline import PipelinedMatmul
-                pm = PipelinedMatmul(row, max_width=max(width, 1 << 20),
-                                     codec=codec)
+                # no cap: batches come in every width, and a cap taken
+                # from the batch compiled a new device program for each
+                pm = PipelinedMatmul(row, max_width=None, codec=codec)
                 out = None
                 for _meta, _d, o in pm.stream([(None, data)]):
                     out = o

@@ -20,8 +20,8 @@ compiled-executable lifecycle through this module via `wrap()`:
   bucketed width maps to itself, so each (entry, bucket) pair compiles
   at most once. A second compile for the same pair increments
   `recompiles` and latches the `sentinel` flag with a bounded offender
-  list. r05's 2 MB/s mesh rebuild would have been a nonzero counter,
-  not a PR-long bisect.
+  list. An earlier round's collapsed mesh rebuild would have been a
+  nonzero counter, not a PR-long bisect.
 
 - **Sampled device-time attribution.** With `SW_EC_DEVICE_TIMING=1`,
   every `SW_EC_DEVICE_TIMING_SAMPLE`th dispatch per entry point runs
@@ -53,12 +53,12 @@ jax at all.
 
 from __future__ import annotations
 
-import sys
 import weakref
 from time import perf_counter as _perf_counter
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..util import config
+from ..util.jax_platform import backend_initialized
 from ..util.locks import make_lock
 
 #: Compiled signatures latched as sentinel offenders are capped here;
@@ -319,23 +319,24 @@ def jit_factory_snapshot() -> Dict[str, dict]:
 # device inventory
 # ---------------------------------------------------------------------------
 
-def device_inventory(force: bool = False) -> dict:
+def device_inventory() -> dict:
     """Platform, device kind×count, and memory_stats() gauges.
 
-    A metrics scrape must never be the thing that boots an XLA
-    backend: unless `force` or jax is already imported, this reports
-    initialized=False and touches nothing."""
-    if not force and "jax" not in sys.modules:
-        return {"initialized": False, "platform": None,
-                "device_kinds": {}, "devices": []}
+    A metrics scrape or a status question must never be the call that
+    boots an XLA backend (a chip belongs to one process): until a codec
+    has initialised one, this reports initialized=False and touches
+    nothing. A backend whose init half-failed reports the error in the
+    payload — only the codec path raises it."""
+    empty = {"initialized": False, "platform": None,
+             "device_kinds": {}, "devices": []}
+    if not backend_initialized():
+        return empty
+    import jax
     try:
-        import jax
         devices = jax.devices()
         platform = jax.default_backend()
-    except Exception as exc:  # pragma: no cover - no backend at all
-        return {"initialized": False, "platform": None,
-                "device_kinds": {}, "devices": [],
-                "error": str(exc)}
+    except RuntimeError as exc:
+        return {**empty, "error": str(exc)}
     kinds: Dict[str, int] = {}
     per_device = []
     for d in devices:
@@ -354,10 +355,9 @@ def device_inventory(force: bool = False) -> dict:
 
 def admin_snapshot() -> dict:
     """The GET /admin/devices payload: full stats + factories +
-    inventory (forces backend init — this endpoint is explicitly for
-    humans asking about devices)."""
+    inventory (which, as on a metrics scrape, never boots a backend)."""
     return {
         "stats": DEVICE_STATS.snapshot(),
         "jit_factories": jit_factory_snapshot(),
-        "inventory": device_inventory(force=True),
+        "inventory": device_inventory(),
     }

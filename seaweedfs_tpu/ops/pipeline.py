@@ -53,13 +53,15 @@ class PipelinedMatmul:
     """
 
     def __init__(self, coeffs: np.ndarray,
-                 max_width: int = 32 << 20, depth: int = 4,
+                 max_width: Optional[int] = 32 << 20, depth: int = 4,
                  prefetch: int = 3, drain_threads: int = 2,
                  timer: Optional[StageTimer] = None,
                  codec=None, pieces: bool = False):
         coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
         self.r, self.k = coeffs.shape
-        self.max_width = int(max_width)
+        # the widest slab stream() accepts, and the bucket full slabs
+        # get; None: any width, each padded to its power-of-two bucket
+        self.max_width = None if max_width is None else int(max_width)
         self.depth = int(depth)
         self.prefetch = int(prefetch)
         self.drain_threads = int(drain_threads)
@@ -155,7 +157,7 @@ class PipelinedMatmul:
                     break
                 meta, data = item
                 w = data.shape[1]
-                if w > self.max_width:
+                if self.max_width is not None and w > self.max_width:
                     raise ValueError(
                         f"slab width {w} exceeds max_width {self.max_width}")
                 bucket = self._bucket(w)

@@ -2,7 +2,7 @@
 
 The round-2 XLA kernel (ops/rs_tpu.py) materialized the 8x bit-plane
 expansion and the 4-byte-per-bit int32 matmul result in HBM around a
-skinny matmul — bandwidth-bound on its own temporaries at ~0.3% MXU.
+skinny matmul — bandwidth-bound on its own temporaries.
 This kernel fuses unpack -> matmul -> pack into one pallas_call so the
 only HBM traffic is the uint8 payload in and the uint8 code rows out
 ((k + r)/k bytes moved per payload byte); the bit-planes and int32
@@ -45,6 +45,8 @@ def _pl():
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    from ..util.jax_platform import configure_compile_cache
+    configure_compile_cache()
     return jax, jnp, pl, pltpu
 
 
@@ -134,38 +136,29 @@ _device_stats.register_jit_factory("rs_pallas._fused_bitmat_cached",
                                    _fused_bitmat_cached)
 
 
-def _use_interpret() -> bool:
-    """Pallas compiles natively only on TPU; everywhere else (the CPU
-    test mesh) the interpreter gives the same bit-exact semantics."""
-    from .rs_tpu import on_tpu
-    return not on_tpu()
-
-
-def fused_matmul(coeffs: np.ndarray, data, interpret: bool = None):
+def fused_matmul(coeffs: np.ndarray, data, interpret: bool):
     """coeffs (r, k) GF(2^8) x data (k, n) uint8 -> (r, n) uint8 (device
-    array). `data` may be a numpy or device array; transfer is implicit."""
+    array). `data` may be a numpy or device array; transfer is implicit.
+    `interpret` is the caller's explicit choice: False compiles for the
+    TPU, True runs the Pallas interpreter (CPU tests only)."""
     import jax.numpy as jnp
     coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
     r, k = coeffs.shape
     n = data.shape[1]
-    if interpret is None:
-        interpret = _use_interpret()
     bitmat = jnp.asarray(fuse_bitmat(coeffs))
     fn = _fused_fn(k, r, n, pick_tile(k, r, n), interpret)
     return fn(bitmat, data)
 
 
 def make_fused_encode_fn(k: int, m: int, n: int,
-                         matrix_kind: str = "vandermonde",
-                         interpret: bool = None):
+                         matrix_kind: str = "vandermonde", *,
+                         interpret: bool):
     """(jitted fn(bitmat, data (k,n) uint8) -> (m,n) uint8, bitmat (8m,8k)).
 
     Direct Pallas-path handle with an explicit interpret switch — the
     production entry point is rs_tpu.make_encode_fn / fn_and_bitmat,
     which dispatches here automatically on TPU.
     """
-    if interpret is None:
-        interpret = _use_interpret()
     matrix = gf256.build_matrix(k, k + m, matrix_kind)
     bitmat = fuse_bitmat(matrix[k:])
     return _fused_fn(k, m, n, pick_tile(k, m, n), interpret), bitmat

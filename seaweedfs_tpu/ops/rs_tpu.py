@@ -26,6 +26,7 @@ linearity makes zero-padding exact).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import numpy as np
 
@@ -33,6 +34,7 @@ from .codec import ReedSolomonCodec
 from . import device_stats
 from . import gf256
 from ..util import config
+from ..util.locks import make_lock
 
 #: lru maxsize for the jit factories below — read once at import, a
 #: registered knob so eviction pressure (a silent recompile source) is
@@ -50,6 +52,8 @@ _PACKED_UNROLL_LIMIT = 4096
 def _jax():
     import jax
     import jax.numpy as jnp
+    from ..util.jax_platform import configure_compile_cache
+    configure_compile_cache()
     return jax, jnp
 
 
@@ -87,11 +91,11 @@ def _packed_fn(k: int, r: int, n: int):
     uint8) -> (r, n) uint8 — the AND/popcount form of the GF(2) matmul.
 
     The bit-plane dot lifts the payload 8x and feeds the CPU a
-    (r*8, k*8) @ (k*8, n) int8 gemm with a tiny M — memory-bound and
-    ~2 MB/s/core in practice (the round-5 mesh rebuild). Packing the
+    (r*8, k*8) @ (k*8, n) int8 gemm with a tiny M — memory-bound (the
+    round-5 mesh rebuild crawled on it). Packing the
     k*8 contraction bits into <=8 uint32 words turns each output bit
     into a handful of vectorized AND + popcount + parity ops: ~64x
-    less arithmetic, no 8x intermediate, and seconds -> sub-second
+    less arithmetic, no 8x intermediate, and much shorter
     compile times. Exact (popcount parity == mod-2 dot), so output is
     bit-identical to every other backend. TPU keeps the MXU dot /
     fused Pallas kernel (rs_pallas) where the matmul IS the fast path.
@@ -172,37 +176,58 @@ for _name, _factory in (("rs_tpu._coded_fn", _coded_fn),
 del _name, _factory
 
 
+#: functools.lru_cache does not serialize concurrent misses: two reader
+#: threads asking for the same new (k, r, n) would each build a jitted
+#: fn and each compile it (a latched recompile). Factory calls from the
+#: serving path go through fn_and_bitmat under this lock; a hit is a
+#: dict lookup, a miss only wraps — the compile happens at first call.
+_factory_lock = make_lock("rs_tpu._factory_lock")
+
+
 def on_tpu() -> bool:
-    import jax
-    return jax.default_backend() == "tpu"
+    """First JAX touch of the device codecs: True on the TPU, False on
+    an explicitly requested CPU (JAX_PLATFORMS=cpu), an error anywhere
+    else — `tpu`/`mesh` never compute on a platform nobody asked for."""
+    from ..util.jax_platform import require_tpu
+    return require_tpu("tpu|mesh") == "tpu"
 
 
 def fn_and_bitmat(coeffs: np.ndarray, n: int):
     """Pick the device kernel for this platform: the fused Pallas kernel
-    on real TPU (ops/rs_pallas — unpack/matmul/pack in VMEM, no HBM
-    temporaries), the packed AND/popcount XLA program elsewhere (the
-    CPU test mesh, where the 8x bit-plane gemm is the bottleneck and
-    Pallas would have to interpret). Returns (jitted fn, host constant
-    — fused bitmat on TPU, packed uint32 bitmat off it) with matching
-    layouts; both are bit-identical to the numpy oracle."""
+    on the TPU (ops/rs_pallas — unpack/matmul/pack in VMEM, no HBM
+    temporaries), the packed AND/popcount XLA program where the CPU was
+    asked for (the test mesh, where the 8x bit-plane gemm is the
+    bottleneck and Pallas would have to interpret). Returns (jitted fn,
+    host constant — fused bitmat on TPU, packed uint32 bitmat off it)
+    with matching layouts; both are bit-identical to the numpy oracle."""
     coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
     r, k = coeffs.shape
     if on_tpu():
         from .rs_pallas import _fused_fn, fuse_bitmat, pick_tile
-        return (_fused_fn(k, r, n, pick_tile(k, r, n), False),
-                fuse_bitmat(coeffs))
-    return _packed_fn(k, r, n), _packed_bitmat(coeffs.tobytes(), r, k)
+        with _factory_lock:
+            fn = _fused_fn(k, r, n, pick_tile(k, r, n), False)
+        return fn, fuse_bitmat(coeffs)
+    with _factory_lock:
+        fn = _packed_fn(k, r, n)
+    return fn, _packed_bitmat(coeffs.tobytes(), r, k)
 
 
-def width_bucket(n: int, cap: int) -> int:
-    """Pad widths up to power-of-two buckets (capped) so varied payload
-    widths reuse compiled executables instead of jitting per exact n."""
-    return min(max(512, 1 << (n - 1).bit_length()), cap)
+def width_bucket(n: int, cap: Optional[int]) -> int:
+    """Pad widths up to power-of-two buckets so varied payload widths
+    reuse compiled executables instead of jitting per exact n. A caller
+    whose slabs all have one width passes it as `cap` and pays no
+    padding on full slabs; one whose widths vary (degraded-read batches)
+    passes None — a cap taken from each width would itself be an exact
+    width, and compile a program per batch."""
+    bucket = max(512, 1 << (n - 1).bit_length())
+    return bucket if cap is None else min(bucket, cap)
 
 
 class TpuCodec(ReedSolomonCodec):
-    """JAX backend. Runs on whatever jax.devices() offers (TPU in prod,
-    virtual CPU mesh in tests) — output is bit-identical everywhere."""
+    """JAX backend on one TPU chip. Computes on the CPU only where
+    JAX_PLATFORMS=cpu asks for it (tests, rehearsals); any other
+    platform is an error at the first device touch (on_tpu). Output is
+    bit-identical everywhere."""
 
     backend = "tpu"
 

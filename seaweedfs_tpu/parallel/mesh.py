@@ -20,6 +20,8 @@ def make_mesh(shape: Optional[Tuple[int, ...]] = None,
     """
     import jax
     from jax.sharding import Mesh
+    from ..util.jax_platform import configure_compile_cache
+    configure_compile_cache()
 
     devices = devices if devices is not None else jax.devices()
     n = len(devices)
@@ -48,6 +50,8 @@ def make_codec_mesh(devices=None, width_devices: Optional[int] = None):
     """
     import jax
     from ..util import config
+    from ..util.jax_platform import configure_compile_cache
+    configure_compile_cache()
 
     devices = list(devices if devices is not None else jax.devices())
     cap = (int(width_devices) if width_devices is not None

@@ -18,8 +18,8 @@ ops/rs_tpu.fn_and_bitmat):
   * everything else (the virtual CPU test mesh) — packed AND/popcount:
     the k*8 contraction bits packed into uint32 words, each output bit
     a parity of popcounts. ~64x less arithmetic and no 8x intermediate;
-    this is what turned the round-5 rebuild from 2 MB/s into a usable
-    hot path on the CPU mesh.
+    this is what made the round-5 rebuild a usable hot path on the
+    CPU mesh.
 
 Dispatch discipline (the round-5 lesson): coefficients are lifted and
 uploaded ONCE per coefficient matrix (bounded LRU, ops/codec._ConstCache),
@@ -36,16 +36,16 @@ payload crossover keep the single-device kernel (sharding a
 kilobyte-wide reconstruct pays partitioning overhead it can't
 amortize), and every sharded put records its per-device byte landing
 in ops/telemetry so a silent fall-back to width-1 dispatch is a
-visible counter regression, not a 74 -> 2 MB/s surprise.
+visible counter regression, not a wall-time surprise.
 
-This is the serving-path face of SURVEY §2.6's device tier: the same
-sharded programs the driver dry-runs via __graft_entry__ become the
-volume server's encode/rebuild engine.
+This is the serving-path face of SURVEY §2.6's device tier: the
+volume server's encode/rebuild engine on a multi-chip host
+(chip_smoke.py --chips 4 runs it on four real chips).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -55,6 +55,14 @@ from ..ops.rs_tpu import width_bucket
 from ..ops.telemetry import STATS
 from ..util import config
 from .mesh import make_codec_mesh
+
+
+#: (mesh, rows_in, rows_out, n) -> jitted program, shared by
+#: every MeshCodec of the process (like ops/rs_tpu's module-level jit
+#: factories): several volume servers in one process — `weed server`
+#: drills, chip_smoke.py — each own a codec, and a per-codec cache made
+#: each of them compile the same program again (a latched recompile).
+_FNS: Dict[Tuple, object] = {}
 
 
 class MeshCodec(ReedSolomonCodec):
@@ -68,7 +76,6 @@ class MeshCodec(ReedSolomonCodec):
         super().__init__(data_shards, parity_shards, matrix_kind)
         self.chunk_bytes = int(chunk_bytes)
         self._mesh = mesh  # lazy: devices may not be initialized yet
-        self._fns: Dict[Tuple[int, int, int], object] = {}
         self.small_dispatch_bytes = (
             small_dispatch_default() if small_dispatch_bytes is None
             else int(small_dispatch_bytes))
@@ -90,15 +97,19 @@ class MeshCodec(ReedSolomonCodec):
         return self._mesh
 
     def _on_tpu_mesh(self) -> bool:
-        return self.mesh.devices.flat[0].platform == "tpu"
+        """True on a TPU mesh, False on an explicitly requested CPU
+        mesh (JAX_PLATFORMS=cpu), an error on anything else."""
+        from ..util.jax_platform import require_tpu
+        return require_tpu(
+            "mesh", self.mesh.devices.flat[0].platform) == "tpu"
 
     def _fn(self, rows_in: int, rows_out: int, n: int):
         """Jitted (const, data (rows_in, n) uint8) -> (rows_out, n)
         uint8, payload sharded over 'data', const replicated. The const
         is the int8 bit-matrix (TPU mesh) or the packed uint32 bit-
         matrix (elsewhere) — _device_const builds the matching form."""
-        key = (rows_in, rows_out, n)
-        fn = self._fns.get(key)
+        key = (self.mesh, rows_in, rows_out, n)
+        fn = _FNS.get(key)
         if fn is not None:
             return fn
         import jax
@@ -151,14 +162,13 @@ class MeshCodec(ReedSolomonCodec):
                               NamedSharding(mesh, P(None, "data"))),
                 out_shardings=NamedSharding(mesh, P(None, "data"))),
             "mesh_codec._fn")
-        self._fns[key] = fn
-        return fn
+        return _FNS.setdefault(key, fn)
 
     def _device_const(self, coeffs: np.ndarray):
         """Device-resident replicated coefficient constant — uploaded
         once per coefficient matrix, reused across every slab of a
         rebuild/encode (round-5 fix: re-lifting + re-uploading per call
-        was most of the 2 MB/s)."""
+        was most of that rebuild's wall)."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -232,7 +242,7 @@ class MeshCodec(ReedSolomonCodec):
             return [(0, full[:, :w] if full.shape[1] > w else full)]
         return sorted(by_off.items())
 
-    def pipeline_width_bucket(self, n: int, cap: int) -> int:
+    def pipeline_width_bucket(self, n: int, cap: Optional[int]) -> int:
         bucket = width_bucket(n, cap)
         return bucket + (-bucket) % self.mesh.shape["data"]
 

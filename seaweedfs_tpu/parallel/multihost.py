@@ -26,48 +26,9 @@ the NumpyCodec oracle.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional
 
 import numpy as np
-
-
-def jax_version() -> Tuple[int, int]:
-    """(major, minor) of the installed jax, (0, 0) when unparsable."""
-    import jax
-    parts = str(jax.__version__).split(".")
-    try:
-        return int(parts[0]), int(parts[1])
-    except (IndexError, ValueError):
-        return (0, 0)
-
-
-def has_native_shard_map() -> bool:
-    """`jax.shard_map` reached the top-level namespace with the 0.5
-    line; before that it lives at jax.experimental.shard_map. The
-    sharded_ec compat shim and the DCN-tier test gate on the same
-    probe so they flip together when the image's jax moves."""
-    import jax
-    return hasattr(jax, "shard_map")
-
-
-def multihost_cpu_capability() -> Tuple[bool, str]:
-    """Can THIS jax build run multi-process collectives on the CPU
-    backend? jax < 0.5 initializes the distributed service but every
-    cross-process collective fails with \"collectives aren't
-    implemented on the CPU backend\" — the capability arrived with the
-    0.5-era CPU collectives implementation. Returns (ok, reason):
-    reason explains a False verdict."""
-    try:
-        import jax
-    except Exception as e:  # noqa: BLE001 - report, don't raise
-        return False, f"jax unavailable: {e!r}"
-    v = jax_version()
-    if v < (0, 5):
-        return False, (f"jax {jax.__version__} has no multiprocess CPU "
-                       f"collectives (needs >= 0.5)")
-    if not hasattr(jax, "distributed"):
-        return False, "jax.distributed unavailable in this build"
-    return True, ""
 
 
 def init_distributed(coordinator_address: str, num_processes: int,

@@ -121,15 +121,7 @@ def sharded_rebuild_fn(mesh, k: int, n_out_shards: int, n: int):
         weights = (jnp.uint8(1) << shifts)[None, :, None]
         return (ybits * weights).sum(axis=1, dtype=jnp.uint8)
 
-    # jax.shard_map only exists from 0.5; fall back to the experimental
-    # home it had before that — gated on the same capability probe the
-    # DCN-tier test uses, so shim and test retire together
-    from .multihost import has_native_shard_map
-    if has_native_shard_map():
-        shard_map = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map
-    smap = shard_map(
+    smap = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("shard", None), P("shard", "data")),
         out_specs=P(None, "data"))

@@ -669,7 +669,7 @@ class VolumeServer:
             HTTP_POOL_CHURN_COUNTER.set_total(total, event)
         # device-runtime plane: compile/recompile accounting, sampled
         # device time, const-cache + jit-factory occupancy. The
-        # inventory is only exported when jax is already initialized —
+        # inventory is only exported once a backend is initialized —
         # a scrape must never be the thing that boots a backend.
         from ..ops import device_stats as _ds
         from ..stats.metrics import observe_device_stats
@@ -710,8 +710,10 @@ class VolumeServer:
         compile/recompile/dispatch counters with the latched recompile
         sentinel, sampled device seconds, jit-factory cache_info,
         const-cache occupancy, and the device inventory incl.
-        memory_stats(). Forces backend init — this endpoint exists to
-        answer questions about devices."""
+        memory_stats(). Never boots a backend itself: a process whose
+        codecs have not initialised one answers
+        inventory.initialized=false (the chip belongs to one process; a
+        status question must not grab it)."""
         from ..ops import device_stats as _ds
         return _ds.admin_snapshot()
 

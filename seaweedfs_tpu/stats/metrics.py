@@ -665,7 +665,7 @@ VOLUME_EC_MESH_DISPATCH_COUNTER = VOLUME_SERVER_GATHER.counter(
 VOLUME_EC_MESH_WIDTH_GAUGE = VOLUME_SERVER_GATHER.gauge(
     "SeaweedFS_volumeServer_ec_mesh_dispatch_width_devices",
     "Devices the last mesh EC operation's dispatches landed bytes on "
-    "(1 = silent fall-back to width-1 dispatch — the r05 regression "
+    "(1 = silent fall-back to width-1 dispatch — the regression "
     "mode this gauge exists to catch).")
 VOLUME_EC_MESH_DEVICE_BYTES = VOLUME_SERVER_GATHER.counter(
     "SeaweedFS_volumeServer_ec_mesh_device_bytes_total",

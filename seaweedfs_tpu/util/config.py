@@ -228,18 +228,6 @@ _knob("SW_BENCH_DAT_MB", "int", 4096,
       "Bench volume size in MB for the headline configs.")
 _knob("SW_BENCH_SLAB_MB", "int", 8,
       "Bench device slab per shard row in MB.")
-_knob("SW_BENCH_INIT_TIMEOUT", "float", 180.0,
-      "Seconds to wait for device backend init before falling back.")
-_knob("SW_BENCH_INIT_RETRIES", "int", 5,
-      "Legacy alias for SW_BENCH_DEVICE_INIT_RETRIES.")
-_knob("SW_BENCH_DEVICE_INIT_RETRIES", "int", 5,
-      "Device-init attempts before the CPU fallback is recorded.")
-_knob("SW_BENCH_INIT_RETRY_TIMEOUT", "float", 120.0,
-      "Per-attempt timeout for device-init retries.")
-_knob("SW_BENCH_INIT_RETRY_SPACING", "float", 15.0,
-      "Base spacing between device-init retries (doubles per attempt).")
-_knob("SW_BENCH_INIT_RETRY_MAX_SPACING", "float", 120.0,
-      "Cap on the exponential device-init retry spacing.")
 _knob("SW_BENCH_DIR", "str", None,
       "Bench working directory (default: a fresh temp dir).")
 _knob("SW_BENCH_KEEP", "bool", False,

@@ -196,7 +196,7 @@ class TestBackendIsolationChecker(unittest.TestCase):
         rep = analyze.analyze_source(
             "import jax\n", "seaweedfs_tpu/util/jax_platform.py")
         self.assertEqual(rep.problems, [])
-        self.assertTrue(any("platform-selection shim" in a
+        self.assertTrue(any("start-up rules for the device modules" in a
                             for a in rep.allowed), rep.allowed)
 
 

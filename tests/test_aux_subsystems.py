@@ -1,6 +1,5 @@
 """Round-3 auxiliary subsystems: write throttler, config tiers, TLS,
-master maintenance cron, status UIs (VERDICT r2 missing #7/#8/#9/#10 +
-§5.6)."""
+master maintenance cron, status UIs (SURVEY §5.6)."""
 
 import os
 import subprocess

@@ -1,8 +1,10 @@
 """tools/bench_diff.py: regression detection between bench records.
 
-Exercised against the REAL r04/r05 records from RESULTS/ (the r05 run
-where cluster rebuild throughput fell off a cliff) plus synthetic
-fixtures for threshold/exit-code behavior.
+Exercised on two small synthetic records in the driver's wrapper shape
+(a throughput, a latency, a failure count and a nested leaf each; the
+newer one also carries a drill the older one lacks, with a rebuild
+throughput that fell off a cliff) plus fixtures for threshold/exit-code
+behavior.
 """
 
 import json
@@ -16,12 +18,28 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 
 import bench_diff  # noqa: E402
 
-R04 = os.path.join(REPO, "BENCH_r04.json")
-R05 = os.path.join(REPO, "BENCH_r05.json")
 
-needs_records = pytest.mark.skipif(
-    not (os.path.exists(R04) and os.path.exists(R05)),
-    reason="bench records not checked in")
+@pytest.fixture
+def records(tmp_path):
+    """(older, newer) record paths in the driver's {n, rc, parsed}
+    wrapper. The older run has no cluster_rebuild drill; the newer one
+    recorded 2 MB/s there."""
+    older = {"n": 4, "rc": 0, "parsed": {
+        "metric": "ec_encode_rs10_4_mbps", "value": 410, "unit": "MB/s",
+        "encode_s": 10.2, "read_errors": 0,
+        "data_plane": {"read_rps": 9800, "p99_ms": 1.9}}}
+    newer = {"n": 5, "rc": 0, "parsed": {
+        "metric": "ec_encode_rs10_4_mbps", "value": 395, "unit": "MB/s",
+        "encode_s": 10.9, "read_errors": 0,
+        "data_plane": {"read_rps": 10100, "p99_ms": 2.0},
+        "cluster_rebuild": {"rebuild_mbps_volume_bytes": 2,
+                            "rebuild_s": 31.0, "recompiles": 0}}}
+    paths = []
+    for name, rec in (("older.json", older), ("newer.json", newer)):
+        p = tmp_path / name
+        p.write_text(json.dumps(rec))
+        paths.append(str(p))
+    return paths
 
 
 class TestDirection:
@@ -86,40 +104,40 @@ class TestDiffRecords:
         assert [r["metric"] for r in d["regressions"]] == ["p99_ms"]
 
 
-@needs_records
-class TestRealRecords:
-    def test_r04_to_r05_runs_clean(self, capsys):
-        """r04 predates the cluster-rebuild drill, so the r05 cliff
-        surfaces as ADDED metrics, not a regression — the differ must
-        not crash on records with disjoint drill sets."""
-        rc = bench_diff.main([R04, R05])
+class TestWholeRecords:
+    def test_disjoint_drill_sets_run_clean(self, records, capsys):
+        """The older record predates the cluster-rebuild drill, so the
+        newer one's cliff surfaces as ADDED metrics, not a regression —
+        the differ must not crash on records with disjoint drill sets."""
+        rc = bench_diff.main(records)
         assert rc == 0
         out = capsys.readouterr().out
         assert "cluster_rebuild" in out  # listed under added
 
-    def test_rebuild_cliff_flagged(self, tmp_path, capsys):
-        """Graft the healthy 72 MB/s rebuild figure onto r04 — the 2
-        MB/s figure r05 actually recorded must then be flagged."""
-        with open(R04) as f:
+    @staticmethod
+    def _with_healthy_rebuild(older_path, tmp_path):
+        """Graft a healthy 72 MB/s rebuild figure onto the older
+        record — the newer one's 2 MB/s must then be flagged."""
+        with open(older_path) as f:
             old = json.load(f)
         old["parsed"]["cluster_rebuild"] = {
             "rebuild_mbps_volume_bytes": 72}
-        p = tmp_path / "r04_healthy.json"
+        p = tmp_path / "older_healthy.json"
         p.write_text(json.dumps(old))
-        rc = bench_diff.main([str(p), R05])
+        return str(p)
+
+    def test_rebuild_cliff_flagged(self, records, tmp_path, capsys):
+        healthy = self._with_healthy_rebuild(records[0], tmp_path)
+        rc = bench_diff.main([healthy, records[1]])
         assert rc == 1
         out = capsys.readouterr().out
         assert "cluster_rebuild.rebuild_mbps_volume_bytes" in out
         assert "-97" in out  # 72 -> 2 is a -97.2% cliff
 
-    def test_json_output_machine_readable(self, tmp_path, capsys):
-        with open(R04) as f:
-            old = json.load(f)
-        old["parsed"]["cluster_rebuild"] = {
-            "rebuild_mbps_volume_bytes": 72}
-        p = tmp_path / "r04_healthy.json"
-        p.write_text(json.dumps(old))
-        rc = bench_diff.main([str(p), R05, "--json"])
+    def test_json_output_machine_readable(self, records, tmp_path,
+                                          capsys):
+        healthy = self._with_healthy_rebuild(records[0], tmp_path)
+        rc = bench_diff.main([healthy, records[1], "--json"])
         assert rc == 1
         d = json.loads(capsys.readouterr().out)
         cliff = next(
@@ -130,9 +148,11 @@ class TestRealRecords:
         assert cliff["delta_frac"] == pytest.approx(-70 / 72,
                                                     abs=1e-4)
 
-    def test_threshold_knob(self, capsys):
-        """At an absurd threshold nothing in r04->r05 regresses."""
-        rc = bench_diff.main([R04, R05, "--threshold", "10.0"])
+    def test_threshold_knob(self, records, tmp_path, capsys):
+        """At an absurd threshold not even the cliff regresses."""
+        healthy = self._with_healthy_rebuild(records[0], tmp_path)
+        rc = bench_diff.main([healthy, records[1],
+                              "--threshold", "10.0"])
         assert rc == 0
         capsys.readouterr()
 

@@ -26,7 +26,7 @@ from seaweedfs_tpu.server.master import MasterServer
 from seaweedfs_tpu.server.volume_server import VolumeServer
 
 # the two longer drills stay opt-in; the node-death drill runs by
-# default on a compressed schedule (VERDICT r4 #9: keep at least one
+# default on a compressed schedule (keep at least one
 # live-cluster failure drill in every `pytest tests` run)
 _FULL = bool(os.environ.get("SW_CHAOS_TESTS"))
 gated = pytest.mark.skipif(

@@ -99,6 +99,27 @@ def test_pipelined_matmul_varied_widths():
         assert np.array_equal(out, oracle._matmul(coeffs, orig))
 
 
+def test_pipelined_matmul_uncapped_widths_share_buckets():
+    """max_width=None (degraded-read batches, every width its own):
+    each width pads to its power-of-two bucket, so ragged widths in one
+    bucket share one compiled program instead of one each — a cap taken
+    from the batch's own width made every batch an exact-width compile."""
+    from seaweedfs_tpu.ops.rs_tpu import width_bucket
+    assert width_bucket(600, None) == width_bucket(700, None) == 1024
+    assert width_bucket(1, None) == 512
+    assert width_bucket(700, 700) == 700      # a caller's own slab width
+    rng = np.random.default_rng(12)
+    coeffs = rng.integers(0, 256, (1, 10), dtype=np.uint8)
+    oracle = NumpyCodec(10, 4)
+    codec = get_codec(10, 4, "tpu")
+    for w in (600, 700, 1025):
+        data = rng.integers(0, 256, (10, w), dtype=np.uint8)
+        pm = PipelinedMatmul(coeffs, max_width=None, codec=codec)
+        assert pm._bucket(w) == width_bucket(w, None)
+        (_, _, out), = pm.stream([(None, data)])
+        assert np.array_equal(out, oracle._matmul(coeffs, data))
+
+
 def test_pipelined_matmul_reader_error_propagates():
     coeffs = np.eye(4, 10, dtype=np.uint8)
 

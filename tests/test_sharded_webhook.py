@@ -1,5 +1,4 @@
-"""Sharded filer store persistence + webhook notification publisher
-(VERDICT r2 missing #5/#6)."""
+"""Sharded filer store persistence + webhook notification publisher."""
 
 import json
 import threading

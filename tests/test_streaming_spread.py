@@ -516,30 +516,3 @@ def test_small_dispatch_auto_apply(monkeypatch):
         assert codec_mod.small_dispatch_default() == applied
     finally:
         codec_mod.set_small_dispatch_override(None)
-
-
-# -- satellite: bench device-init retries are capped + backed off ------------
-
-def test_bench_device_init_retry_cap(monkeypatch):
-    import bench
-    monkeypatch.setenv("SW_BENCH_DEVICE_INIT_RETRIES", "3")
-    monkeypatch.setenv("SW_BENCH_INIT_RETRY_SPACING", "0.01")
-    monkeypatch.setenv("SW_BENCH_INIT_RETRY_MAX_SPACING", "0.02")
-    monkeypatch.setattr(bench, "init_device", lambda timeout_s: None)
-    retry_log = []
-    assert bench.init_device_retrying(retry_log) is None
-    attempts = [e for e in retry_log if "attempt" in e]
-    assert len(attempts) == 3           # capped, not the old fixed six
-    assert all(not e["ok"] for e in attempts)
-    # exponential backoff, clamped at the max, and NOT slept after the
-    # final attempt
-    assert [e.get("backoff_s") for e in attempts] == [0.01, 0.02, None]
-    # the CPU-fallback verdict is in the artifact immediately
-    assert retry_log[-1]["fallback"] == "cpu"
-    assert retry_log[-1]["after_attempts"] == 3
-
-    monkeypatch.setattr(bench, "init_device",
-                        lambda timeout_s: ["dev0"])
-    retry_log = []
-    assert bench.init_device_retrying(retry_log) == ["dev0"]
-    assert len(retry_log) == 1 and retry_log[0]["ok"]

@@ -1,0 +1,143 @@
+"""The served kernels, compiled for the chip without the chip.
+
+The TPU's compiler is installed with libtpu and compiles for a *described*
+v5e 2x2 topology (no device attached): a kernel Mosaic would refuse on the
+chip — a slice off the tiling, too much VMEM, a program that does not fit
+HBM — is refused HERE, at the real served widths, at no chip time. Nothing
+runs, so this says nothing about results or speed; tests/test_rs_pallas.py
+pins the bytes (interpret mode) and chip_smoke.py runs the real thing.
+
+Rules this file keeps (they are why it is ONE file with fixtures):
+the topology is described inside a module-scoped fixture, never at import
+and never in skipif/parametrize arguments — only one process at a time may
+load libtpu, and every xdist worker imports every test file; the compiles
+run in this process (a child could not load the library either); the
+persistent compile cache is off around them (a described-device entry can
+be written but never read back).
+"""
+
+import os
+
+import pytest
+
+COLLECTIVES = ("all-reduce", "all-gather", "all-to-all",
+               "collective-permute", "reduce-scatter", "collective-broadcast")
+MIB = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_fused(one_chip, k, r, n):
+    """Real .lower().compile() of the fused Pallas kernel, not interpret."""
+    import jax
+    import jax.numpy as jnp
+    from seaweedfs_tpu.ops.rs_pallas import _fused_fn, pick_tile
+    fn = _fused_fn(k, r, n, pick_tile(k, r, n), False)
+    compiled = fn.raw_jit.lower(
+        jax.ShapeDtypeStruct((8 * r, 8 * k), jnp.int8, sharding=one_chip),
+        jax.ShapeDtypeStruct((k, n), jnp.uint8, sharding=one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "kernel did not compile through Mosaic"
+    return compiled
+
+
+# the three geometries tests/test_rs_pallas.py used to only *lower*
+# (jax.export never ran the chip's compiler); 8 MiB is the served slab
+@pytest.mark.parametrize("k,m", [(10, 4), (6, 3), (20, 4)])
+def test_fused_encode_compiles_at_served_slab(one_chip, k, m):
+    from seaweedfs_tpu.ec.encoder import DEFAULT_SLAB
+    from seaweedfs_tpu.ops import gf256
+    from seaweedfs_tpu.ops.rs_pallas import fuse_bitmat
+    matrix = gf256.build_matrix(k, k + m, "vandermonde")
+    assert fuse_bitmat(matrix[k:]).shape == (8 * m, 8 * k)
+    assert DEFAULT_SLAB == 8 * MIB
+    mem = _compile_fused(one_chip, k, m, DEFAULT_SLAB).memory_analysis()
+    # payload in, parity out, nothing 8x in HBM: that is the fusion
+    assert mem.argument_size_in_bytes >= k * DEFAULT_SLAB
+    assert mem.temp_size_in_bytes < DEFAULT_SLAB
+
+
+@pytest.mark.parametrize("n", [32 * MIB, 128 << 10],
+                         ids=["chunk-32MiB", "bucket-128KiB"])
+def test_fused_encode_compiles_at_other_served_widths(one_chip, n):
+    """TpuCodec._matmul's 32 MiB chunk and a small power-of-two bucket."""
+    _compile_fused(one_chip, 10, 4, n)
+
+
+@pytest.mark.parametrize("k,r", [(10, 4), (14, 4)],
+                         ids=["decode-4-lost", "syndrome-H-4x14"])
+def test_fused_decode_and_syndrome_compile(one_chip, k, r):
+    """Decode rows of a 4-shard loss (4 x 10) and the scrub's syndrome
+    matrix H (4 x 14), at the 1 MiB scrub slab."""
+    _compile_fused(one_chip, k, r, MIB)
+
+
+def test_fused_piggyback_encode_matrix_compiles(one_chip):
+    """The piggyback layout's sub-chunk encode matrix is (m*alpha,
+    k*alpha) = (128, 320): the widest contraction the kernel serves, at
+    the tile pick_tile gives it."""
+    from seaweedfs_tpu.ops import codec as ops_codec
+    from seaweedfs_tpu.ops.rs_pallas import pick_tile
+    pplan = ops_codec.piggyback_plan(10, 4)
+    r, k = pplan.emat.shape
+    assert (r, k) == (128, 320)
+    assert pick_tile(k, r, MIB) == 896
+    _compile_fused(one_chip, k, r, MIB)
+
+
+@pytest.mark.parametrize("rows_in,rows_out,n", [
+    (10, 4, 8 * MIB), (10, 4, 32 * MIB), (320, 128, MIB)],
+    ids=["slab-8MiB", "chunk-32MiB", "piggyback-1MiB"])
+def test_mesh_program_compiles_on_four_chips(topo, rows_in, rows_out, n):
+    """MeshCodec._fn's TPU branch over a 4-device ('data',) mesh: the
+    payload splits four ways and the partitioner adds no collective."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from seaweedfs_tpu.parallel.mesh import make_codec_mesh
+    from seaweedfs_tpu.parallel.mesh_codec import MeshCodec
+    mesh = make_codec_mesh(devices=topo.devices, width_devices=4)
+    assert mesh.shape["data"] == 4
+    codec = MeshCodec(10, 4, mesh=mesh)
+    assert codec._on_tpu_mesh()
+    compiled = codec._fn(rows_in, rows_out, n).raw_jit.lower(
+        jax.ShapeDtypeStruct((rows_in * 8, rows_out * 8), jnp.int8,
+                             sharding=NamedSharding(mesh, P(None, None))),
+        jax.ShapeDtypeStruct((rows_in, n), jnp.uint8,
+                             sharding=NamedSharding(mesh, P(None, "data")))
+    ).compile()
+    text = compiled.as_text()
+    assert not [c for c in COLLECTIVES if c in text]
+    # each device is handed a quarter of the payload columns ...
+    _, data_sharding = compiled.input_shardings[0]
+    assert data_sharding.shard_shape((rows_in, n)) == (rows_in, n // 4)
+    # ... and holds that quarter, not a replica: argument bytes per
+    # device are the quarter plus the bit-matrix, up to the HBM tiling's
+    # row padding (a 10-row uint8 array is laid out as 16 rows)
+    quarter = rows_in * n // 4
+    per_device = compiled.memory_analysis().argument_size_in_bytes \
+        - rows_in * 8 * rows_out * 8
+    assert quarter <= per_device <= 1.7 * quarter

@@ -67,8 +67,8 @@ JAX_ALLOWED_DIRS = ("seaweedfs_tpu/ops/", "seaweedfs_tpu/parallel/")
 # WHY the exception exists without chasing the file.
 JAX_IMPORT_ALLOWED = {
     "seaweedfs_tpu/util/jax_platform.py":
-        "platform-selection shim: must touch jax.config before ops/ "
-        "imports bake the backend in",
+        "start-up rules for the device modules: compile-cache config "
+        "and the tpu/mesh platform check, imported lazily",
     "seaweedfs_tpu/util/profiling.py":
         "jax.profiler trace capture is this module's entire purpose; "
         "import is lazy inside the capture call",

@@ -1,15 +1,15 @@
 #!/usr/bin/env python
-"""Diff two BENCH_r*.json records and flag per-metric regressions.
+"""Diff two bench.py records and flag per-metric regressions.
 
-The bench driver appends one BENCH_r<NN>.json per round; until now
-comparing rounds meant eyeballing nested dicts, which is how the r05
-mesh-rebuild cliff (rebuild_mbps_volume_bytes 72 -> 2) sat unnoticed
-inside an otherwise-green record. This tool flattens both records to
+bench.py writes one BENCH_r<NN>.json per full run; comparing runs used
+to mean eyeballing nested dicts, which is how a mesh-rebuild cliff
+(rebuild_mbps_volume_bytes 72 -> 2) once sat unnoticed inside an
+otherwise-green record. This tool flattens both records to
 dotted numeric metrics, classifies each metric's good direction from
 its name, and flags any move beyond --threshold (default 20%) in the
 bad direction:
 
-    python tools/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_diff.py older.json newer.json
     python tools/bench_diff.py old.json new.json --json   # CI mode
 
 Exit status: 0 clean, 1 when regressions were flagged, 2 on usage /
@@ -155,7 +155,7 @@ def render_text(report: Dict, old_path: str, new_path: str) -> str:
 
 def main(argv: List[str] = None) -> int:
     parser = argparse.ArgumentParser(
-        description="Diff two BENCH_r*.json records; exit 1 on any "
+        description="Diff two bench.py records; exit 1 on any "
                     "per-metric regression beyond the threshold.")
     parser.add_argument("old", help="baseline BENCH record")
     parser.add_argument("new", help="candidate BENCH record")

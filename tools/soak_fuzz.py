@@ -1,6 +1,6 @@
 """Extended fuzz soak: drive the committed model-fuzz suites with
-fresh seed ranges beyond the fixed CI lists. Evidence run for
-PARITY.md; not part of the committed suite.
+fresh seed ranges beyond the fixed CI lists. An evidence run; not
+part of the committed suite.
 """
 import os
 import sys
