@@ -599,7 +599,7 @@ def measure_cluster_rebuild(size_mb: int = 256, n_servers: int = 4,
                          for d, b in sorted(mesh_bytes.items())}
         else:
             width_devices = timings.get("dispatch_width_devices", 0)
-            busy_frac = timings.get("device_busy_frac", {})
+            busy_frac = timings.get("device_byte_share", {})
 
         # -- single-shard repair drill: the overwhelmingly common
         # failure at fleet scale. Destroy exactly ONE shard and rebuild
@@ -746,7 +746,7 @@ def measure_cluster_rebuild(size_mb: int = 256, n_servers: int = 4,
                "bitmat_uploads": timings.get("bitmat_uploads", 0),
                "mesh_dispatches": timings.get("mesh_dispatches", 0),
                "dispatch_width_devices": width_devices,
-               "device_busy_frac": busy_frac,
+               "device_byte_share": busy_frac,
                "rebuild_device_mbps": round(
                    survivor_bytes / stream_s / 1e6) if stream_s else 0,
                # streaming-gather overlap accounting: gather_s/compute_s

@@ -584,7 +584,7 @@ def mesh_landing(run: dict, emit) -> list:
               "single_device_dispatches_by_design":
                   tel["dispatches"] - tel["mesh_dispatches"],
               "device_bytes": per_dev,
-              "device_busy_frac": tel["device_busy_frac"]})
+              "device_byte_share": tel["device_byte_share"]})
         if tel["dispatch_width_devices"] != 4:
             bad.append(f"mesh {op}: payload landed on "
                        f"{tel['dispatch_width_devices']} devices, not 4")

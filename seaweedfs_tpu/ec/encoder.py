@@ -145,7 +145,8 @@ def piggyback_geometry(codec: ReedSolomonCodec, layout,
 
 
 def _coalesce_slabs(slabs: Iterator[Tuple[None, np.ndarray]],
-                    target_width: int) -> Iterator[Tuple[None, np.ndarray]]:
+                    target_width: int, timer: StageTimer
+                    ) -> Iterator[Tuple[None, np.ndarray]]:
     """Hstack consecutive row-slabs up to target_width per device call.
 
     GF coding is columnwise-independent, so concat-then-encode equals
@@ -153,20 +154,40 @@ def _coalesce_slabs(slabs: Iterator[Tuple[None, np.ndarray]],
     shard file, so the batched rows are exactly the shard byte ranges —
     the 'streaming stripe batches' of BASELINE config 3. Without this, a
     volume of 1MB small rows would reach the device 10MB per call.
+
+    Producing one device call's batch — the row-slab reads it pulls
+    from ``slabs`` and the concatenate — is one stage (span
+    ``ec.encode.read`` under the timer's root) on the thread that
+    iterates this (the pipeline's producer), counted in ops/telemetry
+    beside it.
     """
-    batch: List[np.ndarray] = []
-    total = 0
-    for _, data in slabs:
-        w = data.shape[1]
-        if batch and total + w > target_width:
-            yield None, (batch[0] if len(batch) == 1
-                         else np.concatenate(batch, axis=1))
-            batch, total = [], 0
-        batch.append(data)
-        total += w
-    if batch:
-        yield None, (batch[0] if len(batch) == 1
-                     else np.concatenate(batch, axis=1))
+    from ..ops.telemetry import STATS
+    it = iter(slabs)
+    held: Optional[np.ndarray] = None   # read, but past this batch's width
+    more = True
+    while more:
+        with tracing.Stage("ec.encode.read", timer.root) as st:
+            batch = [] if held is None else [held]
+            total = sum(b.shape[1] for b in batch)
+            held = None
+            for _, data in it:
+                w = data.shape[1]
+                if batch and total + w > target_width:
+                    held = data
+                    break
+                batch.append(data)
+                total += w
+            else:
+                more = False
+            if not batch:
+                return
+            out = batch[0] if len(batch) == 1 \
+                else np.concatenate(batch, axis=1)
+            st.nbytes = out.nbytes
+        STATS.add_read(out.nbytes, st.t1 - st.t0, st.cpu_s)
+        yield None, out
+        # handed on: the next batch is read without this one kept alive
+        out = batch = None
 
 
 def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
@@ -211,7 +232,8 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
     dat_size = os.path.getsize(dat_path)
     # always collect stages: the per-phase spans below need them even
     # when no caller asked for a bench breakdown
-    timer = timer if timer is not None else StageTimer()
+    timer = timer if timer is not None else \
+        StageTimer(root=tracing.current_span())
     slabs = _dat_slabs(dat_path, dat_size, k, large_block, small_block, slab,
                        timer)
     outs = [] if sink is not None else \
@@ -227,7 +249,8 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
     try:
         if piggyback:
             batches = _window_batches(
-                _coalesce_slabs(slabs, max(slab - slab % window, window)),
+                _coalesce_slabs(slabs, max(slab - slab % window, window),
+                                timer),
                 window)
             alpha = pplan.alpha
 
@@ -255,29 +278,26 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
             from ..ops.pipeline import PipelinedMatmul
             pm = PipelinedMatmul(codec.matrix[k:], max_width=slab,
                                  timer=timer, codec=codec, pieces=pieces)
-            stream = pm.stream(_coalesce_slabs(slabs, slab))
+            stream = pm.stream(_coalesce_slabs(slabs, slab, timer))
         else:
             stream = ((meta, data, codec.encode(data))
                       for meta, data in slabs)
         for _, data, parity in stream:
-            t0 = time.perf_counter()
-            if pieces:
-                nbytes = 0
-                for lo, piece in parity:
-                    pw = piece.shape[1]
-                    sink.write_stripe(data[:, lo:lo + pw], piece)
-                    nbytes += k * pw + piece.nbytes
-            elif sink is not None:
-                sink.write_stripe(data, parity)
-                nbytes = data.nbytes + parity.nbytes
-            else:
-                for i in range(k):
-                    outs[i].write(data[i].tobytes())
-                for j in range(m):
-                    outs[k + j].write(parity[j].tobytes())
-                nbytes = data.nbytes + parity.nbytes
-            end = time.perf_counter()
-            timer.add("shard_write", end - t0, nbytes, interval=(t0, end))
+            with timer.stage("shard_write", span="ec.encode.write") as st:
+                if pieces:
+                    for lo, piece in parity:
+                        pw = piece.shape[1]
+                        sink.write_stripe(data[:, lo:lo + pw], piece)
+                        st.nbytes += k * pw + piece.nbytes
+                elif sink is not None:
+                    sink.write_stripe(data, parity)
+                    st.nbytes = data.nbytes + parity.nbytes
+                else:
+                    for i in range(k):
+                        outs[i].write(data[i].tobytes())
+                    for j in range(m):
+                        outs[k + j].write(parity[j].tobytes())
+                    st.nbytes = data.nbytes + parity.nbytes
     finally:
         for o in outs:
             o.close()
@@ -311,7 +331,9 @@ def write_ec_files_spread(base_name: str, sink,
         pipelined = codec.backend in ("tpu", "mesh")
     from ..ops import telemetry
     before = telemetry.STATS.snapshot()
-    timer = StageTimer()
+    # the stream's root span (ec.encode.stream, current here): the
+    # reader, drain and write stages hang under it as real spans
+    timer = StageTimer(root=tracing.current_span())
     t_stream = time.perf_counter()
     try:
         write_ec_files(base_name, codec=codec, large_block=large_block,
@@ -447,17 +469,19 @@ def rebuild_ec_files(base_name: str,
             t0 = time.perf_counter()
             coeffs = _rebuild_coeffs(codec, present, missing)
             phases["plan"] = time.perf_counter() - t0
-            ptimer = StageTimer()
+            ptimer = StageTimer(root=tracing.current_span())
             # pieces: device-shard outputs drain and append to the
             # missing-shard files per device, no full-slab host staging
             pm = PipelinedMatmul(coeffs, max_width=slab, codec=codec,
                                  timer=ptimer, pieces=True)
             for _, _, parts in pm.stream(survivor_slabs()):
-                t0 = time.perf_counter()
-                for _, piece in parts:
-                    for r, i in enumerate(missing):
-                        outs[i].write(piece[r].tobytes())
-                phases["write"] += time.perf_counter() - t0
+                with ptimer.stage("shard_write",
+                                  span="ec.rebuild.write") as st:
+                    for _, piece in parts:
+                        for r, i in enumerate(missing):
+                            outs[i].write(piece[r].tobytes())
+                            st.nbytes += piece[r].nbytes
+            phases["write"] = ptimer.totals.get("shard_write", 0.0)
             # consumer-side accounting: the stream loop's time splits
             # into waiting for survivor reads (gather), h2d puts
             # (dispatch), waiting for device results (drain), and the
@@ -724,18 +748,21 @@ def rebuild_ec_files_streaming(base_name: str,
     try:
         if pipelined:
             from ..ops.pipeline import PipelinedMatmul
-            ptimer = StageTimer()
+            # the stream's root span (ec.rebuild.stream, current here)
+            ptimer = StageTimer(root=tracing.current_span())
             # pieces, same as rebuild_ec_files: the sharded decode's
             # per-device outputs append as they land
             pm = PipelinedMatmul(coeffs, max_width=slab, codec=codec,
                                  timer=ptimer, pieces=True)
             for _, _, parts in pm.stream(source.slabs()):
-                t0 = time.perf_counter()
-                for _, piece in parts:
-                    for r, i in enumerate(missing):
-                        outs[i].write(piece[r].tobytes())
-                        rebuilt_bytes += piece[r].nbytes
-                phases["write"] += time.perf_counter() - t0
+                with ptimer.stage("shard_write",
+                                  span="ec.rebuild.write") as st:
+                    for _, piece in parts:
+                        for r, i in enumerate(missing):
+                            outs[i].write(piece[r].tobytes())
+                            st.nbytes += piece[r].nbytes
+            phases["write"] = ptimer.totals.get("shard_write", 0.0)
+            rebuilt_bytes = ptimer.bytes.get("shard_write", 0)
             # consumer-side accounting, same discipline as
             # rebuild_ec_files: read_wait is the time this thread spent
             # blocked on stripes still in flight — the UNOVERLAPPED

@@ -23,14 +23,14 @@ from __future__ import annotations
 
 import os
 import re
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..util import tracing
 from ..util.locks import make_lock
 from .transport import (  # noqa: F401  - the shared transport, pull role
-    DEFAULT_WINDOW, HEDGE_MS_ENV, GatherStats, LocalShardReader,
+    DEFAULT_WINDOW, FETCH_SPAN, HEDGE_MS_ENV, GatherStats, LocalShardReader,
     RemoteShardReader, StripedPull, TransportStats, default_hedge_ms,
     hedge_pool, pull_window,
 )
@@ -187,6 +187,8 @@ class LocalRepairReader:
     bytes (the range itself never crossed the network)."""
 
     remote = False
+    fetch_span = FETCH_SPAN + ".local"
+    span = None          # set by StripedPull: trace parent
 
     def __init__(self, path: str, masks: Sequence[int],
                  stats: Optional[TransportStats] = None):
@@ -198,16 +200,17 @@ class LocalRepairReader:
 
     def read(self, off: int, n: int, stripe_idx: int = 0) -> bytes:
         from ..ops.codec import project_slab
-        t0 = time.perf_counter()
-        with open(self.path, "rb") as f:
-            f.seek(off)
-            data = f.read(n)
-        if len(data) != n:
-            raise IOError(f"short read of {self.path} at {off}: "
-                          f"{len(data)} < {n}")
-        planes = project_slab(np.frombuffer(data, dtype=np.uint8),
-                              self.masks)
-        self.stats.add_fetch(planes.nbytes, t0, time.perf_counter())
+        with tracing.Stage(self.fetch_span, self.span) as st:
+            with open(self.path, "rb") as f:
+                f.seek(off)
+                data = f.read(n)
+            if len(data) != n:
+                raise IOError(f"short read of {self.path} at {off}: "
+                              f"{len(data)} < {n}")
+            planes = project_slab(np.frombuffer(data, dtype=np.uint8),
+                                  self.masks)
+            st.nbytes = planes.nbytes
+        self.stats.add_fetch(planes.nbytes, st.t0, st.t1)
         return planes.tobytes()
 
 
@@ -251,6 +254,8 @@ class LocalPlaneReader:
     network)."""
 
     remote = False
+    fetch_span = FETCH_SPAN + ".local"
+    span = None          # set by StripedPull: trace parent
 
     def __init__(self, path: str, alpha: int, window: int,
                  plane_bit: int, plane_side: int,
@@ -264,17 +269,18 @@ class LocalPlaneReader:
 
     def read(self, off: int, n: int, stripe_idx: int = 0) -> bytes:
         from ..ops.codec import pb_plane_slice
-        t0 = time.perf_counter()
-        with open(self.path, "rb") as f:
-            f.seek(off)
-            data = f.read(n)
-        if len(data) != n:
-            raise IOError(f"short read of {self.path} at {off}: "
-                          f"{len(data)} < {n}")
-        plane = pb_plane_slice(np.frombuffer(data, dtype=np.uint8),
-                               self.alpha, self.window,
-                               self.plane_bit, self.plane_side)
-        self.stats.add_fetch(plane.nbytes, t0, time.perf_counter())
+        with tracing.Stage(self.fetch_span, self.span) as st:
+            with open(self.path, "rb") as f:
+                f.seek(off)
+                data = f.read(n)
+            if len(data) != n:
+                raise IOError(f"short read of {self.path} at {off}: "
+                              f"{len(data)} < {n}")
+            plane = pb_plane_slice(np.frombuffer(data, dtype=np.uint8),
+                                   self.alpha, self.window,
+                                   self.plane_bit, self.plane_side)
+            st.nbytes = plane.nbytes
+        self.stats.add_fetch(plane.nbytes, st.t0, st.t1)
         return plane.tobytes()
 
 

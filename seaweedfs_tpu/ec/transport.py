@@ -227,6 +227,11 @@ class SpreadStats(TransportStats):
 # ---------------------------------------------------------------------------
 # pull side: stripe readers
 
+# one span per survivor range read, on the thread that made it (an
+# `ec-pull` worker; a hedged duplicate on the hedge pool), from the
+# interval the reader takes for its stats: `.remote` / `.local`
+FETCH_SPAN = "ec.rebuild.fetch"
+
 
 class LocalShardReader:
     """Range reads of a shard already on this node's disk. Opens per
@@ -234,20 +239,23 @@ class LocalShardReader:
     concurrently, and a shared seek pointer would race."""
 
     remote = False
+    fetch_span = FETCH_SPAN + ".local"
+    span = None          # set by StripedPull: trace parent
 
     def __init__(self, path: str, stats: Optional[TransportStats] = None):
         self.path = path
         self.stats = stats or GatherStats()
 
     def read(self, off: int, n: int, stripe_idx: int = 0) -> bytes:
-        t0 = time.perf_counter()
-        with open(self.path, "rb") as f:
-            f.seek(off)
-            data = f.read(n)
-        if len(data) != n:
-            raise IOError(f"short read of {self.path} at {off}: "
-                          f"{len(data)} < {n}")
-        self.stats.add_fetch(n, t0, time.perf_counter())
+        with tracing.Stage(self.fetch_span, self.span) as st:
+            with open(self.path, "rb") as f:
+                f.seek(off)
+                data = f.read(n)
+            if len(data) != n:
+                raise IOError(f"short read of {self.path} at {off}: "
+                              f"{len(data)} < {n}")
+            st.nbytes = n
+        self.stats.add_fetch(n, st.t0, st.t1)
         return data
 
 
@@ -256,6 +264,7 @@ class RemoteShardReader:
     striping, failover retries and optional hedging."""
 
     remote = True
+    fetch_span = FETCH_SPAN + ".remote"
 
     def __init__(self, vid: int, sid: int, holders: Sequence[str],
                  stats: Optional[TransportStats] = None,
@@ -296,22 +305,24 @@ class RemoteShardReader:
         if self.span is not None:
             hdrs = {tracing.TRACEPARENT_HEADER: self.span.traceparent()}
         expect = self._expect_len(n)
-        t0 = time.perf_counter()
-        try:
-            data = http_call(self._method, self._url(holder, off, n),
-                             headers=hdrs, timeout=self.timeout)
-            if len(data) != expect:
-                raise HttpError(
-                    502, f"short shard read {self.vid}.{self.sid} from "
-                         f"{holder} at {off}: {len(data)} < {expect}")
-        except Exception:
-            self.stats.add_holder_error(holder)
-            _health.BOARD.record_error(holder, self._health_kind)
-            raise
-        t1 = time.perf_counter()
-        self.stats.add_fetch(len(data), t0, t1, remote=True,
+        with tracing.Stage(self.fetch_span, self.span) as st:
+            try:
+                data = http_call(self._method, self._url(holder, off, n),
+                                 headers=hdrs, timeout=self.timeout)
+                if len(data) != expect:
+                    raise HttpError(
+                        502, f"short shard read {self.vid}.{self.sid} "
+                             f"from {holder} at {off}: "
+                             f"{len(data)} < {expect}")
+            except Exception:
+                self.stats.add_holder_error(holder)
+                _health.BOARD.record_error(holder, self._health_kind)
+                raise
+            st.nbytes = len(data)
+        self.stats.add_fetch(len(data), st.t0, st.t1, remote=True,
                              holder=holder)
-        _health.BOARD.record_latency(holder, self._health_kind, t1 - t0)
+        _health.BOARD.record_latency(holder, self._health_kind,
+                                     st.t1 - st.t0)
         return data
 
     def _read_failover(self, order: Sequence[str], off: int,
@@ -401,6 +412,9 @@ class StripedPull:
 
     span_name = "gather.stripe"
     span_op = "ec.rebuild.gather"
+    # one span per stripe for stacking its fetched rows into the slab,
+    # on the thread that iterates `slabs()` (the pipeline's producer)
+    assemble_span = "ec.rebuild.assemble"
 
     def __init__(self, readers: Sequence, shard_size: int,
                  slab: int = 8 << 20, window: Optional[int] = None,
@@ -471,7 +485,11 @@ class StripedPull:
                 nxt += 1
             while pending:
                 idx, off, w, t_sub, futs = pending.popleft()
-                data = self._assemble([f.result() for f in futs], w)
+                bufs = [f.result() for f in futs]
+                with tracing.Stage(self.assemble_span,
+                                   self.parent_span) as st:
+                    data = self._assemble(bufs, w)
+                    st.nbytes = data.nbytes
                 tracing.record_span(
                     self.span_name, time.perf_counter() - t_sub,
                     parent=self.parent_span, op=self.span_op,
@@ -664,9 +682,22 @@ class TargetWorker(threading.Thread):
                         item = self.q.get_nowait()
                     except queue.Empty:
                         break
-                for sid, off, chunks in merge_runs(batch):
-                    n = self._send_run(sid, off, chunks)
-                    self.sink._note_buffered(-n)
+                if not batch:
+                    break
+                # one stage per drained batch (span ``ec.spread.send``):
+                # its merged runs go out back to back on this thread,
+                # to the holder it names. The batch must stay
+                # referenced until the next one is drained: releasing
+                # the chunks the moment the queue runs dry makes the
+                # consumer's next `tobytes` fault fresh pages (PERF.md,
+                # PR 25)
+                with tracing.Stage(self.sink.send_span,
+                                   self.sink.parent_span,
+                                   target=self.url or "local") as st:
+                    for sid, off, chunks in merge_runs(batch):
+                        n = self._send_run(sid, off, chunks)
+                        self.sink._note_buffered(-n)
+                        st.nbytes += n
         except BaseException as e:  # noqa: BLE001 - surfaced to consumer
             self.error = e
             self.sink._fail(e)
@@ -677,7 +708,6 @@ class TargetWorker(threading.Thread):
         if (self.sink.hedge_s > 0 and self.url is not None
                 and self.acked == 0 and off == 0):
             if self._send_run_hedged(writer, off, chunks, n):
-                self._trace_run(sid, off, n)
                 return n
         while True:
             last = None
@@ -687,7 +717,6 @@ class TargetWorker(threading.Thread):
                 try:
                     writer.send(self.url, off, chunks)
                     self.acked += n
-                    self._trace_run(sid, off, n)
                     return n
                 except BaseException as e:  # noqa: BLE001 - retry/failover
                     last = e
@@ -767,13 +796,6 @@ class TargetWorker(threading.Thread):
         self.sink._return_spare(spare)
         return False
 
-    def _trace_run(self, sid: int, off: int, n: int):
-        tracing.record_span(
-            self.sink.span_name, 0.0, parent=self.sink.parent_span,
-            op=self.sink.span_op, shard=sid, offset=off,
-            bytes=n, target=self.url or "local")
-
-
 def merge_runs(batch):
     """Merge a drained batch into per-shard contiguous runs, preserving
     per-shard order (queue order is stripe order, so each shard's
@@ -801,8 +823,9 @@ class StripedPush:
     blocked-time, failover spares, hedging, finalize/abort discipline,
     optional MB/s pacing — lives here."""
 
-    span_name = "spread.run"
-    span_op = "ec.encode.spread"
+    # one span per batch a target worker drains from its queue and
+    # sends as merged runs (TargetWorker.run)
+    send_span = "ec.spread.send"
 
     def __init__(self, writers: List, by_target: Dict[Optional[str],
                                                       List[int]],

@@ -23,15 +23,13 @@ compiled-executable lifecycle through this module via `wrap()`:
   list. An earlier round's collapsed mesh rebuild would have been a
   nonzero counter, not a PR-long bisect.
 
-- **Sampled device-time attribution.** With `SW_EC_DEVICE_TIMING=1`,
-  every `SW_EC_DEVICE_TIMING_SAMPLE`th dispatch per entry point runs
-  `block_until_ready` under a timer, giving an unbiased estimate of
-  device seconds per entry (multiply a sample's mean by the dispatch
-  count). Default-off mirrors the native plane's `SW_PLANE_STATS=0`
-  discipline: the hot path increments one counter under one lock and
-  performs ZERO clock reads and zero synchronizations —
-  tests/test_device_stats.py proves it by monkeypatching
-  `device_stats._perf_counter`.
+- **A dispatch reads no clock.** The hot path increments one counter
+  under one lock and performs ZERO clock reads and zero
+  synchronizations — tests/test_device_stats.py proves it by
+  monkeypatching `device_stats._perf_counter`. Device time comes from
+  a profiler trace (the kernels carry stable names, and the stream's
+  stages are mirrored into it: util/tracing.Stage), never from a
+  host-timed `block_until_ready`, which stalls what it measures.
 
 - **Cache accounting.** `_ConstCache` (device-resident bit-matrix
   constants) reports hits/misses/evictions here and registers itself
@@ -46,8 +44,8 @@ Everything lands in `snapshot()` → mirrored to `ec_xla_*` /
 master's `/cluster/metrics`), `GET /admin/devices`, shell
 `cluster.devices`, and bench.py's compile_s/steady-state split.
 
-jax is imported lazily (sampled-timing path and device inventory
-only), matching telemetry.py: this module must import on hosts with no
+jax is imported lazily (device inventory only), matching
+telemetry.py: this module must import on hosts with no
 jax at all.
 """
 
@@ -57,7 +55,6 @@ import weakref
 from time import perf_counter as _perf_counter
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from ..util import config
 from ..util.jax_platform import backend_initialized
 from ..util.locks import make_lock
 
@@ -88,8 +85,6 @@ class DeviceStats:
         self.compile_seconds: Dict[str, float] = {}
         self.recompiles: Dict[str, int] = {}
         self.dispatches: Dict[str, int] = {}
-        self.device_samples: Dict[str, int] = {}
-        self.device_seconds: Dict[str, float] = {}
         # (entry, bucket-signature) -> compile count; >1 latches.
         self._bucket_compiles: Dict[Tuple[str, Any], int] = {}
         self.sentinel = False
@@ -98,30 +93,14 @@ class DeviceStats:
         self.const_cache: Dict[str, int] = {
             "hits": 0, "misses": 0, "evictions": 0}
         self._const_caches: "weakref.WeakSet" = weakref.WeakSet()
-        self.reconfigure()
-
-    # -- configuration -------------------------------------------------
-
-    def reconfigure(self):
-        """Re-read the timing knobs (tests flip them via monkeypatch;
-        production reads them once at import)."""
-        self.timing_enabled = bool(config.env_bool("SW_EC_DEVICE_TIMING"))
-        self.sample_every = max(
-            1, int(config.env_int("SW_EC_DEVICE_TIMING_SAMPLE")))
 
     # -- hot path ------------------------------------------------------
 
-    def tick(self, entry: str) -> bool:
-        """Count one dispatch; True when this one should be timed.
-
-        This is the ONLY per-dispatch cost with timing off: one lock,
+    def tick(self, entry: str):
+        """Count one dispatch: the ONLY per-dispatch cost — one lock,
         one dict increment, no clock reads."""
         with self._lock:
-            n = self.dispatches.get(entry, 0) + 1
-            self.dispatches[entry] = n
-        if not self.timing_enabled:
-            return False
-        return n % self.sample_every == 0
+            self.dispatches[entry] = self.dispatches.get(entry, 0) + 1
 
     # -- slow-path events ----------------------------------------------
 
@@ -138,13 +117,6 @@ class DeviceStats:
                 self.sentinel = True
                 if len(self.offenders) < MAX_OFFENDERS:
                     self.offenders.append(f"{entry}:{bucket_key!r}")
-
-    def note_device_time(self, entry: str, seconds: float):
-        with self._lock:
-            self.device_samples[entry] = \
-                self.device_samples.get(entry, 0) + 1
-            self.device_seconds[entry] = \
-                self.device_seconds.get(entry, 0.0) + seconds
 
     def note_const_cache(self, event: str, n: int = 1):
         with self._lock:
@@ -171,13 +143,9 @@ class DeviceStats:
                 "compile_seconds": dict(self.compile_seconds),
                 "recompiles": dict(self.recompiles),
                 "dispatches": dict(self.dispatches),
-                "device_samples": dict(self.device_samples),
-                "device_seconds": dict(self.device_seconds),
                 "sentinel": self.sentinel,
                 "offenders": list(self.offenders),
                 "const_cache": dict(self.const_cache),
-                "timing_enabled": self.timing_enabled,
-                "sample_every": self.sample_every,
             }
         snap["const_cache_occupancy"] = self.const_cache_occupancy()
         return snap
@@ -191,7 +159,7 @@ def delta(before: dict) -> dict:
     now = DEVICE_STATS.snapshot()
     out = {}
     for field in ("compiles", "compile_seconds", "recompiles",
-                  "dispatches", "device_samples", "device_seconds"):
+                  "dispatches"):
         prev = before.get(field, {})
         moved = {k: v - prev.get(k, 0) for k, v in now[field].items()
                  if v - prev.get(k, 0)}
@@ -271,14 +239,7 @@ class InstrumentedJit:
         exe = self._compiled.get(sig)
         if exe is None:
             exe = self._compile(sig, args)
-        if self._stats.tick(self.entry):
-            import jax
-            t0 = _perf_counter()
-            out = exe(*args)
-            jax.block_until_ready(out)
-            self._stats.note_device_time(self.entry,
-                                         _perf_counter() - t0)
-            return out
+        self._stats.tick(self.entry)
         return exe(*args)
 
 

@@ -39,7 +39,8 @@ import numpy as np
 
 from .rs_tpu import fn_and_bitmat, width_bucket
 from .telemetry import STATS
-from ..util.profiling import StageTimer
+from ..util import tracing
+from ..util.profiling import StageTimer, mirror_stages_to_profiler
 
 _SENTINEL = object()
 
@@ -103,6 +104,7 @@ class PipelinedMatmul:
     def stream(self, slabs: Iterable[Tuple[object, np.ndarray]]
                ) -> Iterator[Tuple[object, np.ndarray, np.ndarray]]:
         import jax.numpy as jnp
+        mirror_stages_to_profiler()
 
         q: "queue.Queue" = queue.Queue(maxsize=self.prefetch)
         err: list = []
@@ -127,25 +129,27 @@ class PipelinedMatmul:
         # output is dispatched instead of serializing behind the next
         # dispatch (host↔device links degrade badly when a single thread
         # interleaves uploads and downloads)
-        drain_pool = ThreadPoolExecutor(max_workers=self.drain_threads)
+        drain_pool = ThreadPoolExecutor(max_workers=self.drain_threads,
+                                        thread_name_prefix="pipeline-drain")
         pending: deque = deque()
         timer = self.timer
         drain_pieces = getattr(self.codec, "drain_pieces", None) \
             if self.pieces else None
 
-        def fetch(out, nbytes, w):
-            t = time.perf_counter() if timer is not None else 0.0
+        def d2h(out, w):
             if drain_pieces is not None:
-                host = drain_pieces(out, w)
-            elif self.pieces:
+                return drain_pieces(out, w)
+            if self.pieces:
                 full = np.asarray(out)
-                host = [(0, full[:, :w] if full.shape[1] > w else full)]
-            else:
-                host = np.asarray(out)
-            if timer is not None:
-                end = time.perf_counter()
-                timer.add("d2h+mxu", end - t, nbytes, interval=(t, end))
-            return host
+                return [(0, full[:, :w] if full.shape[1] > w else full)]
+            return np.asarray(out)
+
+        def fetch(out, nbytes, w):
+            if timer is None:
+                return d2h(out, w)
+            # kernel wait + transfer + host re-layout, on a drain thread
+            with timer.stage("d2h+mxu", nbytes, span="ec.d2h"):
+                return d2h(out, w)
 
         try:
             while True:
@@ -161,19 +165,24 @@ class PipelinedMatmul:
                     raise ValueError(
                         f"slab width {w} exceeds max_width {self.max_width}")
                 bucket = self._bucket(w)
-                if w < bucket:
-                    padded = np.zeros((self.k, bucket), dtype=np.uint8)
-                    padded[:, :w] = data
-                else:
-                    padded = data
-                fn = self._fn(bucket)                # also uploads bitmat
-                put = self._put or jnp.asarray
-                t0 = time.perf_counter()
-                dev = put(padded)                    # h2d (blocking copy)
-                if timer is not None:
-                    end = time.perf_counter()
-                    timer.add("h2d", end - t0, padded.nbytes,
-                              interval=(t0, end))
+                # the span is pad + put; the `h2d` total, which the
+                # `dispatch` phase is made of, stays the put alone
+                with tracing.Stage(
+                        "ec.h2d", timer and timer.root) as up:
+                    if w < bucket:
+                        padded = np.zeros((self.k, bucket), dtype=np.uint8)
+                        padded[:, :w] = data
+                    else:
+                        padded = data
+                    fn = self._fn(bucket)            # also uploads bitmat
+                    put = self._put or jnp.asarray
+                    t0 = time.perf_counter()
+                    dev = put(padded)                # h2d (blocking copy)
+                    if timer is not None:
+                        end = time.perf_counter()
+                        timer.add("h2d", end - t0, padded.nbytes,
+                                  interval=(t0, end))
+                    up.nbytes = padded.nbytes
                 STATS.add("dispatches")
                 STATS.add("device_bytes", data.nbytes)
                 out = fn(self._bitmat_dev, dev)      # async dispatch

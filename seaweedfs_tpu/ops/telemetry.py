@@ -16,8 +16,15 @@ That is the width guard: a MeshCodec built over a width-1 mesh (or a
 crossover silently routing everything to the single-device path)
 compiles, runs, and is bit-identical — only the per-device byte map
 distinguishes it from a dispatch that saturated the mesh, so
-`delta()` derives `dispatch_width_devices` / `device_busy_frac` from
+`delta()` derives `dispatch_width_devices` / `device_byte_share` from
 it and the bench asserts on them.
+
+The `.dat` reader counts here too, beside its span (`ec.encode.read`,
+util/tracing.Stage): the bytes it handed to the pipeline and its busy
+and CPU microseconds, whose ratio says whether the thread copies or
+waits. Transfer and survivor-fetch bytes are not doubled here: the
+stream's StageTimer (`h2d`, `d2h+mxu`) and the transport's
+TransportStats already hold them.
 """
 
 from __future__ import annotations
@@ -31,7 +38,8 @@ class DispatchStats:
     """Monotonic process-global counters (thread-safe)."""
 
     _FIELDS = ("dispatches", "bitmat_uploads", "host_fallbacks",
-               "device_bytes", "mesh_dispatches")
+               "device_bytes", "mesh_dispatches",
+               "read_bytes", "read_busy_us", "read_cpu_us")
 
     def __init__(self):
         self._lock = make_lock("telemetry._lock")
@@ -42,6 +50,13 @@ class DispatchStats:
     def add(self, field: str, n: int = 1):
         with self._lock:
             setattr(self, field, getattr(self, field) + n)
+
+    def add_read(self, nbytes: int, busy_s: float, cpu_s: float):
+        """One dispatch's slab left the `.dat` reader thread."""
+        with self._lock:
+            self.read_bytes += nbytes
+            self.read_busy_us += int(busy_s * 1e6)
+            self.read_cpu_us += int(cpu_s * 1e6)
 
     def add_mesh_device_bytes(self, device: str, n: int):
         """Payload bytes a sharded put landed on one device."""
@@ -65,9 +80,10 @@ def delta(before: dict) -> dict:
     Besides the raw field deltas, derives the mesh width facts the
     bench guards on: `dispatch_width_devices` (devices a sharded put
     landed bytes on during the window; 1 when only single-device
-    dispatches ran, 0 when none did) and `device_busy_frac` (each
-    device's byte share relative to the busiest — 1.0 everywhere means
-    a perfectly even shard split)."""
+    dispatches ran, 0 when none did) and `device_byte_share` (each
+    device's payload bytes relative to the busiest — 1.0 everywhere
+    means a perfectly even shard split; it says nothing of the time a
+    device was busy, which only a profiler trace shows)."""
     now = STATS.snapshot()
     out = {f: now[f] - before.get(f, 0) for f in DispatchStats._FIELDS}
     before_dev = before.get("mesh_device_bytes", {})
@@ -80,9 +96,9 @@ def delta(before: dict) -> dict:
     if per_dev:
         peak = max(per_dev.values())
         out["dispatch_width_devices"] = len(per_dev)
-        out["device_busy_frac"] = {d: round(n / peak, 4)
-                                   for d, n in sorted(per_dev.items())}
+        out["device_byte_share"] = {d: round(n / peak, 4)
+                                    for d, n in sorted(per_dev.items())}
     else:
         out["dispatch_width_devices"] = 1 if out["dispatches"] > 0 else 0
-        out["device_busy_frac"] = {}
+        out["device_byte_share"] = {}
     return out

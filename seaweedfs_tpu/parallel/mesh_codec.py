@@ -63,6 +63,8 @@ from .mesh import make_codec_mesh
 #: drills, chip_smoke.py — each own a codec, and a per-codec cache made
 #: each of them compile the same program again (a latched recompile).
 _FNS: Dict[Tuple, object] = {}
+# the sharded program's name in a profiler trace (module and op scope)
+PROGRAM_NAME = "sw_rs_mesh"
 
 
 class MeshCodec(ReedSolomonCodec):
@@ -154,10 +156,14 @@ class MeshCodec(ReedSolomonCodec):
                     outs.append(byte.astype(jnp.uint8))
                 return jnp.stack(outs)
 
+        def sw_rs_mesh(const, data):    # the module is jit_sw_rs_mesh
+            with jax.named_scope(PROGRAM_NAME):
+                return program(const, data)
+
         mesh = self.mesh
         fn = device_stats.wrap(
             jax.jit(
-                program,
+                sw_rs_mesh,
                 in_shardings=(NamedSharding(mesh, P(None, None)),
                               NamedSharding(mesh, P(None, "data"))),
                 out_shardings=NamedSharding(mesh, P(None, "data"))),

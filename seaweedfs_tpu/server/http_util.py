@@ -191,9 +191,11 @@ def traces_handler(req: Request) -> dict:
     ``/admin/traces?trace=<id>`` for one trace's spans."""
     tid = req.query.get("trace")
     if tid:
-        return {"trace_id": tid, "spans": tracing.RING.get(tid)}
+        return {"trace_id": tid, "spans": tracing.RING.get(tid),
+                "dropped_spans": tracing.RING.dropped_of(tid)}
     n = int(req.query.get("n", "20"))
-    return {"traces": tracing.RING.recent(n)}
+    return {"traces": tracing.RING.recent(n),
+            "dropped_spans": tracing.RING.dropped}
 
 
 def traces_export_handler(req: Request) -> dict:

@@ -667,8 +667,8 @@ class VolumeServer:
         from .http_util import pool_stats_snapshot
         for event, total in pool_stats_snapshot().items():
             HTTP_POOL_CHURN_COUNTER.set_total(total, event)
-        # device-runtime plane: compile/recompile accounting, sampled
-        # device time, const-cache + jit-factory occupancy. The
+        # device-runtime plane: compile/recompile accounting,
+        # const-cache + jit-factory occupancy. The
         # inventory is only exported once a backend is initialized —
         # a scrape must never be the thing that boots a backend.
         from ..ops import device_stats as _ds
@@ -708,7 +708,7 @@ class VolumeServer:
     def admin_devices(self, req: Request):
         """Device-runtime snapshot (ops/device_stats): per-entry-point
         compile/recompile/dispatch counters with the latched recompile
-        sentinel, sampled device seconds, jit-factory cache_info,
+        sentinel, jit-factory cache_info,
         const-cache occupancy, and the device inventory incl.
         memory_stats(). Never boots a backend itself: a process whose
         codecs have not initialised one answers

@@ -136,11 +136,12 @@ def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
         # state (not the master's heartbeat-delayed view) so a failure
         # thaws exactly what this command froze
         froze: List[str] = []
-        for r in replicas:
-            out = env.node_post(r["url"],
-                                f"/admin/volume/readonly?volume={vid}")
-            if not (out or {}).get("was_readonly"):
-                froze.append(r["url"])
+        with tracing.Stage("ec.encode.freeze", root):
+            for r in replicas:
+                out = env.node_post(
+                    r["url"], f"/admin/volume/readonly?volume={vid}")
+                if not (out or {}).get("was_readonly"):
+                    froze.append(r["url"])
         assignment = balanced_ec_distribution(_free_nodes(env))
         by_node: Dict[str, List[int]] = {}
         for sid, url in enumerate(assignment):
@@ -176,8 +177,10 @@ def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
             root.tags.setdefault("error", type(e).__name__)
             raise
         # 5. drop the original volume everywhere
-        for r in replicas:
-            env.node_post(r["url"], f"/admin/delete_volume?volume={vid}")
+        with tracing.Stage("ec.encode.drop", root):
+            for r in replicas:
+                env.node_post(r["url"],
+                              f"/admin/delete_volume?volume={vid}")
         if timings is not None:
             timings["trace_id"] = root.trace_id
     finally:
@@ -248,16 +251,19 @@ def _encode_spread_streaming(env: CommandEnv, vid: int, collection: str,
                            f"&collection={collection}&shards={s}")
         return s
 
-    for (url, _), s in zip(
-            by_node.items(),
-            fan_out_must_succeed(mount, list(by_node.items()),
-                                 what=f"ec shard mount for volume {vid}",
-                                 dedicated=True)):
-        env.write(f"volume {vid}: shards {s} -> {url}")
-    if source not in by_node:
-        # the source kept no shards: drop its now-orphan index sidecars
-        env.node_post(source, f"/admin/ec/delete_shards?volume={vid}"
-                              f"&collection={collection}&shards=")
+    from ..util import tracing
+    with tracing.Stage("ec.encode.mount", tracing.current_span()):
+        for (url, _), s in zip(
+                by_node.items(),
+                fan_out_must_succeed(
+                    mount, list(by_node.items()),
+                    what=f"ec shard mount for volume {vid}",
+                    dedicated=True)):
+            env.write(f"volume {vid}: shards {s} -> {url}")
+        if source not in by_node:
+            # the source kept no shards: drop its now-orphan sidecars
+            env.node_post(source, f"/admin/ec/delete_shards?volume={vid}"
+                                  f"&collection={collection}&shards=")
     if timings is not None:
         timings["encode_wall_s"] = \
             timings.get("encode_wall_s", 0) + wall
@@ -459,11 +465,13 @@ def _rebuild_streaming(env: CommandEnv, vid: int, collection: str,
             stats.get("gather_remote_shards", len(sources))
         _merge_rebuild_stats(timings, out)
     if rebuilt:
+        from ..util import tracing
         t3 = _time.perf_counter()
-        env.node_post(rebuilder,
-                      f"/admin/ec/mount?volume={vid}"
-                      f"&collection={collection}"
-                      f"&shards={','.join(map(str, rebuilt))}")
+        with tracing.Stage("ec.rebuild.mount", root):
+            env.node_post(rebuilder,
+                          f"/admin/ec/mount?volume={vid}"
+                          f"&collection={collection}"
+                          f"&shards={','.join(map(str, rebuilt))}")
         if timings is not None:
             timings["mount_s"] = timings.get("mount_s", 0) + \
                 (_time.perf_counter() - t3)

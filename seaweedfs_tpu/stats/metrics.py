@@ -671,10 +671,12 @@ VOLUME_EC_MESH_DEVICE_BYTES = VOLUME_SERVER_GATHER.counter(
     "SeaweedFS_volumeServer_ec_mesh_device_bytes_total",
     "Payload bytes landed on each mesh device by sharded dispatches.",
     labels=("device",))
-VOLUME_EC_MESH_BUSY_FRAC_GAUGE = VOLUME_SERVER_GATHER.gauge(
-    "SeaweedFS_volumeServer_ec_mesh_device_busy_frac",
-    "Per-device byte share of the last mesh EC operation relative to "
-    "the busiest device (1.0 everywhere = even shard split).",
+VOLUME_EC_MESH_BYTE_SHARE_GAUGE = VOLUME_SERVER_GATHER.gauge(
+    "SeaweedFS_volumeServer_ec_mesh_device_byte_share",
+    "Per-device payload bytes of the last mesh EC operation relative "
+    "to the busiest device (1.0 everywhere = even shard split). A "
+    "share of bytes, not of time: how busy a device was only a "
+    "profiler trace shows.",
     labels=("device",))
 
 
@@ -693,8 +695,8 @@ def observe_mesh(stats: Dict):
     width = stats.get("dispatch_width_devices")
     if width:
         VOLUME_EC_MESH_WIDTH_GAUGE.set(width)
-    for dev, frac in (stats.get("device_busy_frac") or {}).items():
-        VOLUME_EC_MESH_BUSY_FRAC_GAUGE.set(frac, str(dev))
+    for dev, share in (stats.get("device_byte_share") or {}).items():
+        VOLUME_EC_MESH_BYTE_SHARE_GAUGE.set(share, str(dev))
 
 
 # -- device-runtime plane (ops/device_stats via observe_device_stats) --------
@@ -724,17 +726,6 @@ VOLUME_EC_XLA_RECOMPILE_SENTINEL = VOLUME_SERVER_GATHER.gauge(
 VOLUME_EC_XLA_DISPATCHES = VOLUME_SERVER_GATHER.counter(
     "SeaweedFS_volumeServer_ec_xla_dispatches_total",
     "Instrumented jit dispatches per entry point.",
-    labels=("entry",))
-VOLUME_EC_XLA_DEVICE_SAMPLES = VOLUME_SERVER_GATHER.counter(
-    "SeaweedFS_volumeServer_ec_xla_device_samples_total",
-    "Dispatches timed through block_until_ready under "
-    "SW_EC_DEVICE_TIMING (every SW_EC_DEVICE_TIMING_SAMPLE'th).",
-    labels=("entry",))
-VOLUME_EC_XLA_DEVICE_SECONDS = VOLUME_SERVER_GATHER.counter(
-    "SeaweedFS_volumeServer_ec_xla_device_seconds_total",
-    "Summed sampled device seconds per entry point; multiply the "
-    "per-sample mean by ec_xla_dispatches_total for the estimated "
-    "total.",
     labels=("entry",))
 VOLUME_EC_XLA_JIT_CACHE = VOLUME_SERVER_GATHER.counter(
     "SeaweedFS_volumeServer_ec_xla_jit_cache_total",
@@ -785,10 +776,6 @@ def observe_device_stats(snap: Dict, factories: Dict = None,
         1 if snap.get("sentinel") else 0)
     for entry, n in snap.get("dispatches", {}).items():
         VOLUME_EC_XLA_DISPATCHES.set_total(n, entry)
-    for entry, n in snap.get("device_samples", {}).items():
-        VOLUME_EC_XLA_DEVICE_SAMPLES.set_total(n, entry)
-    for entry, s in snap.get("device_seconds", {}).items():
-        VOLUME_EC_XLA_DEVICE_SECONDS.set_total(s, entry)
     for event, n in snap.get("const_cache", {}).items():
         VOLUME_EC_CONST_CACHE_EVENTS.set_total(n, event)
     occ = snap.get("const_cache_occupancy") or {}

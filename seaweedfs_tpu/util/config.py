@@ -176,12 +176,6 @@ _knob("SW_EC_HEALTH_REF_MS", "float", 50.0,
 _knob("SW_EC_HEALTH_ROUTING", "bool", False,
       "Consult holder health scores when routing gathers and choosing "
       "rebuild survivors.")
-_knob("SW_EC_DEVICE_TIMING", "bool", False,
-      "Sampled device-time attribution: every Nth EC dispatch is timed "
-      "through block_until_ready; off adds zero clock reads.")
-_knob("SW_EC_DEVICE_TIMING_SAMPLE", "int", 16,
-      "Sample period for SW_EC_DEVICE_TIMING: one timed dispatch per N "
-      "per entry point (1 times every dispatch).")
 _knob("SW_EC_JIT_CACHE_SIZE", "int", 64,
       "lru_cache maxsize for the jitted EC kernel factories; an evicted "
       "entry recompiles on next use (visible in ec_xla_jit_cache_total).")

@@ -26,7 +26,7 @@ import contextlib
 import cProfile
 import os
 import threading
-from . import config
+from . import config, tracing
 from .locks import make_lock
 import time
 from typing import Dict, List, Optional, Tuple
@@ -46,15 +46,12 @@ def maybe_trace(label: str = "trace", profile_dir: Optional[str] = None):
         yield
 
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region in a captured device trace (no-op outside tracing)."""
-    try:
-        import jax.profiler as jp
-        with jp.TraceAnnotation(name):
-            yield
-    except Exception:  # noqa: BLE001 - tracing must never break the op
-        yield
+def mirror_stages_to_profiler():
+    """Called where JAX is already imported (the pipelined stream): from
+    then on every stage span also opens a `jax.profiler.TraceAnnotation`
+    `sw:<name>` on its thread — a flag test while no trace is running."""
+    from jax.profiler import TraceAnnotation
+    tracing.set_stage_mirror(TraceAnnotation)
 
 
 @contextlib.contextmanager
@@ -146,9 +143,15 @@ class StageTimer:
     """Accumulates wall time per named stage plus timestamped intervals
     for stages whose concurrency matters (d2h drains overlap each other;
     the interesting figure is the union of their busy windows, which is
-    the link's effective busy time)."""
+    the link's effective busy time).
 
-    def __init__(self):
+    Handed a ``root`` span (the stream's: ``ec.encode.stream`` /
+    ``ec.rebuild.stream``), the intervals taken through ``stage`` also
+    leave as real spans under it, on the thread that did the work
+    (``tracing.Stage``). Without a root nothing of that runs."""
+
+    def __init__(self, root: Optional[tracing.Span] = None):
+        self.root = root
         self.totals: Dict[str, float] = {}
         self.bytes: Dict[str, int] = {}
         self.intervals: Dict[str, List[Tuple[float, float]]] = {}
@@ -165,13 +168,21 @@ class StageTimer:
                 self.intervals.setdefault(stage, []).append(interval)
 
     @contextlib.contextmanager
-    def stage(self, name: str, nbytes: int = 0):
-        t = time.perf_counter()
+    def stage(self, name: str, nbytes: int = 0,
+              span: Optional[str] = None):
+        """``with timer.stage("h2d", n, span="ec.h2d") as st:`` — the
+        block's interval into the totals under ``name`` and, where the
+        timer has a root, out as the span ``span``: one
+        ``tracing.Stage``, one pair of clock reads for both. ``nbytes``
+        may be set on the yielded stage before the block ends."""
+        st = tracing.Stage(span or name, self.root if span else None)
+        st.nbytes = nbytes
         try:
-            yield
+            with st:
+                yield st
         finally:
-            end = time.perf_counter()
-            self.add(name, end - t, nbytes, interval=(t, end))
+            self.add(name, st.t1 - st.t0, st.nbytes,
+                     interval=(st.t0, st.t1))
 
     def busy_time(self, stage: str) -> float:
         """Union length of the stage's intervals (overlaps collapsed)."""

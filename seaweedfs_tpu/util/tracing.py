@@ -8,9 +8,12 @@ master, rebuilder volume server, and the peer fetches it triggers.
 
 The current span rides a contextvar, which means it follows ordinary
 call chains within a thread but does NOT cross the pipeline's reader /
-drain worker threads — phase work that interleaves across threads is
-accumulated as plain seconds and materialized with ``record_span``
-instead.
+drain worker threads. Work on those threads is a ``Stage``: a real
+interval taken on the thread that did it, handed its parent span
+explicitly, and mirrored into the profiler's clock once the code that
+imports JAX has handed over its annotation (``set_stage_mirror``).
+The five consumer-side phase sums, which interleave across many
+intervals, stay plain seconds materialized with ``record_span``.
 
 Finished spans fan out three ways (see ``_export``):
 
@@ -212,14 +215,91 @@ def record_span(name: str, duration_s: float,
     return d
 
 
+# name -> context manager on the profiler's clock; None: no mirror
+_stage_mirror: Optional[Callable] = None
+
+
+def set_stage_mirror(factory: Optional[Callable]):
+    """Hand over the profiler's annotation (``jax.profiler
+    .TraceAnnotation``): every ``Stage`` under a parent then also opens
+    ``factory("sw:" + name)`` on its thread, so a captured device trace
+    holds the program's stages on the device's clock. Called by the
+    code that has already imported JAX — this module never does."""
+    global _stage_mirror
+    _stage_mirror = factory
+
+
+class Stage:
+    """One interval of work on the thread that does it: the one stage
+    primitive. ``t0``/``t1`` (perf_counter) and ``cpu_s`` (the thread's
+    CPU time over the interval: well under the duration means the
+    thread waited — for the GIL, a socket, the device) are the caller's
+    to count after the block. Under a ``parent`` (the stream's root
+    span, handed over explicitly: the contextvar does not reach worker
+    threads) the interval also leaves as a span with its true start and
+    end, tagged with the thread's name, ``bytes`` (set ``nbytes`` before
+    the block ends) and ``cpu_s``, and is mirrored into the profiler.
+    Without a parent it is the two clock pairs and nothing else."""
+
+    __slots__ = ("name", "parent", "tags", "nbytes", "t0", "t1", "cpu_s",
+                 "_mirror")
+
+    def __init__(self, name: str, parent: Optional[Span], **tags):
+        self.name = name
+        self.parent = parent
+        self.tags = tags
+        self.nbytes = 0
+        self.t0 = self.t1 = self.cpu_s = 0.0
+        self._mirror = None
+
+    def __enter__(self) -> "Stage":
+        if self.parent is not None and _stage_mirror is not None:
+            self._mirror = _stage_mirror("sw:" + self.name)
+            self._mirror.__enter__()
+        # the CPU reads nest inside the wall reads: cpu_s <= duration
+        self.t0 = time.perf_counter()
+        self.cpu_s = time.thread_time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.cpu_s = time.thread_time() - self.cpu_s
+        self.t1 = time.perf_counter()
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
+        parent = self.parent
+        if parent is None:
+            return False
+        tags = self.tags
+        tags["thread"] = threading.current_thread().name
+        tags["bytes"] = self.nbytes
+        tags["cpu_s"] = self.cpu_s
+        if exc_type is not None:
+            tags.setdefault("error", exc_type.__name__)
+        _export({
+            "trace_id": parent.trace_id,
+            "span_id": _hex_id(8),
+            "parent_id": parent.span_id,
+            "name": self.name,
+            # on the parent's clock pair, so the interval lies inside
+            # the parent's whatever the wall clock did meanwhile
+            "start": parent.start_wall + (self.t0 - parent.start_mono),
+            "duration_s": self.t1 - self.t0,
+            "tags": tags,
+        })
+        return False
+
+
 class TraceRing:
-    """Bounded map of trace_id -> span list; oldest trace evicted."""
+    """Bounded map of trace_id -> span list; oldest trace evicted.
+    Spans past ``max_spans`` of one trace are dropped and counted."""
 
     def __init__(self, max_traces: int = 64, max_spans: int = 512):
         self.max_traces = max_traces
         self.max_spans = max_spans
+        self.dropped = 0
         self._lock = make_lock("tracing._lock")
         self._traces: "OrderedDict[str, List[Dict]]" = OrderedDict()
+        self._dropped: Dict[str, int] = {}
 
     def add(self, span_dict: Dict):
         tid = span_dict.get("trace_id")
@@ -229,20 +309,30 @@ class TraceRing:
             spans = self._traces.get(tid)
             if spans is None:
                 while len(self._traces) >= self.max_traces:
-                    self._traces.popitem(last=False)
+                    old, _ = self._traces.popitem(last=False)
+                    self._dropped.pop(old, None)
                 spans = self._traces[tid] = []
             if len(spans) < self.max_spans:
                 spans.append(span_dict)
+            else:
+                self.dropped += 1
+                self._dropped[tid] = self._dropped.get(tid, 0) + 1
             self._traces.move_to_end(tid)
 
     def get(self, trace_id: str) -> List[Dict]:
         with self._lock:
             return list(self._traces.get(trace_id, ()))
 
+    def dropped_of(self, trace_id: str) -> int:
+        """Spans of this trace that did not fit under ``max_spans``."""
+        with self._lock:
+            return self._dropped.get(trace_id, 0)
+
     def recent(self, n: int = 20) -> List[Dict]:
         """Newest-first list of {trace_id, spans: [...]} dicts."""
         with self._lock:
             items = list(self._traces.items())[-n:]
+            dropped = dict(self._dropped)
         out = []
         for tid, spans in reversed(items):
             total = max((s.get("duration_s") or 0.0) for s in spans)
@@ -250,12 +340,15 @@ class TraceRing:
                         spans[0])
             out.append({"trace_id": tid, "root": root.get("name"),
                         "spans": list(spans), "span_count": len(spans),
+                        "dropped_spans": dropped.get(tid, 0),
                         "max_span_s": total})
         return out
 
     def clear(self):
         with self._lock:
             self._traces.clear()
+            self._dropped.clear()
+            self.dropped = 0
 
 
 # Big enough that steady-state heartbeat/poll traces (one span each)

@@ -318,7 +318,9 @@ class FakeMysql:
         import struct
         from seaweedfs_tpu.filer.mysql_store import _native_password
         try:
-            nonce = os.urandom(20)
+            # like a real server's scramble: no NUL byte (the client
+            # strips the part's terminator, and would strip one of ours)
+            nonce = bytes(b % 255 + 1 for b in os.urandom(20))
             caps = 0x1 | 0x8 | 0x200 | 0x8000 | 0x80000
             hs = (b"\x0a" + b"5.7.0-fake\x00"
                   + struct.pack("<I", 7) + nonce[:8] + b"\x00"

@@ -17,6 +17,8 @@ from seaweedfs_tpu.server.http_util import (HttpError, get_json, http_call,
 from seaweedfs_tpu.server.master import MasterServer
 from seaweedfs_tpu.server.volume_server import VolumeServer
 
+from conftest import wait_until
+
 
 @pytest.fixture
 def cluster(tmp_path):
@@ -38,6 +40,17 @@ def cluster(tmp_path):
 
 def furl(filer, path):
     return f"http://{filer.url}{path}"
+
+
+def _gone(master, fid) -> bool:
+    """The chunk no longer reads. Polled: the filer's own deletion loop
+    may have taken the queue a moment before `flush_deletions()` did,
+    and still be deleting when that returns with nothing to do."""
+    try:
+        op.read_file(master.url, fid)
+    except HttpError:
+        return True
+    return False
 
 
 def test_upload_read_small(cluster):
@@ -99,8 +112,7 @@ def test_overwrite_deletes_old_chunks(cluster):
     post_multipart(furl(filer, "/f.bin"), "f.bin", b"version-two!")
     assert http_call("GET", furl(filer, "/f.bin")) == b"version-two!"
     filer.flush_deletions()
-    with pytest.raises(HttpError):
-        op.read_file(master.url, old_fid)
+    assert wait_until(lambda: _gone(master, old_fid))
 
 
 def test_delete_recursive_cleans_chunks(cluster):
@@ -115,8 +127,7 @@ def test_delete_recursive_cleans_chunks(cluster):
     with pytest.raises(HttpError):
         http_call("GET", furl(filer, "/tree/2.bin"))
     filer.flush_deletions()
-    with pytest.raises(HttpError):
-        op.read_file(master.url, fid)
+    assert wait_until(lambda: _gone(master, fid))
 
 
 def test_rename(cluster):

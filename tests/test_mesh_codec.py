@@ -84,8 +84,8 @@ def test_sharded_slab_is_one_dispatch():
     want_width = codec.mesh.shape["data"]
     assert want_width > 1, "virtual 8-device mesh required (conftest)"
     assert d["dispatch_width_devices"] == want_width
-    assert set(d["device_busy_frac"]) == set(d["mesh_device_bytes"])
-    assert max(d["device_busy_frac"].values()) == 1.0
+    assert set(d["device_byte_share"]) == set(d["mesh_device_bytes"])
+    assert max(d["device_byte_share"].values()) == 1.0
 
 
 def test_small_slab_crosses_over_to_single_device():
@@ -103,7 +103,7 @@ def test_small_slab_crosses_over_to_single_device():
     assert d["dispatches"] == 1
     assert d["mesh_dispatches"] == 0
     assert d["dispatch_width_devices"] == 1
-    assert d["device_busy_frac"] == {}
+    assert d["device_byte_share"] == {}
     assert np.array_equal(out, NumpyCodec(k, m).encode(data))
 
 

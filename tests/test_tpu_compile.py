@@ -59,8 +59,12 @@ def _compile_fused(one_chip, k, r, n):
     compiled = fn.raw_jit.lower(
         jax.ShapeDtypeStruct((8 * r, 8 * k), jnp.int8, sharding=one_chip),
         jax.ShapeDtypeStruct((k, n), jnp.uint8, sharding=one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text(), \
-        "kernel did not compile through Mosaic"
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "kernel did not compile through Mosaic"
+    # the names a profiler trace is reduced by: the module, and the
+    # custom call itself (an 'XLA Ops' event's name is its HLO line)
+    assert "HloModule jit_sw_rs_fused" in text
+    assert "%sw_rs_fused" in text and "/sw_rs_fused/pallas_call" in text
     return compiled
 
 
@@ -131,6 +135,9 @@ def test_mesh_program_compiles_on_four_chips(topo, rows_in, rows_out, n):
     ).compile()
     text = compiled.as_text()
     assert not [c for c in COLLECTIVES if c in text]
+    # the module's name and the scope every op of the program sits in
+    assert "HloModule jit_sw_rs_mesh" in text
+    assert 'op_name="jit(sw_rs_mesh)/sw_rs_mesh/' in text
     # each device is handed a quarter of the payload columns ...
     _, data_sharding = compiled.input_shardings[0]
     assert data_sharding.shard_shape((rows_in, n)) == (rows_in, n // 4)

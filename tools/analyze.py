@@ -652,7 +652,7 @@ REQUIRED_FAMILIES = {
         "SeaweedFS_volumeServer_ec_mesh_dispatches_total",
         "SeaweedFS_volumeServer_ec_mesh_dispatch_width_devices",
         "SeaweedFS_volumeServer_ec_mesh_device_bytes_total",
-        "SeaweedFS_volumeServer_ec_mesh_device_busy_frac",
+        "SeaweedFS_volumeServer_ec_mesh_device_byte_share",
         "SeaweedFS_volumeServer_http_pool_churn_total",
         "SeaweedFS_volumeServer_ec_spread_total",
         "SeaweedFS_volumeServer_ec_spread_seconds_total",
@@ -700,8 +700,6 @@ REQUIRED_FAMILIES = {
         "SeaweedFS_volumeServer_ec_xla_recompiles_total",
         "SeaweedFS_volumeServer_ec_xla_recompile_sentinel",
         "SeaweedFS_volumeServer_ec_xla_dispatches_total",
-        "SeaweedFS_volumeServer_ec_xla_device_samples_total",
-        "SeaweedFS_volumeServer_ec_xla_device_seconds_total",
         "SeaweedFS_volumeServer_ec_xla_jit_cache_total",
         "SeaweedFS_volumeServer_ec_xla_jit_cache_entries",
         "SeaweedFS_volumeServer_ec_xla_device_memory_bytes",
