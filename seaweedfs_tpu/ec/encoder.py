@@ -18,13 +18,17 @@ encode is a few hundred kernel launches instead of ~120k, and the GF math
 runs as one MXU matmul per slab (ops/rs_tpu.py). With a TPU-backed codec
 the slabs additionally flow through ops/pipeline.PipelinedMatmul, which
 overlaps disk reads (reader thread), h2d, MXU compute, d2h and shard-file
-writes. Slab reads are strided (block i of a row lives at start +
-i*block_size), the same column layout the reference uses, so shard bytes
-are identical across all backends.
+writes. Every backend gets its slabs from one reader (_dat_slabs): block i
+of a row lives at start + i*block_size, the same column layout the
+reference uses, so shard bytes are identical across all backends; each
+device call's (k, W) slab is filled in place by vectored positional reads
+(os.preadv: one per small row, whose k blocks are one file range) — one
+pass over every byte of the .dat, no seek, no intermediate copy.
 """
 
 from __future__ import annotations
 
+import collections
 import os
 import time
 from typing import Iterator, List, Optional, Tuple
@@ -57,44 +61,135 @@ def write_sorted_file_from_idx(base_name: str, ext: str = ".ecx"):
     db.save_to_idx(base_name + ext)
 
 
-def _row_slabs(f, k: int, start: int, block_size: int, slab: int,
-               timer: Optional[StageTimer] = None
-               ) -> Iterator[Tuple[None, np.ndarray]]:
-    """Yield the slabs of one row of k blocks at [start, start+k*block)."""
-    step = min(slab, block_size)
-    for off in range(0, block_size, step):
-        width = min(step, block_size - off)  # final chunk may be partial
-        t0 = time.perf_counter()
-        data = np.zeros((k, width), dtype=np.uint8)
-        for i in range(k):
-            f.seek(start + i * block_size + off)
-            chunk = f.read(width)
-            if chunk:
-                data[i, :len(chunk)] = np.frombuffer(chunk, dtype=np.uint8)
-        if timer is not None:
-            end = time.perf_counter()
-            timer.add("disk_read", end - t0, k * width, interval=(t0, end))
-        yield None, data
+def _dispatch_plan(dat_size: int, k: int, large_block: int, small_block: int,
+                   slab: int, target_width: int
+                   ) -> Iterator[List[Tuple[int, int, int, int]]]:
+    """Each device call of a .dat, in shard-file order, as the pieces
+    (row_start, block, off, width) it is made of.
+
+    A row of k blocks (large rows while MORE than one remains, then
+    small ones) is cut into pieces at most ``slab`` wide; whole pieces
+    pack into one call up to ``target_width`` columns. GF coding is
+    columnwise-independent and consecutive pieces append contiguously
+    to each shard file, so a call's rows are exactly the next byte
+    range of every shard — the 'streaming stripe batches' of BASELINE
+    config 3. Without the packing a volume of 1MB small rows would
+    reach the device 10MB per call."""
+    rows = []
+    remaining, start = dat_size, 0
+    for block, at_least in ((large_block, large_block * k), (small_block, 0)):
+        while remaining > at_least:
+            rows.append((start, block))
+            remaining -= block * k
+            start += block * k
+    batch: List[Tuple[int, int, int, int]] = []
+    total = 0
+    for start, block in rows:
+        step = min(slab, block)
+        for off in range(0, block, step):
+            width = min(step, block - off)  # a row's last piece may be partial
+            if batch and total + width > target_width:
+                yield batch
+                batch, total = [], 0
+            batch.append((start, block, off, width))
+            total += width
+    if batch:
+        yield batch
+
+
+def _read_ranges(fd: int, rows: List[np.ndarray], offset: int,
+                 dat_size: int):
+    """One vectored positional read of consecutive file ranges into
+    ``rows``. Only the file's tail may come short: what it did not fill
+    is zeroed (a slab is never handed out clean); short anywhere else
+    is an error."""
+    want = sum(r.size for r in rows)
+    n = os.preadv(fd, rows, offset)
+    if n < min(want, dat_size - offset):
+        raise IOError(f"short .dat read at {offset}: {n} of {want} bytes, "
+                      f"{dat_size - offset} left in the file")
+    for r in rows:
+        if n < r.size:
+            r[max(n, 0):] = 0
+        n -= r.size
+
+
+# Dispatch slabs outlive their encode. glibc maps a block as large as a
+# (k, 8 MiB) slab anew at every allocation and unmaps it when freed: a
+# page fault per 4 KiB on the way in, a TLB shootdown across every core
+# on the way out, and on the v5e hosts (VMs) both stall the whole
+# process — fresh slabs cost a fifth of encode_mbps there (PERF.md, PR
+# 26). A pool that died with its encode recovers little of it: 9 of a
+# GiB volume's 13 slabs are live before the first is written. It holds
+# as many as one stream keeps in flight: the one being read, the
+# pipeline's read-ahead and depth, the one being written.
+_SLAB_POOL: "collections.deque[np.ndarray]" = collections.deque(maxlen=10)
+
+
+def _take_slab(k: int, width: int) -> np.ndarray:
+    """A (k, width) uint8 slab with whatever bytes its last user left."""
+    n = k * width
+    while True:
+        try:
+            buf = _SLAB_POOL.pop()
+        except IndexError:
+            buf = np.empty(n, dtype=np.uint8)
+            break
+        if buf.size >= n:   # a smaller one served another geometry: dropped
+            break
+    return buf[:n].reshape(k, width)
+
+
+def _give_slab(data: np.ndarray):
+    """Hand a slab of _dat_slabs back once nothing reads it any more:
+    after its stripe's write, never earlier (on the CPU backend the
+    device array may alias the host memory until the output is drained)."""
+    _SLAB_POOL.append(data.base)
 
 
 def _dat_slabs(dat_path: str, dat_size: int, k: int, large_block: int,
-               small_block: int, slab: int,
-               timer: Optional[StageTimer] = None
-               ) -> Iterator[Tuple[None, np.ndarray]]:
-    """All slabs of a .dat in shard-file order (large rows, then small)."""
-    with open(dat_path, "rb") as f:
-        remaining = dat_size
-        processed = 0
-        large_row = large_block * k
-        while remaining > large_row:
-            yield from _row_slabs(f, k, processed, large_block, slab, timer)
-            remaining -= large_row
-            processed += large_row
-        small_row = small_block * k
-        while remaining > 0:
-            yield from _row_slabs(f, k, processed, small_block, slab, timer)
-            remaining -= small_row
-            processed += small_row
+               small_block: int, slab: int, target_width: int,
+               timer: StageTimer) -> Iterator[Tuple[None, np.ndarray]]:
+    """The .dat as one (k, W) host slab per device call, every byte
+    landed in its final place by the read itself: no intermediate
+    bytes, no row slab, no concatenate.
+
+    Block i of a row lives at row_start + i*block — the column layout
+    the reference uses — so where a piece spans whole blocks (small
+    rows) the row's k blocks are one contiguous file range that
+    scatters to the slab's k rows in ONE preadv; where a block is wider
+    than the slab (large rows) the k ranges lie a block apart and take
+    a read each.
+
+    Producing one call's slab is one stage (``disk_read`` in the timer,
+    span ``ec.encode.read`` under its root) on the thread that iterates
+    this — the pipeline's producer where there is one — counted in
+    ops/telemetry beside it. The consumer may _give_slab each one back."""
+    from ..ops.telemetry import STATS
+    fd = os.open(dat_path, os.O_RDONLY)
+    try:
+        for pieces in _dispatch_plan(dat_size, k, large_block, small_block,
+                                     slab, target_width):
+            with timer.stage("disk_read", span="ec.encode.read") as st:
+                out = _take_slab(k, sum(p[3] for p in pieces))
+                col = 0
+                for start, block, off, width in pieces:
+                    cols = slice(col, col + width)
+                    if width == block:
+                        _read_ranges(fd, [out[i, cols] for i in range(k)],
+                                     start, dat_size)
+                    else:
+                        for i in range(k):
+                            _read_ranges(fd, [out[i, cols]],
+                                         start + i * block + off, dat_size)
+                    col += width
+                st.nbytes = out.nbytes
+            STATS.add_read(out.nbytes, st.t1 - st.t0, st.cpu_s)
+            yield None, out
+            # handed on: the next slab is read without this one kept alive
+            out = None
+    finally:
+        os.close(fd)
 
 
 def _window_batches(slabs: Iterator[Tuple[None, np.ndarray]],
@@ -144,52 +239,6 @@ def piggyback_geometry(codec: ReedSolomonCodec, layout,
     return pplan, window
 
 
-def _coalesce_slabs(slabs: Iterator[Tuple[None, np.ndarray]],
-                    target_width: int, timer: StageTimer
-                    ) -> Iterator[Tuple[None, np.ndarray]]:
-    """Hstack consecutive row-slabs up to target_width per device call.
-
-    GF coding is columnwise-independent, so concat-then-encode equals
-    encode-then-concat; and consecutive slabs append contiguously to each
-    shard file, so the batched rows are exactly the shard byte ranges —
-    the 'streaming stripe batches' of BASELINE config 3. Without this, a
-    volume of 1MB small rows would reach the device 10MB per call.
-
-    Producing one device call's batch — the row-slab reads it pulls
-    from ``slabs`` and the concatenate — is one stage (span
-    ``ec.encode.read`` under the timer's root) on the thread that
-    iterates this (the pipeline's producer), counted in ops/telemetry
-    beside it.
-    """
-    from ..ops.telemetry import STATS
-    it = iter(slabs)
-    held: Optional[np.ndarray] = None   # read, but past this batch's width
-    more = True
-    while more:
-        with tracing.Stage("ec.encode.read", timer.root) as st:
-            batch = [] if held is None else [held]
-            total = sum(b.shape[1] for b in batch)
-            held = None
-            for _, data in it:
-                w = data.shape[1]
-                if batch and total + w > target_width:
-                    held = data
-                    break
-                batch.append(data)
-                total += w
-            else:
-                more = False
-            if not batch:
-                return
-            out = batch[0] if len(batch) == 1 \
-                else np.concatenate(batch, axis=1)
-            st.nbytes = out.nbytes
-        STATS.add_read(out.nbytes, st.t1 - st.t0, st.cpu_s)
-        yield None, out
-        # handed on: the next batch is read without this one kept alive
-        out = batch = None
-
-
 def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
                    large_block: int = LARGE_BLOCK_SIZE,
                    small_block: int = SMALL_BLOCK_SIZE,
@@ -234,8 +283,10 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
     # when no caller asked for a bench breakdown
     timer = timer if timer is not None else \
         StageTimer(root=tracing.current_span())
+    # one reader for every backend and layout: a whole-window width for
+    # piggyback (re-cut on window boundaries below), the slab otherwise
     slabs = _dat_slabs(dat_path, dat_size, k, large_block, small_block, slab,
-                       timer)
+                       _pb_slab(slab, window) if piggyback else slab, timer)
     outs = [] if sink is not None else \
         [open(base_name + to_ext(i), "wb") for i in range(k + m)]
     # device-parallel compute feeding holder-parallel network: with a
@@ -248,10 +299,7 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
         hasattr(codec, "drain_pieces") and not piggyback
     try:
         if piggyback:
-            batches = _window_batches(
-                _coalesce_slabs(slabs, max(slab - slab % window, window),
-                                timer),
-                window)
+            batches = _window_batches(slabs, window)
             alpha = pplan.alpha
 
             def pb_stream():
@@ -278,7 +326,7 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
             from ..ops.pipeline import PipelinedMatmul
             pm = PipelinedMatmul(codec.matrix[k:], max_width=slab,
                                  timer=timer, codec=codec, pieces=pieces)
-            stream = pm.stream(_coalesce_slabs(slabs, slab, timer))
+            stream = pm.stream(slabs)
         else:
             stream = ((meta, data, codec.encode(data))
                       for meta, data in slabs)
@@ -298,6 +346,10 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
                     for j in range(m):
                         outs[k + j].write(parity[j].tobytes())
                     st.nbytes = data.nbytes + parity.nbytes
+            if not piggyback:   # its window re-cut yields copies and views
+                _give_slab(data)
+            # the stripe is written: hold neither while the next is awaited
+            data = parity = None
     finally:
         for o in outs:
             o.close()

@@ -11,7 +11,7 @@ JAX dispatch is asynchronous: ``fn(bitmat, dev)`` returns a future-like
 device array immediately, so keeping a bounded deque of in-flight slabs
 means the device computes slab t+1..t+depth while the host blocks on
 fetching slab t's output and writing files. The reader thread overlaps
-disk I/O with everything else (file reads release the GIL).
+disk I/O with the rest (the encode's preadv drops the GIL once per row).
 
 PipelinedMatmul computes ``coeffs @ data`` over GF(2^8) for a stream of
 data slabs with a fixed coefficient matrix — encode (coeffs = parity

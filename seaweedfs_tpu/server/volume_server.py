@@ -19,7 +19,7 @@ import numpy as np
 
 from ..ec.constants import DATA_SHARDS, TOTAL_SHARDS, to_ext
 from ..ops.codec import get_codec
-from ..util import tracing
+from ..util import malloc_policy, tracing
 from ..storage.needle import Needle
 from ..storage.store import Store
 from ..storage.types import parse_file_id
@@ -222,6 +222,8 @@ class VolumeServer:
 
     # -- lifecycle ---------------------------------------------------------
     def start(self):
+        # the EC streams this server runs live on recycled buffers
+        malloc_policy.keep_freed_memory()
         self.server.start()
         try:
             self.heartbeat_once()
