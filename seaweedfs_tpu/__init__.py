@@ -12,3 +12,8 @@ This is NOT a port; architecture is TPU-first (see SURVEY.md §7).
 """
 
 VERSION = "0.1.0"
+
+# before anything here starts a thread (util/malloc_policy.py says why)
+from .util import malloc_policy as _malloc_policy  # noqa: E402
+
+_malloc_policy.one_arena()

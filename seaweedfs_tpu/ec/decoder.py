@@ -157,6 +157,19 @@ def _copy_block(src, offset: int, length: int, dst, buf_size: int):
 # single-shard repair (ops/codec.repair_plan has the scheme math).
 # ---------------------------------------------------------------------------
 
+def _write_relaid(timer: StageTimer, out, span: str, w: int, relayout) -> int:
+    """One drained block of a single-shard repair: its host re-layout
+    into ``w`` shard bytes, then the append. Under the timer's root (the
+    stream's ``ec.rebuild.stream``) both leave as spans, ``span`` and
+    ``ec.rebuild.write``; the repair's ``write`` phase is their sum."""
+    with timer.stage("relayout", span=span) as st:
+        block = relayout()
+        st.nbytes = block.nbytes
+    with timer.stage("shard_write", w, span="ec.rebuild.write"):
+        out.write(block.tobytes())
+    return w
+
+
 def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
                            codec=None, slab: int = 8 << 20,
                            pipelined: Optional[bool] = None,
@@ -188,28 +201,39 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
     before = telemetry.STATS.snapshot()
     phases = {"gather": 0.0, "plan": 0.0, "dispatch": 0.0,
               "drain": 0.0, "write": 0.0}
+    # the source hands out blocks as tall as its row bucket (zero rows
+    # below the symbol planes); the combine gets as many zero columns,
+    # so the product is the plan's own
+    combine = plan.combine
+    rows = source.rows
+    if rows > plan.total_bits:
+        combine = np.zeros((combine.shape[0], rows), dtype=np.uint8)
+        combine[:, :plan.total_bits] = plan.combine
     out_path = base_name + to_ext(lost_sid)
     out = open(out_path, "wb")
     rebuilt_bytes = 0
     # plane widths are byte strides: an 8 MB slab arrives as
     # total_bits x 1 MB planes, so the pipeline buckets on the stride
     stride_cap = (max(1, int(slab)) + 7) // 8
+    timer = StageTimer(root=tracing.current_span())
+
+    def write_block(planes, w):
+        return _write_relaid(
+            timer, out, "ec.rebuild.trace_unpack", w,
+            lambda: combine_planes_to_bytes(
+                np.asarray(planes, dtype=np.uint8), w))
+
     t_stream = time.perf_counter()
     try:
         if pipelined:
             from ..ops.pipeline import PipelinedMatmul
-            ptimer = StageTimer()
-            pm = PipelinedMatmul(plan.combine, max_width=stride_cap,
-                                 codec=codec, timer=ptimer)
+            pm = PipelinedMatmul(combine, max_width=stride_cap,
+                                 codec=codec, timer=timer)
             for meta, _, planes in pm.stream(source.slabs()):
-                _, _, w = meta
-                t0 = time.perf_counter()
-                out.write(combine_planes_to_bytes(planes, w).tobytes())
-                rebuilt_bytes += w
-                phases["write"] += time.perf_counter() - t0
-            phases["gather"] = ptimer.totals.get("read_wait", 0.0)
-            phases["dispatch"] = ptimer.totals.get("h2d", 0.0)
-            phases["drain"] = ptimer.totals.get("drain_wait", 0.0)
+                rebuilt_bytes += write_block(planes, meta[2])
+            phases["gather"] = timer.totals.get("read_wait", 0.0)
+            phases["dispatch"] = timer.totals.get("h2d", 0.0)
+            phases["drain"] = timer.totals.get("drain_wait", 0.0)
         else:
             it = source.slabs()
             while True:
@@ -218,17 +242,14 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
                     meta, planes = next(it)
                 except StopIteration:
                     break
-                _, _, w = meta
                 t1 = time.perf_counter()
-                combined = codec._matmul(plan.combine, planes)
+                combined = codec._matmul(combine, planes)
                 t2 = time.perf_counter()
-                out.write(combine_planes_to_bytes(
-                    np.asarray(combined, dtype=np.uint8), w).tobytes())
-                rebuilt_bytes += w
-                t3 = time.perf_counter()
+                rebuilt_bytes += write_block(combined, meta[2])
                 phases["gather"] += t1 - t0
                 phases["dispatch"] += t2 - t1
-                phases["write"] += t3 - t2
+        phases["write"] = timer.totals.get("relayout", 0.0) + \
+            timer.totals.get("shard_write", 0.0)
     except BaseException:
         out.close()
         try:
@@ -271,6 +292,7 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
         # the repair story: symbol bytes moved vs the k*shard baseline
         # the full-RS gather would have pulled for the same rebuild
         stats["repair_mode"] = "trace"
+        stats["operand"] = list(combine.shape)
         stats["repair_helpers"] = len(plan.helpers)
         stats["repair_total_bits"] = plan.total_bits
         stats["repair_bits"] = {int(s): plan.bits_for(s)
@@ -320,24 +342,25 @@ def rebuild_ec_file_piggyback(base_name: str, lost_sid: int, source,
     rebuilt_bytes = 0
     # stripe columns are w/alpha wide for a w-byte shard range
     stride_cap = max(1, int(slab)) // alpha + 1
+    timer = StageTimer(root=tracing.current_span())
+
+    def write_block(sub, w):
+        return _write_relaid(
+            timer, out, "ec.rebuild.pb_merge", w,
+            lambda: pb_merge(np.asarray(sub, dtype=np.uint8),
+                             alpha, window)[0])
+
     t_stream = time.perf_counter()
     try:
         if pipelined:
             from ..ops.pipeline import PipelinedMatmul
-            ptimer = StageTimer()
             pm = PipelinedMatmul(rplan.matrix, max_width=stride_cap,
-                                 codec=codec, timer=ptimer)
+                                 codec=codec, timer=timer)
             for meta, _, sub in pm.stream(source.slabs()):
-                _, _, w = meta
-                t0 = time.perf_counter()
-                merged = pb_merge(np.asarray(sub, dtype=np.uint8),
-                                  alpha, window)
-                out.write(merged[0].tobytes())
-                rebuilt_bytes += w
-                phases["write"] += time.perf_counter() - t0
-            phases["gather"] = ptimer.totals.get("read_wait", 0.0)
-            phases["dispatch"] = ptimer.totals.get("h2d", 0.0)
-            phases["drain"] = ptimer.totals.get("drain_wait", 0.0)
+                rebuilt_bytes += write_block(sub, meta[2])
+            phases["gather"] = timer.totals.get("read_wait", 0.0)
+            phases["dispatch"] = timer.totals.get("h2d", 0.0)
+            phases["drain"] = timer.totals.get("drain_wait", 0.0)
         else:
             it = source.slabs()
             while True:
@@ -346,18 +369,14 @@ def rebuild_ec_file_piggyback(base_name: str, lost_sid: int, source,
                     meta, stacked = next(it)
                 except StopIteration:
                     break
-                _, _, w = meta
                 t1 = time.perf_counter()
                 sub = codec._matmul(rplan.matrix, stacked)
                 t2 = time.perf_counter()
-                merged = pb_merge(np.asarray(sub, dtype=np.uint8),
-                                  alpha, window)
-                out.write(merged[0].tobytes())
-                rebuilt_bytes += w
-                t3 = time.perf_counter()
+                rebuilt_bytes += write_block(sub, meta[2])
                 phases["gather"] += t1 - t0
                 phases["dispatch"] += t2 - t1
-                phases["write"] += t3 - t2
+        phases["write"] = timer.totals.get("relayout", 0.0) + \
+            timer.totals.get("shard_write", 0.0)
     except BaseException:
         out.close()
         try:
@@ -401,6 +420,7 @@ def rebuild_ec_file_piggyback(base_name: str, lost_sid: int, source,
         # the repair story: half-plane bytes moved vs the k*shard
         # baseline the full-RS gather would have pulled
         stats["repair_mode"] = "piggyback"
+        stats["operand"] = list(rplan.matrix.shape)
         stats["repair_helpers"] = len(rplan.helpers)
         stats["repair_bytes"] = gs.bytes
         stats["repair_remote_bytes"] = gs.remote_bytes

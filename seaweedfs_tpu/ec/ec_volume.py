@@ -123,8 +123,24 @@ class EcVolumeShard:
         self.size = os.path.getsize(self.path)
 
     def read_at(self, offset: int, length: int) -> bytes:
-        self.f.seek(offset)
-        return self.f.read(length)
+        """Up to `length` bytes at `offset`, short only at the end of the
+        file. `os.pread` and not seek + read: a rebuild keeps several
+        ranges of one shard in flight on as many handler threads, and a
+        seek another thread made between this one's seek and its read
+        returned the wrong range at the right length."""
+        fd = self.f.fileno()
+        data = os.pread(fd, length, offset)
+        if len(data) == length or not data:
+            return data
+        parts = [data]
+        got = len(data)
+        while got < length:
+            data = os.pread(fd, length - got, offset + got)
+            if not data:
+                break
+            parts.append(data)
+            got += len(data)
+        return b"".join(parts)
 
     def close(self):
         self.f.close()

@@ -193,7 +193,8 @@ def _dat_slabs(dat_path: str, dat_size: int, k: int, large_block: int,
 
 
 def _window_batches(slabs: Iterator[Tuple[None, np.ndarray]],
-                    window: int) -> Iterator[Tuple[None, np.ndarray]]:
+                    window: int, timer: StageTimer
+                    ) -> Iterator[Tuple[None, np.ndarray]]:
     """Re-chunk a slab stream onto sub-chunk window boundaries.
 
     The piggyback parity transform is window-local (ops/codec.pb_split
@@ -203,18 +204,27 @@ def _window_batches(slabs: Iterator[Tuple[None, np.ndarray]],
     buffering the non-aligned remainder into the next batch preserves
     shard bytes exactly. The stream total is window-aligned by
     construction (both stripe blocks divide by the window), so the
-    buffer always drains."""
+    buffer always drains.
+
+    One stage a slab (``pb_recut`` in the timer, span
+    ``ec.encode.pb_recut``) on the thread that iterates this, after the
+    slab's ``ec.encode.read`` has closed: a slab that is window-aligned
+    already (every one, at the default slab and window) passes through
+    as it came, and the stage holds nothing but the test."""
     held: Optional[np.ndarray] = None
     for _, data in slabs:
-        if held is not None:
-            data = np.concatenate([held, data], axis=1)
-            held = None
-        cut = (data.shape[1] // window) * window
-        if cut < data.shape[1]:
-            held = np.ascontiguousarray(data[:, cut:])
-            data = data[:, :cut]
+        with timer.stage("pb_recut", span="ec.encode.pb_recut") as st:
+            if held is not None:
+                data = np.concatenate([held, data], axis=1)
+                held = None
+            cut = (data.shape[1] // window) * window
+            if cut < data.shape[1]:
+                held = np.ascontiguousarray(data[:, cut:])
+                data = data[:, :cut]
+            data = np.ascontiguousarray(data)
+            st.nbytes = data.nbytes
         if data.shape[1]:
-            yield None, np.ascontiguousarray(data)
+            yield None, data
     if held is not None and held.shape[1]:
         raise ValueError(
             f"stream tail of {held.shape[1]} bytes is not window-aligned "
@@ -266,6 +276,9 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
     differ, computed per window by one (m*alpha, k*alpha) matmul on
     the same kernels. Callers record the layout in the volume's
     sidecars (ec/layout.py); this function only shapes the bytes.
+
+    Returns the (rows, cols) of the coefficient matrix every call ran:
+    (m, k) flat, (m*alpha, k*alpha) piggyback.
     """
     from ..ops import codec as ops_codec
     codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
@@ -299,8 +312,29 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
         hasattr(codec, "drain_pieces") and not piggyback
     try:
         if piggyback:
-            batches = _window_batches(slabs, window)
             alpha = pplan.alpha
+            operand = pplan.emat.shape
+
+            # the two host re-layouts around the coupled matmul, one
+            # stage a dispatch each: the split (an 80 MiB transpose into
+            # a new array) on the thread that iterates the batches (the
+            # pipeline's producer where there is one), the merge of the
+            # drained parity on the consumer
+            def split():
+                for _, data in _window_batches(slabs, window, timer):
+                    with timer.stage("pb_split",
+                                     span="ec.encode.pb_split") as st:
+                        sub = ops_codec.pb_split(data, alpha, window)
+                        st.nbytes = sub.nbytes
+                    yield data, sub
+
+            def merge(psub):
+                with timer.stage("pb_merge",
+                                 span="ec.encode.pb_merge") as st:
+                    parity = ops_codec.pb_merge(
+                        np.asarray(psub, dtype=np.uint8), alpha, window)
+                    st.nbytes = parity.nbytes
+                return parity
 
             def pb_stream():
                 if pipelined:
@@ -309,25 +343,21 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
                         pplan.emat,
                         max_width=max(slab // alpha, window // alpha),
                         timer=timer, codec=codec)
-                    split = ((data, ops_codec.pb_split(data, alpha, window))
-                             for _, data in batches)
-                    for orig, _sub, psub in pm.stream(split):
-                        yield orig, ops_codec.pb_merge(
-                            np.asarray(psub, dtype=np.uint8), alpha, window)
+                    for orig, _sub, psub in pm.stream(split()):
+                        yield orig, merge(psub)
                 else:
-                    for _, data in batches:
-                        sub = ops_codec.pb_split(data, alpha, window)
-                        psub = np.asarray(
-                            codec._matmul(pplan.emat, sub), dtype=np.uint8)
-                        yield data, ops_codec.pb_merge(psub, alpha, window)
+                    for data, sub in split():
+                        yield data, merge(codec._matmul(pplan.emat, sub))
 
             stream = ((None, data, parity) for data, parity in pb_stream())
         elif pipelined:
             from ..ops.pipeline import PipelinedMatmul
+            operand = codec.matrix[k:].shape
             pm = PipelinedMatmul(codec.matrix[k:], max_width=slab,
                                  timer=timer, codec=codec, pieces=pieces)
             stream = pm.stream(slabs)
         else:
+            operand = codec.matrix[k:].shape
             stream = ((meta, data, codec.encode(data))
                       for meta, data in slabs)
         for _, data, parity in stream:
@@ -354,6 +384,7 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
         for o in outs:
             o.close()
     _record_phase_spans(timer, pipelined, op="ec.encode")
+    return tuple(int(n) for n in operand)
 
 
 def write_ec_files_spread(base_name: str, sink,
@@ -388,10 +419,10 @@ def write_ec_files_spread(base_name: str, sink,
     timer = StageTimer(root=tracing.current_span())
     t_stream = time.perf_counter()
     try:
-        write_ec_files(base_name, codec=codec, large_block=large_block,
-                       small_block=small_block, slab=slab,
-                       pipelined=pipelined, timer=timer, sink=sink,
-                       layout=layout)
+        operand = write_ec_files(
+            base_name, codec=codec, large_block=large_block,
+            small_block=small_block, slab=slab, pipelined=pipelined,
+            timer=timer, sink=sink, layout=layout)
         sink.finish()
     except BaseException:
         sink.abort()
@@ -404,6 +435,7 @@ def write_ec_files_spread(base_name: str, sink,
         stats["shard_size"] = sink.offset
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
+        stats["operand"] = list(operand)
         stats["phases"] = {n: round(s, 6) for n, s in
                            _phases_from_timer(timer, pipelined).items()}
         # encode busy = stream wall minus the time the consumer spent
@@ -436,7 +468,10 @@ def _phases_from_timer(timer: StageTimer, pipelined: bool) -> dict:
         "gather": t.get("read_wait" if pipelined else "disk_read", 0.0),
         "dispatch": t.get("h2d", 0.0),
         "drain": t.get("drain_wait", 0.0),
-        "write": t.get("shard_write", 0.0),
+        # piggyback: the consumer merges each drained parity block back
+        # into shard bytes before it writes them (as the plane repair's
+        # `write` holds its merge, ec/decoder.py)
+        "write": t.get("shard_write", 0.0) + t.get("pb_merge", 0.0),
     }
 
 
@@ -587,6 +622,7 @@ def rebuild_ec_files(base_name: str,
         stats["rebuilt_bytes"] = shard_size * len(missing)
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
+        stats["operand"] = [len(missing), k]
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
     return missing
 
@@ -663,6 +699,7 @@ def _rebuild_ec_files_piggyback(base_name, codec, layout, present,
         stats["rebuilt_bytes"] = shard_size * len(missing)
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
+        stats["operand"] = list(coeffs.shape)
         stats["layout"] = "piggyback"
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
     return list(missing)
@@ -752,6 +789,7 @@ def rebuild_ec_files_streaming_piggyback(base_name: str,
         stats["rebuilt_bytes"] = rebuilt_bytes
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
+        stats["operand"] = list(coeffs.shape)
         stats["layout"] = "piggyback"
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
         stats["gather_mbps"] = round(gs.mbps(), 1)
@@ -868,6 +906,7 @@ def rebuild_ec_files_streaming(base_name: str,
         stats["rebuilt_bytes"] = rebuilt_bytes
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
+        stats["operand"] = list(coeffs.shape)
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
         gather_busy = gs.busy_s()
         compute_busy = max(stream_s - phases["gather"], 0.0)

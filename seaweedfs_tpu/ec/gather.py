@@ -159,6 +159,7 @@ class RemoteRepairReader(RemoteShardReader):
 
     _method = "POST"
     _health_kind = "repair_read"
+    fetch_span = FETCH_SPAN + ".trace.remote"
 
     def __init__(self, vid: int, sid: int, holders: Sequence[str],
                  masks: Sequence[int],
@@ -187,7 +188,7 @@ class LocalRepairReader:
     bytes (the range itself never crossed the network)."""
 
     remote = False
-    fetch_span = FETCH_SPAN + ".local"
+    fetch_span = FETCH_SPAN + ".trace.local"
     span = None          # set by StripedPull: trace parent
 
     def __init__(self, path: str, masks: Sequence[int],
@@ -223,6 +224,7 @@ class RemotePlaneReader(RemoteShardReader):
 
     _method = "POST"
     _health_kind = "plane_read"
+    fetch_span = FETCH_SPAN + ".plane.remote"
 
     def __init__(self, vid: int, sid: int, holders: Sequence[str],
                  alpha: int, window: int, plane_bit: int, plane_side: int,
@@ -254,7 +256,7 @@ class LocalPlaneReader:
     network)."""
 
     remote = False
-    fetch_span = FETCH_SPAN + ".local"
+    fetch_span = FETCH_SPAN + ".plane.local"
     span = None          # set by StripedPull: trace parent
 
     def __init__(self, path: str, alpha: int, window: int,
@@ -336,7 +338,13 @@ class RepairGatherSource(StripedPull):
     concatenated planes in helper-then-mask order, ready for the fused
     combine matmul. The bounded window, round-robin rotation, failover
     and hedging all come from the shared transport; only the stripe
-    shape and memory accounting differ."""
+    shape and memory accounting differ.
+
+    A plan's bit count differs by lost shard (50 to 56 of 80 for
+    RS(10,4)), and a device program is compiled per operand shape, so
+    every block comes ``rows`` tall — total_bits rounded up to
+    ``ops/codec.REPAIR_ROW_BUCKET``, the tail rows zero — and the
+    repairs of one geometry share one compiled combine."""
 
     def __init__(self, readers: Sequence, shard_size: int, plan,
                  slab: int = 8 << 20, window: Optional[int] = None,
@@ -349,6 +357,8 @@ class RepairGatherSource(StripedPull):
         super().__init__(readers, shard_size, slab=slab, window=window,
                          stats=stats, parent_span=parent_span)
         self.plan = plan
+        from ..ops.codec import REPAIR_ROW_BUCKET as bucket
+        self.rows = -(-plan.total_bits // bucket) * bucket
 
     def _stripe_nbytes(self, w: int) -> int:
         return self.plan.total_bits * ((w + 7) // 8)
@@ -357,6 +367,9 @@ class RepairGatherSource(StripedPull):
         stride = (w + 7) // 8
         rows = [np.frombuffer(b, dtype=np.uint8).reshape(-1, stride)
                 for b in bufs]
+        if self.rows > self.plan.total_bits:
+            rows.append(np.zeros((self.rows - self.plan.total_bits, stride),
+                                 dtype=np.uint8))
         return np.concatenate(rows, axis=0)
 
 

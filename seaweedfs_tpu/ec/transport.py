@@ -229,7 +229,10 @@ class SpreadStats(TransportStats):
 
 # one span per survivor range read, on the thread that made it (an
 # `ec-pull` worker; a hedged duplicate on the hedge pool), from the
-# interval the reader takes for its stats: `.remote` / `.local`
+# interval the reader takes for its stats: `.remote` / `.local` for a
+# full range, `.plane.remote|local` for a piggyback half-plane and
+# `.trace.remote|local` for projected trace bits (ec/gather.py), so a
+# reader of one name never averages a full range with a part of one
 FETCH_SPAN = "ec.rebuild.fetch"
 
 

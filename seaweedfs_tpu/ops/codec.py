@@ -411,6 +411,10 @@ def get_codec(data_shards: int, parity_shards: int,
 # ---------------------------------------------------------------------------
 
 REPAIR_MAX_SUBSETS = 400   # cap on vanish-subset enumeration (RS(20,4))
+# rows a repair stream pads its symbol blocks to (a multiple of):
+# RS(10,4)'s plans have 50-56 bits by lost shard, and a compiled program
+# per bit count would compile in the middle of a repair
+REPAIR_ROW_BUCKET = 8
 REPAIR_RESTARTS = 3        # greedy restarts with shuffled candidate order
 
 

@@ -25,6 +25,12 @@ and CPU microseconds, whose ratio says whether the thread copies or
 waits. Transfer and survivor-fetch bytes are not doubled here: the
 stream's StageTimer (`h2d`, `d2h+mxu`) and the transport's
 TransportStats already hold them.
+
+Which route a streaming rebuild took is counted here as well
+(`repair_route`: piggyback / trace / full, and `repair_fallbacks` for
+an `auto` rebuild that had to leave its layout's single-shard route):
+a fall-back to the full gather is bit-identical and so shows nowhere
+else than in bytes moved.
 """
 
 from __future__ import annotations
@@ -39,13 +45,16 @@ class DispatchStats:
 
     _FIELDS = ("dispatches", "bitmat_uploads", "host_fallbacks",
                "device_bytes", "mesh_dispatches",
-               "read_bytes", "read_busy_us", "read_cpu_us")
+               "read_bytes", "read_busy_us", "read_cpu_us",
+               "repair_fallbacks")
+    REPAIR_ROUTES = ("piggyback", "trace", "full")
 
     def __init__(self):
         self._lock = make_lock("telemetry._lock")
         for f in self._FIELDS:
             setattr(self, f, 0)
         self._mesh_device_bytes: Dict[str, int] = {}
+        self._repair_route = dict.fromkeys(self.REPAIR_ROUTES, 0)
 
     def add(self, field: str, n: int = 1):
         with self._lock:
@@ -58,6 +67,11 @@ class DispatchStats:
             self.read_busy_us += int(busy_s * 1e6)
             self.read_cpu_us += int(cpu_s * 1e6)
 
+    def add_repair_route(self, route: str):
+        """One streaming rebuild finished on this route."""
+        with self._lock:
+            self._repair_route[route] += 1
+
     def add_mesh_device_bytes(self, device: str, n: int):
         """Payload bytes a sharded put landed on one device."""
         with self._lock:
@@ -68,6 +82,7 @@ class DispatchStats:
         with self._lock:
             snap = {f: getattr(self, f) for f in self._FIELDS}
             snap["mesh_device_bytes"] = dict(self._mesh_device_bytes)
+            snap["repair_route"] = dict(self._repair_route)
             return snap
 
 

@@ -29,6 +29,16 @@ from .http_util import (HttpError, HttpServer, Request, Response, Router,
                         traces_export_handler, traces_handler)
 
 
+def _tag_holder_read(bytes_read: int, bytes_sent: int):
+    """On the server span of a holder-side repair read: the range read
+    off the disk against what leaves the holder — the byte reduction of
+    the trace and half-plane routes, per request."""
+    span = tracing.current_span()
+    if span is not None:
+        span.tags["bytes_read"] = int(bytes_read)
+        span.tags["bytes_sent"] = int(bytes_sent)
+
+
 class VolumeServer:
     def __init__(self, port: int = 8080, host: str = "127.0.0.1",
                  directories=None, master_url: str = "127.0.0.1:9333",
@@ -665,6 +675,10 @@ class VolumeServer:
             # family (observe_mesh), not the flat kind counter
             if isinstance(total, (int, float)):
                 DEVICE_TELEMETRY_COUNTER.set_total(total, kind)
+            elif kind == "repair_route":
+                for route, n in total.items():
+                    DEVICE_TELEMETRY_COUNTER.set_total(
+                        n, f"repair_route.{route}")
         # connection-pool churn (process-global, same mirror pattern)
         from .http_util import pool_stats_snapshot
         for event, total in pool_stats_snapshot().items():
@@ -1326,6 +1340,7 @@ class VolumeServer:
                 416, f"range {offset}+{size} beyond shard size {shard.size}")
         data = np.frombuffer(shard.read_at(offset, size), dtype=np.uint8)
         planes = ops_codec.project_slab(data, masks)
+        _tag_holder_read(size, planes.nbytes)
         return Response(
             planes.tobytes(),
             headers={
@@ -1374,6 +1389,7 @@ class VolumeServer:
                 416, f"range {offset}+{size} beyond shard size {shard.size}")
         data = np.frombuffer(shard.read_at(offset, size), dtype=np.uint8)
         plane = ops_codec.pb_plane_slice(data, alpha, window, bit, side)
+        _tag_holder_read(size, plane.nbytes)
         return Response(
             plane.tobytes(),
             headers={

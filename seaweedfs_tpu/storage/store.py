@@ -486,7 +486,8 @@ class Store:
                         vid, base, local, present, missing, sources,
                         sized, stats, slab, window, hedge_ms, root,
                         mode)
-            if rebuilt is None and li.piggyback:
+            full = rebuilt is None
+            if full and li.piggyback:
                 # full coupled decode: readers follow the decode
                 # plan's src order (surviving data, then just enough
                 # parities), stripes clamp to sub-chunk windows
@@ -527,7 +528,7 @@ class Store:
                 observe_transport("pull", gstats, window=source.window)
                 if stats is not None:
                     stats["repair_mode"] = "full"
-            elif rebuilt is None:
+            elif full:
                 gather_present = self._health_survivor_mask(
                     present, local, sources, k, stats)
                 src = [i for i, p in enumerate(gather_present) if p][:k]
@@ -554,6 +555,10 @@ class Store:
                 observe_transport("pull", gstats, window=source.window)
                 if stats is not None:
                     stats["repair_mode"] = "full"
+            from ..ops import telemetry
+            telemetry.STATS.add_repair_route(
+                "full" if full else
+                "piggyback" if li.piggyback else "trace")
             t0 = _time.perf_counter()
             rebuild_ecx_file(base, ec_offset_width(base))
             ecx_s = _time.perf_counter() - t0
@@ -608,11 +613,14 @@ class Store:
         from ..ec import decoder as ec_decoder
         from ..ec import gather
         from ..ops import codec as ops_codec
+        from ..ops import telemetry
         from ..server.http_util import HttpError
+        from ..util import tracing
 
         def bail(reason: str):
             if mode == "piggyback":
                 raise VolumeError(f"-repair piggyback: {reason}")
+            telemetry.STATS.add("repair_fallbacks")
             if stats is not None:
                 stats["repair_fallback"] = reason
             return None
@@ -624,16 +632,18 @@ class Store:
         k = self.codec.k if self.codec is not None else DATA_SHARDS
         m = (self.codec.m if self.codec is not None
              else TOTAL_SHARDS - DATA_SHARDS)
-        try:
-            pplan = ops_codec.piggyback_plan(
-                k, m,
-                matrix_kind=(self.codec.matrix_kind
-                             if self.codec is not None else "vandermonde"),
-                matrix=(self.codec.matrix
-                        if self.codec is not None else None),
-                pairs=li.pairs)
-        except ValueError as e:
-            return bail(f"no piggyback scheme: {e}")
+        with tracing.Stage("ec.rebuild.plan", root) as planning:
+            try:
+                pplan = ops_codec.piggyback_plan(
+                    k, m,
+                    matrix_kind=(self.codec.matrix_kind
+                                 if self.codec is not None
+                                 else "vandermonde"),
+                    matrix=(self.codec.matrix
+                            if self.codec is not None else None),
+                    pairs=li.pairs)
+            except ValueError as e:
+                return bail(f"no piggyback scheme: {e}")
         if lost >= pplan.coupled:
             return bail(f"shard {lost} not coupled "
                         f"(coupled prefix is 0..{pplan.coupled - 1})")
@@ -688,6 +698,7 @@ class Store:
             raise
         from ..stats.metrics import observe_transport
         observe_transport("pull", gstats, window=source.window)
+        rstats["phases"]["plan"] = round(planning.t1 - planning.t0, 6)
         if stats is not None:
             stats.update(rstats)
         return rebuilt
@@ -703,11 +714,14 @@ class Store:
         from ..ec import decoder as ec_decoder
         from ..ec import gather
         from ..ops import codec as ops_codec
+        from ..ops import telemetry
         from ..server.http_util import HttpError
+        from ..util import tracing
 
         def bail(reason: str):
             if mode == "trace":
                 raise VolumeError(f"-repair trace: {reason}")
+            telemetry.STATS.add("repair_fallbacks")
             if stats is not None:
                 stats["repair_fallback"] = reason
             return None
@@ -719,15 +733,19 @@ class Store:
         m = (self.codec.m if self.codec is not None
              else TOTAL_SHARDS - DATA_SHARDS)
         helpers = [i for i, p in enumerate(present) if p and i != lost]
-        try:
-            plan = ops_codec.repair_plan(
-                k, m, lost, survivors=helpers,
-                matrix_kind=(self.codec.matrix_kind
-                             if self.codec is not None else "vandermonde"),
-                matrix=(self.codec.matrix
-                        if self.codec is not None else None))
-        except ValueError as e:
-            return bail(f"no repair scheme: {e}")
+        # a scheme search of ~0.5 s the first time a (lost, helpers)
+        # pair is seen, a cache hit after: a stage of the stream
+        with tracing.Stage("ec.rebuild.plan", root) as planning:
+            try:
+                plan = ops_codec.repair_plan(
+                    k, m, lost, survivors=helpers,
+                    matrix_kind=(self.codec.matrix_kind
+                                 if self.codec is not None
+                                 else "vandermonde"),
+                    matrix=(self.codec.matrix
+                            if self.codec is not None else None))
+            except ValueError as e:
+                return bail(f"no repair scheme: {e}")
         if mode == "auto" and plan.frac >= 1.0:
             return bail(f"no trace gain (frac={plan.frac:.3f})")
         shard_size = sized(plan.helpers)
@@ -760,6 +778,7 @@ class Store:
             raise
         from ..stats.metrics import observe_transport
         observe_transport("pull", gstats, window=source.window)
+        rstats["phases"]["plan"] = round(planning.t1 - planning.t0, 6)
         if stats is not None:
             stats.update(rstats)
         return rebuilt
