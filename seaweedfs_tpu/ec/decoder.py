@@ -166,7 +166,7 @@ def _write_relaid(timer: StageTimer, out, span: str, w: int, relayout) -> int:
         block = relayout()
         st.nbytes = block.nbytes
     with timer.stage("shard_write", w, span="ec.rebuild.write"):
-        out.write(block.tobytes())
+        out.write(block.data)
     return w
 
 
@@ -184,8 +184,9 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
     GF(2^8) matmul and the existing device kernels (PipelinedMatmul
     over the codec's device_fn) run it unchanged: one fused dispatch
     per slab, same as the full-RS decode. The 8 output planes are
-    interleaved back into shard bytes on the host (a packbits
-    transpose) and appended to the lost shard file.
+    interleaved back into shard bytes on the host (an 8x8 bit transpose
+    per 8 bytes, ops/codec.combine_planes_to_bytes) and appended to the
+    lost shard file.
 
     All-or-nothing like rebuild_ec_files_streaming: any failure removes
     the partial shard file before propagating, so the caller can fall
@@ -220,8 +221,7 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
     def write_block(planes, w):
         return _write_relaid(
             timer, out, "ec.rebuild.trace_unpack", w,
-            lambda: combine_planes_to_bytes(
-                np.asarray(planes, dtype=np.uint8), w))
+            lambda: combine_planes_to_bytes(planes, w))
 
     t_stream = time.perf_counter()
     try:

@@ -199,7 +199,7 @@ class LocalRepairReader:
         self.masks = [int(x) for x in masks]
         self.stats = stats or GatherStats()
 
-    def read(self, off: int, n: int, stripe_idx: int = 0) -> bytes:
+    def read(self, off: int, n: int, stripe_idx: int = 0) -> memoryview:
         from ..ops.codec import project_slab
         with tracing.Stage(self.fetch_span, self.span) as st:
             with open(self.path, "rb") as f:
@@ -212,7 +212,7 @@ class LocalRepairReader:
                                   self.masks)
             st.nbytes = planes.nbytes
         self.stats.add_fetch(planes.nbytes, st.t0, st.t1)
-        return planes.tobytes()
+        return planes.reshape(-1).data
 
 
 class RemotePlaneReader(RemoteShardReader):

@@ -455,24 +455,89 @@ class RepairPlan:
         return self.total_bits * ((width + 7) // 8)
 
 
+# Both ends of the trace route re-lay bits on the host: the holder turns
+# bytes into packed planes of trace bits, the rebuilder 8 packed planes
+# into bytes. Either runs beside a dozen threads doing the same, on
+# cores that share a memory bus and an interpreter lock, so what each
+# costs is the bytes its passes stream and how often it comes back for
+# the lock — whole-range numpy calls, few of them, and no temporary
+# wider than the range (the chip's own readings: PERF.md section 6,
+# PR 28).
+
 def project_slab(data: np.ndarray, masks) -> np.ndarray:
     """Holder-side projection: trace bits Tr(mask * data) packed
     little-bit-first per mask. data (w,) uint8 -> (len(masks),
-    ceil(w/8)) uint8. One LUT gather + packbits — cheap enough to run
-    on the volume server's host CPU."""
+    ceil(w/8)) uint8, the tail bits of a ragged w zero.
+
+    The masks (at most 8) fold into one table — bit j of lut[b] is
+    Tr(masks[j] * b) — so all of a byte's trace bits come from one
+    gather, made two bytes a lookup (a 65536-entry table of pairs:
+    half the lookups and half of take()'s index copy). Plane j is then
+    bit j of every gathered byte, packed. Cheap enough to run on the
+    volume server's host CPU."""
     data = np.ascontiguousarray(data, dtype=np.uint8)
     m = np.asarray(list(masks), dtype=np.uint8)
-    bits = gf256.TRACE_MUL[m[:, None], data[None, :]]
-    return np.packbits(bits, axis=1, bitorder="little")
+    nm = len(m)
+    if nm > 8:      # a byte's trace bits have to fit a byte of the table
+        raise ValueError(f"at most 8 masks to a projection, got {nm}")
+    lut = np.bitwise_or.reduce(
+        gf256.TRACE_MUL[m] << np.arange(nm, dtype=np.uint8)[:, None],
+        axis=0, dtype=np.uint8).astype("<u2")
+    pair_lut = ((lut << 8)[:, None] | lut[None, :]).reshape(-1)
+    w = data.shape[0]
+    even = w - (w & 1)
+    traces = np.empty(w, dtype=np.uint8)
+    # uint16 indices cannot leave the table: "wrap" only spares take()
+    # the bounce buffer its default mode keeps for out=
+    np.take(pair_lut, data[:even].view("<u2"),
+            out=traces[:even].view("<u2"), mode="wrap")
+    if w & 1:
+        traces[-1] = lut[data[-1]]
+    out = np.empty((nm, (w + 7) // 8), dtype=np.uint8)
+    plane = np.empty(w, dtype=np.uint8)
+    for j in range(nm):
+        np.bitwise_and(traces, 1 << j, out=plane)
+        out[j] = np.packbits(plane, bitorder="little")   # non-zero is 1
+    return out
+
+
+# the 8x8 bit transpose's three block swaps: 1x1 blocks of each 2x2,
+# 2x2 of each 4x4, 4x4 of the 8x8
+_BIT_SWAPS = ((7, 0x00AA00AA00AA00AA), (14, 0x0000CCCC0000CCCC),
+              (28, 0x00000000F0F0F0F0))
+
+
+def _transpose_bits8(x: np.ndarray) -> None:
+    """Transpose, in place, the 8x8 bit matrix in every little-endian
+    64-bit word of ``x`` (byte i of a word is row i, bit j of it column
+    j: bit 8i+j <-> bit 8j+i). A round is the classic
+    ``t = (x ^ (x >> s)) & mask; x ^= t ^ (t << s)``; t's bits and their
+    shifted copies never meet, so ``t ^ (t << s)`` is ``t * (2**s + 1)``
+    and a round is five passes over x and one scratch array."""
+    t = np.empty_like(x)
+    for s, mask in _BIT_SWAPS:
+        np.right_shift(x, s, out=t)
+        np.bitwise_xor(t, x, out=t)
+        np.bitwise_and(t, mask, out=t)
+        np.multiply(t, (1 << s) + 1, out=t)
+        np.bitwise_xor(x, t, out=x)
 
 
 def combine_planes_to_bytes(planes: np.ndarray, width: int) -> np.ndarray:
     """Rebuilder-side interleave: 8 packed output bit-planes (8,
     ceil(width/8)) -> the lost shard's bytes (width,). Plane b holds
-    bit b of every output byte."""
-    bits = np.unpackbits(np.ascontiguousarray(planes, dtype=np.uint8),
-                         axis=1, count=width, bitorder="little")
-    return np.packbits(bits, axis=0, bitorder="little").reshape(-1)
+    bit b of every output byte, so byte q of the 8 planes, laid side by
+    side as one 64-bit word, is the bit transpose of output bytes
+    8q..8q+7: lay them so, transpose every word, and the words are the
+    bytes."""
+    planes = np.asarray(planes, dtype=np.uint8)
+    stride = (width + 7) // 8
+    out = np.empty(stride * 8, dtype=np.uint8)
+    rows = out.reshape(stride, 8)
+    for b in range(8):
+        rows[:, b] = planes[b, :stride]
+    _transpose_bits8(out.view("<u8"))
+    return out[:width]
 
 
 class _PlanLRU:

@@ -1313,11 +1313,13 @@ class VolumeServer:
     def admin_ec_shard_repair_read(self, req: Request):
         """Projected shard read for single-shard trace repair: read the
         ``offset``/``size`` range of a local shard, apply the caller's
-        GF(2^8) trace masks locally (one LUT gather + packbits), and
+        GF(2^8) trace masks locally (ops/codec.project_slab: one gather
+        through the masks' folded table, then a pack per plane), and
         return only the packed repair-symbol bit-planes — ``len(masks)``
-        planes of ``ceil(size/8)`` bytes each, concatenated. This is
-        where the sub-k*slab byte reduction happens: the full range is
-        read off disk but never leaves the holder."""
+        (at most 8) planes of ``ceil(size/8)`` bytes each,
+        little-bit-first, in mask order, concatenated. This is where the
+        sub-k*slab byte reduction happens: the full range is read off
+        disk but never leaves the holder."""
         from ..ops import codec as ops_codec
         vid = int(req.query["volume"])
         sid = int(req.query["shard"])
@@ -1333,8 +1335,9 @@ class VolumeServer:
             raise HttpError(400, "need offset/size/masks query params")
         if offset < 0 or size <= 0:
             raise HttpError(400, f"bad range {offset}+{size}")
-        if not masks or any(not (0 < x < 256) for x in masks):
-            raise HttpError(400, f"masks must be 1..255, got {masks}")
+        if not 0 < len(masks) <= 8 or any(not (0 < x < 256) for x in masks):
+            raise HttpError(
+                400, f"need 1 to 8 masks, each 1..255, got {masks}")
         if offset + size > shard.size:
             raise HttpError(
                 416, f"range {offset}+{size} beyond shard size {shard.size}")
@@ -1342,7 +1345,7 @@ class VolumeServer:
         planes = ops_codec.project_slab(data, masks)
         _tag_holder_read(size, planes.nbytes)
         return Response(
-            planes.tobytes(),
+            planes.reshape(-1).data,    # the socket reads the array itself
             headers={
                 "X-Repair-Planes": str(planes.shape[0]),
                 "X-Repair-Stride": str(planes.shape[1]),

@@ -33,6 +33,7 @@ from seaweedfs_tpu.ec.gather import (GatherStats, LocalRepairReader,
 from seaweedfs_tpu.ops.codec import (NumpyCodec, combine_planes_to_bytes,
                                      project_slab, repair_gain,
                                      repair_plan)
+from seaweedfs_tpu.ops.gf256 import TRACE_MUL
 from seaweedfs_tpu.server.http_util import (HttpError, HttpServer,
                                             Response, Router, http_call,
                                             parse_range)
@@ -129,6 +130,116 @@ def test_project_combine_roundtrip(k, m):
     out = combine_planes_to_bytes(
         np.asarray(combined, dtype=np.uint8), w)
     assert np.array_equal(out, shards[lost])
+
+
+# -- the two bit re-layouts against their definition -------------------------
+#
+# The definition, spelled here and nowhere shared with the code under
+# test: plane j of a projection is Tr(masks[j] * byte) of every byte,
+# packed little-bit-first (8 bytes a plane byte, a ragged tail padded
+# with zero bits); byte n of a combine has bit b = bit n of plane b.
+
+RELAYOUT_WIDTHS = [1, 7, 8, 9, 63, 64, 65, 4097, (1 << 20) + 3]
+
+
+def _projection_by_definition(data, masks):
+    out = np.zeros((len(masks), (len(data) + 7) // 8), dtype=np.uint8)
+    for j, mask in enumerate(masks):
+        bits = TRACE_MUL[mask][np.asarray(data)].astype(np.uint8)
+        for i in range(8):
+            lane = bits[i::8]
+            out[j, :len(lane)] |= lane << i
+    return out
+
+
+def _bytes_by_definition(planes, width):
+    n = np.arange(width)
+    out = np.zeros(width, dtype=np.uint8)
+    for b in range(8):
+        out |= ((planes[b, n // 8] >> (n % 8)) & 1).astype(np.uint8) << b
+    return out
+
+
+def _masks(rng, count):
+    return [int(x) for x in rng.choice(np.arange(1, 256), count,
+                                       replace=False)]
+
+
+@pytest.mark.parametrize("n_masks", range(1, 9))
+@pytest.mark.parametrize("w", RELAYOUT_WIDTHS)
+def test_project_slab_is_the_definition(w, n_masks):
+    rng = np.random.default_rng([w, n_masks])
+    masks = _masks(rng, n_masks)
+    # what a holder hands it: a read-only view of the bytes it read
+    data = np.frombuffer(
+        rng.integers(0, 256, w, dtype=np.uint8).tobytes(), dtype=np.uint8)
+    assert not data.flags.writeable
+    planes = project_slab(data, masks)
+    assert planes.dtype == np.uint8 and planes.flags.c_contiguous
+    assert planes.shape == (n_masks, (w + 7) // 8)
+    assert np.array_equal(planes, _projection_by_definition(data, masks))
+
+
+@pytest.mark.parametrize("w", RELAYOUT_WIDTHS)
+def test_project_slab_of_a_strided_slice(w):
+    rng = np.random.default_rng([w, 99])
+    masks = _masks(rng, 5)
+    wide = rng.integers(0, 256, 3 * w + 2, dtype=np.uint8)
+    data = wide[1::3][:w]
+    assert len(data) == w and (w == 1 or not data.flags.c_contiguous)
+    assert np.array_equal(project_slab(data, masks),
+                          _projection_by_definition(data, masks))
+    # and the input is left as it was
+    assert np.array_equal(data, wide[1::3][:w])
+
+
+@pytest.mark.parametrize("w", RELAYOUT_WIDTHS)
+def test_combine_planes_is_the_definition(w):
+    rng = np.random.default_rng([w, 7])
+    planes = rng.integers(0, 256, (8, (w + 7) // 8), dtype=np.uint8)
+    before = planes.copy()
+    out = combine_planes_to_bytes(planes, w)
+    assert out.dtype == np.uint8 and out.shape == (w,)
+    assert out.flags.c_contiguous    # handed to the shard file as it is
+    assert np.array_equal(out, _bytes_by_definition(planes, w))
+    assert np.array_equal(planes, before)
+    # a drained block may be wider than the stripe's stride (the
+    # pipeline pads to its bucket): the columns beyond it are not read
+    wider = np.concatenate(
+        [planes, rng.integers(0, 256, (8, 3), dtype=np.uint8)], axis=1)
+    assert np.array_equal(combine_planes_to_bytes(wider[:, :-1], w), out)
+    # nor does a view with strides of its own change the bytes
+    assert np.array_equal(
+        combine_planes_to_bytes(np.asfortranarray(planes), w), out)
+
+
+def _dual_basis_masks():
+    """The masks m_b with Tr(m_b * x) = bit b of x, found by search of
+    the table (the trace form is non-degenerate, so each exists)."""
+    x = np.arange(256)
+    out = []
+    for b in range(8):
+        hits = [m for m in range(1, 256)
+                if np.array_equal(TRACE_MUL[m], (x >> b) & 1)]
+        assert len(hits) == 1
+        out.append(hits[0])
+    return out
+
+
+@pytest.mark.parametrize("w", RELAYOUT_WIDTHS)
+def test_project_unit_masks_then_combine_is_identity(w):
+    # the eight masks whose traces are a byte's own bits 0..7 (the dual
+    # of the bit basis) project data onto its bit-planes, and the
+    # combine of all eight planes is the data again
+    rng = np.random.default_rng([w, 3])
+    data = rng.integers(0, 256, w, dtype=np.uint8)
+    planes = project_slab(data, _dual_basis_masks())
+    assert np.array_equal(combine_planes_to_bytes(planes, w), data)
+
+
+def test_project_slab_refuses_a_ninth_mask():
+    with pytest.raises(ValueError):
+        project_slab(np.zeros(16, dtype=np.uint8), list(range(1, 10)))
 
 
 # -- file-level bit identity on every backend -------------------------------
@@ -238,6 +349,68 @@ class RepairHolder:
 
     def stop(self):
         self.server.stop()
+
+
+# -- the wire format of shard_repair_read, pinned ---------------------------
+
+@pytest.mark.parametrize("offset,size,masks", [
+    (0, 64, [3]),
+    (16, 40, [3, 5]),
+    (5, 4097, [1, 2, 4, 8, 16, 32]),
+    (1, 9, [255, 254, 253, 252, 251, 250, 249, 248]),
+])
+def test_shard_repair_read_wire_format(tmp_path, offset, size, masks):
+    """The real handler behind a real socket: a body of
+    ``len(masks) * ceil(size / 8)`` bytes, plane after plane in mask
+    order, each the definition's bits little-bit-first, and the two
+    headers — what a rebuilder of any version of this code expects of a
+    holder of any other."""
+    from types import SimpleNamespace
+
+    from seaweedfs_tpu.ec.ec_volume import EcVolumeShard
+    from seaweedfs_tpu.server.volume_server import VolumeServer
+    vid, sid = 7, 2
+    raw = np.random.default_rng(size).integers(
+        0, 256, 8192, dtype=np.uint8)
+    base = os.path.join(str(tmp_path), str(vid))
+    with open(base + to_ext(sid), "wb") as f:
+        f.write(raw.tobytes())
+    shard = EcVolumeShard(base, vid, sid)
+    holder = SimpleNamespace(store=SimpleNamespace(
+        find_ec_volume=lambda v: SimpleNamespace(shards={sid: shard})
+        if v == vid else None))
+    router = Router()
+    router.add("POST", "/admin/ec/shard_repair_read",
+               lambda req: VolumeServer.admin_ec_shard_repair_read(
+                   holder, req))
+    server = HttpServer(0, router).start()
+    conn = http.client.HTTPConnection("127.0.0.1", server.port)
+    try:
+        conn.request(
+            "POST", f"/admin/ec/shard_repair_read?volume={vid}&shard={sid}"
+                    f"&offset={offset}&size={size}"
+                    f"&masks={','.join(map(str, masks))}")
+        resp = conn.getresponse()
+        body = resp.read()
+        stride = (size + 7) // 8
+        assert resp.status == 200
+        assert resp.getheader("X-Repair-Planes") == str(len(masks))
+        assert resp.getheader("X-Repair-Stride") == str(stride)
+        assert resp.getheader("Content-Length") == str(len(masks) * stride)
+        assert len(body) == len(masks) * stride
+        assert body == _projection_by_definition(
+            raw[offset:offset + size], masks).tobytes()
+        # nine masks cannot be one table lookup a byte: refused, not run
+        conn.request(
+            "POST", f"/admin/ec/shard_repair_read?volume={vid}&shard={sid}"
+                    f"&offset=0&size=8&masks=1,2,3,4,5,6,7,8,9")
+        resp = conn.getresponse()
+        resp.read()
+        assert resp.status == 400
+    finally:
+        conn.close()
+        server.stop()
+        shard.close()
 
 
 def test_remote_repair_symbol_bytes_and_failover(tmp_path):
@@ -536,8 +709,8 @@ def test_cluster_trace_repair_end_to_end(cluster3):
         assert resp.status == 200
         assert resp.getheader("X-Repair-Planes") == "2"
         assert resp.getheader("X-Repair-Stride") == "5"
-        expect = project_slab(shard_head[16:56], [3, 5])
-        assert body == expect.tobytes()
+        expect = _projection_by_definition(shard_head[16:56], [3, 5])
+        assert body == expect.tobytes() and len(body) == 2 * 5
         # beyond the shard -> 416
         conn.request("POST", f"/admin/ec/shard_repair_read?volume={vid}"
                              f"&shard={some_sid}&offset={total - 4}"
