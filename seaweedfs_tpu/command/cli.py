@@ -203,14 +203,6 @@ def _load_tier_config(path: str):
 
 def cmd_volume(args):
     _apply_security_config(args)
-    if getattr(args, "meshCoordinator", ""):
-        # join the multi-host device mesh BEFORE any jax work: the
-        # -ec.backend mesh/tpu codecs then compile over the global
-        # device list, collectives riding ICI intra-host and DCN
-        # across hosts (SURVEY §5.8; parallel/multihost.py)
-        from ..parallel import init_distributed
-        init_distributed(args.meshCoordinator, args.meshProcesses,
-                         args.meshProcessId)
     from ..server.volume_server import VolumeServer
     _load_tier_config(args.tierConfig)
     dirs = args.dir.split(",")
@@ -919,15 +911,6 @@ def build_parser() -> argparse.ArgumentParser:
     v.add_argument("-pulseSeconds", type=int, default=5)
     v.add_argument("-ec.backend", dest="ec_backend", default="auto",
                    choices=["auto", "numpy", "native", "tpu", "mesh"])
-    v.add_argument("-mesh.coordinator", dest="meshCoordinator",
-                   default="",
-                   help="host:port of process 0 — joins a multi-host "
-                        "device mesh via jax.distributed before the "
-                        "EC codec compiles (DCN tier)")
-    v.add_argument("-mesh.processes", dest="meshProcesses", type=int,
-                   default=1)
-    v.add_argument("-mesh.processId", dest="meshProcessId", type=int,
-                   default=0)
     v.add_argument("-fastPort", type=int, default=0,
                    help="native C++ read plane port (0 = auto-pick, "
                         "-1 = disabled); plain needle GETs are served "

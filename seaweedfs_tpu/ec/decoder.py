@@ -196,7 +196,7 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
     from .constants import PARITY_SHARDS
     codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
     if pipelined is None:
-        pipelined = codec.backend in ("tpu", "mesh")
+        pipelined = codec.pipelined
     if lost_sid != plan.lost:
         raise ValueError(f"plan repairs shard {plan.lost}, not {lost_sid}")
     before = telemetry.STATS.snapshot()
@@ -330,7 +330,7 @@ def rebuild_ec_file_piggyback(base_name: str, lost_sid: int, source,
     from .constants import PARITY_SHARDS
     codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
     if pipelined is None:
-        pipelined = codec.backend in ("tpu", "mesh")
+        pipelined = codec.pipelined
     if lost_sid != rplan.lost:
         raise ValueError(f"plan repairs shard {rplan.lost}, not {lost_sid}")
     alpha = rplan.alpha

@@ -284,7 +284,7 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
     codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
     k, m = codec.k, codec.m
     if pipelined is None:
-        pipelined = codec.backend in ("tpu", "mesh")
+        pipelined = codec.pipelined
     piggyback = layout == "piggyback"
     pplan = window = None
     if piggyback:
@@ -411,7 +411,7 @@ def write_ec_files_spread(base_name: str, sink,
     encode-side analogue of the streaming rebuild's gather stats."""
     codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
     if pipelined is None:
-        pipelined = codec.backend in ("tpu", "mesh")
+        pipelined = codec.pipelined
     from ..ops import telemetry
     before = telemetry.STATS.snapshot()
     # the stream's root span (ec.encode.stream, current here): the
@@ -508,7 +508,7 @@ def rebuild_ec_files(base_name: str,
     codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
     k, total = codec.k, codec.total
     if pipelined is None:
-        pipelined = codec.backend in ("tpu", "mesh")
+        pipelined = codec.pipelined
     piggyback = layout is not None and getattr(layout, "piggyback", False)
     present = [os.path.exists(base_name + to_ext(i)) for i in range(total)]
     missing = [i for i, p in enumerate(present) if not p]
@@ -819,7 +819,7 @@ def rebuild_ec_files_streaming(base_name: str,
     codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
     k, total = codec.k, codec.total
     if pipelined is None:
-        pipelined = codec.backend in ("tpu", "mesh")
+        pipelined = codec.pipelined
     if not missing:
         return []
     if sum(present) < k:

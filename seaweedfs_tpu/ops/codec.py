@@ -147,12 +147,15 @@ class ReedSolomonCodec:
 
     Subclasses implement _matmul(coeffs, data) — the GF(2^8) matrix-vector
     product over byte rows — which is the only compute-heavy primitive.
-    Device-backed subclasses additionally expose device_fn() so
-    ops/pipeline.PipelinedMatmul can stream slabs through their kernel
-    (encode and rebuild share the same pipelined hot path).
+    A codec says itself whether encode and rebuild stream their slabs
+    through ops/pipeline.PipelinedMatmul: the device codecs
+    (ops/rs_tpu.DeviceCodec) set ``pipelined`` and carry the hooks that
+    stream needs (device_fn, pipeline_width_bucket); a host codec
+    computes each slab where it is read.
     """
 
     backend = "abstract"
+    pipelined = False
     # 0 = never delegate; device codecs override with the env default
     small_dispatch_bytes = 0
 
@@ -174,20 +177,6 @@ class ReedSolomonCodec:
     # -- primitive ---------------------------------------------------------
     def _matmul(self, coeffs: np.ndarray, data: np.ndarray) -> np.ndarray:
         raise NotImplementedError
-
-    # -- device streaming hooks (ops/pipeline.PipelinedMatmul) -------------
-    def device_fn(self, coeffs: np.ndarray, width: int):
-        """Device-backed codecs return (jitted fn, device-resident
-        constant, put) for `width`-wide slabs: ``fn(constant,
-        put(slab))`` dispatches asynchronously and the constant stays
-        resident across slabs. Host codecs return None (no pipeline)."""
-        return None
-
-    def pipeline_width_bucket(self, n: int, cap: Optional[int]) -> int:
-        """Bucket a slab width for compiled-executable reuse; mesh
-        codecs additionally pad to their shard split."""
-        from .rs_tpu import width_bucket
-        return width_bucket(n, cap)
 
     # -- public API --------------------------------------------------------
     def encode(self, data: np.ndarray) -> np.ndarray:

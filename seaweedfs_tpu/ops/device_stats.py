@@ -1,8 +1,8 @@
 """Device-runtime observability plane: compile vs execute, split open.
 
-Every jitted EC entry point (the `rs_tpu` factories, `rs_pallas`'s
-fused kernel, `MeshCodec._fn`, the sharded encode/rebuild programs —
-and `PipelinedMatmul` transitively through all of them) routes its
+Every jitted EC entry point (`rs_tpu._packed_fn`, `rs_pallas`'s
+fused kernel, `MeshCodec._fn` — and `PipelinedMatmul` transitively
+through all of them) routes its
 compiled-executable lifecycle through this module via `wrap()`:
 
 - **Explicit compile/execute separation.** The wrapper AOT-compiles
@@ -42,7 +42,7 @@ compiled-executable lifecycle through this module via `wrap()`:
 Everything lands in `snapshot()` → mirrored to `ec_xla_*` /
 `ec_const_cache_*` metric families on `/metrics` (aggregated onto the
 master's `/cluster/metrics`), `GET /admin/devices`, shell
-`cluster.devices`, and bench.py's compile_s/steady-state split.
+`cluster.devices`, and the benchmark's `compiles_in_window` check.
 
 jax is imported lazily (device inventory only), matching
 telemetry.py: this module must import on hosts with no
@@ -155,7 +155,8 @@ DEVICE_STATS = DeviceStats()
 
 
 def delta(before: dict) -> dict:
-    """Movement since a snapshot() — bench.py's per-phase report."""
+    """Movement since a snapshot() — a phase's own compiles and
+    dispatches (chip_smoke.py's device_proof line)."""
     now = DEVICE_STATS.snapshot()
     out = {}
     for field in ("compiles", "compile_seconds", "recompiles",

@@ -360,9 +360,8 @@ def decode_coeff_rows(matrix: np.ndarray, k: int, survivor_rows,
 
     Data rows come from the inverse of the first-k-survivors submatrix,
     parity rows from matrix[row] @ that inverse — one derivation shared
-    by ReedSolomonCodec.decode_plan, ec/encoder._rebuild_coeffs and
-    parallel/sharded_ec.decode_bitmat, so the three call sites cannot
-    drift apart.
+    by ReedSolomonCodec.decode_plan and ec/encoder._rebuild_coeffs, so
+    the two call sites cannot drift apart.
     """
     src = list(survivor_rows)[:k]
     if inv is None:

@@ -19,11 +19,10 @@ rows) and rebuild (coeffs = fused decode-plan rows vs survivors) both
 reduce to this. Only the r output rows round-trip back to the host; for
 encode that is m/k of the h2d traffic.
 
-The device kernel is pluggable: pass ``codec`` and the stream runs
-through ``codec.device_fn()`` — single-chip TpuCodec and the SPMD
-MeshCodec (sharded payloads, replicated device-resident coefficients)
-both pipeline through the same loop. Without a codec the single-device
-rs_tpu kernel is used directly (bench/raw callers).
+The device kernel is the codec's: the stream runs through
+``codec.device_fn()`` — single-chip TpuCodec and the SPMD MeshCodec
+(sharded payloads, replicated device-resident coefficients) both
+pipeline through the same loop.
 """
 
 from __future__ import annotations
@@ -37,7 +36,6 @@ from typing import Iterable, Iterator, Optional, Tuple
 
 import numpy as np
 
-from .rs_tpu import fn_and_bitmat, width_bucket
 from .telemetry import STATS
 from ..util import tracing
 from ..util.profiling import StageTimer, mirror_stages_to_profiler
@@ -53,11 +51,16 @@ class PipelinedMatmul:
     read-ahead in the reader queue.
     """
 
-    def __init__(self, coeffs: np.ndarray,
+    def __init__(self, coeffs: np.ndarray, codec,
                  max_width: Optional[int] = 32 << 20, depth: int = 4,
                  prefetch: int = 3, drain_threads: int = 2,
                  timer: Optional[StageTimer] = None,
-                 codec=None, pieces: bool = False):
+                 pieces: bool = False):
+        if not codec.pipelined:
+            raise TypeError(
+                f"PipelinedMatmul streams slabs through a device codec's "
+                f"device_fn; the {codec.backend!r} codec computes on the "
+                f"host and has none — call its encode/reconstruct instead")
         coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
         self.r, self.k = coeffs.shape
         # the widest slab stream() accepts, and the bucket full slabs
@@ -75,35 +78,12 @@ class PipelinedMatmul:
         # the full slab; codecs without drain_pieces yield one piece
         self.pieces = bool(pieces)
         self._coeffs = coeffs
-        self._bitmat_dev = None
-        self._put = None
 
     def _bucket(self, width: int) -> int:
-        if self.codec is not None:
-            return self.codec.pipeline_width_bucket(width, self.max_width)
-        return width_bucket(width, self.max_width)
-
-    def _fn(self, width: int):
-        """Kernel for this width from the codec (mesh-sharded program
-        with device-resident replicated coefficients, or the single-chip
-        kernel) or, codec-less, the platform rs_tpu kernel (fused Pallas
-        on TPU, packed-popcount XLA elsewhere). Constants upload on
-        first use — the choice must happen at stream time, after the
-        backend is known."""
-        if self.codec is not None:
-            fn, self._bitmat_dev, self._put = \
-                self.codec.device_fn(self._coeffs, width)
-            return fn
-        fn, bitmat_np = fn_and_bitmat(self._coeffs, width)
-        if self._bitmat_dev is None:
-            import jax.numpy as jnp
-            self._bitmat_dev = jnp.asarray(bitmat_np)
-            STATS.add("bitmat_uploads")
-        return fn
+        return self.codec.pipeline_width_bucket(width, self.max_width)
 
     def stream(self, slabs: Iterable[Tuple[object, np.ndarray]]
                ) -> Iterator[Tuple[object, np.ndarray, np.ndarray]]:
-        import jax.numpy as jnp
         mirror_stages_to_profiler()
 
         q: "queue.Queue" = queue.Queue(maxsize=self.prefetch)
@@ -174,8 +154,13 @@ class PipelinedMatmul:
                         padded[:, :w] = data
                     else:
                         padded = data
-                    fn = self._fn(bucket)            # also uploads bitmat
-                    put = self._put or jnp.asarray
+                    # the codec's kernel for this width (mesh-sharded
+                    # program or single-chip kernel), its constant
+                    # (uploaded on first use, device-resident after) and
+                    # its put: chosen at stream time, once the backend
+                    # is known
+                    fn, const, put = self.codec.device_fn(self._coeffs,
+                                                          bucket)
                     t0 = time.perf_counter()
                     dev = put(padded)                # h2d (blocking copy)
                     if timer is not None:
@@ -185,7 +170,7 @@ class PipelinedMatmul:
                     up.nbytes = padded.nbytes
                 STATS.add("dispatches")
                 STATS.add("device_bytes", data.nbytes)
-                out = fn(self._bitmat_dev, dev)      # async dispatch
+                out = fn(const, dev)                 # async dispatch
                 fut = drain_pool.submit(fetch, out, self.r * bucket, w)
                 pending.append((meta, data, fut, w))
                 if len(pending) >= self.depth:

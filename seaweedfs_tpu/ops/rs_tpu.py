@@ -30,10 +30,11 @@ from typing import Optional
 
 import numpy as np
 
-from .codec import ReedSolomonCodec
+from .codec import ReedSolomonCodec, _ConstCache, small_dispatch_default
 from . import device_stats
 from . import gf256
-from ..util import config
+from .telemetry import STATS
+from ..util import config, tracing
 from ..util.locks import make_lock
 
 #: lru maxsize for the jit factories below — read once at import, a
@@ -57,12 +58,15 @@ def _jax():
     return jax, jnp
 
 
-@functools.lru_cache(maxsize=_JIT_CACHE_SIZE)
-def _coded_fn(k: int, r: int, n: int):
-    """Jitted (bitmat (k*8, r*8) int8, data (k, n) uint8) -> (r, n) uint8."""
+def bitplane_program(k: int, r: int, n: int):
+    """Un-jitted (bitmat (k*8, r*8) int8, data (k, n) uint8) -> (r, n)
+    uint8: the bit-plane dot of the module docstring as an XLA program.
+    MeshCodec shards it over the chips of a host (the only caller: one
+    chip runs the same math as the fused Pallas kernel, ops/rs_pallas,
+    which keeps the 8x bit-plane intermediate out of HBM)."""
     jax, jnp = _jax()
 
-    def fn(bitmat, data):
+    def program(bitmat, data):
         shifts = jnp.arange(8, dtype=jnp.uint8)
         # unpack to bit-planes: row j*8+l is bit l of input shard j
         bits = ((data[:, None, :] >> shifts[None, :, None]) & 1)
@@ -76,18 +80,11 @@ def _coded_fn(k: int, r: int, n: int):
         weights = (jnp.uint8(1) << shifts)[None, :, None]
         return (ybits * weights).sum(axis=1, dtype=jnp.uint8)
 
-    return device_stats.wrap(jax.jit(fn), "rs_tpu._coded_fn")
+    return program
 
 
-@functools.lru_cache(maxsize=_JIT_CACHE_SIZE)
-def _bitmat_cached(coeff_bytes: bytes, r: int, k: int):
-    coeffs = np.frombuffer(coeff_bytes, dtype=np.uint8).reshape(r, k)
-    return gf256.bit_matrix(coeffs).astype(np.int8)
-
-
-@functools.lru_cache(maxsize=_JIT_CACHE_SIZE)
-def _packed_fn(k: int, r: int, n: int):
-    """Jitted (packed bitmat (ceil(k*8/32), r*8) uint32, data (k, n)
+def packed_program(k: int, r: int, n: int):
+    """Un-jitted (packed bitmat (ceil(k*8/32), r*8) uint32, data (k, n)
     uint8) -> (r, n) uint8 — the AND/popcount form of the GF(2) matmul.
 
     The bit-plane dot lifts the payload 8x and feeds the CPU a
@@ -107,7 +104,7 @@ def _packed_fn(k: int, r: int, n: int):
         # flat-geometry matrices (parity rows, decode coeffs, repair
         # rows: r*8*nw in the hundreds): full unroll traces in
         # milliseconds and lets XLA see every constant index
-        def fn(bmp, data):
+        def program(bmp, data):
             d32 = data.astype(jnp.uint32)
             words = []
             for wi in range(nw):
@@ -135,7 +132,7 @@ def _packed_fn(k: int, r: int, n: int):
         # bomb — tens of minutes on CPU. Same math, rolled: lax.scan
         # over output bytes keeps the graph O(1) in r and k, and the
         # per-step live set at nw*n words.
-        def fn(bmp, data):
+        def program(bmp, data):
             d32 = data.astype(jnp.uint32)
             pad = nw * 4 - k
             if pad:
@@ -159,7 +156,16 @@ def _packed_fn(k: int, r: int, n: int):
                 row, None, bmp.T.reshape(r, 8, nw))
             return out
 
-    return device_stats.wrap(jax.jit(fn), "rs_tpu._packed_fn")
+    return program
+
+
+@functools.lru_cache(maxsize=_JIT_CACHE_SIZE)
+def _packed_fn(k: int, r: int, n: int):
+    """packed_program jitted for one device: what a `tpu` or `mesh`
+    codec dispatches where JAX_PLATFORMS=cpu asked for the CPU."""
+    jax, _ = _jax()
+    return device_stats.wrap(jax.jit(packed_program(k, r, n)),
+                             "rs_tpu._packed_fn")
 
 
 @functools.lru_cache(maxsize=_JIT_CACHE_SIZE)
@@ -168,12 +174,8 @@ def _packed_bitmat(coeff_bytes: bytes, r: int, k: int):
     return gf256.pack_bit_matrix(coeffs)
 
 
-for _name, _factory in (("rs_tpu._coded_fn", _coded_fn),
-                        ("rs_tpu._bitmat_cached", _bitmat_cached),
-                        ("rs_tpu._packed_fn", _packed_fn),
-                        ("rs_tpu._packed_bitmat", _packed_bitmat)):
-    device_stats.register_jit_factory(_name, _factory)
-del _name, _factory
+device_stats.register_jit_factory("rs_tpu._packed_fn", _packed_fn)
+device_stats.register_jit_factory("rs_tpu._packed_bitmat", _packed_bitmat)
 
 
 #: functools.lru_cache does not serialize concurrent misses: two reader
@@ -223,13 +225,18 @@ def width_bucket(n: int, cap: Optional[int]) -> int:
     return bucket if cap is None else min(bucket, cap)
 
 
-class TpuCodec(ReedSolomonCodec):
-    """JAX backend on one TPU chip. Computes on the CPU only where
+class DeviceCodec(ReedSolomonCodec):
+    """What the JAX backends share: chunked dispatch with every chunk
+    issued before any is drained, device-resident constants, and the
+    hooks ops/pipeline.PipelinedMatmul runs a stream through
+    (device_fn, pipeline_width_bucket). As it stands it dispatches on
+    one device; parallel/mesh_codec.MeshCodec lays the same dispatch
+    over the chips of a host. Both compute on the CPU only where
     JAX_PLATFORMS=cpu asks for it (tests, rehearsals); any other
     platform is an error at the first device touch (on_tpu). Output is
     bit-identical everywhere."""
 
-    backend = "tpu"
+    pipelined = True
 
     def __init__(self, data_shards: int, parity_shards: int,
                  matrix_kind: str = "vandermonde",
@@ -237,16 +244,17 @@ class TpuCodec(ReedSolomonCodec):
                  small_dispatch_bytes: int = None):
         super().__init__(data_shards, parity_shards, matrix_kind)
         self.chunk_bytes = int(chunk_bytes)
-        from .codec import _ConstCache, small_dispatch_default
         self.small_dispatch_bytes = (
             small_dispatch_default() if small_dispatch_bytes is None
             else int(small_dispatch_bytes))
         self._consts = _ConstCache()
 
     def device_fn(self, coeffs: np.ndarray, width: int):
-        """(fn, device-resident constant, put) for `width`-wide slabs;
-        the constant (fused/packed bitmat) uploads once per coefficient
-        matrix and stays device-resident across the stream."""
+        """(fn, device-resident constant, put) for `width`-wide slabs:
+        ``fn(constant, put(slab))`` dispatches asynchronously. Here the
+        platform's single-device kernel (fn_and_bitmat); the constant
+        (fused/packed bitmat) uploads once per coefficient matrix and
+        stays device-resident across the stream."""
         import jax.numpy as jnp
         coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
         fn, const_host = fn_and_bitmat(coeffs, width)
@@ -254,56 +262,58 @@ class TpuCodec(ReedSolomonCodec):
                                      lambda: jnp.asarray(const_host))
         return fn, const_dev, jnp.asarray
 
+    def pipeline_width_bucket(self, n: int, cap: Optional[int]) -> int:
+        """The compiled width an n-wide slab is padded to."""
+        return width_bucket(n, cap)
+
+    def _chunk_bucket(self, w: int, n: int) -> int:
+        """The compiled width of a w-wide chunk of an n-wide _matmul."""
+        return self.pipeline_width_bucket(w, self.chunk_bytes)
+
     def _matmul(self, coeffs: np.ndarray, data: np.ndarray) -> np.ndarray:
-        from .telemetry import STATS
         coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
         data = np.ascontiguousarray(data, dtype=np.uint8)
         r, k = coeffs.shape
         n = data.shape[1]
         if n == 0:
             return np.zeros((r, 0), dtype=np.uint8)
-        if n <= self.chunk_bytes:
-            bucket = width_bucket(n, self.chunk_bytes)
-            fn, bitmat, put = self.device_fn(coeffs, bucket)
-            STATS.add("dispatches")
-            STATS.add("device_bytes", data.nbytes)
-            if n < bucket:
-                pad = np.zeros((k, bucket), dtype=np.uint8)
-                pad[:, :n] = data
-                return np.asarray(fn(bitmat, put(pad)))[:, :n]
-            return np.asarray(fn(bitmat, put(data)))
         out = np.empty((r, n), dtype=np.uint8)
-        fn, bitmat, put = self.device_fn(coeffs, self.chunk_bytes)
         # dispatch every chunk before draining any: JAX dispatch is
         # async, so the device crunches chunk t+1 while chunk t copies
         # back — blocking np.asarray inside the dispatch loop would
         # serialize the two
         pending = []
-        for off in range(0, n, self.chunk_bytes):
-            end = min(off + self.chunk_bytes, n)
-            chunk = data[:, off:end]
-            STATS.add("dispatches")
-            STATS.add("device_bytes", chunk.nbytes)
-            if end - off < self.chunk_bytes:
-                pad = np.zeros((k, self.chunk_bytes), dtype=np.uint8)
-                pad[:, : end - off] = chunk
-                chunk = pad
-            pending.append((off, end, fn(bitmat, put(chunk))))
-        for off, end, dev in pending:
-            out[:, off:end] = np.asarray(dev)[:, : end - off]
+        with tracing.span("dispatch", backend=self.backend,
+                          bytes=int(n * k)):
+            for off in range(0, n, self.chunk_bytes):
+                end = min(off + self.chunk_bytes, n)
+                w = end - off
+                bucket = self._chunk_bucket(w, n)
+                fn, bitmat, put = self.device_fn(coeffs, bucket)
+                chunk = data[:, off:end]
+                if w < bucket:  # zero-pad: GF-linear, so exact
+                    padded = np.zeros((k, bucket), dtype=np.uint8)
+                    padded[:, :w] = chunk
+                    chunk = padded
+                STATS.add("dispatches")
+                STATS.add("device_bytes", w * k)
+                pending.append((off, end, fn(bitmat, put(chunk))))
+        with tracing.span("drain", backend=self.backend,
+                          bytes=int(n * r)):
+            for off, end, dev in pending:
+                out[:, off:end] = np.asarray(dev)[:, : end - off]
         return out
 
 
-# ---------------------------------------------------------------------------
-# Raw jax-level entry points (used by bench.py, __graft_entry__, parallel/)
-# ---------------------------------------------------------------------------
+class TpuCodec(DeviceCodec):
+    """JAX backend on one TPU chip (`-ec.backend tpu`)."""
 
-def make_encode_fn(k: int, m: int, n: int, matrix_kind: str = "vandermonde"):
-    """Returns (jitted_fn, bitmat): jitted_fn(bitmat, data (k, n)) -> (m, n).
+    backend = "tpu"
 
-    This is the single-device flagship kernel (fused Pallas on TPU, XLA
-    elsewhere); parallel/sharded_ec wraps the XLA variant in a mesh for
-    multi-chip encode.
-    """
-    matrix = gf256.build_matrix(k, k + m, matrix_kind)
-    return fn_and_bitmat(matrix[k:], n)
+    def _chunk_bucket(self, w: int, n: int) -> int:
+        """The tail of a call of several chunks pads to the full chunk:
+        one shape for the whole call, where a bucket of the tail's own
+        would be a second program."""
+        if n > self.chunk_bytes:
+            return self.chunk_bytes
+        return super()._chunk_bucket(w, n)

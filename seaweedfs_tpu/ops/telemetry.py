@@ -5,10 +5,10 @@ compute_frac=0.99 — pure dispatch overhead (per-slab bitmat re-lift +
 re-upload, two matmuls per slab, no overlap), not GF math. These
 counters make that overhead *observable*: every device dispatch,
 bit-matrix upload and host-path small-read fallback increments a
-process-global counter, and rebuild_ec_files / bench.py report the
-deltas (`dispatches`, `bitmat_uploads`) so a regression back to
-per-slab uploads shows up in `vs_baseline` instead of hiding inside
-wall time.
+process-global counter, and rebuild_ec_files reports the deltas
+(`dispatches`, `bitmat_uploads`) in its reply, where benchmarks/run.py
+reads them, so a regression back to per-slab uploads shows up as a
+count instead of hiding inside wall time.
 
 Mesh-sharded dispatches additionally record which devices a put
 actually landed bytes on (`mesh_dispatches`, per-device byte map).

@@ -1,14 +1,7 @@
-"""parallel — multi-chip EC compute over a jax.sharding.Mesh.
-
-The reference scales EC work by fanning volumes across volume servers over
-gRPC (SURVEY §2.6); the TPU-native equivalent adds a second, device-level
-tier: stripes and shard outputs sharded over a ('data', 'shard') mesh with
-XLA collectives over ICI (psum for the GF(2) XOR-reductions in distributed
-rebuild), multi-host over DCN via the same mesh axes.
+"""parallel — how one EC dispatch is laid over the chips of a host:
+the mesh (mesh.make_codec_mesh) and the codec that shards a slab's
+width over it (mesh_codec.MeshCodec). The programs are ops/rs_tpu's.
 """
 
-from .mesh import make_mesh  # noqa: F401
-from .multihost import init_distributed, multihost_ec_step  # noqa: F401
-from .sharded_ec import (  # noqa: F401
-    sharded_encode_fn, sharded_rebuild_fn, distributed_ec_step,
-)
+from .mesh import make_codec_mesh  # noqa: F401
+from .mesh_codec import MeshCodec  # noqa: F401

@@ -4,7 +4,8 @@
 `jax.sharding.Mesh` of all visible devices: the payload axis shards
 over 'data' (stripes are independent byte positions — zero
 communication), coefficients replicate, and XLA partitions the
-GF(2) program (parallel/sharded_ec.py documents the math). On an
+GF(2) program (ops/rs_tpu.py documents the math and owns both program
+bodies; this module only places them). On an
 8-chip host a volume encode therefore streams through all chips from
 the same `write_ec_files` call sites the single-chip TpuCodec uses;
 on the CPU test mesh it exercises the identical program. Outputs are
@@ -29,9 +30,7 @@ and the pipelined encode/rebuild path streams slabs through device_fn()
 with bounded in-flight depth (ops/pipeline.PipelinedMatmul).
 
 Width discipline (the round-16 lesson): the codec mesh puts EVERY
-device on the 'data' axis (mesh.make_codec_mesh — the default
-(n/2, 2) layout exists for the psum rebuild programs and would idle
-half the mesh here), slabs below the SW_EC_MESH_SHARD_MIN_BYTES
+device on the 'data' axis (mesh.make_codec_mesh), slabs below the SW_EC_MESH_SHARD_MIN_BYTES
 payload crossover keep the single-device kernel (sharding a
 kilobyte-wide reconstruct pays partitioning overhead it can't
 amortize), and every sharded put records its per-device byte landing
@@ -50,8 +49,8 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from ..ops import device_stats, gf256
-from ..ops.codec import ReedSolomonCodec, _ConstCache, small_dispatch_default
-from ..ops.rs_tpu import width_bucket
+from ..ops.rs_tpu import (DeviceCodec, bitplane_program, packed_program,
+                          width_bucket)
 from ..ops.telemetry import STATS
 from ..util import config
 from .mesh import make_codec_mesh
@@ -67,7 +66,7 @@ _FNS: Dict[Tuple, object] = {}
 PROGRAM_NAME = "sw_rs_mesh"
 
 
-class MeshCodec(ReedSolomonCodec):
+class MeshCodec(DeviceCodec):
     backend = "mesh"
 
     def __init__(self, data_shards: int, parity_shards: int,
@@ -75,26 +74,19 @@ class MeshCodec(ReedSolomonCodec):
                  chunk_bytes: int = 32 << 20,
                  small_dispatch_bytes: int = None,
                  mesh_shard_min_bytes: int = None):
-        super().__init__(data_shards, parity_shards, matrix_kind)
-        self.chunk_bytes = int(chunk_bytes)
+        super().__init__(data_shards, parity_shards, matrix_kind,
+                         chunk_bytes, small_dispatch_bytes)
         self._mesh = mesh  # lazy: devices may not be initialized yet
-        self.small_dispatch_bytes = (
-            small_dispatch_default() if small_dispatch_bytes is None
-            else int(small_dispatch_bytes))
         # payload bytes (k * width) below which a dispatch keeps the
         # single-device path: sharding a small slab pays partitioning
         # overhead on every device without enough columns to amortize it
         self.mesh_shard_min_bytes = (
             config.env_int("SW_EC_MESH_SHARD_MIN_BYTES")
             if mesh_shard_min_bytes is None else int(mesh_shard_min_bytes))
-        self._consts = _ConstCache()
 
     @property
     def mesh(self):
         if self._mesh is None:
-            # ALL devices on the width axis — the default make_mesh
-            # (data, shard) = (n/2, 2) layout is for the psum rebuild
-            # programs and would leave half the mesh idle here
             self._mesh = make_codec_mesh()
         return self._mesh
 
@@ -115,46 +107,10 @@ class MeshCodec(ReedSolomonCodec):
         if fn is not None:
             return fn
         import jax
-        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        if self._on_tpu_mesh():
-            def program(bitmat, data):
-                shifts = jnp.arange(8, dtype=jnp.uint8)
-                bits = ((data[:, None, :] >> shifts[None, :, None]) & 1)
-                x = bits.reshape(rows_in * 8, n).astype(jnp.int8)
-                y = jax.lax.dot_general(
-                    bitmat.T, x,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)
-                ybits = (y & 1).astype(jnp.uint8).reshape(rows_out, 8, n)
-                weights = (jnp.uint8(1) << shifts)[None, :, None]
-                return (ybits * weights).sum(axis=1, dtype=jnp.uint8)
-        else:
-            nw = (rows_in * 8 + 31) // 32
-
-            def program(bmp, data):
-                d32 = data.astype(jnp.uint32)
-                words = []
-                for wi in range(nw):
-                    acc = jnp.zeros((n,), jnp.uint32)
-                    for b in range(4):
-                        j = wi * 4 + b
-                        if j < rows_in:
-                            acc = acc | (d32[j] << (8 * b))
-                    words.append(acc)
-                outs = []
-                for i in range(rows_out):
-                    byte = jnp.zeros((n,), jnp.uint32)
-                    for bit in range(8):
-                        col = i * 8 + bit
-                        ones = jnp.zeros((n,), jnp.uint32)
-                        for wi in range(nw):
-                            ones = ones + jax.lax.population_count(
-                                words[wi] & bmp[wi, col])
-                        byte = byte | ((ones & 1) << bit)
-                    outs.append(byte.astype(jnp.uint8))
-                return jnp.stack(outs)
+        program = (bitplane_program if self._on_tpu_mesh()
+                   else packed_program)(rows_in, rows_out, n)
 
         def sw_rs_mesh(const, data):    # the module is jit_sw_rs_mesh
             with jax.named_scope(PROGRAM_NAME):
@@ -202,28 +158,19 @@ class MeshCodec(ReedSolomonCodec):
                                         shard.data.nbytes)
         return arr
 
-    def _single_device_fn(self, coeffs: np.ndarray, width: int):
-        """The current single-device path (fused Pallas on TPU, packed
-        popcount XLA elsewhere — ops/rs_tpu.fn_and_bitmat), for
-        dispatches too small to amortize mesh partitioning."""
-        import jax.numpy as jnp
-        from ..ops.rs_tpu import fn_and_bitmat
-        fn, const_host = fn_and_bitmat(coeffs, width)
-        const = self._consts.get((coeffs.tobytes(), "single"),
-                                 lambda: jnp.asarray(const_host))
-        return fn, const, jnp.asarray
-
     def device_fn(self, coeffs: np.ndarray, width: int):
         """Streaming hook for PipelinedMatmul: (fn, resident const,
         put). `width` must come from pipeline_width_bucket (even shard
         split over 'data'). Below the SW_EC_MESH_SHARD_MIN_BYTES
-        payload crossover (k * width) the single-device kernel is
-        returned instead of the sharded program."""
+        payload crossover (k * width) the single-device kernel
+        (DeviceCodec.device_fn: fused Pallas on TPU, packed popcount
+        elsewhere) is returned instead of the sharded program —
+        dispatches too small to amortize mesh partitioning."""
         coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
         r, k = coeffs.shape
         if k * width < self.mesh_shard_min_bytes or \
                 self.mesh.shape["data"] <= 1:
-            return self._single_device_fn(coeffs, width)
+            return super().device_fn(coeffs, width)
         return self._fn(k, r, width), self._device_const(coeffs), self._put
 
     def drain_pieces(self, out_dev, w: int):
@@ -249,45 +196,7 @@ class MeshCodec(ReedSolomonCodec):
         return sorted(by_off.items())
 
     def pipeline_width_bucket(self, n: int, cap: Optional[int]) -> int:
+        """Power-of-two bucket (compile reuse), then up to a multiple
+        of the 'data' axis so the shard split is even."""
         bucket = width_bucket(n, cap)
         return bucket + (-bucket) % self.mesh.shape["data"]
-
-    def _width_bucket(self, n: int) -> int:
-        """Pad widths to power-of-two buckets (compile reuse), then up to
-        a multiple of the 'data' axis so the shard split is even."""
-        data_ax = self.mesh.shape["data"]
-        bucket = min(max(512, 1 << (n - 1).bit_length()), self.chunk_bytes)
-        bucket = max(bucket, n)  # chunk_bytes cap may undershoot n's chunk
-        return bucket + (-bucket) % data_ax
-
-    def _matmul(self, coeffs: np.ndarray, data: np.ndarray) -> np.ndarray:
-        coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
-        data = np.ascontiguousarray(data, dtype=np.uint8)
-        r, k = coeffs.shape
-        n = data.shape[1]
-        if n == 0:
-            return np.zeros((r, 0), dtype=np.uint8)
-        from ..util import tracing
-        out = np.empty((r, n), dtype=np.uint8)
-        step = self.chunk_bytes
-        # dispatch all chunks, then drain: the async dispatches overlap
-        # device compute with the d2h of earlier chunks
-        pending = []
-        with tracing.span("dispatch", backend="mesh", bytes=int(n * k)):
-            for off in range(0, n, step):
-                end = min(off + step, n)
-                w = end - off
-                bucket = self._width_bucket(w)
-                fn, bitmat, put = self.device_fn(coeffs, bucket)
-                if w < bucket:  # zero-pad: GF-linear, so exact
-                    padded = np.zeros((k, bucket), dtype=np.uint8)
-                    padded[:, :w] = data[:, off:end]
-                else:
-                    padded = data[:, off:end]
-                STATS.add("dispatches")
-                STATS.add("device_bytes", w * k)
-                pending.append((off, end, fn(bitmat, put(padded))))
-        with tracing.span("drain", backend="mesh", bytes=int(n * r)):
-            for off, end, dev in pending:
-                out[:, off:end] = np.asarray(dev)[:, : end - off]
-        return out

@@ -710,8 +710,8 @@ VOLUME_EC_XLA_COMPILES = VOLUME_SERVER_GATHER.counter(
 VOLUME_EC_XLA_COMPILE_SECONDS = VOLUME_SERVER_GATHER.counter(
     "SeaweedFS_volumeServer_ec_xla_compile_seconds_total",
     "Wall seconds spent inside timed lower().compile() calls per "
-    "entry point — the warmup cost bench.py splits out of every "
-    "headline.",
+    "entry point — the warmup cost the benchmark keeps in setup_s, "
+    "outside its window.",
     labels=("entry",))
 VOLUME_EC_XLA_RECOMPILES = VOLUME_SERVER_GATHER.counter(
     "SeaweedFS_volumeServer_ec_xla_recompiles_total",

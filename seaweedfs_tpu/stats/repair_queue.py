@@ -20,8 +20,7 @@ mount for loss).
 detected_at``: the window during which the affected volume ran below
 its configured redundancy (or above it but silently wrong).  It is the
 integrity plane's headline SLO — p50/p99 over recent incidents are
-exported as ``repair_queue_ttr_seconds`` and reported by the
-``bench.py cluster_scrub_repair`` drill.
+exported as ``repair_queue_ttr_seconds``.
 """
 
 import threading

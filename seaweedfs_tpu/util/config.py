@@ -181,8 +181,6 @@ _knob("SW_EC_JIT_CACHE_SIZE", "int", 64,
       "entry recompiles on next use (visible in ec_xla_jit_cache_total).")
 
 # debug / tooling
-_knob("SW_PROFILE_DIR", "str", None,
-      "Directory for jax.profiler traces; profiling is off when unset.")
 _knob("SW_PROFILE_MAX_S", "float", 30.0,
       "Ceiling on POST /admin/profile?seconds=N sampling windows.")
 _knob("SW_PLANE_STATS", "bool", True,
@@ -214,88 +212,6 @@ _knob("SW_LOCK_DEBUG", "bool", False,
 _knob("SW_LOCK_GRAPH_DIR", "str", None,
       "Directory where instrumented processes dump their lock graph at "
       "exit for cross-process cycle checks.")
-
-# bench.py drills
-_knob("SW_BENCH_TRIALS", "int", 2,
-      "Best-of trials per timed bench pass.")
-_knob("SW_BENCH_DAT_MB", "int", 4096,
-      "Bench volume size in MB for the headline configs.")
-_knob("SW_BENCH_SLAB_MB", "int", 8,
-      "Bench device slab per shard row in MB.")
-_knob("SW_BENCH_DIR", "str", None,
-      "Bench working directory (default: a fresh temp dir).")
-_knob("SW_BENCH_KEEP", "bool", False,
-      "Keep the bench working directory instead of deleting it.")
-_knob("SW_BENCH_GEO_MB", "int", 256,
-      "Volume MB for the RS-geometry sweep configs.")
-_knob("SW_BENCH_SMALL_VOLS", "int", 4,
-      "Volumes in the batched small-needle config.")
-_knob("SW_BENCH_SMALL_NEEDLES", "int", 8192,
-      "4 KB needles per volume in the batched small-needle config.")
-_knob("SW_BENCH_CLUSTER_MB", "int", 256,
-      "Volume MB for the live-cluster rebuild drill.")
-_knob("SW_BENCH_CLUSTER_TPU_MB", "int", 64,
-      "Volume MB for the TPU live-cluster rebuild drill.")
-_knob("SW_BENCH_CLUSTER_SERVERS", "int", 4,
-      "Volume servers in the live-cluster drills.")
-_knob("SW_BENCH_CLUSTER_BACKEND", "str", "mesh",
-      "EC backend for the live-cluster rebuild drill.")
-_knob("SW_BENCH_DRILL_TIMEOUT", "float", 900.0,
-      "Subprocess timeout for each cluster drill phase.")
-_knob("SW_BENCH_DP_SECONDS", "float", 5.0,
-      "Duration of each data-plane saturation pass.")
-_knob("SW_BENCH_DP_CONNS", "int", 12,
-      "Concurrent connections in the data-plane saturation pass.")
-_knob("SW_BENCH_DP_DURABLE_SECONDS", "float", 2.0,
-      "Duration of each durable-mode (fsync) data-plane trial; "
-      "0 skips the durability trial set.")
-_knob("SW_BENCH_DP_DURABLE_CONNS", "int", 128,
-      "Concurrent connections in each durable-mode trial (all three "
-      "modes share the load shape; group commit needs enough "
-      "in-flight writers to accumulate riders per fsync).")
-_knob("SW_BENCH_DP_CRASH_RUNS", "int", 3,
-      "kill -9 crash-consistency drill runs in the data-plane bench; "
-      "0 skips the drill.")
-_knob("SW_BENCH_DP_DIR", "str", "",
-      "Volume directory handed to the crash-drill child server.")
-_knob("SW_BENCH_DP_MASTER", "str", "",
-      "Master URL handed to the crash-drill child server.")
-_knob("SW_BENCH_DEGRADED_NEEDLES", "int", 24,
-      "Needles written for the degraded-read drill.")
-_knob("SW_BENCH_DEGRADED_KB", "int", 64,
-      "Needle KB for the degraded-read drill.")
-_knob("SW_BENCH_DEGRADED_READERS", "int", 8,
-      "Concurrent readers in the degraded-read drill.")
-_knob("SW_BENCH_DEGRADED_ROUNDS", "int", 3,
-      "Read rounds per phase in the degraded-read drill.")
-_knob("SW_BENCH_DEGRADED_BACKEND", "str", "numpy",
-      "EC backend for the degraded-read drill.")
-_knob("SW_BENCH_SCRUB_VOLUMES", "int", 3,
-      "EC volumes in the scrub/repair drill.")
-_knob("SW_BENCH_SCRUB_NEEDLES", "int", 8,
-      "Needles per volume in the scrub/repair drill.")
-_knob("SW_BENCH_SCRUB_KB", "int", 64,
-      "Needle KB in the scrub/repair drill.")
-_knob("SW_BENCH_SCRUB_READERS", "int", 4,
-      "Concurrent foreground readers in the scrub/repair drill.")
-_knob("SW_BENCH_TIER_MB", "int", 8,
-      "Volume size limit in MB for the write-through tiering drill.")
-_knob("SW_BENCH_TIER_NEEDLES", "int", 32,
-      "Needles written into the demotion-candidate volume.")
-_knob("SW_BENCH_TIER_KB", "int", 64,
-      "Needle KB in the tiering drill.")
-_knob("SW_BENCH_TIER_READERS", "int", 4,
-      "Concurrent foreground readers in the tiering drill.")
-_knob("SW_BENCH_TIER_WRITERS", "int", 2,
-      "Concurrent foreground writers in the tiering drill.")
-_knob("SW_BENCH_TIER_RATE_MBPS", "float", 4.0,
-      "SW_TIER_RATE_MBPS handed to the drill's tierer; kept below "
-      "the unpaced streaming-spread throughput so the cap genuinely "
-      "paces the demotion under the foreground load.")
-_knob("SW_BENCH_DIFF", "bool", True,
-      "Auto-diff each cluster drill record against the latest "
-      "BENCH_r*.json via tools/bench_diff.py and exit 2 on >20% "
-      "regressions.")
 
 _UNSET = object()
 _TRUTHY = ("1", "true", "yes", "on")

@@ -4,10 +4,6 @@ The reference wires Go pprof behind -cpuprofile/-memprofile flags
 (reference weed/command/volume.go:71-72, weed/util/pprof.go). The TPU
 build's equivalents:
 
-  * ``maybe_trace(label)`` — a context manager that captures a JAX/XLA
-    profiler trace (viewable in TensorBoard / Perfetto) when
-    ``SW_PROFILE_DIR`` is set, and is free when it is not. Wrap device
-    call sites (the EC pipeline does this around its stream loop).
   * ``cpu_profile(path)`` — cProfile for single-threaded host code
     (offline tools, kernels).
   * ``SamplingProfiler`` — an all-thread stack sampler for the servers
@@ -24,26 +20,11 @@ from __future__ import annotations
 
 import contextlib
 import cProfile
-import os
 import threading
-from . import config, tracing
+from . import tracing
 from .locks import make_lock
 import time
 from typing import Dict, List, Optional, Tuple
-
-
-@contextlib.contextmanager
-def maybe_trace(label: str = "trace", profile_dir: Optional[str] = None):
-    """Capture a jax.profiler trace into ``$SW_PROFILE_DIR/<label>`` (or
-    ``profile_dir``) when configured; otherwise do nothing."""
-    out = profile_dir or config.env_str("SW_PROFILE_DIR")
-    if not out:
-        yield
-        return
-    import jax
-
-    with jax.profiler.trace(os.path.join(out, label)):
-        yield
 
 
 def mirror_stages_to_profiler():

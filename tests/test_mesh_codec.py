@@ -2,7 +2,7 @@
 device tier) — bit-identical to the numpy oracle on the virtual
 8-device CPU mesh."""
 
-import hashlib
+import contextlib
 import os
 
 import numpy as np
@@ -38,6 +38,79 @@ def test_reconstruct_matches_oracle():
     ref = NumpyCodec(k, m).encode_to_all(data)
     for sid in range(k + m):
         assert np.array_equal(rebuilt[sid], ref[sid]), sid
+
+
+@contextlib.contextmanager
+def _private_programs():
+    """A second mesh of the process compiles a bucket the first one has
+    already compiled: that is what the recompile sentinel latches on,
+    and tests/test_device_stats.py asserts the global one is unlatched.
+    Programs of a mesh that is not the process's own go to a cache and
+    a DeviceStats of the test's."""
+    from seaweedfs_tpu.ops import device_stats
+    from seaweedfs_tpu.parallel import mesh_codec
+    saved = device_stats.DEVICE_STATS, mesh_codec._FNS
+    device_stats.DEVICE_STATS = device_stats.DeviceStats()
+    mesh_codec._FNS = {}
+    try:
+        yield
+    finally:
+        device_stats.DEVICE_STATS, mesh_codec._FNS = saved
+
+
+@pytest.mark.parametrize("k,m", [(10, 4), (6, 3)])
+@pytest.mark.parametrize("width_devices", [1, 2, 3, 5, 8])
+def test_mesh_widths_and_geometries(width_devices, k, m):
+    """Every width of the codec mesh, both geometries, a payload that is
+    no multiple of the 128 lanes nor of the device count: encode equals
+    the oracle, and with m shards dropped (data and parity) reconstruct
+    gives the data back exactly — on the device, not the host path that
+    small reads take."""
+    from seaweedfs_tpu.parallel.mesh import make_codec_mesh
+    n = 333 * width_devices + 7
+    rng = np.random.default_rng(100 * width_devices + k)
+    data = rng.integers(0, 256, (k, n), dtype=np.uint8)
+    # one device takes the single-device kernel of the process (shared
+    # with every other test: no private copy of that)
+    with _private_programs() if width_devices > 1 \
+            else contextlib.nullcontext():
+        mesh = make_codec_mesh(width_devices=width_devices)
+        assert dict(mesh.shape) == {"data": width_devices, "shard": 1}
+        codec = MeshCodec(k, m, mesh=mesh, mesh_shard_min_bytes=0,
+                          small_dispatch_bytes=0)
+        before = STATS.snapshot()
+        shards = list(codec.encode_to_all(data))
+        ref = NumpyCodec(k, m).encode_to_all(data)
+        assert all(np.array_equal(a, b) for a, b in zip(shards, ref))
+        lost = [0, k - 1, k, k + m - 1][:m]
+        for sid in lost:
+            shards[sid] = None
+        rebuilt = codec.reconstruct(shards)
+        d = delta(before)
+    for sid in range(k + m):
+        assert np.array_equal(rebuilt[sid], ref[sid]), sid
+    assert d["dispatches"] == 2 and d["host_fallbacks"] == 0
+    assert d["mesh_dispatches"] == (2 if width_devices > 1 else 0)
+
+
+@pytest.mark.parametrize("r,k", [(32, 80), (128, 320)])
+def test_rolled_packed_program_on_the_mesh(r, k):
+    """An operand over _PACKED_UNROLL_LIMIT — (32, 80), and the coupled
+    encode of the piggyback layout, (m*alpha, k*alpha) = (128, 320) —
+    takes the rolled form of the packed program on the CPU mesh as it
+    does on one device (unrolled, it is ~10^5 ops and minutes of XLA
+    compile), and equals the host product."""
+    from seaweedfs_tpu.ops import rs_tpu
+    from seaweedfs_tpu.ops.codec import host_matmul
+    assert r * 8 * ((k * 8 + 31) // 32) > rs_tpu._PACKED_UNROLL_LIMIT
+    rng = np.random.default_rng(r)
+    coeffs = rng.integers(0, 256, (r, k), dtype=np.uint8)
+    data = rng.integers(0, 256, (k, 2048 + 24), dtype=np.uint8)
+    codec = MeshCodec(10, 4, mesh_shard_min_bytes=0)
+    before = STATS.snapshot()
+    out = codec._matmul(coeffs, data)
+    assert delta(before)["mesh_dispatches"] == 1
+    assert np.array_equal(out, host_matmul(coeffs, data))
 
 
 def test_multi_chunk_widths():
@@ -119,7 +192,7 @@ def test_drain_pieces_reassembles_device_resident_output():
     rng = np.random.default_rng(9)
     data = rng.integers(0, 256, (k, w), dtype=np.uint8)
     coeffs = gf256.build_matrix(k, k + m)[k:]
-    bucket = codec._width_bucket(w)
+    bucket = codec.pipeline_width_bucket(w, codec.chunk_bytes)
     fn, bitmat, put = codec.device_fn(coeffs, bucket)
     padded = np.zeros((k, bucket), dtype=np.uint8)
     padded[:, :w] = data

@@ -794,7 +794,7 @@ class TestPlaneHealthRatio:
 class TestNativeBenchmarkMode:
     """`weed benchmark -native`: the C++ engine driven through
     run_native_benchmark against live in-process servers — the path
-    bench.py's data_plane section and the CLI both take."""
+    the CLI takes."""
 
     def test_single_target_write_then_read(self, cluster, capsys):
         from seaweedfs_tpu.command.benchmark import run_native_benchmark

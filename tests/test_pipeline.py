@@ -91,7 +91,8 @@ def test_pipelined_matmul_varied_widths():
     widths = [512, 100, 512, 1, 317, 512]
     slabs = [(idx, rng.integers(0, 256, (10, w), dtype=np.uint8))
              for idx, w in enumerate(widths)]
-    pm = PipelinedMatmul(coeffs, max_width=512, depth=2, prefetch=2)
+    pm = PipelinedMatmul(coeffs, get_codec(10, 4, "tpu"), max_width=512,
+                         depth=2, prefetch=2)
     got = list(pm.stream(iter(slabs)))
     assert [meta for meta, _, _ in got] == list(range(len(widths)))
     for (meta, data, out), (_, orig) in zip(got, slabs):
@@ -114,7 +115,7 @@ def test_pipelined_matmul_uncapped_widths_share_buckets():
     codec = get_codec(10, 4, "tpu")
     for w in (600, 700, 1025):
         data = rng.integers(0, 256, (10, w), dtype=np.uint8)
-        pm = PipelinedMatmul(coeffs, max_width=None, codec=codec)
+        pm = PipelinedMatmul(coeffs, codec, max_width=None)
         assert pm._bucket(w) == width_bucket(w, None)
         (_, _, out), = pm.stream([(None, data)])
         assert np.array_equal(out, oracle._matmul(coeffs, data))
@@ -127,14 +128,60 @@ def test_pipelined_matmul_reader_error_propagates():
         yield 0, np.zeros((10, 64), dtype=np.uint8)
         raise RuntimeError("disk exploded")
 
-    pm = PipelinedMatmul(coeffs, max_width=512, depth=2)
+    pm = PipelinedMatmul(coeffs, get_codec(10, 4, "tpu"), max_width=512,
+                         depth=2)
     with pytest.raises(RuntimeError, match="disk exploded"):
         list(pm.stream(bad_slabs()))
 
 
 def test_pipelined_matmul_width_over_max_raises():
     coeffs = np.eye(4, 10, dtype=np.uint8)
-    pm = PipelinedMatmul(coeffs, max_width=128)
+    pm = PipelinedMatmul(coeffs, get_codec(10, 4, "tpu"), max_width=128)
     slabs = [(0, np.zeros((10, 256), dtype=np.uint8))]
     with pytest.raises(ValueError, match="exceeds max_width"):
         list(pm.stream(iter(slabs)))
+
+
+@pytest.mark.parametrize("op", ["write_ec_files", "rebuild_ec_files"])
+@pytest.mark.parametrize("backend", ["numpy", "native", "tpu", "mesh"])
+def test_codec_decides_pipelining(tmp_path, monkeypatch, backend, op):
+    """pipelined=None leaves the choice to the codec: a device codec's
+    run goes through PipelinedMatmul.stream, a host codec's does not,
+    and the shard files are the same bytes either way."""
+    if backend == "native":
+        from seaweedfs_tpu.ops.rs_native import native_available
+        if not native_available():
+            pytest.skip("native codec not built")
+    base = _make_volume(tmp_path, needles=30)
+    write_ec_files(base, codec=NumpyCodec(10, 4), large_block=LARGE,
+                   small_block=SMALL, slab=SLAB, pipelined=False)
+    originals = _read_shards(base)
+    streams = []
+    real = PipelinedMatmul.stream
+
+    def spy(self, slabs):
+        streams.append(self.codec.backend)
+        return real(self, slabs)
+
+    monkeypatch.setattr(PipelinedMatmul, "stream", spy)
+    codec = get_codec(10, 4, backend=backend)
+    if op == "write_ec_files":
+        write_ec_files(base, codec=codec, large_block=LARGE,
+                       small_block=SMALL, slab=SLAB)
+    else:
+        for i in (1, 8, 10, 13):
+            os.remove(base + to_ext(i))
+        assert rebuild_ec_files(base, codec=codec, slab=SLAB) == \
+            [1, 8, 10, 13]
+    assert _read_shards(base) == originals
+    device = backend in ("tpu", "mesh")
+    assert streams == ([backend] if device else [])
+
+
+def test_host_codec_is_refused_with_a_sentence():
+    """A host codec has no device_fn to stream through: the pipeline
+    says so where it is built, not with an AttributeError mid-stream."""
+    assert NumpyCodec(10, 4).pipelined is False
+    with pytest.raises(TypeError, match="'numpy' codec computes on the "
+                                        "host"):
+        PipelinedMatmul(np.eye(4, 10, dtype=np.uint8), NumpyCodec(10, 4))

@@ -3,8 +3,9 @@
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.ops.codec import NumpyCodec
-from seaweedfs_tpu.ops.rs_tpu import TpuCodec
+from seaweedfs_tpu.ops import gf256
+from seaweedfs_tpu.ops.codec import NumpyCodec, host_matmul
+from seaweedfs_tpu.ops.rs_tpu import TpuCodec, bitplane_program
 
 
 @pytest.mark.parametrize("k,m", [(10, 4), (6, 3), (20, 4)])
@@ -72,3 +73,38 @@ def test_odd_sizes():
     for n in (1, 7, 127, 129, 1000003 % 2048):
         data = rng.integers(0, 256, (10, n)).astype(np.uint8)
         assert np.array_equal(c_ref.encode(data), c_tpu.encode(data))
+
+
+def _cell_operands():
+    """What the benchmark's cells and the next configurations dispatch."""
+    rs = NumpyCodec(10, 4)
+    lost4 = tuple(i not in (0, 3, 10, 12) for i in range(14))
+    lost1 = tuple(i != 6 for i in range(14))
+    rng = np.random.default_rng(56)
+    return [
+        pytest.param(rs.matrix[10:], id="encode-4x10"),
+        pytest.param(rs.decode_plan(lost4)[2], id="decode-4x10"),
+        pytest.param(rs.decode_plan(lost1)[2], id="decode-1x10"),
+        pytest.param(NumpyCodec(6, 3).matrix[6:], id="rs6-3-encode-3x6"),
+        pytest.param(NumpyCodec(20, 4).matrix[20:], id="rs20-4-encode-4x20"),
+        # the trace repair's combine: {0,1} coefficients, 56 symbol rows
+        pytest.param(rng.integers(0, 2, (8, 56), dtype=np.uint8),
+                     id="trace-combine-8x56"),
+    ]
+
+
+@pytest.mark.parametrize("n", [2048, 1000 + 37])
+@pytest.mark.parametrize("coeffs", _cell_operands())
+def test_bitplane_program_matches_oracle(coeffs, n, request):
+    """The bit-plane dot — the program the mesh cell runs on four chips,
+    which JAX_PLATFORMS=cpu never picks — jitted on the CPU, against the
+    host product, for each operand the cells dispatch."""
+    import jax
+    r, k = coeffs.shape
+    assert f"-{r}x{k}-" in request.node.name    # the id says the operand
+    rng = np.random.default_rng(n + r)
+    data = rng.integers(0, 256, (k, n), dtype=np.uint8)
+    bitmat = gf256.bit_matrix(coeffs).astype(np.int8)
+    out = jax.jit(bitplane_program(k, r, n))(bitmat, data)
+    assert out.dtype == np.uint8 and out.shape == (r, n)
+    assert np.array_equal(np.asarray(out), host_matmul(coeffs, data))

@@ -134,19 +134,14 @@ class TestMetrics:
         assert not t.is_alive(), "stop_event did not stop the loop"
 
     def test_check_metrics_lint(self):
-        """tools/check_metrics.py validates every registry (tier-1)."""
+        """tools/analyze.py's metrics sub-checker validates every
+        registry (tier-1)."""
         import os
-        import subprocess
         import sys
-        root = os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))
-        proc = subprocess.run(
-            [sys.executable, os.path.join(root, "tools",
-                                          "check_metrics.py")],
-            capture_output=True, text=True,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"})
-        assert proc.returncode == 0, proc.stderr
-        assert "OK" in proc.stdout
+        sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools"))
+        import analyze
+        assert analyze.run_metrics_checks() == []
 
     def test_servers_expose_metrics(self, tmp_path):
         from seaweedfs_tpu.server.http_util import http_call

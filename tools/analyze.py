@@ -22,16 +22,14 @@ Static checkers (AST, no imports of the checked modules needed):
   calls, file opens, subprocess waits, device dispatch) lexically
   inside a ``with <lock>:`` body.  Deliberate cases are allowlisted
   below WITH a one-line justification that the lint echoes.
-- backend-isolation: ``import jax`` only in ops/, parallel/ (and
-  bench.py, outside the package).  Everything else must stay
-  importable on a box with no JAX at all.
+- backend-isolation: ``import jax`` only in ops/ and parallel/.
+  Everything else must stay importable on a box with no JAX at all.
 - thread-hygiene: every thread is named (the lock-graph and the fleet
   traces key on thread names), non-daemon threads must be joined
   somewhere in their file, and no bare ``except:``.
 
-The metrics lint that used to live in tools/check_metrics.py is folded
-in as the ``metrics`` sub-checker (check_metrics.py remains as a thin
-shim).  The dynamic half lives in seaweedfs_tpu/util/locks.py: under
+The metrics lint is the ``metrics`` sub-checker (``run_metrics_checks``).
+The dynamic half lives in seaweedfs_tpu/util/locks.py: under
 SW_LOCK_DEBUG=1 every factory lock records the cross-thread
 acquisition graph; ``--lock-report`` (and the conftest session hook)
 fail on any cycle not covered by LOCK_ORDER_ALLOWED.
@@ -94,11 +92,7 @@ LOCK_ORDER_ALLOWED_EDGES = set(LOCK_ORDER_ALLOWED)
 
 # (path, knob) -> justification for raw env access the registry lint
 # would otherwise flag.
-ENV_RAW_ALLOWED: Dict[Tuple[str, str], str] = {
-    ("bench.py", "SW_EC_DEGRADED_MODE"):
-        "save/restore of the raw env around a drill that steers "
-        "subprocess workers through inheritance",
-}
+ENV_RAW_ALLOWED: Dict[Tuple[str, str], str] = {}
 
 # path -> justification for bare jax.jit( calls outside the
 # device_stats.wrap instrumentation.  Every production jit entry point
@@ -529,7 +523,6 @@ def iter_scope_files() -> List[str]:
         for n in sorted(names):
             if n.endswith(".py"):
                 out.append(rel(os.path.join(base, n)))
-    out.append("bench.py")
     for n in sorted(os.listdir(os.path.join(REPO_ROOT, "tools"))):
         if n.endswith(".py"):
             out.append(f"tools/{n}")
@@ -600,7 +593,7 @@ def apply_readme_table() -> None:
 
 
 # ---------------------------------------------------------------------------
-# metrics sub-checker (folded in from tools/check_metrics.py)
+# metrics sub-checker
 # ---------------------------------------------------------------------------
 
 # https://prometheus.io/docs/concepts/data_model/

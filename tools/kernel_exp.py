@@ -2,7 +2,7 @@
 best MXU mapping, or does a bf16 x bf16 -> f32 variant (exact for 0/1
 operands with row sums <= 2048) run faster on the live chip?
 
-Chained-slope methodology lifted from bench.py: serially-dependent
+Chained-slope methodology: serially-dependent
 iterations, scalar fetch, rotating buffers; slope over >=3 chain
 lengths.
 """
