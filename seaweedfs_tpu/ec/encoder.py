@@ -38,7 +38,9 @@ import numpy as np
 from ..ops.codec import ReedSolomonCodec, get_codec
 from ..storage.needle_map import MemDb
 from ..util import tracing
+from ..util.locks import make_lock
 from ..util.profiling import StageTimer
+from .transport import DEFAULT_WINDOW
 from .constants import (DATA_SHARDS, LARGE_BLOCK_SIZE, PARITY_SHARDS,
                         SMALL_BLOCK_SIZE, to_ext)
 
@@ -122,8 +124,13 @@ def _read_ranges(fd: int, rows: List[np.ndarray], offset: int,
 # 26). A pool that died with its encode recovers little of it: 9 of a
 # GiB volume's 13 slabs are live before the first is written. It holds
 # as many as one stream keeps in flight: the one being read, the
-# pipeline's read-ahead and depth, the one being written.
-_SLAB_POOL: "collections.deque[np.ndarray]" = collections.deque(maxlen=10)
+# pipeline's read-ahead (3) and depth (4), the one being written, one
+# in the producer's hand — and, since the spread queues views of a
+# slab's rows and not copies of them (PR 30), the stripes its workers
+# have not had acknowledged: a window in the queues, a window in the
+# workers' hands, the one being routed.
+_SLAB_POOL: "collections.deque[np.ndarray]" = collections.deque(
+    maxlen=10 + 2 * DEFAULT_WINDOW + 1)
 
 
 def _take_slab(k: int, width: int) -> np.ndarray:
@@ -142,9 +149,38 @@ def _take_slab(k: int, width: int) -> np.ndarray:
 
 def _give_slab(data: np.ndarray):
     """Hand a slab of _dat_slabs back once nothing reads it any more:
-    after its stripe's write, never earlier (on the CPU backend the
-    device array may alias the host memory until the output is drained)."""
+    after its stripe's rows are on their holders' disks (a spread sends
+    views of them), never earlier (and on the CPU backend the device
+    array may alias the host memory until the output is drained)."""
     _SLAB_POOL.append(data.base)
+
+
+class _SlabLease:
+    """Who still reads a slab: the encode's loop, and every stripe of
+    it a spread has not had acknowledged (one a slab, one a piece on
+    the mesh). The last to let go hands the slab back; a lease nobody
+    lets go of — a failed spread's — just drops its slab, and so does
+    a lease on ``None`` (piggyback's window re-cut yields copies and
+    views of the reader's slabs: never recycled, only kept referenced
+    by the stripes that read them)."""
+
+    def __init__(self, data: Optional[np.ndarray]):
+        self.data = data
+        self.users = 1
+        self._lock = make_lock("encoder._SlabLease._lock")
+
+    def share(self):
+        """One more reader; returns its ``release``."""
+        with self._lock:
+            self.users += 1
+        return self.release
+
+    def release(self):
+        with self._lock:
+            self.users -= 1
+            last = self.users == 0
+        if last and self.data is not None:
+            _give_slab(self.data)
 
 
 def _dat_slabs(dat_path: str, dat_size: int, k: int, large_block: int,
@@ -361,14 +397,16 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
             stream = ((meta, data, codec.encode(data))
                       for meta, data in slabs)
         for _, data, parity in stream:
+            lease = _SlabLease(None if piggyback else data)
             with timer.stage("shard_write", span="ec.encode.write") as st:
                 if pieces:
                     for lo, piece in parity:
                         pw = piece.shape[1]
-                        sink.write_stripe(data[:, lo:lo + pw], piece)
+                        sink.write_stripe(data[:, lo:lo + pw], piece,
+                                          done=lease.share())
                         st.nbytes += k * pw + piece.nbytes
                 elif sink is not None:
-                    sink.write_stripe(data, parity)
+                    sink.write_stripe(data, parity, done=lease.share())
                     st.nbytes = data.nbytes + parity.nbytes
                 else:
                     for i in range(k):
@@ -376,10 +414,10 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
                     for j in range(m):
                         outs[k + j].write(parity[j].tobytes())
                     st.nbytes = data.nbytes + parity.nbytes
-            if not piggyback:   # its window re-cut yields copies and views
-                _give_slab(data)
-            # the stripe is written: hold neither while the next is awaited
-            data = parity = None
+            lease.release()
+            # the stripe is handed on: hold neither while the next is
+            # awaited
+            data = parity = lease = None
     finally:
         for o in outs:
             o.close()

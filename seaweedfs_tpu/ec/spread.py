@@ -8,9 +8,12 @@ source disk and only then lets every target pull its shards whole over
 The streaming spread instead takes the stripe stream coming out of the
 encode (each stripe is one slab-aligned ``[off, off+w)`` range of every
 shard) and pushes each shard's ranges straight to its assigned holder
-via the chunked ``/admin/ec/shard_write`` endpoint while later slabs
-are still encoding. Shards bound for remote holders never touch the
-source disk.
+via the ``/admin/ec/shard_write`` endpoint while later slabs are still
+encoding: a run of a shard's contiguous ranges is one POST whose body
+is views of the stripes' rows, on the connection the target's worker
+keeps open, and the holder streams it from the socket into the
+``.part`` stage. Shards bound for remote holders never touch the
+source disk, and no shard byte is copied in Python on its way.
 
 All of the transport — the bounded ``SW_EC_SPREAD_WINDOW`` per-target
 window with peak-buffer and blocked-time accounting, contiguous-run

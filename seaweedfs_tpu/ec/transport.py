@@ -115,6 +115,7 @@ class TransportStats:
         self._lock = make_lock("transport.TransportStats._lock")
         self.fetches = 0
         self.sends = 0
+        self.connects = 0
         self.bytes = 0
         self.remote_bytes = 0
         self.hedges_fired = 0
@@ -153,6 +154,13 @@ class TransportStats:
             if holder:
                 self.holder_fetches[holder] = \
                     self.holder_fetches.get(holder, 0) + 1
+
+    def add_connects(self, n: int):
+        """Connections a remote writer opened: one a push worker and
+        holder when nothing fails, one more a retry or failover."""
+        if n:
+            with self._lock:
+                self.connects += n
 
     def add_holder_error(self, holder: str):
         with self._lock:
@@ -222,6 +230,11 @@ class SpreadStats(TransportStats):
     have always carried)."""
 
     stage = "spread"
+
+    def snapshot(self) -> Dict[str, float]:
+        out = super().snapshot()
+        out["spread_connects"] = self.connects
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -526,8 +539,10 @@ class LocalShardWriter:
         self.span = None
         self._f = None
 
-    def send(self, url: Optional[str], off: int,
-             chunks: Sequence[bytes]) -> int:
+    def send(self, url: Optional[str], off: int, chunks: Sequence,
+             link=None) -> int:
+        """Append one run: ``chunks`` are buffers (row views of the
+        encode's slabs), written as they lie."""
         t0 = time.perf_counter()
         if self._f is None:
             self._f = open(self.part, "wb" if off == 0 else "ab")
@@ -566,12 +581,16 @@ class LocalShardWriter:
 
 class RemoteShardWriter:
     """Pushes one shard's slab ranges to its holder: each run of
-    contiguous chunks goes out as ONE chunked POST to
-    ``/admin/ec/shard_write`` (append-at-expected-offset, 409 on
-    mismatch), carrying the caller span's traceparent so the holder's
-    spans join the trace. Every send feeds the health scoreboard under
-    the ``shard_write`` kind — the push path sees slow holders with the
-    same eyes the pull path does."""
+    contiguous chunks goes out as ONE POST to ``/admin/ec/shard_write``
+    (append-at-expected-offset, 409 on mismatch) whose body is the
+    chunks themselves — row views of the encode's slabs under a
+    Content-Length, written to the socket as they lie — on the
+    connection its push worker keeps to that holder (``link``, an
+    http_util.KeptConnection; without one, a connection for this run
+    alone: the hedged duplicates). It carries the caller span's
+    traceparent so the holder's spans join the trace. Every send feeds
+    the health scoreboard under the ``shard_write`` kind — the push
+    path sees slow holders with the same eyes the pull path does."""
 
     remote = True
     _health_kind = "shard_write"
@@ -586,9 +605,12 @@ class RemoteShardWriter:
         self.span = None     # set by StripedPush: trace parent
         self.timeout = timeout
 
-    def _url(self, holder: str, query: str) -> str:
-        return (f"http://{holder}/admin/ec/shard_write?volume={self.vid}"
+    def _target(self, query: str) -> str:
+        return (f"/admin/ec/shard_write?volume={self.vid}"
                 f"&collection={self.collection}&shard={self.sid}&{query}")
+
+    def _url(self, holder: str, query: str) -> str:
+        return f"http://{holder}{self._target(query)}"
 
     def _headers(self) -> Optional[dict]:
         # target worker threads don't inherit the tracing contextvar —
@@ -597,13 +619,18 @@ class RemoteShardWriter:
             return None
         return {tracing.TRACEPARENT_HEADER: self.span.traceparent()}
 
-    def send(self, url: str, off: int, chunks: Sequence[bytes]) -> int:
-        from ..server.http_util import HttpError, post_chunked
+    def send(self, url: str, off: int, chunks: Sequence,
+             link=None) -> int:
+        from ..server.http_util import HttpError, KeptConnection
         n = sum(len(c) for c in chunks)
+        once = link is None
+        if once:
+            link = KeptConnection(url, timeout=self.timeout)
+        opened = link.connects
         t0 = time.perf_counter()
         try:
-            post_chunked(self._url(url, f"offset={off}"), chunks,
-                         headers=self._headers(), timeout=self.timeout)
+            link.post_parts(self._target(f"offset={off}"), chunks,
+                            headers=self._headers())
         except HttpError as e:
             if e.status == 409:
                 # the holder's staged size disagrees; if it already
@@ -621,6 +648,10 @@ class RemoteShardWriter:
             self.stats.add_holder_error(url)
             _health.BOARD.record_error(url, self._health_kind)
             raise
+        finally:
+            self.stats.add_connects(link.connects - opened)
+            if once:
+                link.close()
         t1 = time.perf_counter()
         self.stats.add_send(n, t0, t1, holder=url)
         _health.BOARD.record_latency(url, self._health_kind, t1 - t0)
@@ -643,10 +674,13 @@ class RemoteShardWriter:
 
 class TargetWorker(threading.Thread):
     """Drains one target's bounded send queue: pops queued
-    ``(sid, off, chunk)`` items, merges per-shard contiguous runs, and
-    sends each run as one chunked POST. Owns the target url so
-    failover (re-assigning every shard of a dead target to a spare)
-    is a single-variable swap. The FIRST run to a remote target may be
+    ``(sid, off, chunk, stripe)`` items, merges per-shard contiguous
+    runs, and sends each run as one POST on the connection it keeps to
+    its holder. A chunk is a view of its stripe's rows, held until the
+    run it went out in is acknowledged; then the stripe hears of it
+    (``StripedPush._row_done``). Owns the target url so failover
+    (re-assigning every shard of a dead target to a spare) is a
+    single-variable swap. The FIRST run to a remote target may be
     hedged: past the ``SW_EC_HEDGE_MS`` deadline the same run races a
     duplicate stage on a spare, the first ack wins the shard set, and
     the loser's stage is aborted once its send drains."""
@@ -662,6 +696,24 @@ class TargetWorker(threading.Thread):
         self.q: queue.Queue = queue.Queue(maxsize=self.max_batch)
         self.acked = 0
         self.error: Optional[BaseException] = None
+        self._link = None    # the kept connection, and to which holder
+
+    def link(self):
+        """The connection this worker keeps to its holder: opened by
+        the first run, closed by a failed one (the retry opens the
+        next) and when the holder changes."""
+        from ..server.http_util import KeptConnection
+        if self.url is None:
+            return None
+        if self._link is None or self._link.netloc != self.url:
+            self._close_link()
+            self._link = KeptConnection(self.url)
+        return self._link
+
+    def _close_link(self):
+        link, self._link = self._link, None
+        if link is not None:
+            link.close()
 
     def run(self):
         try:
@@ -689,21 +741,21 @@ class TargetWorker(threading.Thread):
                     break
                 # one stage per drained batch (span ``ec.spread.send``):
                 # its merged runs go out back to back on this thread,
-                # to the holder it names. The batch must stay
-                # referenced until the next one is drained: releasing
-                # the chunks the moment the queue runs dry makes the
-                # consumer's next `tobytes` fault fresh pages (PERF.md,
-                # PR 25)
+                # to the holder it names
                 with tracing.Stage(self.sink.send_span,
                                    self.sink.parent_span,
                                    target=self.url or "local") as st:
-                    for sid, off, chunks in merge_runs(batch):
+                    for sid, off, chunks, stripes in merge_runs(batch):
                         n = self._send_run(sid, off, chunks)
-                        self.sink._note_buffered(-n)
+                        for chunk, stripe in zip(chunks, stripes):
+                            self.sink._row_done(stripe, len(chunk))
                         st.nbytes += n
+                batch = None
         except BaseException as e:  # noqa: BLE001 - surfaced to consumer
             self.error = e
             self.sink._fail(e)
+        finally:
+            self._close_link()
 
     def _send_run(self, sid: int, off: int, chunks) -> int:
         writer = self.sink.writers[sid]
@@ -718,7 +770,7 @@ class TargetWorker(threading.Thread):
                 if attempt:
                     self.sink.stats.add_retry()
                 try:
-                    writer.send(self.url, off, chunks)
+                    writer.send(self.url, off, chunks, self.link())
                     self.acked += n
                     return n
                 except BaseException as e:  # noqa: BLE001 - retry/failover
@@ -800,21 +852,23 @@ class TargetWorker(threading.Thread):
         return False
 
 def merge_runs(batch):
-    """Merge a drained batch into per-shard contiguous runs, preserving
-    per-shard order (queue order is stripe order, so each shard's
-    offsets arrive ascending and contiguous)."""
-    runs = []          # [sid, start_off, [chunks], next_off]
+    """Merge a drained batch of ``(sid, off, chunk, stripe)`` items into
+    per-shard contiguous runs ``(sid, off, [chunks], [stripes])``,
+    preserving per-shard order (queue order is stripe order, so each
+    shard's offsets arrive ascending and contiguous)."""
+    runs = []          # [sid, start_off, [chunks], [stripes], next_off]
     open_run: Dict[int, list] = {}
-    for sid, off, chunk in batch:
+    for sid, off, chunk, stripe in batch:
         run = open_run.get(sid)
-        if run is not None and run[3] == off:
+        if run is not None and run[4] == off:
             run[2].append(chunk)
-            run[3] += len(chunk)
+            run[3].append(stripe)
+            run[4] += len(chunk)
         else:
-            run = [sid, off, [chunk], off + len(chunk)]
+            run = [sid, off, [chunk], [stripe], off + len(chunk)]
             runs.append(run)
             open_run[sid] = run
-    return [(sid, off, chunks) for sid, off, chunks, _ in runs]
+    return [tuple(run[:4]) for run in runs]
 
 
 class StripedPush:
@@ -947,23 +1001,37 @@ class StripedPush:
             time.sleep(min(need - spent, 0.25))
 
     # -- the stream ---------------------------------------------------------
-    def write_stripe(self, data, parity):
+    def write_stripe(self, data, parity, done=None):
         """Route one stripe: row i of ``data``/``parity`` is the next
-        ``w`` bytes of shard i / shard k+i."""
+        ``w`` bytes of shard i / shard k+i. The rows are queued as
+        views, not copies: the stripe's arrays belong to the sink until
+        every row's run is acknowledged, and ``done()`` is called then
+        (from a worker thread) — the caller's leave to write into them
+        again. A stripe of a failed spread is never done."""
         k = data.shape[0]
         w = data.shape[1]
         off = self.offset
+        stripe = [self.total, done]     # rows still unacknowledged
         stripe_bytes = 0
         for sid in range(self.total):
-            row = data[sid] if sid < k else parity[sid - k]
-            chunk = row.tobytes()
-            stripe_bytes += len(chunk)
-            self._note_buffered(len(chunk))
-            self._put(self._worker_of[sid], (sid, off, chunk))
+            row = memoryview(
+                data[sid] if sid < k else parity[sid - k]).cast("B")
+            stripe_bytes += len(row)
+            self._note_buffered(len(row))
+            self._put(self._worker_of[sid], (sid, off, row, stripe))
         self.offset = off + w
         with self._lock:
             self.stats.stripes += 1
         self._pace(stripe_bytes)
+
+    def _row_done(self, stripe: list, nbytes: int):
+        """One row of ``stripe`` is on its holder's disk."""
+        with self._lock:
+            self._buffered -= nbytes
+            stripe[0] -= 1
+            last = stripe[0] == 0
+        if last and stripe[1] is not None:
+            stripe[1]()
 
     def finish(self):
         """Drain every window, join the workers, then finalize all

@@ -67,31 +67,40 @@ class Request:
         return "chunked" in \
             (self.headers.get("Transfer-Encoding") or "").lower()
 
+    def _next_chunk_size(self) -> int:
+        """The size line of the next chunk of a chunked body (the
+        framing post_chunked emits); at the last chunk, its trailers
+        too. Any framing violation severs the connection:
+        resynchronizing a keep-alive stream after a bad chunk header is
+        not possible."""
+        rfile = self.handler.rfile
+        line = rfile.readline(1 << 16)
+        if not line or not line.endswith(b"\n"):
+            self.handler.close_connection = True
+            raise HttpError(400, "truncated chunked body")
+        size_s = line.split(b";", 1)[0].strip()
+        try:
+            size = int(size_s, 16)
+        except ValueError:
+            self.handler.close_connection = True
+            raise HttpError(400, "bad chunk size") from None
+        if size == 0:
+            # consume optional trailers up to the blank line
+            while True:
+                t = rfile.readline(1 << 16)
+                if t in (b"\r\n", b"\n", b""):
+                    break
+        return size
+
     def _read_chunked(self) -> bytes:
-        """Decode a chunked transfer-encoded body (the framing
-        post_chunked emits: streaming uploads whose size isn't known —
-        or not yet complete — when the request line goes out). Any
-        framing violation severs the connection: resynchronizing a
-        keep-alive stream after a bad chunk header is not possible."""
+        """Decode a chunked transfer-encoded body whole (streaming
+        uploads whose size isn't known — or not yet complete — when
+        the request line goes out)."""
         rfile = self.handler.rfile
         out: List[bytes] = []
         while True:
-            line = rfile.readline(1 << 16)
-            if not line or not line.endswith(b"\n"):
-                self.handler.close_connection = True
-                raise HttpError(400, "truncated chunked body")
-            size_s = line.split(b";", 1)[0].strip()
-            try:
-                size = int(size_s, 16)
-            except ValueError:
-                self.handler.close_connection = True
-                raise HttpError(400, "bad chunk size") from None
+            size = self._next_chunk_size()
             if size == 0:
-                # consume optional trailers up to the blank line
-                while True:
-                    t = rfile.readline(1 << 16)
-                    if t in (b"\r\n", b"\n", b""):
-                        break
                 return b"".join(out)
             data = rfile.read(size)
             if len(data) != size:
@@ -99,6 +108,48 @@ class Request:
                 raise HttpError(400, "truncated chunk")
             out.append(data)
             rfile.read(2)  # chunk-terminating CRLF
+
+    def body_pieces(self, buf: memoryview):
+        """The body piece by piece, for a handler that moves it on
+        without ever holding it: each piece is a view of ``buf`` (the
+        caller's, reused) filled straight from the connection, good
+        until the next is asked for. Both framings. A body that ends
+        short raises HttpError 400 after the pieces that did arrive,
+        and a generator left before its end leaves unread bytes in the
+        socket: either way the connection is closed after the
+        response. ``.body`` is empty afterwards."""
+        if self._body is not None:
+            raise RuntimeError("request body already read")
+        self._body = b""
+        handler = self.handler
+        chunked = self._chunked()
+        left = 0
+        if not chunked:
+            try:
+                left = int(self.headers.get("Content-Length") or 0)
+            except ValueError:
+                left = -1
+            if left < 0:
+                handler.close_connection = True
+                raise HttpError(400, "bad Content-Length header")
+        keep, handler.close_connection = handler.close_connection, True
+        rfile = handler.rfile
+        while True:
+            if chunked:
+                left = self._next_chunk_size()
+                if left == 0:
+                    break
+            while left:
+                piece = buf[:min(left, len(buf))]
+                # what the reader holds, then socket -> piece directly
+                if rfile.readinto(piece) != len(piece):
+                    raise HttpError(400, "truncated body")
+                left -= len(piece)
+                yield piece
+            if not chunked:
+                break
+            rfile.read(2)  # chunk-terminating CRLF
+        handler.close_connection = keep
 
     def drain(self, cap: int = 4 << 20):
         """Discard any unread request body. Keep-alive framing depends
@@ -365,6 +416,10 @@ def _make_handler(router: Router):
                 self.send_response(status)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Content-Length", str(len(data)))
+                if self.close_connection:
+                    # a body left unread: tell a client that keeps its
+                    # connection not to send the next request down it
+                    self.send_header("Connection", "close")
                 self.end_headers()
                 self.wfile.write(data)
             except (BrokenPipeError, ConnectionResetError):
@@ -812,9 +867,10 @@ def _pooled_call(method: str, url: str, body, headers: dict,
     # on a fresh connection — but only for idempotent methods with a
     # replayable body. A POST whose server died between processing and
     # responding must NOT silently re-execute (double assign/publish) —
-    # Go's http.Client draws the same line. Streaming bodies cannot be
-    # re-sent at all, so they always go out on a FRESH connection
-    # (their transfer time dwarfs the handshake).
+    # Go's http.Client draws the same line. A chunked body from an
+    # iterator cannot be re-sent at all, so it always goes out on a
+    # FRESH connection. (A caller that still holds its body's buffers
+    # and owns the retry keeps a connection of its own: KeptConnection.)
     replayable = not encode_chunked and \
         (body is None or isinstance(body, (bytes, bytearray)))
     idempotent = method in ("GET", "HEAD", "DELETE", "PUT")
@@ -949,10 +1005,11 @@ def post_json(url: str, obj=None, timeout: float = 30.0) -> dict:
 def post_chunked(url: str, chunks, headers: Optional[dict] = None,
                  timeout: float = 300.0) -> bytes:
     """POST an iterable of byte chunks with chunked transfer-encoding —
-    the body can start flowing before its total size is known (the EC
-    spread pushes shard ranges as the encode produces them). Chunked
+    the body can start flowing before its total size is known. Chunked
     bodies are not replayable, so the call always goes out on a fresh
-    connection and is never retried here; the spread layer owns retry."""
+    connection and is never retried here. (The EC spread knows a run's
+    length and holds its buffers, so it sends through a KeptConnection;
+    its holders take this framing too, from an older sender.)"""
     url = _client_url(url)
     h = dict(headers or {})
     h["Transfer-Encoding"] = "chunked"
@@ -963,6 +1020,72 @@ def post_chunked(url: str, chunks, headers: Optional[dict] = None,
         raise
     except (OSError, _httpc.HTTPException) as e:
         raise HttpError(503, f"POST {url}: {e}") from None
+
+
+class KeptConnection:
+    """One connection to one cluster peer that its owner keeps open
+    across POSTs whose bodies are buffers the owner still holds (the EC
+    spread: one a push worker and holder, ec/transport.py). A body goes
+    out under a Content-Length as the buffers it is made of — row views
+    of a slab are written to the socket as they lie, never joined or
+    framed into new bytes — and is therefore replayable: any failure
+    closes the connection and raises, and the owner's retry opens the
+    next. One thread at a time."""
+
+    def __init__(self, netloc: str, timeout: float = 300.0):
+        self.netloc = netloc
+        self.timeout = timeout
+        self.connects = 0       # connections opened over its life
+        self._conn = None
+
+    def _connect(self):
+        scheme = "https" if _TLS["client_ctx"] is not None else "http"
+        conn = _new_conn(scheme, self.netloc, self.timeout)
+        conn.connect()
+        _nodelay(conn)
+        self.connects += 1
+        _pool_count("created")
+        return conn
+
+    def post_parts(self, target: str, parts,
+                   headers: Optional[dict] = None) -> bytes:
+        """POST ``target`` (path + query) with the concatenation of the
+        buffers ``parts`` as body; the response body, HttpError on a
+        status of 400 and above."""
+        try:
+            if self._conn is None:
+                self._conn = self._connect()
+            conn = self._conn
+            conn.putrequest("POST", target, skip_accept_encoding=True)
+            for k, v in (headers or {}).items():
+                conn.putheader(k, v)
+            conn.putheader("Content-Length",
+                           str(sum(len(p) for p in parts)))
+            conn.endheaders()
+            for p in parts:
+                conn.send(p)
+            resp = conn.getresponse()
+            data = resp.read()
+        except (OSError, _httpc.HTTPException) as e:
+            self.close()
+            raise HttpError(
+                503, f"POST http://{self.netloc}{target}: {e}") from None
+        except BaseException:
+            self.close()
+            raise
+        if resp.will_close:
+            self.close()
+        if resp.status >= 400:
+            detail = data.decode("utf-8", "replace")[:500]
+            raise HttpError(
+                resp.status,
+                f"POST http://{self.netloc}{target}: {detail}")
+        return data
+
+    def close(self):
+        conn, self._conn = self._conn, None
+        if conn is not None:
+            conn.close()
 
 
 def _quote_name(name: str) -> str:

@@ -39,6 +39,23 @@ def _tag_holder_read(bytes_read: int, bytes_sent: int):
         span.tags["bytes_sent"] = int(bytes_sent)
 
 
+# the holder's end of the streaming spread moves a run from the socket to
+# its .part file through one buffer of this size (admin_ec_shard_write):
+# a whole 8 MiB row of the default slab a piece (1 MiB pieces read 5 %
+# less encode_mbps on the chip host, one run: PERF.md, PR 30)
+SHARD_WRITE_PIECE = 8 << 20
+
+
+def _tag_shard_write(nbytes: int, pieces: int):
+    """On the server span of a holder's shard_write append: the bytes
+    it moved from the socket to the stage file, and in how many pieces
+    of its buffer (also when the run ended short and was rolled back)."""
+    span = tracing.current_span()
+    if span is not None:
+        span.tags["bytes"] = int(nbytes)
+        span.tags["pieces"] = int(pieces)
+
+
 class VolumeServer:
     def __init__(self, port: int = 8080, host: str = "127.0.0.1",
                  directories=None, master_url: str = "127.0.0.1:9333",
@@ -145,6 +162,10 @@ class VolumeServer:
             data_center=data_center, rack=rack, codec=codec,
             index_kind=index_kind)
         self.volume_size_limit = 30 * 1024 * 1024 * 1024
+        # shard_write's piece buffers, kept between requests (a fresh
+        # 8 MiB block is page faults on these hosts): a handler takes
+        # one and puts it back, so there are as many as ever ran at once
+        self._shard_write_bufs: List[memoryview] = []
         # upload size cap (reference -fileSizeLimitMB: "limit file size
         # to avoid out of memory"); 0 (or negative) disables
         self.file_size_limit = max(0, int(file_size_limit_mb)) << 20
@@ -928,9 +949,16 @@ class VolumeServer:
 
     def admin_ec_shard_write(self, req: Request):
         """Receive one shard's ranges from a streaming encode+spread
-        (ec/spread.py): chunked POSTs append at the expected offset into
-        ``<shard>.part`` (409 carries the staged size on a mismatch, so
-        a sender that lost an ack can tell delivered from diverged);
+        (ec/spread.py): each POST appends one run at the expected offset
+        into ``<shard>.part`` (409 carries the staged size on a
+        mismatch, so a sender that lost an ack can tell delivered from
+        diverged). The body streams: socket -> a reused buffer -> the
+        file, a piece at a time (``Request.body_pieces``, either
+        framing), so no run is ever held or joined and the write of one
+        piece overlaps the arrival of the next. A run is appended whole
+        or not at all — a body that ends short truncates the stage back
+        to the request's offset before the error goes out — and is
+        acknowledged only after its last byte is written to the file.
         ``action=finalize&size=`` verifies the stage and atomically
         renames it into place; ``action=abort`` drops the stages —
         failures never leave partial shard files."""
@@ -966,21 +994,41 @@ class VolumeServer:
                     "finalized": True}
         off = int(req.query.get("offset", "0"))
         staged = os.path.getsize(part) if os.path.exists(part) else 0
-        if off != staged and off != 0:
-            # consume the (window-bounded) body so the sender can read
-            # this response off a cleanly framed connection — a sender
-            # that lost an ack needs the staged size to tell delivered
-            # from diverged
-            _ = req.body
-            raise HttpError(409, f"shard {sid} offset mismatch: "
-                                 f"staged={staged} offset={off}")
-        data = req.body
-        # offset 0 truncates: a replayed first range (failover to this
-        # node, or a retry whose original died mid-body) starts clean
-        with open(part, "wb" if off == 0 else "ab") as f:
-            f.write(data)
-            staged = f.tell()
-        return {"volume": vid, "shard": sid, "staged": staged}
+        try:
+            buf = self._shard_write_bufs.pop()
+        except IndexError:
+            buf = memoryview(bytearray(SHARD_WRITE_PIECE))
+        try:
+            if off != staged and off != 0:
+                # consume the (window-bounded) body so the sender can
+                # read this response off a cleanly framed connection —
+                # a sender that lost an ack needs the staged size to
+                # tell delivered from diverged
+                for _ in req.body_pieces(buf):
+                    pass
+                raise HttpError(409, f"shard {sid} offset mismatch: "
+                                     f"staged={staged} offset={off}")
+            # offset 0 truncates: a replayed first range (failover to
+            # this node, or a retry whose original died mid-body)
+            # starts clean
+            fd = os.open(part, os.O_WRONLY | os.O_CREAT | os.O_APPEND
+                         | (os.O_TRUNC if off == 0 else 0), 0o644)
+            nbytes = pieces = 0
+            try:
+                for piece in req.body_pieces(buf):
+                    nbytes += len(piece)
+                    pieces += 1
+                    while piece:
+                        piece = piece[os.write(fd, piece):]
+            except BaseException:
+                os.ftruncate(fd, off)   # whole or not at all
+                raise
+            finally:
+                os.close(fd)
+                _tag_shard_write(nbytes, pieces)
+        finally:
+            self._shard_write_bufs.append(buf)
+        return {"volume": vid, "shard": sid, "staged": off + nbytes}
 
     def admin_ec_mount(self, req: Request):
         vid = int(req.query["volume"])

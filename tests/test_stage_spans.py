@@ -212,7 +212,10 @@ def test_phases_keep_their_keys_and_their_sum(cycle):
     enc = cycle["encode"]
     assert set(enc["phases"]) == {"gather", "dispatch", "drain", "write"}
     writes = sum(s["duration_s"] for s in _named(cycle, "ec.encode.write"))
-    assert enc["phases"]["write"] == pytest.approx(writes, rel=1e-3)
+    # (the reply rounds a phase to the microsecond, and since PR 30 a
+    # stripe's write is queueing 14 views: tens of microseconds)
+    assert enc["phases"]["write"] == pytest.approx(writes, rel=1e-3,
+                                                   abs=1e-6)
     assert sum(enc["phases"].values()) <= enc["stream_s"] * 1.01
     # the phase spans themselves are still there, one of each a command
     tid = enc["trace_id"]

@@ -120,7 +120,7 @@ class FakeTarget:
 ENC = dict(large_block=64 << 10, small_block=16 << 10, slab=16 << 10)
 
 
-def _seed_oracle(dirpath, codec, nbytes, seed=7):
+def _seed_oracle(dirpath, codec, nbytes, seed=7, enc=None, layout="flat"):
     """Write 1.dat in dirpath, encode it in a sibling oracle dir with
     the same codec/geometry, return (base, {sid: sha256})."""
     rng = np.random.default_rng(seed)
@@ -132,7 +132,7 @@ def _seed_oracle(dirpath, codec, nbytes, seed=7):
     os.makedirs(odir, exist_ok=True)
     obase = os.path.join(odir, "1")
     shutil.copy(base + ".dat", obase + ".dat")
-    write_ec_files(obase, codec=codec, **ENC)
+    write_ec_files(obase, codec=codec, layout=layout, **(enc or ENC))
     digests = {}
     for i in range(codec.total):
         with open(obase + to_ext(i), "rb") as f:
@@ -516,3 +516,320 @@ def test_small_dispatch_auto_apply(monkeypatch):
         assert codec_mod.small_dispatch_default() == applied
     finally:
         codec_mod.set_small_dispatch_override(None)
+
+
+# -- PR 30: a shard byte crosses each side of the socket once -----------------
+# The sender queues views of a stripe's rows (no copies) and sends a run
+# as those views on one kept connection; the holder streams the body from
+# the socket through one reused buffer into the .part file.
+
+class CuttingTarget(FakeTarget):
+    """A holder whose first append dies in the middle of its body: it
+    reads one piece of the run, then fails — nothing of the run is
+    staged and the connection is closed under the sender."""
+
+    def __init__(self, directory):
+        super().__init__(directory)
+        self.cuts = 1
+
+    def _shard_write(self, req):
+        if req.query.get("action", "append") == "append" and self.cuts:
+            self.cuts -= 1
+            next(req.body_pieces(memoryview(bytearray(4096))))
+            raise HttpError(500, "injected cut in the middle of a run")
+        return super()._shard_write(req)
+
+
+def _encode_through(tmp_path, codec, targets, remote, window=2,
+                    spares=None, nbytes=6 * (64 << 10) + 70_001):
+    src = tmp_path / "src"
+    src.mkdir(parents=True)
+    base, oracle = _seed_oracle(src, codec, nbytes)
+    total = codec.total
+    assignment = {sid: remote.get(sid, LOCAL) for sid in range(total)}
+    stats = {}
+    sink = StripedSpreadSink(1, base, assignment, total, local_url=LOCAL,
+                             window=window, spares=spares)
+    write_ec_files_spread(base, sink, codec=codec, stats=stats, **ENC)
+    for sid, url in sink.assignment().items():
+        holder = targets[url].dir if url else str(src)
+        assert _digest(os.path.join(holder, f"1{to_ext(sid)}")) \
+            == oracle[sid], f"shard {sid} diverged"
+    return stats, sink
+
+
+def test_one_connection_a_worker_and_holder(tmp_path):
+    """`spread_connects`: a clean encode opens one connection to each
+    remote target however many runs it sends; a retry after a run cut in
+    the middle opens one more, and so does a failover to a spare."""
+    codec = NumpyCodec(6, 3)
+    dirs = [tmp_path / n for n in ("a", "b", "cut", "dead", "spare")]
+    for d in dirs:
+        d.mkdir()
+    a, b = FakeTarget(str(dirs[0])), FakeTarget(str(dirs[1]))
+    cut = CuttingTarget(str(dirs[2]))
+    dead, spare = FakeTarget(str(dirs[3])), FakeTarget(str(dirs[4]))
+    dead.fail = True
+    targets = {t.url: t for t in (a, b, cut, dead, spare)}
+    try:
+        clean, _ = _encode_through(
+            tmp_path / "clean", codec, targets,
+            {1: a.url, 4: a.url, 7: a.url, 2: b.url, 8: b.url})
+        assert clean["spread_connects"] == 2
+        assert clean["spread_sends"] > 2 * clean["spread_connects"]
+        assert clean["spread_retries"] == 0
+        assert clean["holder_fetches"][a.url] == a.appends >= 2
+
+        # a run cut mid-body: nothing of it staged, the retry (a new
+        # connection: the holder closed the one it cut) lands it whole
+        retried, _ = _encode_through(
+            tmp_path / "retried", codec, targets,
+            {1: a.url, 4: a.url, 2: cut.url, 8: cut.url})
+        assert retried["spread_retries"] == 1
+        assert retried["spread_connects"] == 2 + 1
+
+        # failover: the dead holder answered 503 on its one connection
+        # (both attempts), the spare gets the worker's next
+        moved, sink = _encode_through(
+            tmp_path / "moved", codec, targets,
+            {1: a.url, 7: dead.url, 8: dead.url}, spares=[spare.url])
+        assert moved["spread_failovers"] == 1
+        assert sink.assignment()[7] == spare.url
+        assert moved["spread_connects"] == 2 + 1
+    finally:
+        for t in targets.values():
+            t.stop()
+
+
+@pytest.fixture
+def one_server(tmp_path):
+    from seaweedfs_tpu.server.master import MasterServer
+    from seaweedfs_tpu.server.volume_server import VolumeServer
+    master = MasterServer(port=0, pulse_seconds=1).start()
+    vs = VolumeServer(port=0, directories=[str(tmp_path / "v")],
+                      master_url=master.url, pulse_seconds=1,
+                      max_volume_counts=[5], ec_backend="numpy").start()
+    yield vs
+    vs.stop()
+    master.stop()
+
+
+def _raw_request(vs, head: bytes, body: bytes):
+    """Send exactly these bytes, half-close, and read the reply: a
+    sender that died in the middle of a body."""
+    import socket
+    host, port = vs.url.split(":")
+    with socket.create_connection((host, int(port)), timeout=10) as s:
+        s.sendall(head + body)
+        s.shutdown(socket.SHUT_WR)
+        reply = b""
+        while chunk := s.recv(65536):
+            reply += chunk
+    return reply
+
+
+@pytest.mark.parametrize("framing", ["content-length", "chunked"])
+def test_a_run_is_appended_whole_or_not_at_all(one_server, framing):
+    """A body cut in the middle leaves the stage at the request's
+    offset, the sender's retry at that offset lands the run, and the
+    409 `staged=` reply tells delivered-but-unacked from diverged —
+    under both framings of the endpoint."""
+    from seaweedfs_tpu.ec.transport import RemoteShardWriter
+    from seaweedfs_tpu.server.http_util import KeptConnection
+    vs = one_server
+    target = "/admin/ec/shard_write?volume=78&collection=&shard=3"
+    link = KeptConnection(vs.url)
+
+    def send(off, parts):
+        if framing == "chunked":
+            return post_chunked(f"http://{vs.url}{target}&offset={off}",
+                                parts)
+        return link.post_parts(f"{target}&offset={off}", parts)
+
+    p1, p2 = b"x" * 70_000, b"y" * 100_000
+    send(0, [memoryview(p1)[:30_000], memoryview(p1)[30_000:]])
+    part = os.path.join(vs.store.locations[0].directory,
+                        f"78{to_ext(3)}.part")
+    assert os.path.getsize(part) == len(p1)
+
+    # the run's head arrives, the rest never does
+    if framing == "chunked":
+        head = (f"POST {target}&offset={len(p1)} HTTP/1.1\r\n"
+                f"Host: x\r\nTransfer-Encoding: chunked\r\n\r\n").encode()
+        body = b"%x\r\n" % 40_000 + p2[:40_000] + b"\r\n" \
+            + b"%x\r\n" % 60_000 + p2[40_000:55_000]
+    else:
+        head = (f"POST {target}&offset={len(p1)} HTTP/1.1\r\nHost: x\r\n"
+                f"Content-Length: {len(p2)}\r\n\r\n").encode()
+        body = p2[:55_000]
+    reply = _raw_request(vs, head, body)
+    assert reply.startswith(b"HTTP/1.1 400"), reply[:200]
+    assert b"Connection: close" in reply
+    assert os.path.getsize(part) == len(p1)     # rolled back
+
+    # the retry at the same offset lands the run, through the writer
+    # the spread uses (its 409 handling included)
+    w = RemoteShardWriter(78, 3)
+    if framing == "chunked":
+        send(len(p1), [p2])
+    else:
+        assert w.send(vs.url, len(p1), [memoryview(p2)], link) == len(p2)
+    assert os.path.getsize(part) == len(p1) + len(p2)
+    with open(part, "rb") as f:
+        assert f.read() == p1 + p2
+
+    # delivered but the ack was lost: the same run again is a 409 whose
+    # staged size covers it — the writer takes that as delivered
+    with pytest.raises(HttpError) as ei:
+        send(len(p1), [p2])
+    assert ei.value.status == 409
+    assert f"staged={len(p1) + len(p2)}" in str(ei.value)
+    assert w.send(vs.url, len(p1), [memoryview(p2)], link) == len(p2)
+    # diverged: a run the stage does not end with stays an error
+    with pytest.raises(HttpError) as ei:
+        w.send(vs.url, 10, [memoryview(b"z" * 7)], link)
+    assert ei.value.status == 409 and "staged=170000" in str(ei.value)
+    assert os.path.getsize(part) == len(p1) + len(p2)
+    # the kept connection outlived every 409 (their bodies were consumed)
+    assert framing == "chunked" or link.connects == 1
+    link.close()
+
+
+def test_holder_never_holds_more_than_its_buffer(one_server, monkeypatch):
+    """A run larger than the holder's buffer goes through it piece by
+    piece (`pieces` on the server span), into one buffer that the next
+    request finds again."""
+    from seaweedfs_tpu.server import volume_server
+    from seaweedfs_tpu.server.http_util import KeptConnection
+    from seaweedfs_tpu.util import tracing
+    vs = one_server
+    monkeypatch.setattr(volume_server, "SHARD_WRITE_PIECE", 64 << 10)
+    rng = np.random.default_rng(30)
+    run = rng.integers(0, 256, (3 << 16) + 5, dtype=np.uint8)
+    rows = [memoryview(run[:100_000]), memoryview(run[100_000:])]
+    spans = []
+    tracing.add_finish_hook(spans.append)
+    link = KeptConnection(vs.url)
+    try:
+        target = "/admin/ec/shard_write?volume=79&collection=&shard=0"
+        link.post_parts(f"{target}&offset=0", rows)
+        link.post_parts(f"{target}&offset={run.size}", rows)
+        post_chunked(f"http://{vs.url}{target}&offset={2 * run.size}",
+                     [bytes(r) for r in rows])
+    finally:
+        tracing.remove_finish_hook(spans.append)
+        link.close()
+    appends = [s for s in spans
+               if s["name"] == "POST /admin/ec/shard_write"]
+    assert [s["tags"]["bytes"] for s in appends] == [run.size] * 3
+    # Content-Length: the buffer full but for the tail; chunked: a chunk
+    # never shares a piece with the next
+    assert [s["tags"]["pieces"] for s in appends] == [4, 4, 2 + 2]
+    assert len(vs._shard_write_bufs) == 1
+    assert len(vs._shard_write_bufs[0]) == 64 << 10
+    part = os.path.join(vs.store.locations[0].directory,
+                        f"79{to_ext(0)}.part")
+    with open(part, "rb") as f:
+        assert f.read() == run.tobytes() * 3
+    assert link.connects == 1
+
+
+@pytest.mark.parametrize("case", ["flat", "mesh-pieces", "piggyback"])
+def test_slab_goes_back_only_after_its_last_row_is_acknowledged(
+        tmp_path, monkeypatch, case):
+    """The spread sends views of a slab's rows, so the slab is the
+    sink's until the last of them is on its holder's disk: with a slow
+    holder and a reader that writes into every recycled slab, no slab
+    is handed back to the pool before the holder has staged its stripe,
+    and the shards come out bit-identical to the copy flow's."""
+    if case == "flat":
+        from seaweedfs_tpu.ops.rs_tpu import TpuCodec
+        # pipelined: the reader runs ahead of the spread
+        _slab_case(tmp_path, monkeypatch, TpuCodec(6, 3))
+    elif case == "mesh-pieces":
+        from test_mesh_codec import _private_programs
+        from seaweedfs_tpu.parallel.mesh import make_codec_mesh
+        from seaweedfs_tpu.parallel.mesh_codec import MeshCodec
+        with _private_programs():
+            codec = MeshCodec(6, 3, mesh=make_codec_mesh(width_devices=4),
+                              mesh_shard_min_bytes=0,
+                              small_dispatch_bytes=0)
+            pieces = _slab_case(tmp_path, monkeypatch, codec)
+        # four pieces a dispatch, each a stripe of its own
+        assert pieces.count((16 << 10) // 4) >= 4 * 24
+    else:
+        _slab_case(tmp_path, monkeypatch, NumpyCodec(10, 4),
+                   layout="piggyback",
+                   enc=dict(large_block=4096, small_block=512, slab=3000))
+
+
+def _slab_case(tmp_path, monkeypatch, codec, layout="flat", enc=ENC):
+    from seaweedfs_tpu.ec import encoder
+    k, m = codec.k, codec.m
+    src, tdir = tmp_path / "src", tmp_path / "t"
+    src.mkdir()
+    tdir.mkdir()
+    nbytes = 77_003 if layout == "piggyback" \
+        else k * (16 << 10) * 24 + 333
+    base, oracle = _seed_oracle(src, codec, nbytes, enc=enc, layout=layout)
+    tgt = FakeTarget(str(tdir))
+    tgt.delay = 0.004
+    remote = [sid for sid in range(k + m) if sid % 3]
+    ends, given, reused, early, pieces = {}, [], [], [], []
+
+    def staged(sid):
+        p = os.path.join(str(tdir), f"1{to_ext(sid)}")
+        p = p + ".part" if os.path.exists(p + ".part") else p
+        return os.path.getsize(p) if os.path.exists(p) else 0
+
+    class NotingSink(StripedSpreadSink):
+        def write_stripe(self, data, parity, done=None):
+            ends[id(data.base)] = self.offset + data.shape[1]
+            pieces.append(data.shape[1])
+            super().write_stripe(data, parity, done)
+
+    real_give, real_take = encoder._give_slab, encoder._take_slab
+
+    def checked_give(data):
+        end = ends[id(data.base)]
+        behind = [sid for sid in remote if staged(sid) < end]
+        if behind:
+            early.append((end, behind))
+        given.append(id(data.base))
+        real_give(data)
+
+    def noting_take(kk, width):
+        out = real_take(kk, width)
+        if id(out.base) in given:
+            reused.append(id(out.base))
+        return out
+
+    monkeypatch.setattr(encoder, "_give_slab", checked_give)
+    monkeypatch.setattr(encoder, "_take_slab", noting_take)
+    encoder._SLAB_POOL.clear()
+    try:
+        assignment = {sid: tgt.url if sid in remote else LOCAL
+                      for sid in range(k + m)}
+        stats = {}
+        sink = NotingSink(1, base, assignment, k + m, local_url=LOCAL,
+                          window=1)
+        write_ec_files_spread(base, sink, codec=codec, stats=stats,
+                              layout=layout, **enc)
+        for sid in range(k + m):
+            holder = str(tdir) if sid in remote else str(src)
+            assert _digest(os.path.join(holder, f"1{to_ext(sid)}")) \
+                == oracle[sid], f"shard {sid} diverged"
+        assert early == [], "slabs handed back before their rows were " \
+                            f"on the holder: {early[:3]}"
+        if layout == "piggyback":
+            # never recycled: the stripes only keep them referenced
+            assert given == [] and len(encoder._SLAB_POOL) == 0
+        else:
+            assert len(given) == 25          # every slab came back once
+            assert reused, "no recycled slab was read into again"
+            assert len(encoder._SLAB_POOL) <= encoder._SLAB_POOL.maxlen
+        assert stats["spread_connects"] == 1
+    finally:
+        tgt.stop()
+        encoder._SLAB_POOL.clear()
+    return pieces

@@ -288,3 +288,77 @@ def test_pull_push_roundtrip_bit_identical(tmp_path):
         a.stop()
         b.stop()
         tgt.stop()
+
+
+# -- PR 30: the push side under TLS, and the kept connection's edges ---------
+
+def test_push_over_tls_real_holders(tmp_path):
+    """The views-on-a-kept-connection sender and the streaming holder
+    under TLS, through a real volume server: shards bit-identical."""
+    from seaweedfs_tpu.server.http_util import configure_tls, reset_tls
+    from seaweedfs_tpu.server.master import MasterServer
+    from seaweedfs_tpu.server.volume_server import VolumeServer
+    from test_aux_subsystems import _make_cert
+    cert, key = _make_cert(tmp_path)
+    k, m = 6, 3
+    codec = NumpyCodec(k, m)
+    src = tmp_path / "src"
+    src.mkdir()
+    base, oracle = _seed_oracle(src, codec, k * (16 << 10) * 6 + 11)
+    master = holder = None
+    try:
+        configure_tls(cert, key)
+        master = MasterServer(port=0, pulse_seconds=1).start()
+        holder = VolumeServer(
+            port=0, directories=[str(tmp_path / "h")],
+            master_url=master.url, pulse_seconds=1,
+            max_volume_counts=[5], ec_backend="numpy").start()
+        remote = (1, 4, 8)
+        assignment = {sid: holder.url if sid in remote else LOCAL
+                      for sid in range(k + m)}
+        stats = {}
+        sink = StripedSpreadSink(1, base, assignment, k + m,
+                                 local_url=LOCAL, window=2)
+        write_ec_files_spread(base, sink, codec=codec, stats=stats,
+                              **ENC)
+        hdir = holder.store.locations[0].directory
+        for sid in range(k + m):
+            d = hdir if sid in remote else str(src)
+            assert _digest(os.path.join(d, f"1{to_ext(sid)}")) \
+                == oracle[sid], f"shard {sid} diverged"
+        assert stats["spread_connects"] == 1
+        assert stats["spread_retries"] == 0
+    finally:
+        for s in (holder, master):
+            if s is not None:
+                s.stop()
+        reset_tls()
+
+
+def test_stale_kept_connection_is_one_retry(tmp_path):
+    """A holder that closed the kept connection between two runs (its
+    idle timeout, a restart): the next run fails on it and the
+    worker's existing retry opens the next — one more connect, one
+    retry, nothing lost or doubled."""
+    from seaweedfs_tpu.server.http_util import KeptConnection
+    tdir = tmp_path / "t"
+    tdir.mkdir()
+    tgt = FakeTarget(str(tdir))
+    try:
+        stats = transport.SpreadStats()
+        w = transport.RemoteShardWriter(5, 0, stats=stats)
+        link = KeptConnection(tgt.url)
+        assert w.send(tgt.url, 0, [memoryview(b"a" * 5000)], link) == 5000
+        tgt.server.httpd.close_all_connections()    # the holder's side
+        time.sleep(0.05)
+        with pytest.raises(Exception):
+            w.send(tgt.url, 5000, [memoryview(b"b" * 7000)], link)
+        # the sender's retry: same run, same offset, a new connection
+        assert w.send(tgt.url, 5000, [memoryview(b"b" * 7000)],
+                      link) == 7000
+        assert link.connects == 2 and stats.connects == 2
+        assert os.path.getsize(
+            os.path.join(str(tdir), f"5{to_ext(0)}.part")) == 12000
+        link.close()
+    finally:
+        tgt.stop()
