@@ -277,18 +277,7 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
-        gather_busy = gs.busy_s()
-        compute_busy = max(stream_s - phases["gather"], 0.0)
-        serialized = gather_busy + compute_busy
-        overlap = 0.0
-        if serialized > 0:
-            overlap = max(0.0, min(1.0,
-                                   (serialized - stream_s) / serialized))
-        stats["gather_busy_s"] = round(gather_busy, 3)
-        stats["compute_busy_s"] = round(compute_busy, 3)
-        stats["overlap_frac"] = round(overlap, 4)
-        stats["gather_mbps"] = round(gs.mbps(), 1)
-        stats["gather_remote_shards"] = gs.remote_shards
+        stats.update(gs.overlap(stream_s, phases["gather"]))
         # the repair story: symbol bytes moved vs the k*shard baseline
         # the full-RS gather would have pulled for the same rebuild
         stats["repair_mode"] = "trace"
@@ -405,18 +394,7 @@ def rebuild_ec_file_piggyback(base_name: str, lost_sid: int, source,
         stats["backend"] = codec.backend
         stats["layout"] = "piggyback"
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
-        gather_busy = gs.busy_s()
-        compute_busy = max(stream_s - phases["gather"], 0.0)
-        serialized = gather_busy + compute_busy
-        overlap = 0.0
-        if serialized > 0:
-            overlap = max(0.0, min(1.0,
-                                   (serialized - stream_s) / serialized))
-        stats["gather_busy_s"] = round(gather_busy, 3)
-        stats["compute_busy_s"] = round(compute_busy, 3)
-        stats["overlap_frac"] = round(overlap, 4)
-        stats["gather_mbps"] = round(gs.mbps(), 1)
-        stats["gather_remote_shards"] = gs.remote_shards
+        stats.update(gs.overlap(stream_s, phases["gather"]))
         # the repair story: half-plane bytes moved vs the k*shard
         # baseline the full-RS gather would have pulled
         stats["repair_mode"] = "piggyback"

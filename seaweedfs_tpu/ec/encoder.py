@@ -537,12 +537,10 @@ def rebuild_ec_files(base_name: str,
     device_bytes / host_fallbacks deltas, survivor_bytes, stream_s) —
     the bench's regression counters.
 
-    ``layout``: an ec.layout.LayoutInfo (or None for flat). Piggyback
-    volumes decode through ops/codec.piggyback_decode_plan — the same
-    one-fused-dispatch-per-slab stream, with each survivor slab split
-    into sub-chunk rows per window before the matmul and each rebuilt
-    slab merged back before the write."""
-    from ..ops import codec as ops_codec
+    ``layout``: an ec.layout.LayoutInfo (or None for flat). A piggyback
+    volume goes through rebuild_ec_files_piggyback, the body the
+    streaming rebuild runs too, with every survivor read from a local
+    file."""
     codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
     k, total = codec.k, codec.total
     if pipelined is None:
@@ -564,9 +562,18 @@ def rebuild_ec_files(base_name: str,
             elif shard_size != sz:
                 raise ValueError("surviving shards differ in size")
     if piggyback:
-        return _rebuild_ec_files_piggyback(
-            base_name, codec, layout, present, missing, shard_size,
-            slab, stats)
+        from .gather import GatherStats, LocalShardReader, \
+            StripedGatherSource
+        gstats = GatherStats()
+        root = tracing.current_span()
+        return rebuild_ec_files_piggyback(
+            base_name, present, missing, layout,
+            lambda src: StripedGatherSource(
+                [LocalShardReader(base_name + to_ext(i), gstats)
+                 for i in src], shard_size,
+                slab=_pb_slab(slab, layout.window), stats=gstats,
+                parent_span=root),
+            codec=codec, pipelined=pipelined, stats=stats)
     ins = [open(base_name + to_ext(i), "rb") if present[i] else None
            for i in range(total)]
     outs = {i: open(base_name + to_ext(i), "wb") for i in missing}
@@ -671,137 +678,98 @@ def _pb_slab(slab: int, window: int) -> int:
     return max(window, slab - slab % window)
 
 
-def _rebuild_ec_files_piggyback(base_name, codec, layout, present,
-                                missing, shard_size, slab, stats
-                                ) -> List[int]:
-    """Local piggyback rebuild: decode every missing shard (data AND
-    parity) from the coupled decode plan's source set in one fused
-    matmul per slab. Shard sizes are window-aligned by construction
-    (both stripe blocks divide by the window), so slabs clamp to whole
-    windows with no tail special-case."""
-    import time as _time
-    from ..ops import codec as ops_codec
-    from ..ops import telemetry
-    k = codec.k
-    alpha, window = layout.alpha, layout.window
-    if shard_size % window:
-        raise ValueError(
-            f"piggyback shard size {shard_size} not window-aligned "
-            f"({window}); sidecar geometry is wrong for these shards")
-    src, plan_missing, coeffs = ops_codec.piggyback_decode_plan(
-        codec.k, codec.m, tuple(bool(p) for p in present),
-        matrix_kind=getattr(codec, "matrix_kind", "vandermonde"),
-        matrix=getattr(codec, "matrix", None),
-        pairs=layout.pairs)
-    rows = [plan_missing.index(i) for i in missing]
-    eff_slab = _pb_slab(slab, window)
-    before = telemetry.STATS.snapshot()
-    phases = {"gather": 0.0, "plan": 0.0, "dispatch": 0.0,
-              "drain": 0.0, "write": 0.0}
-    ins = {i: open(base_name + to_ext(i), "rb") for i in src}
-    outs = {i: open(base_name + to_ext(i), "wb") for i in missing}
-    t_stream = _time.perf_counter()
-    try:
-        for off in range(0, shard_size, eff_slab):
-            n = min(eff_slab, shard_size - off)
-            t0 = _time.perf_counter()
-            stack = []
-            for i in src:
-                ins[i].seek(off)
-                stack.append(np.frombuffer(ins[i].read(n), dtype=np.uint8))
-            block = np.stack(stack, axis=0)
-            t1 = _time.perf_counter()
-            sub = ops_codec.pb_split(block, alpha, window)
-            out = np.asarray(codec._matmul(coeffs, sub), dtype=np.uint8)
-            merged = ops_codec.pb_merge(out, alpha, window)
-            t2 = _time.perf_counter()
-            for r, i in zip(rows, missing):
-                outs[i].write(merged[r].tobytes())
-            t3 = _time.perf_counter()
-            phases["gather"] += t1 - t0
-            phases["dispatch"] += t2 - t1
-            phases["write"] += t3 - t2
-    finally:
-        for h in ins.values():
-            h.close()
-        for h in outs.values():
-            h.close()
-    stream_s = _time.perf_counter() - t_stream
-    for name, secs in phases.items():
-        if secs > 0:
-            tracing.record_span(name, secs, op="ec.rebuild",
-                                backend=codec.backend, layout="piggyback")
-    if stats is not None:
-        stats.update(telemetry.delta(before))
-        stats["survivor_bytes"] = shard_size * len(src)
-        stats["rebuilt_bytes"] = shard_size * len(missing)
-        stats["stream_s"] = round(stream_s, 3)
-        stats["backend"] = codec.backend
-        stats["operand"] = list(coeffs.shape)
-        stats["layout"] = "piggyback"
-        stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
-    return list(missing)
+def rebuild_ec_files_piggyback(base_name: str, present: List[bool],
+                               missing: List[int], layout, make_source,
+                               codec: Optional[ReedSolomonCodec] = None,
+                               pipelined: Optional[bool] = None,
+                               stats: Optional[dict] = None) -> List[int]:
+    """The full coupled decode of a piggyback volume: every shard of
+    ``missing`` — data and parity, any loss RS(k, m) survives — from k
+    whole survivor shards, local files and remote holders alike.
 
-
-def rebuild_ec_files_streaming_piggyback(base_name: str,
-                                         present: List[bool],
-                                         missing: List[int],
-                                         source,
-                                         layout,
-                                         codec: Optional[
-                                             ReedSolomonCodec] = None,
-                                         slab: int = DEFAULT_SLAB,
-                                         stats: Optional[dict] = None
-                                         ) -> List[int]:
-    """Streaming full decode for a piggyback volume: ``source`` yields
-    survivor stripes whose ROWS ARE THE DECODE PLAN'S src ORDER (every
-    surviving data shard, then the plan's parity picks — the caller
-    builds readers from piggyback_decode_plan's src list, not first-k).
-    Each stripe is window-split, pushed through the fused coupled
-    decode, merged, and appended to the missing shard files. Failure
-    removes partial outputs, same contract as the flat streaming
-    rebuild."""
-    import time as _time
+    ``make_source(src)`` is handed the decode plan's source shards (every
+    surviving data shard, then as many parities as data shards are lost:
+    not the first k) and returns the gather that reads them in that row
+    order (an ec.gather.StripedGatherSource whose slab is a whole number
+    of windows). Each stripe is window-split on the thread that gathers
+    it (the pipeline's producer), runs one fused matmul against the
+    (alpha * lost, alpha * k) plan — through PipelinedMatmul where the
+    codec pipelines, ``codec._matmul`` where it computes on the host, as
+    the coupled encode does — and is merged back into shard bytes and
+    appended, as views, on the consumer. One span a stripe and stage
+    under the stream's root (``ec.rebuild.plan``, ``.pb_split``,
+    ``ec.h2d``, ``ec.d2h``, ``.pb_merge``, ``.write``, beside the
+    gather's ``.fetch.*`` and ``.assemble``). Failure removes the
+    partial outputs: a caller gets whole shards or nothing."""
     from ..ops import codec as ops_codec
     from ..ops import telemetry
     codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
+    if pipelined is None:
+        pipelined = codec.pipelined
     if not missing:
         return []
     alpha, window = layout.alpha, layout.window
     before = telemetry.STATS.snapshot()
-    phases = {"gather": 0.0, "plan": 0.0, "dispatch": 0.0,
-              "drain": 0.0, "write": 0.0}
-    t0 = _time.perf_counter()
-    src, plan_missing, coeffs = ops_codec.piggyback_decode_plan(
-        codec.k, codec.m, tuple(bool(p) for p in present),
-        matrix_kind=getattr(codec, "matrix_kind", "vandermonde"),
-        matrix=getattr(codec, "matrix", None),
-        pairs=layout.pairs)
-    rows = [plan_missing.index(i) for i in missing]
-    phases["plan"] = _time.perf_counter() - t0
-    outs = {i: open(base_name + to_ext(i), "wb") for i in missing}
-    rebuilt_bytes = 0
-    t_stream = _time.perf_counter()
-    try:
-        it = source.slabs()
+    timer = StageTimer(root=tracing.current_span())
+    # dense, and a cache hit after the first loss of a pattern
+    with timer.stage("plan", span="ec.rebuild.plan"):
+        src, plan_missing, coeffs = ops_codec.piggyback_decode_plan(
+            codec.k, codec.m, tuple(bool(p) for p in present),
+            matrix_kind=getattr(codec, "matrix_kind", "vandermonde"),
+            matrix=getattr(codec, "matrix", None),
+            pairs=layout.pairs)
+    if plan_missing != list(missing):
+        raise ValueError(f"shards {list(missing)} asked for, the pattern "
+                         f"has {plan_missing} missing")
+    source = make_source(src)
+    if source.shard_size % window or source.slab % window:
+        raise ValueError(
+            f"piggyback shard size {source.shard_size} or stripe "
+            f"{source.slab} not window-aligned ({window}); sidecar "
+            f"geometry is wrong for these shards")
+
+    def split():
+        for meta, block in source.slabs():
+            with timer.stage("pb_split", span="ec.rebuild.pb_split") as st:
+                sub = ops_codec.pb_split(block, alpha, window)
+                st.nbytes = sub.nbytes
+            yield meta, sub
+
+    def decoded():
+        if pipelined:
+            from ..ops.pipeline import PipelinedMatmul
+            pm = PipelinedMatmul(coeffs, codec=codec,
+                                 max_width=source.slab // alpha,
+                                 timer=timer)
+            for _, _, out in pm.stream(split()):
+                yield out
+            return
+        # a host codec: gather + split, then the matmul, on this thread,
+        # timed under the pipeline's names so that one account serves both
+        stripes = split()
         while True:
-            t0 = _time.perf_counter()
-            try:
-                _, block = next(it)
-            except StopIteration:
-                break
-            t1 = _time.perf_counter()
-            sub = ops_codec.pb_split(block, alpha, window)
-            out = np.asarray(codec._matmul(coeffs, sub), dtype=np.uint8)
-            merged = ops_codec.pb_merge(out, alpha, window)
-            t2 = _time.perf_counter()
-            for r, i in zip(rows, missing):
-                outs[i].write(merged[r].tobytes())
-                rebuilt_bytes += merged.shape[1]
-            t3 = _time.perf_counter()
-            phases["gather"] += t1 - t0
-            phases["dispatch"] += t2 - t1
-            phases["write"] += t3 - t2
+            t0 = time.perf_counter()
+            item = next(stripes, None)
+            t1 = time.perf_counter()
+            timer.add("read_wait", t1 - t0)
+            if item is None:
+                return
+            out = codec._matmul(coeffs, item[1])
+            timer.add("h2d", time.perf_counter() - t1)
+            yield out
+
+    outs = {i: open(base_name + to_ext(i), "wb") for i in missing}
+    t_stream = time.perf_counter()
+    try:
+        for out in decoded():
+            with timer.stage("pb_merge", span="ec.rebuild.pb_merge") as st:
+                merged = ops_codec.pb_merge(
+                    np.asarray(out, dtype=np.uint8), alpha, window)
+                st.nbytes = merged.nbytes
+            with timer.stage("shard_write", merged.nbytes,
+                             span="ec.rebuild.write"):
+                for row, i in zip(merged, missing):
+                    outs[i].write(row)
     except BaseException:
         for i, h in outs.items():
             h.close()
@@ -813,25 +781,39 @@ def rebuild_ec_files_streaming_piggyback(base_name: str,
     finally:
         for h in outs.values():
             h.close()
-    stream_s = _time.perf_counter() - t_stream
+    stream_s = time.perf_counter() - t_stream
+    telemetry.STATS.add("coupled_decodes")
+    t = timer.totals
+    phases = {"gather": t.get("read_wait", 0.0), "plan": t["plan"],
+              "dispatch": t.get("h2d", 0.0),
+              "drain": t.get("drain_wait", 0.0),
+              "write": t.get("pb_merge", 0.0) + t.get("shard_write", 0.0)}
+    # what the consumer did between its stages (pads, dispatch issuance)
+    # goes to dispatch, so that the phases tile the stream's wall
+    phases["dispatch"] += max(
+        stream_s - (sum(phases.values()) - phases["plan"]), 0.0)
     for name, secs in phases.items():
         if secs > 0:
             tracing.record_span(name, secs, op="ec.rebuild",
-                                backend=codec.backend, streaming=True,
-                                layout="piggyback")
+                                backend=codec.backend, layout="piggyback")
     if stats is not None:
         gs = source.stats
         stats.update(telemetry.delta(before))
         stats.update(gs.snapshot())
         stats["survivor_bytes"] = source.shard_size * len(src)
-        stats["rebuilt_bytes"] = rebuilt_bytes
+        stats["rebuilt_bytes"] = timer.bytes.get("shard_write", 0)
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
         stats["operand"] = list(coeffs.shape)
         stats["layout"] = "piggyback"
+        stats["lost"] = list(missing)
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
-        stats["gather_mbps"] = round(gs.mbps(), 1)
-        stats["gather_remote_shards"] = gs.remote_shards
+        stats.update(gs.overlap(stream_s, phases["gather"]))
+        # the byte account the single-shard routes give: k whole shards
+        # gathered of the k a full gather pulls
+        stats["repair_bytes"] = gs.bytes
+        stats["repair_remote_bytes"] = gs.remote_bytes
+        stats["repair_baseline_bytes"] = codec.k * source.shard_size
     return list(missing)
 
 
@@ -946,18 +928,7 @@ def rebuild_ec_files_streaming(base_name: str,
         stats["backend"] = codec.backend
         stats["operand"] = list(coeffs.shape)
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
-        gather_busy = gs.busy_s()
-        compute_busy = max(stream_s - phases["gather"], 0.0)
-        serialized = gather_busy + compute_busy
-        overlap = 0.0
-        if serialized > 0:
-            overlap = max(0.0, min(1.0,
-                                   (serialized - stream_s) / serialized))
-        stats["gather_busy_s"] = round(gather_busy, 3)
-        stats["compute_busy_s"] = round(compute_busy, 3)
-        stats["overlap_frac"] = round(overlap, 4)
-        stats["gather_mbps"] = round(gs.mbps(), 1)
-        stats["gather_remote_shards"] = gs.remote_shards
+        stats.update(gs.overlap(stream_s, phases["gather"]))
     return list(missing)
 
 

@@ -223,6 +223,24 @@ class GatherStats(TransportStats):
 
     stage = "gather"
 
+    def overlap(self, stream_s: float, gather_wait_s: float) -> dict:
+        """How far a streaming rebuild's gather hid behind its compute,
+        as every rebuild's stats report it: the gather's busy union,
+        the stream's wall less the consumer's wait for stripes, and the
+        clamped serialized-against-wall estimate."""
+        gather_busy = self.busy_s()
+        compute_busy = max(stream_s - gather_wait_s, 0.0)
+        serialized = gather_busy + compute_busy
+        overlap = 0.0
+        if serialized > 0:
+            overlap = max(0.0, min(1.0,
+                                   (serialized - stream_s) / serialized))
+        return {"gather_busy_s": round(gather_busy, 3),
+                "compute_busy_s": round(compute_busy, 3),
+                "overlap_frac": round(overlap, 4),
+                "gather_mbps": round(self.mbps(), 1),
+                "gather_remote_shards": self.remote_shards}
+
 
 class SpreadStats(TransportStats):
     """Push-side role of the shared stats: snapshot keys are

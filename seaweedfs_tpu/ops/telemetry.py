@@ -28,9 +28,13 @@ TransportStats already hold them.
 
 Which route a streaming rebuild took is counted here as well
 (`repair_route`: piggyback / trace / full, and `repair_fallbacks` for
-an `auto` rebuild that had to leave its layout's single-shard route):
-a fall-back to the full gather is bit-identical and so shows nowhere
-else than in bytes moved.
+an `auto` rebuild that tried its layout's single-shard route and had to
+leave it): a fall-back to the full gather is bit-identical and so shows
+nowhere else than in bytes moved. A loss those routes were never meant
+for (more than one shard, a parity shard of a piggyback volume) takes
+the full decode as its own route and counts no fallback.
+`coupled_decodes` counts the full coupled decodes of piggyback volumes
+(ec/encoder.rebuild_ec_files_piggyback), local or streaming.
 """
 
 from __future__ import annotations
@@ -46,7 +50,7 @@ class DispatchStats:
     _FIELDS = ("dispatches", "bitmat_uploads", "host_fallbacks",
                "device_bytes", "mesh_dispatches",
                "read_bytes", "read_busy_us", "read_cpu_us",
-               "repair_fallbacks")
+               "repair_fallbacks", "coupled_decodes")
     REPAIR_ROUTES = ("piggyback", "trace", "full")
 
     def __init__(self):

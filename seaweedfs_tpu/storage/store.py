@@ -387,12 +387,17 @@ class Store:
         ((k+1)/2k of the baseline, piggyback-layout volumes only),
         ``full`` is the full streaming decode, ``auto`` (default)
         routes by the volume's layout — piggyback repair on coupled
-        layouts, trace on flat — and falls back to the layout's full
-        decode bit-identically for multi-shard loss, no-gain
-        geometries, uncoupled shards, or holders that predate the
-        repair routes. Forcing ``trace`` on a piggyback volume (or
-        ``piggyback`` on flat) is an error: the modes read parity bytes
-        the other layout does not have."""
+        layouts, trace on flat. A loss those routes were never meant
+        for (more than one shard; a parity or uncoupled shard of a
+        piggyback volume) takes the layout's full decode as its own
+        route: the reply names ``repair_mode`` full and nothing else.
+        A single-shard route that was tried and abandoned (no-gain
+        geometry, too few parities, a holder that predates the repair
+        routes) falls back to it bit-identically, counted in
+        ``telemetry.repair_fallbacks`` and named under
+        ``repair_fallback``. Forcing ``trace`` on a piggyback volume
+        (or ``piggyback`` on flat) is an error: the modes read parity
+        bytes the other layout does not have."""
         import time as _time
         from ..ec import gather
         from ..util import tracing
@@ -488,44 +493,30 @@ class Store:
                         mode)
             full = rebuilt is None
             if full and li.piggyback:
-                # full coupled decode: readers follow the decode
-                # plan's src order (surviving data, then just enough
-                # parities), stripes clamp to sub-chunk windows
-                from ..ops import codec as ops_codec
-                src, _, _ = ops_codec.piggyback_decode_plan(
-                    k, self.codec.m if self.codec is not None
-                    else total - k,
-                    tuple(bool(p) for p in present),
-                    matrix_kind=(self.codec.matrix_kind
-                                 if self.codec is not None
-                                 else "vandermonde"),
-                    matrix=(self.codec.matrix
-                            if self.codec is not None else None),
-                    pairs=li.pairs)
+                # full coupled decode: the body plans, then asks for
+                # the gather of its plan's sources (surviving data,
+                # then just enough parities: not the first k), in
+                # stripes of whole sub-chunk windows
                 gstats = gather.GatherStats()
-                readers = []
-                for i in src:
-                    if local[i]:
-                        readers.append(gather.LocalShardReader(
-                            base + to_ext(i), gstats))
-                    else:
-                        readers.append(gather.RemoteShardReader(
-                            vid, i, sources[i], gstats,
-                            hedge_ms=hedge_ms))
-                shard_size = sized(src)
-                eff_slab = slab or gather.auto_slab(
-                    shard_size, default=ec_encoder.DEFAULT_SLAB)
-                eff_slab = max(li.window,
-                               eff_slab - eff_slab % li.window)
-                source = gather.StripedGatherSource(
-                    readers, shard_size, slab=eff_slab,
-                    window=window, stats=gstats, parent_span=root)
-                rebuilt = \
-                    ec_encoder.rebuild_ec_files_streaming_piggyback(
-                        base, present, missing, source, li,
-                        codec=self.codec, slab=eff_slab, stats=stats)
+
+                def coupled_source(src):
+                    shard_size = sized(src)
+                    eff_slab = slab or gather.auto_slab(
+                        shard_size, default=ec_encoder.DEFAULT_SLAB)
+                    return gather.StripedGatherSource(
+                        [gather.LocalShardReader(base + to_ext(i), gstats)
+                         if local[i] else gather.RemoteShardReader(
+                             vid, i, sources[i], gstats, hedge_ms=hedge_ms)
+                         for i in src], shard_size,
+                        slab=max(li.window, eff_slab - eff_slab % li.window),
+                        window=window, stats=gstats, parent_span=root)
+
+                rebuilt = ec_encoder.rebuild_ec_files_piggyback(
+                    base, present, missing, li, coupled_source,
+                    codec=self.codec, stats=stats)
                 from ..stats.metrics import observe_transport
-                observe_transport("pull", gstats, window=source.window)
+                observe_transport("pull", gstats,
+                                  window=window or gather.gather_window())
                 if stats is not None:
                     stats["repair_mode"] = "full"
             elif full:
@@ -607,9 +598,11 @@ class Store:
                                      li):
         """Attempt the half-plane piggyback repair; returns the rebuilt
         shard list or None to signal 'use the full coupled decode
-        instead'. Forced mode ('piggyback') converts every fallback
-        into an error; 'auto' records the reason in stats and lets the
-        caller fall through bit-identically."""
+        instead'. A loss the route was never meant for (more than one
+        shard, a parity or uncoupled shard) goes there as its own
+        route; a route that was tried and abandoned is a fallback,
+        counted and named in stats. Forced mode ('piggyback') converts
+        both into an error."""
         from ..ec import decoder as ec_decoder
         from ..ec import gather
         from ..ops import codec as ops_codec
@@ -617,16 +610,18 @@ class Store:
         from ..server.http_util import HttpError
         from ..util import tracing
 
-        def bail(reason: str):
+        def refuse(reason: str):
             if mode == "piggyback":
                 raise VolumeError(f"-repair piggyback: {reason}")
+
+        def bail(reason: str):
+            refuse(reason)
             telemetry.STATS.add("repair_fallbacks")
             if stats is not None:
                 stats["repair_fallback"] = reason
-            return None
 
         if len(missing) != 1:
-            return bail(
+            return refuse(
                 f"{len(missing)} shards lost, piggyback repairs one")
         lost = missing[0]
         k = self.codec.k if self.codec is not None else DATA_SHARDS
@@ -645,8 +640,8 @@ class Store:
             except ValueError as e:
                 return bail(f"no piggyback scheme: {e}")
         if lost >= pplan.coupled:
-            return bail(f"shard {lost} not coupled "
-                        f"(coupled prefix is 0..{pplan.coupled - 1})")
+            return refuse(f"shard {lost} not coupled "
+                          f"(coupled prefix is 0..{pplan.coupled - 1})")
         par = [k + j for j in range(m) if present[k + j]]
         if len(par) < 2:
             return bail(f"{len(par)} surviving parities, plane repair "
@@ -707,10 +702,11 @@ class Store:
                                  missing, sources, sized, stats, slab,
                                  window, hedge_ms, root, mode):
         """Attempt the trace-repair path; returns the rebuilt shard list
-        or None to signal 'use the full streaming gather instead'.
-        Forced mode ('trace') converts every fallback into an error;
-        'auto' records the reason in stats and lets the caller fall
-        through bit-identically."""
+        or None to signal 'use the full streaming gather instead'. More
+        than one lost shard was never this route's: the full gather is
+        then the rebuild's own route; a route that was tried and
+        abandoned is a fallback, counted and named in stats. Forced
+        mode ('trace') converts both into an error."""
         from ..ec import decoder as ec_decoder
         from ..ec import gather
         from ..ops import codec as ops_codec
@@ -718,16 +714,18 @@ class Store:
         from ..server.http_util import HttpError
         from ..util import tracing
 
-        def bail(reason: str):
+        def refuse(reason: str):
             if mode == "trace":
                 raise VolumeError(f"-repair trace: {reason}")
+
+        def bail(reason: str):
+            refuse(reason)
             telemetry.STATS.add("repair_fallbacks")
             if stats is not None:
                 stats["repair_fallback"] = reason
-            return None
 
         if len(missing) != 1:
-            return bail(f"{len(missing)} shards lost, trace repairs one")
+            return refuse(f"{len(missing)} shards lost, trace repairs one")
         lost = missing[0]
         k = self.codec.k if self.codec is not None else DATA_SHARDS
         m = (self.codec.m if self.codec is not None

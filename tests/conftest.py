@@ -34,6 +34,8 @@ import os
 import sys
 import tempfile
 
+import pytest
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from seaweedfs_tpu.util.jax_platform import (  # noqa: E402
@@ -78,6 +80,28 @@ def wait_until(pred, timeout=8.0, interval=0.02):
         if v or time.monotonic() >= deadline:
             return v
         time.sleep(interval)
+
+
+@pytest.fixture
+def private_packed_programs(monkeypatch):
+    """For a test that runs a tiny geometry through the `tpu` and the
+    `mesh` codec in turn: the mesh pads a width to its eight CPU devices,
+    so the two compile two exact widths of one width bucket, which is
+    what the recompile sentinel latches on — and tests/test_device_stats.py
+    asserts the process's own is unlatched when it shares an xdist worker
+    with that test. The test's programs go to a factory cache and a
+    DeviceStats of its own (as tests/test_mesh_codec._private_programs
+    and tests/test_rs_pallas._own_device_stats have it); the process's
+    are put back untouched."""
+    import functools
+
+    from seaweedfs_tpu.ops import device_stats, rs_tpu
+    from seaweedfs_tpu.parallel import mesh_codec
+    monkeypatch.setattr(device_stats, "DEVICE_STATS",
+                        device_stats.DeviceStats())
+    monkeypatch.setattr(rs_tpu, "_packed_fn", functools.lru_cache(
+        maxsize=None)(rs_tpu._packed_fn.__wrapped__))
+    monkeypatch.setattr(mesh_codec, "_FNS", {})
 
 
 def pytest_sessionfinish(session, exitstatus):

@@ -118,6 +118,7 @@ def test_plan_cache_lru_and_stats():
 
 # -- encode: backend/pipeline identity, flat data bytes unchanged ------------
 
+@pytest.mark.usefixtures("private_packed_programs")
 @pytest.mark.parametrize("backend", ["numpy", "tpu", "mesh"])
 def test_piggyback_encode_identity(tmp_path, backend):
     oracle_dir = tmp_path / "oracle"
@@ -166,6 +167,7 @@ def test_piggyback_data_shards_equal_flat(tmp_path):
 
 # -- plane repair: <= 0.55 * k * shard, bit-identical ------------------------
 
+@pytest.mark.usefixtures("private_packed_programs")
 @pytest.mark.parametrize("backend", ["numpy", "tpu", "mesh"])
 def test_plane_repair_frac_and_bit_identity(tmp_path, backend):
     base, shard_size = _seed_pb(tmp_path)
@@ -224,6 +226,7 @@ def test_plane_repair_failure_removes_partial(tmp_path):
 
 # -- full coupled decode: multi-loss, parity + data --------------------------
 
+@pytest.mark.usefixtures("private_packed_programs")
 @pytest.mark.parametrize("backend", ["numpy", "tpu"])
 def test_piggyback_full_rebuild_multi_loss(tmp_path, backend):
     base, _ = _seed_pb(tmp_path)

@@ -157,7 +157,9 @@ def test_a_lost_data_shard_takes_the_layouts_route(stores, layout, route,
     assert after["repair_fallbacks"] == before["repair_fallbacks"]
 
 
-def test_two_lost_shards_fall_back_and_are_counted(stores):
+def test_two_lost_shards_take_the_full_decode_as_their_route(stores):
+    """More than one lost shard was never the plane route's: the full
+    coupled decode is the rebuild's own route, not a fallback."""
     store, base, want = stores["piggyback"]
     for sid in (2, 11):
         os.remove(base + to_ext(sid))
@@ -166,11 +168,14 @@ def test_two_lost_shards_fall_back_and_are_counted(stores):
     assert store.rebuild_ec_shards_streaming(1, "", stats=stats) == [2, 11]
     after = telemetry.STATS.snapshot()
     assert _shas(base) == want
-    assert stats["repair_mode"] == "full"
-    assert "2 shards lost" in stats["repair_fallback"]
+    assert stats["repair_mode"] == "full" and stats["lost"] == [2, 11]
+    assert "repair_fallback" not in stats
     assert stats["operand"] == [2 * ALPHA, K * ALPHA]
+    assert stats["repair_bytes"] == stats["repair_baseline_bytes"] == \
+        stats["survivor_bytes"] == K * os.path.getsize(base + to_ext(0))
     assert after["repair_route"]["full"] - before["repair_route"]["full"] == 1
-    assert after["repair_fallbacks"] - before["repair_fallbacks"] == 1
+    assert after["repair_fallbacks"] == before["repair_fallbacks"]
+    assert after["coupled_decodes"] - before["coupled_decodes"] == 1
 
 
 def test_the_device_trace_combine_has_one_shape(tmp_path):
@@ -322,3 +327,45 @@ def test_the_benchmarks_roofline_counts_the_equations_terms():
     assert least["seconds"] == pytest.approx(
         (1 << 20) * 60 * 128 / 393e12)
     assert roofline_terms.least_seconds(rep, peak)["bound"] == "hbm"
+
+
+HOLDER_SETS = ([0, 4, 8, 12], [1, 5, 9, 13], [2, 6, 10], [3, 7, 11])
+
+
+@pytest.mark.parametrize("lost", HOLDER_SETS + ([12], [2, 7], [3, 11]),
+                         ids=lambda lost: "-".join(map(str, lost)))
+def test_the_decodes_roofline_counts_the_equations_terms(lost):
+    """benchmarks/lib/roofline_terms_decode.py counts the full coupled
+    decode from the configuration's equation: a rebuilt byte is as many
+    terms as an encoded parity byte (k flat + one gated a pair). A lone
+    lost parity shard dispatches exactly those; a lost data shard's rows
+    fill in (the inverse is the program's choice of operand) and are
+    never fewer."""
+    import json
+    from lib import roofline_terms, roofline_terms_decode
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "f4-warm-piggyback-4srv-1chip.json")) as f:
+        config = json.load(f)
+    assert [sorted(s) for s in config["holder_sets"].values()] == \
+        [list(s) for s in HOLDER_SETS]
+    work = roofline_terms_decode.coupled_decode_work(config, 1 << 20, lost)
+    per_parity_byte = roofline_terms.encode_work(
+        config, 1 << 20)["column_terms"] // M
+    assert work == {"columns": 1 << 20, "column_bytes": K + len(lost),
+                    "column_terms": len(lost) * per_parity_byte}
+    assert per_parity_byte == K + PAIRS
+    src, missing, coeffs = ops_codec.piggyback_decode_plan(
+        K, M, tuple(i not in lost for i in range(K + M)))
+    assert missing == list(lost) and len(src) == K
+    assert coeffs.shape == (ALPHA * len(lost), ALPHA * K)
+    nnz = int(np.count_nonzero(coeffs))
+    if all(s >= K for s in lost):
+        assert nnz == work["column_terms"] * ALPHA
+    else:
+        assert work["column_terms"] * ALPHA < nnz <= coeffs.size
+    # the least time it gives is that of as many parity bytes encoded
+    peak = {"hbm_bytes_per_s": 819e9, "int8_ops_per_s": 393e12}
+    least = roofline_terms.least_seconds(work, peak)
+    assert least["int8_seconds"] == pytest.approx(
+        (1 << 20) * work["column_terms"] * 128 / 393e12)

@@ -112,6 +112,20 @@ def test_fused_piggyback_encode_matrix_compiles(one_chip):
     _compile_fused(one_chip, k, r, MIB)
 
 
+@pytest.mark.parametrize("lost", [[0, 4, 8, 12], [2, 6, 10]],
+                         ids=["holder-of-4", "holder-of-3"])
+def test_fused_coupled_decode_operands_compile(one_chip, lost):
+    """A lost holder's full coupled decode: a dense (32 x lost, 320)
+    operand against (320, 8 MiB / 32) stripes, the served width."""
+    from seaweedfs_tpu.ec.encoder import DEFAULT_SLAB
+    from seaweedfs_tpu.ops import codec as ops_codec
+    _, _, coeffs = ops_codec.piggyback_decode_plan(
+        10, 4, tuple(i not in lost for i in range(14)))
+    r, k = coeffs.shape
+    assert (r, k) == (32 * len(lost), 320)
+    _compile_fused(one_chip, k, r, DEFAULT_SLAB // 32)
+
+
 @pytest.mark.parametrize("rows_in,rows_out,n", [
     (10, 4, 8 * MIB), (10, 4, 32 * MIB), (320, 128, MIB)],
     ids=["slab-8MiB", "chunk-32MiB", "piggyback-1MiB"])
