@@ -24,6 +24,7 @@ from ..storage.super_block import SUPER_BLOCK_SIZE, SuperBlock
 from ..storage.types import NEEDLE_ENTRY_SIZE, NEEDLE_ID_SIZE, \
     TOMBSTONE_FILE_SIZE, bytes_to_needle_id
 from .constants import DATA_SHARDS, LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, to_ext
+from .transport import _give_slab
 
 
 def iterate_ecx_file(base_name: str, offset_width: int = 4):
@@ -229,7 +230,8 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
             from ..ops.pipeline import PipelinedMatmul
             pm = PipelinedMatmul(combine, max_width=stride_cap,
                                  codec=codec, timer=timer)
-            for meta, _, planes in pm.stream(source.slabs()):
+            for meta, block, planes in pm.stream(source.slabs()):
+                _give_slab(block)   # drained: the gather's again
                 rebuilt_bytes += write_block(planes, meta[2])
             phases["gather"] = timer.totals.get("read_wait", 0.0)
             phases["dispatch"] = timer.totals.get("h2d", 0.0)
@@ -245,6 +247,7 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
                 t1 = time.perf_counter()
                 combined = codec._matmul(combine, planes)
                 t2 = time.perf_counter()
+                _give_slab(planes)
                 rebuilt_bytes += write_block(combined, meta[2])
                 phases["gather"] += t1 - t0
                 phases["dispatch"] += t2 - t1
@@ -345,7 +348,8 @@ def rebuild_ec_file_piggyback(base_name: str, lost_sid: int, source,
             from ..ops.pipeline import PipelinedMatmul
             pm = PipelinedMatmul(rplan.matrix, max_width=stride_cap,
                                  codec=codec, timer=timer)
-            for meta, _, sub in pm.stream(source.slabs()):
+            for meta, block, sub in pm.stream(source.slabs()):
+                _give_slab(block)   # drained: the gather's again
                 rebuilt_bytes += write_block(sub, meta[2])
             phases["gather"] = timer.totals.get("read_wait", 0.0)
             phases["dispatch"] = timer.totals.get("h2d", 0.0)
@@ -361,6 +365,7 @@ def rebuild_ec_file_piggyback(base_name: str, lost_sid: int, source,
                 t1 = time.perf_counter()
                 sub = codec._matmul(rplan.matrix, stacked)
                 t2 = time.perf_counter()
+                _give_slab(stacked)
                 rebuilt_bytes += write_block(sub, meta[2])
                 phases["gather"] += t1 - t0
                 phases["dispatch"] += t2 - t1

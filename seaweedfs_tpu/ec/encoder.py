@@ -28,7 +28,6 @@ pass over every byte of the .dat, no seek, no intermediate copy.
 
 from __future__ import annotations
 
-import collections
 import os
 import time
 from typing import Iterator, List, Optional, Tuple
@@ -40,7 +39,9 @@ from ..storage.needle_map import MemDb
 from ..util import tracing
 from ..util.locks import make_lock
 from ..util.profiling import StageTimer
-from .transport import DEFAULT_WINDOW
+# the slab pool is the transport's: one for the encode's reader and the
+# rebuild's gather
+from .transport import _SLAB_POOL, _give_slab, _take_slab  # noqa: F401
 from .constants import (DATA_SHARDS, LARGE_BLOCK_SIZE, PARITY_SHARDS,
                         SMALL_BLOCK_SIZE, to_ext)
 
@@ -116,53 +117,15 @@ def _read_ranges(fd: int, rows: List[np.ndarray], offset: int,
         n -= r.size
 
 
-# Dispatch slabs outlive their encode. glibc maps a block as large as a
-# (k, 8 MiB) slab anew at every allocation and unmaps it when freed: a
-# page fault per 4 KiB on the way in, a TLB shootdown across every core
-# on the way out, and on the v5e hosts (VMs) both stall the whole
-# process — fresh slabs cost a fifth of encode_mbps there (PERF.md, PR
-# 26). A pool that died with its encode recovers little of it: 9 of a
-# GiB volume's 13 slabs are live before the first is written. It holds
-# as many as one stream keeps in flight: the one being read, the
-# pipeline's read-ahead (3) and depth (4), the one being written, one
-# in the producer's hand — and, since the spread queues views of a
-# slab's rows and not copies of them (PR 30), the stripes its workers
-# have not had acknowledged: a window in the queues, a window in the
-# workers' hands, the one being routed.
-_SLAB_POOL: "collections.deque[np.ndarray]" = collections.deque(
-    maxlen=10 + 2 * DEFAULT_WINDOW + 1)
-
-
-def _take_slab(k: int, width: int) -> np.ndarray:
-    """A (k, width) uint8 slab with whatever bytes its last user left."""
-    n = k * width
-    while True:
-        try:
-            buf = _SLAB_POOL.pop()
-        except IndexError:
-            buf = np.empty(n, dtype=np.uint8)
-            break
-        if buf.size >= n:   # a smaller one served another geometry: dropped
-            break
-    return buf[:n].reshape(k, width)
-
-
-def _give_slab(data: np.ndarray):
-    """Hand a slab of _dat_slabs back once nothing reads it any more:
-    after its stripe's rows are on their holders' disks (a spread sends
-    views of them), never earlier (and on the CPU backend the device
-    array may alias the host memory until the output is drained)."""
-    _SLAB_POOL.append(data.base)
-
-
 class _SlabLease:
     """Who still reads a slab: the encode's loop, and every stripe of
     it a spread has not had acknowledged (one a slab, one a piece on
-    the mesh). The last to let go hands the slab back; a lease nobody
-    lets go of — a failed spread's — just drops its slab, and so does
-    a lease on ``None`` (piggyback's window re-cut yields copies and
-    views of the reader's slabs: never recycled, only kept referenced
-    by the stripes that read them)."""
+    the mesh). The last to let go hands the slab back
+    (transport._give_slab, the pool the rebuild's gather takes its
+    blocks from too); a lease nobody lets go of — a failed spread's —
+    just drops its slab, and so does a lease on ``None`` (a stripe
+    piggyback's window re-cut made of copies: the reader's slab went
+    back when the copies were made, _window_batches)."""
 
     def __init__(self, data: Optional[np.ndarray]):
         self.data = data
@@ -207,7 +170,8 @@ def _dat_slabs(dat_path: str, dat_size: int, k: int, large_block: int,
         for pieces in _dispatch_plan(dat_size, k, large_block, small_block,
                                      slab, target_width):
             with timer.stage("disk_read", span="ec.encode.read") as st:
-                out = _take_slab(k, sum(p[3] for p in pieces))
+                out = _take_slab(k, sum(p[3] for p in pieces),
+                                 room=k * target_width)
                 col = 0
                 for start, block, off, width in pieces:
                     cols = slice(col, col + width)
@@ -230,7 +194,7 @@ def _dat_slabs(dat_path: str, dat_size: int, k: int, large_block: int,
 
 def _window_batches(slabs: Iterator[Tuple[None, np.ndarray]],
                     window: int, timer: StageTimer
-                    ) -> Iterator[Tuple[None, np.ndarray]]:
+                    ) -> Iterator[Tuple[Optional[np.ndarray], np.ndarray]]:
     """Re-chunk a slab stream onto sub-chunk window boundaries.
 
     The piggyback parity transform is window-local (ops/codec.pb_split
@@ -246,21 +210,30 @@ def _window_batches(slabs: Iterator[Tuple[None, np.ndarray]],
     ``ec.encode.pb_recut``) on the thread that iterates this, after the
     slab's ``ec.encode.read`` has closed: a slab that is window-aligned
     already (every one, at the default slab and window) passes through
-    as it came, and the stage holds nothing but the test."""
+    as it came, and the stage holds nothing but the test.
+
+    Yields ``(slab, batch)``: the reader's slab where the batch is that
+    slab itself, for the consumer to hand back when the stripe is
+    written; ``None`` where the batch is a copy — the slab it was cut
+    from has gone back to the pool here."""
     held: Optional[np.ndarray] = None
-    for _, data in slabs:
+    for _, slab in slabs:
+        data = slab
         with timer.stage("pb_recut", span="ec.encode.pb_recut") as st:
             if held is not None:
                 data = np.concatenate([held, data], axis=1)
                 held = None
             cut = (data.shape[1] // window) * window
             if cut < data.shape[1]:
-                held = np.ascontiguousarray(data[:, cut:])
+                held = data[:, cut:].copy()
                 data = data[:, :cut]
             data = np.ascontiguousarray(data)
             st.nbytes = data.nbytes
+        if not np.may_share_memory(data, slab):
+            _give_slab(slab)
+            slab = None
         if data.shape[1]:
-            yield None, data
+            yield slab, data
     if held is not None and held.shape[1]:
         raise ValueError(
             f"stream tail of {held.shape[1]} bytes is not window-aligned "
@@ -357,12 +330,12 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
             # pipeline's producer where there is one), the merge of the
             # drained parity on the consumer
             def split():
-                for _, data in _window_batches(slabs, window, timer):
+                for whole, data in _window_batches(slabs, window, timer):
                     with timer.stage("pb_split",
                                      span="ec.encode.pb_split") as st:
                         sub = ops_codec.pb_split(data, alpha, window)
                         st.nbytes = sub.nbytes
-                    yield data, sub
+                    yield (whole, data), sub
 
             def merge(psub):
                 with timer.stage("pb_merge",
@@ -380,12 +353,14 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
                         max_width=max(slab // alpha, window // alpha),
                         timer=timer, codec=codec)
                     for orig, _sub, psub in pm.stream(split()):
-                        yield orig, merge(psub)
+                        yield (*orig, merge(psub))
                 else:
-                    for data, sub in split():
-                        yield data, merge(codec._matmul(pplan.emat, sub))
+                    for orig, sub in split():
+                        yield (*orig,
+                               merge(codec._matmul(pplan.emat, sub)))
 
-            stream = ((None, data, parity) for data, parity in pb_stream())
+            # (the reader's slab where the stripe is it, stripe, parity)
+            stream = pb_stream()
         elif pipelined:
             from ..ops.pipeline import PipelinedMatmul
             operand = codec.matrix[k:].shape
@@ -396,8 +371,8 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
             operand = codec.matrix[k:].shape
             stream = ((meta, data, codec.encode(data))
                       for meta, data in slabs)
-        for _, data, parity in stream:
-            lease = _SlabLease(None if piggyback else data)
+        for whole, data, parity in stream:
+            lease = _SlabLease(whole if piggyback else data)
             with timer.stage("shard_write", span="ec.encode.write") as st:
                 if pieces:
                     for lo, piece in parity:
@@ -611,7 +586,7 @@ def rebuild_ec_files(base_name: str,
                                   span="ec.rebuild.write") as st:
                     for _, piece in parts:
                         for r, i in enumerate(missing):
-                            outs[i].write(piece[r].tobytes())
+                            outs[i].write(piece[r])   # a view: no copy
                             st.nbytes += piece[r].nbytes
             phases["write"] = ptimer.totals.get("shard_write", 0.0)
             # consumer-side accounting: the stream loop's time splits
@@ -639,7 +614,7 @@ def rebuild_ec_files(base_name: str,
                 rebuilt = codec.reconstruct(shards)
                 t2 = time.perf_counter()
                 for i in missing:
-                    outs[i].write(rebuilt[i].tobytes())
+                    outs[i].write(np.ascontiguousarray(rebuilt[i]))
                 t3 = time.perf_counter()
                 phases["gather"] += t1 - t0
                 phases["dispatch"] += t2 - t1
@@ -692,11 +667,12 @@ def rebuild_ec_files_piggyback(base_name: str, present: List[bool],
     not the first k) and returns the gather that reads them in that row
     order (an ec.gather.StripedGatherSource whose slab is a whole number
     of windows). Each stripe is window-split on the thread that gathers
-    it (the pipeline's producer), runs one fused matmul against the
-    (alpha * lost, alpha * k) plan — through PipelinedMatmul where the
-    codec pipelines, ``codec._matmul`` where it computes on the host, as
-    the coupled encode does — and is merged back into shard bytes and
-    appended, as views, on the consumer. One span a stripe and stage
+    it (the pipeline's producer; the split is a copy, so the gather's
+    block goes back to the slab pool there), runs one fused matmul
+    against the (alpha * lost, alpha * k) plan — through PipelinedMatmul
+    where the codec pipelines, ``codec._matmul`` where it computes on
+    the host, as the coupled encode does — and is merged back into shard
+    bytes and appended, as views, on the consumer. One span a stripe and stage
     under the stream's root (``ec.rebuild.plan``, ``.pb_split``,
     ``ec.h2d``, ``ec.d2h``, ``.pb_merge``, ``.write``, beside the
     gather's ``.fetch.*`` and ``.assemble``). Failure removes the
@@ -733,6 +709,10 @@ def rebuild_ec_files_piggyback(base_name: str, present: List[bool],
             with timer.stage("pb_split", span="ec.rebuild.pb_split") as st:
                 sub = ops_codec.pb_split(block, alpha, window)
                 st.nbytes = sub.nbytes
+            # the split made its copy (of a stripe one window wide it
+            # is a view): the gather's block can be filled again
+            if not np.may_share_memory(sub, block):
+                _give_slab(block)
             yield meta, sub
 
     def decoded():
@@ -864,12 +844,14 @@ def rebuild_ec_files_streaming(base_name: str,
             # per-device outputs append as they land
             pm = PipelinedMatmul(coeffs, max_width=slab, codec=codec,
                                  timer=ptimer, pieces=True)
-            for _, _, parts in pm.stream(source.slabs()):
+            for _, data, parts in pm.stream(source.slabs()):
+                # its output is drained: the gather may fill it again
+                _give_slab(data)
                 with ptimer.stage("shard_write",
                                   span="ec.rebuild.write") as st:
                     for _, piece in parts:
                         for r, i in enumerate(missing):
-                            outs[i].write(piece[r].tobytes())
+                            outs[i].write(piece[r])   # a view: no copy
                             st.nbytes += piece[r].nbytes
             phases["write"] = ptimer.totals.get("shard_write", 0.0)
             rebuilt_bytes = ptimer.bytes.get("shard_write", 0)
@@ -891,9 +873,10 @@ def rebuild_ec_files_streaming(base_name: str,
                 t1 = time.perf_counter()
                 out = codec._matmul(coeffs, data)
                 t2 = time.perf_counter()
+                _give_slab(data)
                 for r, i in enumerate(missing):
-                    outs[i].write(np.asarray(out[r],
-                                             dtype=np.uint8).tobytes())
+                    outs[i].write(np.ascontiguousarray(out[r],
+                                                       dtype=np.uint8))
                     rebuilt_bytes += data.shape[1]
                 t3 = time.perf_counter()
                 phases["gather"] += t1 - t0

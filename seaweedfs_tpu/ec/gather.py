@@ -269,7 +269,7 @@ class LocalPlaneReader:
         self.plane_side = int(plane_side)
         self.stats = stats or GatherStats()
 
-    def read(self, off: int, n: int, stripe_idx: int = 0) -> bytes:
+    def read(self, off: int, n: int, stripe_idx: int = 0) -> memoryview:
         from ..ops.codec import pb_plane_slice
         with tracing.Stage(self.fetch_span, self.span) as st:
             with open(self.path, "rb") as f:
@@ -283,7 +283,7 @@ class LocalPlaneReader:
                                    self.plane_bit, self.plane_side)
             st.nbytes = plane.nbytes
         self.stats.add_fetch(plane.nbytes, st.t0, st.t1)
-        return plane.tobytes()
+        return plane.data
 
 
 def fetch_index_files(base_name: str, holders: Sequence[str],
@@ -327,7 +327,9 @@ class StripedGatherSource(StripedPull):
     ``readers`` are the first-k survivors in decode plan order — local
     files and remote holders mixed freely. Pure transport: the window,
     pool, ordering, rotation, failover and hedging all come from
-    ``StripedPull``."""
+    ``StripedPull``, and so does the hand-over — every reader fills its
+    own row of the stripe's pooled block, which the consumer hands back
+    (``transport._give_slab``) when the decode has drained it."""
 
 
 class RepairGatherSource(StripedPull):
@@ -344,7 +346,14 @@ class RepairGatherSource(StripedPull):
     RS(10,4)), and a device program is compiled per operand shape, so
     every block comes ``rows`` tall — total_bits rounded up to
     ``ops/codec.REPAIR_ROW_BUCKET``, the tail rows zero — and the
-    repairs of one geometry share one compiled combine."""
+    repairs of one geometry share one compiled combine.
+
+    A helper's planes are a contiguous row range of the pooled block;
+    they are laid there from the buffers the readers' ``read`` returns
+    (one pass into recycled memory where a concatenate filled a new
+    array) and the tail rows zeroed, once a block."""
+
+    lands_in_place = False
 
     def __init__(self, readers: Sequence, shard_size: int, plan,
                  slab: int = 8 << 20, window: Optional[int] = None,
@@ -363,14 +372,22 @@ class RepairGatherSource(StripedPull):
     def _stripe_nbytes(self, w: int) -> int:
         return self.plan.total_bits * ((w + 7) // 8)
 
-    def _assemble(self, bufs: List[bytes], w: int) -> np.ndarray:
-        stride = (w + 7) // 8
-        rows = [np.frombuffer(b, dtype=np.uint8).reshape(-1, stride)
-                for b in bufs]
-        if self.rows > self.plan.total_bits:
-            rows.append(np.zeros((self.rows - self.plan.total_bits, stride),
-                                 dtype=np.uint8))
-        return np.concatenate(rows, axis=0)
+    def _block_shape(self, w: int) -> Tuple[int, int]:
+        return self.rows, (w + 7) // 8
+
+    def _assemble(self, block: np.ndarray, bufs: List, w: int
+                  ) -> np.ndarray:
+        stride = block.shape[1]
+        row = 0
+        for b in bufs:
+            planes = np.frombuffer(b, dtype=np.uint8).reshape(-1, stride)
+            block[row:row + len(planes)] = planes
+            row += len(planes)
+        if row != self.plan.total_bits:
+            raise ValueError(f"helpers sent {row} planes, the plan has "
+                             f"{self.plan.total_bits}")
+        block[row:] = 0
+        return block
 
 
 class PlaneGatherSource(StripedPull):
@@ -381,7 +398,11 @@ class PlaneGatherSource(StripedPull):
     ``(meta, ((k+1)*alpha/2, w/alpha) uint8)`` blocks — the restacked
     plane rows in plan column order, ready for the fused repair matmul.
     Stripes are clamped to sub-chunk windows so every holder-side slice
-    and rebuilder-side restack is window-local."""
+    and rebuilder-side restack is window-local. The restack is a true
+    transpose: ``_assemble`` writes it straight into the pooled block,
+    one pass a helper."""
+
+    lands_in_place = False
 
     def __init__(self, readers: Sequence, shard_size: int, plan,
                  window: int, slab: int = 8 << 20,
@@ -406,9 +427,16 @@ class PlaneGatherSource(StripedPull):
     def _stripe_nbytes(self, w: int) -> int:
         return len(self.readers) * (w // 2)
 
-    def _assemble(self, bufs: List[bytes], w: int) -> np.ndarray:
+    def _block_shape(self, w: int) -> Tuple[int, int]:
+        alpha = self.plan.alpha
+        return len(self.readers) * (alpha // 2), w // alpha
+
+    def _assemble(self, block: np.ndarray, bufs: List, w: int
+                  ) -> np.ndarray:
         from ..ops.codec import pb_plane_rows
-        rows = [pb_plane_rows(np.frombuffer(b, dtype=np.uint8),
-                              self.plan.alpha, self.pb_window)
-                for b in bufs]
-        return np.concatenate(rows, axis=0)
+        half = self.plan.alpha // 2
+        for i, b in enumerate(bufs):
+            pb_plane_rows(np.frombuffer(b, dtype=np.uint8),
+                          self.plan.alpha, self.pb_window,
+                          out=block[i * half:(i + 1) * half])
+        return block

@@ -18,6 +18,12 @@ a ``(k, w)`` uint8 block for the decode; the push side receives it as
 ``(k, w)`` data + ``(m, w)`` parity rows from the encode. In-flight
 memory is O(window * shards * slab) on either side, never O(volume).
 
+Both sides move a shard byte once on the host. The blocks are slabs of
+one pool (``_take_slab`` / ``_give_slab``, below): the pull side hands
+every reader its row of a stripe's block to fill (``read_into``), the
+push side sends views of the encode's rows, and a block goes back to
+the pool when its last reader is done with it.
+
 Straggler defenses (shared):
   * rotation: stripe ``s`` leads with holder ``s % len(holders)`` so
     consecutive stripes split across replicas instead of hammering one.
@@ -49,6 +55,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..ops.telemetry import STATS
 from ..stats import health as _health
 from ..util import config, tracing
 from ..util.locks import make_lock
@@ -96,6 +103,64 @@ class SpreadError(Exception):
     """A transport operation failed beyond what retry/failover can
     absorb. (Historic name — the push side raised it first; the shared
     layer kept it so existing handlers don't churn.)"""
+
+
+# ---------------------------------------------------------------------------
+# the slab pool: one for both directions
+
+# Stripe-sized host blocks outlive the stream that filled them. glibc
+# maps a block as large as a (k, 8 MiB) slab anew at every allocation
+# and unmaps it when freed: a page fault per 4 KiB on the way in, a TLB
+# shootdown across every core on the way out, and on the v5e hosts
+# (VMs) both stall the whole process — fresh slabs cost a fifth of
+# encode_mbps there (PERF.md, PR 26). A pool that died with its stream
+# recovers little of it: 9 of a GiB volume's 13 slabs are live before
+# the first is written. One pool serves the encode's reader
+# (ec/encoder._dat_slabs) and the rebuild's gather (StripedPull), which
+# never run in one stream, and it holds as many as one stream keeps in
+# flight. An encode: the one being read, the pipeline's read-ahead (3)
+# and depth (4), the one being written, one in the producer's hand —
+# and, since the spread queues views of a slab's rows and not copies of
+# them (PR 30), the stripes its workers have not had acknowledged: a
+# window in the queues, a window in the workers' hands, the one being
+# routed (19). A gather: a window (4) the readers are filling, one in
+# the producer's hand, the read-ahead (3) and depth (4), the one whose
+# rebuilt rows are being appended: 13 of the 19.
+_SLAB_POOL: "deque[np.ndarray]" = deque(
+    maxlen=10 + 2 * DEFAULT_WINDOW + 1)
+
+
+def _take_slab(k: int, width: int, room: int = 0) -> np.ndarray:
+    """A (k, width) uint8 slab with whatever bytes its last user left.
+    One the pool could not serve is new memory, counted in
+    ops/telemetry (``slab_fresh_bytes``): none once a process has run
+    its first volume. ``room``: the bytes of the stream's widest call;
+    a new slab is made that large, so that a volume's narrower last
+    call leaves the pool nothing the next volume's wide calls must
+    drop."""
+    n = k * width
+    while True:
+        try:
+            buf = _SLAB_POOL.pop()
+        except IndexError:
+            buf = np.empty(max(n, room), dtype=np.uint8)
+            STATS.add("slab_fresh_bytes", buf.size)
+            break
+        if buf.size >= n:   # a smaller one served another geometry: dropped
+            break
+    return buf[:n].reshape(k, width)
+
+
+def _give_slab(data: np.ndarray):
+    """Hand a slab of _take_slab back once nothing reads or writes it
+    any more. An encode's: after its stripe's rows are on their
+    holders' disks (a spread sends views of them). A gathered stripe's:
+    after the decode's output for it has been drained (on the CPU
+    backend the device array may alias the host memory until then), or
+    after a host re-layout has made its copy. Never earlier; a stream
+    that fails or is abandoned hands none of its blocks back, their
+    readers may still be writing."""
+    _SLAB_POOL.append(data.base)
 
 
 class TransportStats:
@@ -219,9 +284,33 @@ class TransportStats:
 class GatherStats(TransportStats):
     """Pull-side role of the shared stats: snapshot keys are
     ``gather_*`` (what ``observe_gather`` and the rebuild/repair stats
-    dicts have always carried)."""
+    dicts have always carried), beside how a gather's rows reached
+    their stripe's block: ``rows_in_place``, written there by the
+    reader that fetched them, and ``rows_copied``, fetched into a
+    buffer of the attempt's own and copied over (a read that may hedge;
+    none where every shard has one holder). Rows a subclass's
+    ``_assemble`` re-lays from the readers' buffers count under
+    neither."""
 
     stage = "gather"
+
+    def __init__(self):
+        super().__init__()
+        self.rows_in_place = 0
+        self.rows_copied = 0
+
+    def add_row(self, copied: bool = False):
+        with self._lock:
+            if copied:
+                self.rows_copied += 1
+            else:
+                self.rows_in_place += 1
+
+    def snapshot(self) -> Dict[str, float]:
+        out = super().snapshot()
+        out["rows_in_place"] = self.rows_in_place
+        out["rows_copied"] = self.rows_copied
+        return out
 
     def overlap(self, stream_s: float, gather_wait_s: float) -> dict:
         """How far a streaming rebuild's gather hid behind its compute,
@@ -270,7 +359,11 @@ FETCH_SPAN = "ec.rebuild.fetch"
 class LocalShardReader:
     """Range reads of a shard already on this node's disk. Opens per
     call — the pull pool reads several stripes of one shard
-    concurrently, and a shared seek pointer would race."""
+    concurrently, and a shared seek pointer would race; a descriptor
+    kept for the reader's life would have to outlive the reads an
+    abandoned stream leaves in flight. ``read_into`` reads at a
+    position (``os.preadv``: no seek), straight into the row it is
+    handed."""
 
     remote = False
     fetch_span = FETCH_SPAN + ".local"
@@ -291,6 +384,26 @@ class LocalShardReader:
             st.nbytes = n
         self.stats.add_fetch(n, st.t0, st.t1)
         return data
+
+    def read_into(self, off: int, n: int, stripe_idx: int,
+                  dest: np.ndarray):
+        """The shard's ``[off, off + n)`` into ``dest`` (n writable
+        bytes: the reader's row of the stripe's block)."""
+        with tracing.Stage(self.fetch_span, self.span) as st:
+            fd = os.open(self.path, os.O_RDONLY)
+            try:
+                got = 0
+                while got < n:
+                    step = os.preadv(fd, [dest[got:]], off + got)
+                    if step <= 0:
+                        raise IOError(f"short read of {self.path} at "
+                                      f"{off}: {got} < {n}")
+                    got += step
+            finally:
+                os.close(fd)
+            st.nbytes = n
+        self.stats.add_fetch(n, st.t0, st.t1)
+        self.stats.add_row()
 
 
 class RemoteShardReader:
@@ -330,8 +443,12 @@ class RemoteShardReader:
         """Response bytes expected for an n-byte shard range."""
         return n
 
-    def _read_one(self, holder: str, off: int, n: int) -> bytes:
-        from ..server.http_util import HttpError, http_call
+    def _read_one(self, holder: str, off: int, n: int,
+                  dest: Optional[np.ndarray] = None):
+        """One attempt at one holder: the body as ``bytes``, or read
+        off the socket into ``dest`` where one is given."""
+        from ..server.http_util import HttpError, http_call, \
+            http_read_into
         # pool/hedge worker threads don't inherit the tracing
         # contextvar — carry the caller span's traceparent explicitly
         # so the holders' shard_read spans join the caller's trace
@@ -339,34 +456,45 @@ class RemoteShardReader:
         if self.span is not None:
             hdrs = {tracing.TRACEPARENT_HEADER: self.span.traceparent()}
         expect = self._expect_len(n)
+        url = self._url(holder, off, n)
         with tracing.Stage(self.fetch_span, self.span) as st:
             try:
-                data = http_call(self._method, self._url(holder, off, n),
-                                 headers=hdrs, timeout=self.timeout)
-                if len(data) != expect:
+                if dest is None:
+                    data = http_call(self._method, url, headers=hdrs,
+                                     timeout=self.timeout)
+                    got = len(data)
+                else:
+                    data = None
+                    got = http_read_into(self._method, url, dest,
+                                         headers=hdrs,
+                                         timeout=self.timeout)
+                if got != expect:
                     raise HttpError(
                         502, f"short shard read {self.vid}.{self.sid} "
                              f"from {holder} at {off}: "
-                             f"{len(data)} < {expect}")
+                             f"{got} < {expect}")
             except Exception:
                 self.stats.add_holder_error(holder)
                 _health.BOARD.record_error(holder, self._health_kind)
                 raise
-            st.nbytes = len(data)
-        self.stats.add_fetch(len(data), st.t0, st.t1, remote=True,
+            st.nbytes = got
+        self.stats.add_fetch(got, st.t0, st.t1, remote=True,
                              holder=holder)
         _health.BOARD.record_latency(holder, self._health_kind,
                                      st.t1 - st.t0)
         return data
 
-    def _read_failover(self, order: Sequence[str], off: int,
-                       n: int) -> bytes:
+    def _read_failover(self, order: Sequence[str], off: int, n: int,
+                       dest: Optional[np.ndarray] = None):
+        """The holders of ``order`` in turn until one answers. A failed
+        attempt has returned before the next begins, so they may share
+        ``dest``."""
         last = None
         for i, holder in enumerate(order):
             if i:
                 self.stats.add_retry()
             try:
-                return self._read_one(holder, off, n)
+                return self._read_one(holder, off, n, dest)
             except Exception as e:  # noqa: BLE001 - try the next holder
                 last = e
         raise last
@@ -386,7 +514,7 @@ class RemoteShardReader:
 
         loser_future.add_done_callback(_done)
 
-    def read(self, off: int, n: int, stripe_idx: int = 0) -> bytes:
+    def _order(self, stripe_idx: int) -> List[str]:
         h = self.holders
         # rotation both spreads load (consecutive stripes of a
         # replicated shard split across its holders) and fixes the
@@ -397,8 +525,36 @@ class RemoteShardReader:
             # hedge order (stable within each class, so the rotation's
             # load-spreading survives among healthy peers)
             order = _health.BOARD.order_by_health(order)
-        if self.hedge_s <= 0 or len(order) < 2:
+        return order
+
+    def _may_hedge(self, order: Sequence[str]) -> bool:
+        return self.hedge_s > 0 and len(order) >= 2
+
+    def read(self, off: int, n: int, stripe_idx: int = 0) -> bytes:
+        order = self._order(stripe_idx)
+        if not self._may_hedge(order):
             return self._read_failover(order, off, n)
+        return self._read_hedged(order, off, n)
+
+    def read_into(self, off: int, n: int, stripe_idx: int,
+                  dest: np.ndarray):
+        """The range into ``dest`` (as many writable bytes as the
+        holder answers with: the reader's row of the stripe's block).
+        Two writers never share a row: where a second holder arms the
+        hedge, every attempt reads into a buffer of its own — the loser
+        of a race finishes whenever it does — and the winner's is
+        copied over."""
+        order = self._order(stripe_idx)
+        if not self._may_hedge(order):
+            self._read_failover(order, off, n, dest)
+            self.stats.add_row()
+            return
+        dest[:] = np.frombuffer(self._read_hedged(order, off, n),
+                                dtype=np.uint8)
+        self.stats.add_row(copied=True)
+
+    def _read_hedged(self, order: Sequence[str], off: int,
+                     n: int) -> bytes:
         ex = hedge_pool()
         primary = ex.submit(self._read_one, order[0], off, n)
         try:
@@ -440,15 +596,32 @@ class StripedPull:
     """The pull pump: ``slabs()`` yields ``(meta, block)`` stripes in
     strict order, fetching up to ``window`` stripes ahead across a
     shared thread pool. ``readers`` are per-row endpoints (local files
-    and remote holders mixed freely). Subclasses reshape the stream via
-    the ``_stripe_nbytes``/``_assemble`` hooks without touching the
-    window/pool/ordering machinery."""
+    and remote holders mixed freely).
+
+    A stripe's block comes from the slab pool (``_take_slab``) before
+    its fetches are submitted, and every reader is handed the row its
+    bytes belong in (``read_into``): a fetched byte is written once, by
+    the thread that fetched it, and when the stripe's futures are done
+    the block *is* the stripe. Whoever consumes the stream hands each
+    block back (``_give_slab``) once nothing reads it; a stream that
+    fails or is abandoned drops the blocks still with it — reads left
+    in flight by ``shutdown(wait=False)`` may yet write into them.
+
+    Subclasses reshape the stream via the ``_stripe_nbytes`` /
+    ``_block_shape`` / ``_assemble`` hooks without touching the
+    window/pool/ordering machinery; one whose readers' bytes have to be
+    re-laid (``lands_in_place`` false) gets them as buffers
+    (``read``) and writes its block in ``_assemble``."""
 
     span_name = "gather.stripe"
     span_op = "ec.rebuild.gather"
-    # one span per stripe for stacking its fetched rows into the slab,
-    # on the thread that iterates `slabs()` (the pipeline's producer)
+    # one span per stripe around ``_assemble``, on the thread that
+    # iterates `slabs()` (the pipeline's producer): bookkeeping where
+    # the readers filled the block, the re-layout where a subclass does
+    # one
     assemble_span = "ec.rebuild.assemble"
+    # the readers write their rows of the block themselves
+    lands_in_place = True
 
     def __init__(self, readers: Sequence, shard_size: int,
                  slab: int = 8 << 20, window: Optional[int] = None,
@@ -483,10 +656,27 @@ class StripedPull:
         """Buffered bytes one in-flight stripe accounts for."""
         return len(self.readers) * w
 
-    def _assemble(self, bufs: List[bytes], w: int) -> np.ndarray:
-        """Row buffers of one stripe -> the block the consumer wants."""
-        rows = [np.frombuffer(b, dtype=np.uint8) for b in bufs]
-        return np.stack(rows, axis=0)
+    def _block_shape(self, w: int) -> Tuple[int, int]:
+        """(rows, columns) of the block a ``w``-byte stripe becomes."""
+        return len(self.readers), w
+
+    def _assemble(self, block: np.ndarray, bufs: List, w: int
+                  ) -> np.ndarray:
+        """The stripe's block, complete. ``bufs`` is what the readers'
+        ``read`` returned, for a subclass to lay into ``block``; here
+        the readers have filled their rows and nothing is left to do."""
+        return block
+
+    def _take_block(self, w: int) -> np.ndarray:
+        rows, cols = self._block_shape(w)
+        full = self._block_shape(min(self.slab, self.shard_size))
+        return _take_slab(rows, cols, room=full[0] * full[1])
+
+    def _fetch(self, r: int, off: int, w: int, idx: int,
+               block: Optional[np.ndarray]):
+        if block is None:
+            return self.readers[r].read(off, w, idx)
+        return self.readers[r].read_into(off, w, idx, block[r])
 
     def slabs(self):
         k = len(self.readers)
@@ -508,9 +698,10 @@ class StripedPull:
             # every submitted row completes before the consumer drains
             self._note_buffered(self._stripe_nbytes(w))
             t_sub = time.perf_counter()
-            futs = [pool.submit(self.readers[r].read, off, w, idx)
+            block = self._take_block(w) if self.lands_in_place else None
+            futs = [pool.submit(self._fetch, r, off, w, idx, block)
                     for r in range(k)]
-            pending.append((idx, off, w, t_sub, futs))
+            pending.append((idx, off, w, t_sub, block, futs))
 
         try:
             nxt = 0
@@ -518,12 +709,15 @@ class StripedPull:
                 submit(nxt)
                 nxt += 1
             while pending:
-                idx, off, w, t_sub, futs = pending.popleft()
+                idx, off, w, t_sub, block, futs = pending.popleft()
                 bufs = [f.result() for f in futs]
                 with tracing.Stage(self.assemble_span,
                                    self.parent_span) as st:
-                    data = self._assemble(bufs, w)
+                    if block is None:
+                        block = self._take_block(w)
+                    data = self._assemble(block, bufs, w)
                     st.nbytes = data.nbytes
+                bufs = None     # a subclass's fetched buffers: let go
                 tracing.record_span(
                     self.span_name, time.perf_counter() - t_sub,
                     parent=self.parent_span, op=self.span_op,
@@ -535,6 +729,7 @@ class StripedPull:
                     nxt += 1
                 yield (idx, off, w), data
         finally:
+            # blocks still pending are dropped, never handed back
             pool.shutdown(wait=False, cancel_futures=True)
 
 

@@ -1160,11 +1160,14 @@ def pb_plane_slice(shard: np.ndarray, alpha: int, window: int,
     return np.ascontiguousarray(x[:, zs, :].reshape(-1))
 
 
-def pb_plane_rows(plane: np.ndarray, alpha: int, window: int) -> np.ndarray:
+def pb_plane_rows(plane: np.ndarray, alpha: int, window: int,
+                  out: np.ndarray) -> np.ndarray:
     """Rebuilder-side restack of one helper's plane bytes:
-    (W/2,) -> (alpha/2, W/alpha) rows in plan column order."""
+    (W/2,) -> (alpha/2, W/alpha) rows in plan column order, written
+    into ``out`` (C-contiguous rows of that shape: the helper's range
+    of a gather's block) in one pass."""
     half = alpha // 2
     wsub = window // alpha
-    x = plane.reshape(-1, half, wsub)
-    return np.ascontiguousarray(
-        x.transpose(1, 0, 2).reshape(half, -1))
+    out.reshape(half, -1, wsub)[...] = \
+        plane.reshape(-1, half, wsub).transpose(1, 0, 2)
+    return out

@@ -35,6 +35,13 @@ for (more than one shard, a parity shard of a piggyback volume) takes
 the full decode as its own route and counts no fallback.
 `coupled_decodes` counts the full coupled decodes of piggyback volumes
 (ec/encoder.rebuild_ec_files_piggyback), local or streaming.
+
+`slab_fresh_bytes` counts the bytes of stripe-sized host blocks that
+were new memory (ec/transport._take_slab found its pool empty, or
+holding nothing large enough): what these hosts charge for is memory a
+thread has not touched, and once a process has run its first volume
+the encode's reader and the rebuild's gather take every block from the
+pool, so the count stands still.
 """
 
 from __future__ import annotations
@@ -50,7 +57,8 @@ class DispatchStats:
     _FIELDS = ("dispatches", "bitmat_uploads", "host_fallbacks",
                "device_bytes", "mesh_dispatches",
                "read_bytes", "read_busy_us", "read_cpu_us",
-               "repair_fallbacks", "coupled_decodes")
+               "repair_fallbacks", "coupled_decodes",
+               "slab_fresh_bytes")
     REPAIR_ROUTES = ("piggyback", "trace", "full")
 
     def __init__(self):

@@ -853,10 +853,28 @@ def _traced_headers(headers: Optional[dict]) -> dict:
     return h
 
 
+def _read_body_into(resp, into) -> int:
+    """A response's body read off the socket into ``into``, a writable
+    buffer the caller expects it to fill: no ``bytes`` of the body's
+    size is made. Returns the body's length — less than the buffer's
+    where the body came short, more where it ran past it (the excess is
+    read and dropped, so that the connection can be kept)."""
+    view = memoryview(into).cast("B")
+    got = 0
+    while got < len(view):
+        n = resp.readinto(view[got:])
+        if not n:
+            break
+        got += n
+    return got + len(resp.read())
+
+
 def _pooled_call(method: str, url: str, body, headers: dict,
                  timeout: float, max_redirects: int = 5,
                  want_headers: bool = False,
-                 encode_chunked: bool = False):
+                 encode_chunked: bool = False, into=None):
+    """``into``: a 2xx body goes there (``_read_body_into``) and the
+    call returns its length in place of the body."""
     headers = _traced_headers(headers)
     parsed = urllib.parse.urlsplit(url)
     netloc, scheme = parsed.netloc, parsed.scheme
@@ -887,7 +905,10 @@ def _pooled_call(method: str, url: str, body, headers: dict,
             conn.request(method, target, body=body, headers=headers,
                          encode_chunked=encode_chunked)
             resp = conn.getresponse()
-            data = resp.read()
+            if into is not None and 200 <= resp.status < 300:
+                data = _read_body_into(resp, into)
+            else:
+                data = resp.read()
         except _RETRIABLE_STALE:
             conn.close()
             if reused and attempt + 1 < attempts:
@@ -912,7 +933,7 @@ def _pooled_call(method: str, url: str, body, headers: dict,
             # redirects) — re-apply the cluster TLS scheme rewrite
             return _pooled_call(method, _client_url(loc), body, headers,
                                 timeout, max_redirects - 1,
-                                want_headers)
+                                want_headers, into=into)
         if resp.status >= 400:
             detail = data.decode("utf-8", "replace")[:500]
             raise HttpError(resp.status, f"{method} {url}: {detail}")
@@ -964,6 +985,23 @@ def http_call(method: str, url: str, body: bytes = None,
         detail = e.read().decode("utf-8", "replace")[:500]
         raise HttpError(e.code, f"{method} {url}: {detail}") from None
     except (urllib.error.URLError, socket.timeout, ConnectionError) as e:
+        raise HttpError(503, f"{method} {url}: {e}") from None
+
+
+def http_read_into(method: str, url: str, into, headers: dict = None,
+                   timeout: float = 30.0) -> int:
+    """A cluster call whose reply body is read straight into ``into``
+    (a writable buffer: a row of a gather's slab) through the
+    keep-alive pool, with http_call's retry on a stale connection,
+    status check and errors. Returns the body's length, which the
+    caller holds against what it asked for."""
+    url = _client_url(url)
+    try:
+        return _pooled_call(method, url, None, headers or {}, timeout,
+                            into=into)
+    except HttpError:
+        raise
+    except (OSError, _httpc.HTTPException) as e:
         raise HttpError(503, f"{method} {url}: {e}") from None
 
 

@@ -140,6 +140,9 @@ def test_pipelined_coupled_decode_gives_the_references_shards(
     assert stats["rebuilt_bytes"] == len(lost) * SHARD_BYTES
     assert stats["repair_bytes"] == stats["repair_baseline_bytes"] == \
         stats["survivor_bytes"] == K * SHARD_BYTES
+    # every reader filled its own row of every stripe's block
+    assert stats["rows_in_place"] == K * STRIPES
+    assert stats["rows_copied"] == 0
     assert set(stats["phases"]) == {"gather", "plan", "dispatch", "drain",
                                     "write"}
     assert after["coupled_decodes"] - before["coupled_decodes"] == 1
@@ -150,6 +153,52 @@ def test_pipelined_coupled_decode_gives_the_references_shards(
         moved = {r: after["repair_route"][r] - before["repair_route"][r]
                  for r in after["repair_route"]}
         assert moved == {"piggyback": 0, "trace": 0, "full": 1}
+
+
+@pytest.mark.parametrize("backend", ["numpy", "tpu"])
+@pytest.mark.parametrize("slab,copied", [(SLAB, True), (SB, False)],
+                         ids=["two-windows", "one-window"])
+def test_a_block_goes_back_once_the_split_has_copied_it(
+        encoded, tmp_path, monkeypatch, slab, copied, backend):
+    """`pb_split` copies a stripe of several windows, and the gather's
+    block goes back to the pool right after it: a second decode is
+    gathered into the first's memory. Of a stripe one window wide the
+    split is a view, and that block is never handed back under it."""
+    from seaweedfs_tpu.ec import transport
+    from seaweedfs_tpu.storage.store import Store
+    lost = LOSSES["holder-A"]
+    base = _volume_without(encoded, tmp_path, lost)
+    if backend == "tpu":
+        from seaweedfs_tpu.ops.rs_tpu import TpuCodec
+        codec = TpuCodec(K, M)
+    else:
+        codec = NumpyCodec(K, M)
+    store = Store([str(tmp_path)], codec=codec)
+    given, real_give = [], ec_encoder._give_slab
+
+    def noting_give(block):
+        given.append(block.base)
+        real_give(block)
+
+    monkeypatch.setattr(ec_encoder, "_give_slab", noting_give)
+    transport._SLAB_POOL.clear()
+    try:
+        replies = []
+        for _ in range(2):
+            stats = {}
+            assert store.rebuild_ec_shards_streaming(
+                1, "", stats=stats, slab=slab) == lost
+            assert _shas(base) == encoded[1]
+            replies.append(stats)
+            for i in lost:
+                os.remove(base + to_ext(i))
+        stripes = SHARD_BYTES // slab
+        assert len(given) == (2 * stripes if copied else 0)
+        assert replies[0]["slab_fresh_bytes"] > 0
+        assert (replies[1]["slab_fresh_bytes"] == 0) == copied
+        assert replies[1]["rows_in_place"] == K * stripes
+    finally:
+        transport._SLAB_POOL.clear()
 
 
 def test_one_lost_data_shard_still_takes_the_plane_route(encoded, tmp_path):

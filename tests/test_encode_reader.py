@@ -307,11 +307,38 @@ def test_a_slab_goes_back_only_after_its_stripe_is_written(
     assert 1 <= len(encoder._SLAB_POOL) <= encoder._SLAB_POOL.maxlen
 
 
-def test_piggyback_keeps_its_slabs_out_of_the_pool(tmp_path):
-    """Its window re-cut hands the consumer copies and views of the
-    reader's slabs: none of them may be written into by the next read."""
+@pytest.mark.parametrize("slab,recut", [(3000, True), (2048, False)])
+def test_a_piggyback_stripe_is_never_in_the_pool(tmp_path, monkeypatch,
+                                                 slab, recut):
+    """A stripe that is the reader's slab goes back when it is written;
+    one the window re-cut made of copies lets its slab go back at once.
+    Either way no stripe the sink is handed lies in the pool, and the
+    shards are the ones a pool that takes nothing back gives."""
     path = _write_dat(tmp_path, 77_003, seed=11)
-    write_ec_files(path[:-len(".dat")], codec=NumpyCodec(10, 4),
-                   large_block=4096, small_block=512, slab=3000,
-                   pipelined=False, layout="piggyback")
+    base = path[:-len(".dat")]
+    geometry = dict(codec=NumpyCodec(10, 4), large_block=4096,
+                    small_block=512, slab=slab, pipelined=False,
+                    layout="piggyback")
+    monkeypatch.setattr(encoder, "_give_slab", lambda data: None)
+    write_ec_files(base, **geometry)
+    monkeypatch.undo()
+    want = _read_shards(base)
     assert len(encoder._SLAB_POOL) == 0
+
+    class Sink:
+        stripes, copies = [], 0
+
+        def write_stripe(self, data, parity, done=None):
+            assert not any(np.may_share_memory(data, buf)
+                           for buf in encoder._SLAB_POOL), \
+                "a stripe being written lies in the pool"
+            self.copies += data.base is None
+            self.stripes.append(np.concatenate([data, parity]))
+            done()
+
+    sink = Sink()
+    write_ec_files(base, sink=sink, **geometry)
+    got = np.concatenate(sink.stripes, axis=1)
+    assert [row.tobytes() for row in got] == want
+    assert (sink.copies > 0) == recut
+    assert 1 <= len(encoder._SLAB_POOL) <= encoder._SLAB_POOL.maxlen

@@ -23,7 +23,7 @@ import time
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.ec import to_ext, write_ec_files
+from seaweedfs_tpu.ec import to_ext, transport, write_ec_files
 from seaweedfs_tpu.ec.constants import SMALL_BLOCK_SIZE, TOTAL_SHARDS
 from seaweedfs_tpu.ec.decoder import rebuild_ec_file_piggyback
 from seaweedfs_tpu.ec.encoder import rebuild_ec_files
@@ -198,6 +198,13 @@ def test_plane_repair_frac_and_bit_identity(tmp_path, backend):
         assert stats["repair_bytes"] == gstats.bytes
         assert stats["repair_bytes"] <= 0.55 * K * shard_size
         assert stats["repair_bytes_frac"] == pytest.approx(0.55)
+        # the helpers' half-planes are restacked into a pooled block by
+        # the gather's _assemble: no row is landed or copied by a
+        # reader, and the block goes back once its output is drained
+        assert stats["rows_in_place"] == stats["rows_copied"] == 0
+        assert len(transport._SLAB_POOL) >= 1
+        if backend == "numpy" and lost == 7:
+            assert stats["slab_fresh_bytes"] == 0   # shard 0's block
 
 
 def test_plane_repair_failure_removes_partial(tmp_path):

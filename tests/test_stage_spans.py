@@ -241,7 +241,7 @@ def test_counters_at_the_stage_boundaries(cycle):
                        "device_byte_share"} == {
         "dispatches", "bitmat_uploads", "host_fallbacks", "device_bytes",
         "mesh_dispatches", "read_bytes", "read_busy_us", "read_cpu_us",
-        "repair_fallbacks", "coupled_decodes"}
+        "repair_fallbacks", "coupled_decodes", "slab_fresh_bytes"}
     fetched = sum(s["tags"]["bytes"] for s in cycle["spans"]
                   if s["name"].startswith("ec.rebuild.fetch."))
     assert fetched == cycle["rebuild"]["survivor_bytes"] > 0

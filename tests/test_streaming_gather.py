@@ -249,7 +249,78 @@ def test_streaming_rebuild_bit_identical(tmp_path, backend):
         assert out_stats["gather_bytes"] == shard_size * k
         assert 0.0 <= out_stats["overlap_frac"] <= 1.0
         assert out_stats["gather_remote_shards"] == k - 2
+        # every reader, local or remote, wrote its own row of every
+        # stripe's block; no hedge could arm, so none was copied
+        assert out_stats["rows_in_place"] == \
+            k * out_stats["gather_stripes"]
+        assert out_stats["rows_copied"] == 0
     finally:
+        holder.stop()
+
+
+@pytest.mark.parametrize("backend", ["numpy", "tpu"])
+def test_a_second_rebuild_takes_the_firsts_blocks(tmp_path, monkeypatch,
+                                                  backend):
+    """The consumer hands each stripe's block back once its output is
+    drained (after the matmul on a host codec): a second rebuild is
+    gathered into the first's memory and allocates none — the reply's
+    ``slab_fresh_bytes`` stands still — with the same shards out."""
+    from seaweedfs_tpu.ec import transport
+    if backend == "tpu":
+        from seaweedfs_tpu.ops.rs_tpu import TpuCodec as Codec
+    else:
+        Codec = NumpyCodec
+    k, m, lost, slab = 6, 3, (0, 5, 8), 16 << 10
+    holder_dir = tmp_path / "holder"
+    holder_dir.mkdir()
+    _, ref = _seed_shards(holder_dir, k, m, 500_000 + 11)
+    base = str(tmp_path / "1")
+    shutil.copy(os.path.join(str(holder_dir), f"1{to_ext(2)}"),
+                base + to_ext(2))
+    shard_size = os.path.getsize(base + to_ext(2))
+    present = [i not in lost for i in range(k + m)]
+    src = [i for i in range(k + m) if present[i]][:k]
+    taken, real_take = [], transport._take_slab
+
+    def noting_take(rows, width, **kw):
+        out = real_take(rows, width, **kw)
+        taken.append(out.base)
+        return out
+
+    monkeypatch.setattr(transport, "_take_slab", noting_take)
+    holder = FakeHolder(str(holder_dir))
+    transport._SLAB_POOL.clear()
+    try:
+        runs = []
+        for _ in range(2):
+            stats_ = GatherStats()
+            readers = [LocalShardReader(base + to_ext(i), stats_)
+                       if i == 2
+                       else RemoteShardReader(1, i, [holder.url], stats_,
+                                              hedge_ms=0)
+                       for i in src]
+            source = StripedGatherSource(readers, shard_size, slab=slab,
+                                         window=2, stats=stats_)
+            out_stats = {}
+            first = len(taken)
+            assert sorted(rebuild_ec_files_streaming(
+                base, present, list(lost), source, codec=Codec(k, m),
+                slab=slab, stats=out_stats)) == sorted(lost)
+            for sid in lost:
+                with open(base + to_ext(sid), "rb") as f:
+                    assert hashlib.sha256(f.read()).hexdigest() == \
+                        ref[sid], f"shard {sid} diverged"
+                os.remove(base + to_ext(sid))
+            runs.append((out_stats, taken[first:]))
+        (one, blocks1), (two, blocks2) = runs
+        stripes = -(-shard_size // slab)
+        assert len(blocks1) == len(blocks2) == stripes > 4
+        assert 0 < one["slab_fresh_bytes"] <= stripes * k * slab
+        assert two["slab_fresh_bytes"] == 0
+        assert all(any(b is a for a in blocks1) for b in blocks2)
+        assert two["rows_in_place"] == k * stripes
+    finally:
+        transport._SLAB_POOL.clear()
         holder.stop()
 
 
@@ -266,11 +337,11 @@ def test_bounded_gather_window():
         def __init__(self):
             self.stats = stats
 
-        def read(self, off, n, stripe_idx=0):
+        def read_into(self, off, n, stripe_idx, dest):
             time.sleep(0.002)
             t = time.perf_counter()
             self.stats.add_fetch(n, t - 0.002, t)
-            return bytes([stripe_idx & 0xFF]) * n
+            dest[:] = stripe_idx & 0xFF
 
     source = StripedGatherSource([SlowReader() for _ in range(k)],
                                  shard_size, slab=slab, window=window,
@@ -298,12 +369,12 @@ def test_streaming_rebuild_failure_leaves_no_partials(tmp_path):
             self.path = path
             self.stats = stats
 
-        def read(self, off, n, stripe_idx=0):
+        def read_into(self, off, n, stripe_idx, dest):
             if stripe_idx >= 1:
                 raise HttpError(503, "holder went away")
             with open(self.path, "rb") as f:
                 f.seek(off)
-                return f.read(n)
+                f.readinto(dest)
 
     present = [i not in lost for i in range(k + m)]
     src = [i for i in range(k + m) if present[i]][:k]

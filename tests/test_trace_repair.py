@@ -283,6 +283,12 @@ def test_trace_repair_bit_identical(tmp_path, k, m, backend):
     assert stats["repair_mode"] == "trace"
     assert stats["repair_helpers"] == k + m - 1
     assert stats["rebuilt_bytes"] == shard_size
+    # the helpers' planes are laid into a pooled block by the gather's
+    # _assemble (tail rows zeroed): no reader landed or copied a row,
+    # and the blocks came back when their outputs were drained
+    from seaweedfs_tpu.ec import transport
+    assert stats["rows_in_place"] == stats["rows_copied"] == 0
+    assert len(transport._SLAB_POOL) >= 1
 
 
 # -- fake holder speaking both shard_read and shard_repair_read -------------

@@ -12,6 +12,7 @@ import hashlib
 import os
 import time
 
+import numpy as np
 import pytest
 
 from seaweedfs_tpu.ec import gather, spread, transport
@@ -120,9 +121,9 @@ def test_bounded_window_both_sides(tmp_path):
             self.stats = None
             self.span = None
 
-        def read(self, off, n, stripe_idx=0):
+        def read_into(self, off, n, stripe_idx, dest):
             time.sleep(0.01)
-            return bytes(n)
+            dest[:] = 0
 
     pull_stats = transport.GatherStats()
     src = transport.StripedPull([SlowReader() for _ in range(k)],
@@ -213,7 +214,6 @@ def test_push_rate_cap_paces_producer(tmp_path):
     sink = transport.StripedPush(
         writers, {None: list(range(total))}, window=4, stats=stats,
         rate_mbps=rate)
-    import numpy as np
     rng = np.random.default_rng(5)
     t0 = time.perf_counter()
     for _ in range(n_stripes):
@@ -232,7 +232,6 @@ def test_push_rate_cap_paces_producer(tmp_path):
 def test_rate_zero_means_unpaced(tmp_path):
     writers = [transport.LocalShardWriter(str(tmp_path / "s0.ec00"))]
     sink = transport.StripedPush(writers, {None: [0]}, window=4)
-    import numpy as np
     row = np.zeros((1, 4096), dtype=np.uint8)
     t0 = time.perf_counter()
     for _ in range(4):
@@ -270,7 +269,6 @@ def test_pull_push_roundtrip_bit_identical(tmp_path):
                    range(k + m)]
         sink = transport.StripedPush(
             writers, {tgt.url: list(range(k + m))}, window=3)
-        import numpy as np
         step = 16 << 10
         for off in range(0, shard_size, step):
             w = min(step, shard_size - off)
@@ -362,3 +360,212 @@ def test_stale_kept_connection_is_one_retry(tmp_path):
         link.close()
     finally:
         tgt.stop()
+
+
+# -- PR 33: the gather's hand-over — a survivor byte lands once --------------
+
+@pytest.fixture
+def empty_slab_pool():
+    transport._SLAB_POOL.clear()
+    yield transport._SLAB_POOL
+    transport._SLAB_POOL.clear()
+
+
+class ShortHolder(FakeHolder):
+    """Answers every shard_read with half the bytes asked for, all 0xAA:
+    what it sends reaches the reader's row before the read fails."""
+
+    def _shard_read(self, req):
+        from seaweedfs_tpu.server.http_util import Response
+        with self._lock:
+            self.calls += 1
+        return Response(b"\xaa" * (int(req.query["size"]) // 2))
+
+
+def _range_of(base, sid, off, n):
+    with open(base + to_ext(sid), "rb") as f:
+        f.seek(off)
+        return f.read(n)
+
+
+@pytest.mark.parametrize("mix", ["local", "remote", "mixed"])
+def test_a_gathered_block_is_its_readers_bytes(tmp_path, empty_slab_pool,
+                                               mix):
+    """Every reader fills its own row of a pooled block: the block's
+    rows are the shards' ranges, the short last stripe's too, none was
+    copied, and a block handed back is the next stripe's memory."""
+    k, m, slab = 6, 3, 16 << 10
+    base, _ = _seed_shards(tmp_path, k, m, 300_000 + 77)
+    shard_size = os.path.getsize(base + to_ext(0))
+    assert shard_size % slab          # the last stripe is short
+    holder = FakeHolder(str(tmp_path))
+    try:
+        stats = transport.GatherStats()
+        remote = {"local": (), "remote": range(k), "mixed": (1, 3, 4)}[mix]
+        readers = [
+            transport.RemoteShardReader(1, i, [holder.url], stats,
+                                        hedge_ms=0) if i in remote
+            else transport.LocalShardReader(base + to_ext(i), stats)
+            for i in range(k)]
+        src = gather.StripedGatherSource(readers, shard_size, slab=slab,
+                                         window=2, stats=stats)
+        bases, n = set(), 0
+        for (idx, off, w), block in src.slabs():
+            assert block.shape == (k, w) and block.flags.c_contiguous
+            for i in range(k):
+                assert block[i].tobytes() == _range_of(base, i, off, w), \
+                    f"stripe {idx} row {i}"
+            bases.add(id(block.base))
+            transport._give_slab(block)
+            n += 1
+        assert n == -(-shard_size // slab)
+        assert stats.snapshot()["rows_in_place"] == k * n
+        assert stats.snapshot()["rows_copied"] == 0
+        assert stats.bytes == k * shard_size
+        assert stats.fetches == k * n
+        assert holder.calls == len(remote) * n
+        # handed back as it went: the window's blocks served every stripe
+        assert len(bases) <= 2 + 1 < n
+    finally:
+        holder.stop()
+
+
+def test_a_hedged_read_is_copied_into_its_row(tmp_path):
+    """Where a second holder arms the hedge, both attempts read into
+    buffers of their own: the row gets the winner's bytes by a copy, and
+    the loser, finishing later, writes nowhere near it."""
+    base, _ = _seed_shards(tmp_path, 6, 3, 60_000)
+    a, b = FakeHolder(str(tmp_path)), FakeHolder(str(tmp_path))
+    try:
+        a.delay = 0.4       # the straggler leads stripe 0
+        stats = transport.GatherStats()
+        r = transport.RemoteShardReader(1, 1, [a.url, b.url], stats,
+                                        hedge_ms=50)
+        row = np.full(8192, 0xEE, dtype=np.uint8)
+        t0 = time.perf_counter()
+        r.read_into(0, 8192, 0, row)
+        assert time.perf_counter() - t0 < 0.35
+        assert row.tobytes() == _range_of(base, 1, 0, 8192)
+        assert stats.hedges_won == 1
+        snap = stats.snapshot()
+        assert snap["rows_copied"] == 1 and snap["rows_in_place"] == 0
+        row[:] = 0x55       # the row is the next stripe's now
+        deadline = time.time() + 5
+        while stats.fetches < 2 and time.time() < deadline:
+            time.sleep(0.02)
+        assert stats.fetches == 2       # the loser's read ran to its end
+        assert bool((row == 0x55).all())
+        assert stats.snapshot()["rows_copied"] == 1
+    finally:
+        a.stop()
+        b.stop()
+
+
+@pytest.mark.parametrize("first", ["refuses", "sends-half"])
+def test_a_failed_over_read_fills_the_row(tmp_path, first):
+    """One holder a time: the failed attempt has returned, whatever it
+    wrote, before the next fills the same row."""
+    base, _ = _seed_shards(tmp_path, 6, 3, 60_000)
+    bad = FakeHolder(str(tmp_path)) if first == "refuses" \
+        else ShortHolder(str(tmp_path))
+    bad.fail = True
+    live = FakeHolder(str(tmp_path))
+    try:
+        stats = transport.GatherStats()
+        r = transport.RemoteShardReader(1, 2, [bad.url, live.url], stats,
+                                        hedge_ms=0)
+        row = np.full(4096, 0xEE, dtype=np.uint8)
+        r.read_into(0, 4096, 0, row)
+        assert row.tobytes() == _range_of(base, 2, 0, 4096)
+        assert bad.calls == 1 and stats.retries == 1
+        assert stats.holder_errors == {bad.url: 1}
+        snap = stats.snapshot()
+        assert snap["rows_in_place"] == 1 and snap["rows_copied"] == 0
+        assert stats.fetches == 1 and stats.bytes == 4096
+        # no holder left: the short read's own error comes out
+        if first == "sends-half":
+            from seaweedfs_tpu.server.http_util import HttpError
+            alone = transport.RemoteShardReader(1, 2, [bad.url], stats,
+                                                hedge_ms=0)
+            with pytest.raises(HttpError, match="short shard read 1.2 "
+                                                "from .* 2048 < 4096"):
+                alone.read_into(0, 4096, 0, row)
+            assert bool((row[:2048] == 0xAA).all())
+    finally:
+        bad.stop()
+        live.stop()
+
+
+def test_a_failed_stream_hands_no_block_back(tmp_path, monkeypatch,
+                                             empty_slab_pool):
+    """A reader raises mid-stream: the blocks the stream still had —
+    the failed stripe's, and those being filled behind it — are
+    dropped, for reads left in flight may still write into them."""
+    k, m, slab = 4, 2, 8 << 10
+    base, _ = _seed_shards(tmp_path, k, m, 400_000)
+    shard_size = os.path.getsize(base + to_ext(0))
+    taken, real_take = [], transport._take_slab
+
+    def noting_take(rows, width, **kw):
+        out = real_take(rows, width, **kw)
+        taken.append(out.base)
+        return out
+
+    class Flaky(transport.LocalShardReader):
+        def read_into(self, off, n, stripe_idx, dest):
+            if stripe_idx == 3:
+                raise IOError("disk went away")
+            if stripe_idx > 3:
+                time.sleep(0.05)    # still writing when the stream fails
+            super().read_into(off, n, stripe_idx, dest)
+
+    monkeypatch.setattr(transport, "_take_slab", noting_take)
+    stats = transport.GatherStats()
+    readers = [transport.LocalShardReader(base + to_ext(i), stats)
+               for i in range(k - 1)] + \
+        [Flaky(base + to_ext(k - 1), stats)]
+    src = gather.StripedGatherSource(readers, shard_size, slab=slab,
+                                     window=3, stats=stats)
+    given = []
+    with pytest.raises(IOError, match="disk went away"):
+        for _, block in src.slabs():
+            given.append(block.base)
+            transport._give_slab(block)
+    assert len(given) == 3
+    lost = [b for b in taken if not any(b is g for g in given)]
+    assert lost, "the failed stripe's block was taken before its fetches"
+    time.sleep(0.2)                     # the reads left in flight end
+    assert not [b for b in lost
+                if any(b is p for p in empty_slab_pool)]
+    assert len(empty_slab_pool) <= 3
+
+
+def test_read_into_reads_the_body_off_the_socket(tmp_path):
+    """http_read_into: the pool's connection kept, the status checked,
+    the body's length returned for the caller to hold against what it
+    asked for — short, exact or past the buffer."""
+    from seaweedfs_tpu.server import http_util as hu
+    base, _ = _seed_shards(tmp_path, 4, 2, 60_000)
+    holder = FakeHolder(str(tmp_path))
+    try:
+        hu.clear_conn_pool()
+        url = (f"http://{holder.url}/admin/ec/shard_read?volume=1&shard=0"
+               f"&offset=100&size=5000")
+        before = hu.pool_stats_snapshot()
+        for size, want in ((5000, 5000), (8000, 5000), (3000, 5000)):
+            buf = np.full(size, 0xEE, dtype=np.uint8)
+            assert hu.http_read_into("GET", url, buf) == want
+            n = min(size, want)
+            assert buf[:n].tobytes() == _range_of(base, 0, 100, 5000)[:n]
+            assert bool((buf[n:] == 0xEE).all())
+        after = hu.pool_stats_snapshot()
+        assert after["created"] - before["created"] == 1
+        assert after["reused"] - before["reused"] == 2
+        holder.fail = True
+        buf = np.full(5000, 0xEE, dtype=np.uint8)
+        with pytest.raises(hu.HttpError, match="injected failure") as ei:
+            hu.http_read_into("GET", url, buf)
+        assert ei.value.status == 503 and bool((buf == 0xEE).all())
+    finally:
+        hu.clear_conn_pool()
+        holder.stop()
