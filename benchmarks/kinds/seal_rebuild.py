@@ -9,7 +9,9 @@ before any wait: every shard it made is on a holder's disk at its full
 size, and after ec.encode the `.dat` is gone. Every volume of the window is the one sealed volume of
 set-up under a further volume id (hard links of its `.dat`/`.idx`, mounted
 on the servers in turn), so one reference pass serves them all and the
-supply never runs dry.
+supply never runs dry. Which shards a rebuild has lost is the traffic
+file's to say (`lose_sets`, taken in turn), never the seed's: every run
+of a cell does the same work.
 """
 
 import glob
@@ -22,7 +24,13 @@ from lib import datagen, observe, reference
 
 def prepare(run) -> dict:
     config, traffic, cluster = run.config, run.traffic, run.cluster
-    state = {"cycles": []}
+    sets = [sorted(int(s) for s in lost) for lost in traffic["lose_sets"]]
+    cl.check(sets and all(
+        0 < len(lost) <= cluster.m and len(set(lost)) == len(lost) and
+        0 <= lost[0] and lost[-1] < cluster.total for lost in sets),
+        f"lose_sets {sets}: each has to name 1 to {cluster.m} of the "
+        f"{cluster.total} shards")
+    state = {"cycles": [], "sets": sets, "rebuilds": 0}
     sizes = datagen.needle_sizes(traffic["needles"],
                                  int(config["volume_mib"]) << 20,
                                  run.seed, 0)
@@ -33,20 +41,19 @@ def prepare(run) -> dict:
     state["dat_bytes"] = os.path.getsize(state["kept"] + ".dat")
     state["shard_bytes"] = reference.shard_bytes(state["dat_bytes"],
                                                  cluster.k)
-    state["lost"] = cl.lost_shards(run.seed, cluster.k, cluster.m,
-                                  traffic["lose"])
     state["next_vid"] = volume["vid"] + 1
     run.emit({"phase": "upload", "needles": len(sizes),
               "payload_bytes": int(sizes.sum()),
-              "dat_bytes": state["dat_bytes"], "lost": state["lost"],
+              "dat_bytes": state["dat_bytes"], "lose_sets": sets,
               "seconds": time.perf_counter() - t0})
     # warm-up: the same two commands on the uploaded volume itself, which
-    # compiles (or finds in the cache) every shape the window uses
-    state["warm"] = _cycle(run, state, volume["vid"], timed=False)
+    # compiles (or finds in the cache) every shape the window uses: a
+    # rebuild's is one whichever set of that many shards is lost
+    state["warm"] = _cycle(run, state, volume["vid"], sets[0], timed=False)
     return state
 
 
-def _timed(run, state, op: str, nbytes: int, timed: bool, *args):
+def _timed(run, op: str, nbytes: int, rows: int, timed: bool, *args):
     """One shell command under the host's clock, with the counters it
     moved and the stats its computing node replied with."""
     cluster = run.cluster
@@ -63,8 +70,7 @@ def _timed(run, state, op: str, nbytes: int, timed: bool, *args):
               "counters": observe.counters_delta(before,
                                                  observe.counters_now()),
               "traced": run.tracer.active, "error": error,
-              "rows": cluster.m if op == "ec.encode" else len(state["lost"]),
-              "k": cluster.k}
+              "rows": rows, "k": cluster.k}
     if timed:
         run.ops.append(record)
     run.emit({"phase": op, "timed": timed, "wall_s": wall,
@@ -92,13 +98,13 @@ def _landed(run, state, cycle, op: str, vid: int, sids) -> bool:
     return not (short or dats)
 
 
-def _cycle(run, state, vid: int, timed: bool) -> dict:
-    cluster, lost = run.cluster, state["lost"]
+def _cycle(run, state, vid: int, lost: list, timed: bool) -> dict:
+    cluster = run.cluster
     every = set(range(cluster.total))
-    cycle = {"vid": vid, "encoded": None, "rebuilt": None, "error": None,
-             "raised": False, "not_landed": 0}
+    cycle = {"vid": vid, "lost": lost, "encoded": None, "rebuilt": None,
+             "error": None, "raised": False, "not_landed": 0}
     state["cycles"].append(cycle)
-    enc = _timed(run, state, "ec.encode", state["dat_bytes"], timed,
+    enc = _timed(run, "ec.encode", state["dat_bytes"], cluster.m, timed,
                  "-volumeId", str(vid))
     if enc["error"]:
         cycle["error"], cycle["raised"] = enc["error"], True
@@ -111,9 +117,8 @@ def _cycle(run, state, vid: int, timed: bool) -> dict:
         cycle["encoded"] = reference.sha256_files(
             [files[s] for s in range(cluster.total)])
         cluster.delete_shards(vid, lost)
-    reb = _timed(run, state, "ec.rebuild",
-                 state["shard_bytes"] * len(lost), timed,
-                 "-collection", cluster.collection)
+    reb = _timed(run, "ec.rebuild", state["shard_bytes"] * len(lost),
+                 len(lost), timed, "-collection", cluster.collection)
     if reb["error"]:
         cycle["error"], cycle["raised"] = reb["error"], True
         return cycle
@@ -143,7 +148,10 @@ def window(run, state):
         with run.tracer.mark("clone_and_mount"):
             cluster.clone_sealed(state["kept"], vid,
                                  n % len(cluster.servers))
-        cycle = _cycle(run, state, vid, timed=True)
+        # the n-th rebuild of the window loses the file's n-th set
+        lost = state["sets"][state["rebuilds"] % len(state["sets"])]
+        state["rebuilds"] += 1
+        cycle = _cycle(run, state, vid, lost, timed=True)
         run.tracer.stop()       # the trace covers the first whole cycle
         n += 1
         if cycle["error"]:
@@ -182,6 +190,7 @@ def verify(run, state):
     run.check("shards_not_on_disk_when_command_returned", not_landed, 0,
               not_landed == 0)
     run.emit({"phase": "verify", "cycles": len(state["cycles"]),
+              "lost": [c["lost"] for c in state["cycles"]],
               "reference_s": time.perf_counter() - t0})
 
 

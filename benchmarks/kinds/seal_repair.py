@@ -10,23 +10,23 @@ Only the shell commands are timed; waits, deletions and checks sit between
 them, as in `seal_rebuild.py`, whose loop this is but for three things.
 The plain reference is the module the configuration's `layout` names
 (`lib/reference.py` flat, `lib/reference_piggyback.py` piggyback). Shards
-are lost one at a time, so that `ec.rebuild`, given no `-repair` flag, has
-to take the layout's single-shard route by itself (the traffic mix's
-`routes`): a reply that names another route, or carries a
-`repair_fallback`, or gathered more of k x shard than the route may, makes
-the run not correct — a fall-back to the full gather is a wrong result
-here, not a slower one. And each record carries the `work` its operation
-needs by its equation (lib/roofline_terms.py, from the configuration: the
-roofline count reads that) beside the `operand` its computing node replied
-with (`rows`, `k`: the matrix it dispatched, zeros and padding included).
+are lost one at a time, in the order the traffic file names (`lost_order`,
+never the seed's: every run of a cell repairs the same sequence), so that
+`ec.rebuild`, given no `-repair` flag, has to take the layout's
+single-shard route by itself (the traffic mix's `routes`): a reply that
+names another route, or carries a `repair_fallback`, or gathered more of
+k x shard than the route may, makes the run not correct — a fall-back to
+the full gather is a wrong result here, not a slower one. And each record
+carries the `work` its operation needs by its equation
+(lib/roofline_terms.py, from the configuration: the roofline count reads
+that) beside the `operand` its computing node replied with (`rows`, `k`:
+the matrix it dispatched, zeros and padding included).
 """
 
 import glob
 import os
 import resource
 import time
-
-import numpy as np
 
 from lib import cluster as cl
 from lib import controls, datagen, observe, reference, reference_piggyback
@@ -100,9 +100,16 @@ controls.CONTROLS.update(force_full_gather=force_full_gather,
 
 def prepare(run) -> dict:
     config, traffic, cluster = run.config, run.traffic, run.cluster
+    order = [int(s) for s in traffic["lost_order"]]
+    cl.check(len(order) > 1 and all(
+        0 <= sid < cluster.k and sid != order[n - 1]
+        for n, sid in enumerate(order)),
+        f"lost_order {order}: data shards (under {cluster.k}), no two "
+        f"neighbours the same, the last and the first neither")
     state = {"cycles": [], "ref": REFERENCES[config["layout"]],
              "route": traffic["routes"][config["layout"]],
-             "repairs": int(traffic["repairs_per_seal"])}
+             "repairs": int(traffic["repairs_per_seal"]),
+             "order": order, "losses": 0}
     sizes = datagen.needle_sizes(traffic["needles"],
                                  int(config["volume_mib"]) << 20,
                                  run.seed, 0)
@@ -118,15 +125,16 @@ def prepare(run) -> dict:
               "payload_bytes": int(sizes.sum()),
               "dat_bytes": state["dat_bytes"],
               "layout": config["layout"], "route": state["route"],
-              "repairs_per_seal": state["repairs"],
+              "repairs_per_seal": state["repairs"], "lost_order": order,
               "seconds": time.perf_counter() - t0})
     # warm-up: the same commands on the uploaded volume itself, which
     # compiles (or finds in the cache) every shape the window uses: the
     # encode's, and the repair's, which is one shape whichever shard is
     # lost (compiles_in_window holds the program to that); then the plan
-    # of every shard the window may draw
+    # of every shard the window may lose
     state["warm"] = _cycle(run, state, volume["vid"], timed=False,
                            deadline=None)
+    state["losses"] = 0     # the window starts the order anew
     if state["route"]["repair_mode"] == "trace":
         _warm_trace_plans(run)
     return state
@@ -138,7 +146,7 @@ def _warm_trace_plans(run):
     searches one the first time a (lost, helpers) pair is seen (0.5 s of
     a 4 s repair) and keeps it for the life of the process (ops/codec's
     plan cache); the window measures the server after that, whichever
-    shards its seed draws. Asked of the program's own planner with what a
+    shards the mix names. Asked of the program's own planner with what a
     store passes it, once a data shard; the half-plane route's plans take
     no search (0.04 ms) and need none."""
     from seaweedfs_tpu.ops import codec as planner
@@ -152,13 +160,6 @@ def _warm_trace_plans(run):
             matrix_kind=codec.matrix_kind, matrix=codec.matrix)
     run.emit({"phase": "warm_plans", "plans": cluster.k,
               "seconds": time.perf_counter() - t0})
-
-
-def lost_shards(seed: int, vid: int, k: int, repairs: int) -> list:
-    """The data shards volume `vid` loses, in order: drawn from the seed
-    among the k for this volume alone, without replacement within it."""
-    order = np.random.default_rng([seed, 4, vid]).permutation(k)
-    return [int(s) for s in order[:repairs]]
 
 
 def _timed(run, op: str, nbytes: int, timed: bool, *args):
@@ -212,15 +213,15 @@ def _landed(run, state, cycle, op: str, vid: int, sids) -> bool:
 
 def _cycle(run, state, vid: int, timed: bool, deadline) -> dict:
     """One sealed volume: the encode, then its repairs. The warm-up
-    (no deadline) makes one repair; a cycle of the window stops losing
-    shards once the time is up, so that the command in flight then is
-    the last one."""
+    (no deadline) makes one repair, of the order's first shard; a cycle
+    of the window loses the next ones of the order (the n-th repair of
+    the window the n-th entry, round and round) and stops losing shards
+    once the time is up, so that the command in flight then is the last
+    one."""
     cluster = run.cluster
     every = set(range(cluster.total))
     cycle = {"vid": vid, "encoded": None, "repairs": [], "error": None,
-             "raised": False, "not_landed": 0,
-             "lost": lost_shards(run.seed, vid, cluster.k,
-                                 state["repairs"] if deadline else 1)}
+             "raised": False, "not_landed": 0, "lost": []}
     state["cycles"].append(cycle)
     enc = _timed(run, "ec.encode", state["dat_bytes"], timed,
                  "-volumeId", str(vid))
@@ -236,10 +237,12 @@ def _cycle(run, state, vid: int, timed: bool, deadline) -> dict:
         files = cluster.shard_files(vid)
         cycle["encoded"] = reference.sha256_files(
             [files[s] for s in range(cluster.total)])
-    for n, sid in enumerate(cycle["lost"]):
+    for _ in range(state["repairs"] if deadline else 1):
         if deadline and time.perf_counter() >= deadline:
-            cycle["lost"] = cycle["lost"][:n]
             break
+        sid = state["order"][state["losses"] % len(state["order"])]
+        state["losses"] += 1
+        cycle["lost"].append(sid)
         repair = {"sid": sid, "sha": None, "reply": None}
         cycle["repairs"].append(repair)
         with run.tracer.mark("lose"):

@@ -266,12 +266,3 @@ def needle_payload(seed: int, i: int, size: int) -> bytes:
     """Needle i's bytes, from the seed alone."""
     return np.random.default_rng([seed, i]).bytes(max(int(size), 1))
 
-
-def lost_shards(seed: int, k: int, m: int, spec: dict) -> list:
-    """The shards a mix loses: `{"data": a, "parity": b}` draws a data
-    and b parity shards from the seed (chip_smoke.py's rule)."""
-    rng = np.random.default_rng([seed, 4])
-    return sorted(
-        [int(s) for s in rng.choice(k, int(spec["data"]), replace=False)] +
-        [k + int(s) for s in rng.choice(m, int(spec["parity"]),
-                                        replace=False)])

@@ -218,6 +218,14 @@ def main(argv=None) -> int:
         last["rehearsal_not_a_chip_run"] = True
     if args.control:
         last["control"] = args.control
+    # each number compared beside its limit: the last key of the result
+    # line and the last lines on standard error
+    last["checks"] = {c["check"]: {"value": c["value"], "limit": c["limit"]}
+                      for c in run.checks}
+    for c in run.checks:
+        print(f"check {c['check']}: {c['value']} (limit {c['limit']})"
+              f"{'' if c['ok'] else '  NOT MET'}", file=sys.stderr)
+    sys.stderr.flush()
     out.write(json.dumps(last) + "\n")
     out.flush()
     return 0
@@ -252,6 +260,7 @@ def measure(run, kind, observe, trace_reduce) -> dict:
           "compiles": {k: v for k, v in before.items()
                        if k.startswith("jit.compile")}})
     tracing.add_finish_hook(on_span)
+    host_before = observe.host_now()
     t0 = time.perf_counter()
     try:
         kind.window(run, state)
@@ -259,6 +268,7 @@ def measure(run, kind, observe, trace_reduce) -> dict:
         run.tracer.stop()
         tracing.remove_finish_hook(on_span)
     window_s = time.perf_counter() - t0
+    emit(observe.host_line(run.ops, host_before, observe.host_now()))
     run.counters = observe.counters_delta(
         before, observe.counters_now(run.cluster))
     emit({"phase": "window_done", "window_s": window_s,

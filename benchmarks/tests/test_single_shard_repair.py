@@ -7,8 +7,9 @@ rehearsal is `run.py --rehearse` at a 32 MiB volume in a process of its
 own. What they hold: `correct` true with the two route checks printed
 beside their limits; a traced rehearsal's result line CONTAINS the cell's
 listed metrics that have something to read off the chip; the reference is
-the one the configuration's layout names; lost shards are drawn for each
-volume alone and no window repair searches a plan; and the three controls
+the one the configuration's layout names; lost shards are taken in the
+order the traffic file names, whatever the seed, and no window repair
+searches a plan; and the three controls
 of the mix come out not correct with the named checks over their limits.
 """
 
@@ -34,6 +35,8 @@ DEVICE_TRACE = {"kernel_terms_roofline", "device_idle_share.seal",
 # long enough for a whole cycle where the CPU stands in for the kernel: the
 # coupled encode of 32 MiB takes 2-4 s there (a later --seconds wins)
 WINDOW = ("--seconds", "8")
+with open(os.path.join(BENCH, "traffic", "single-shard-repair.json")) as _f:
+    ORDER = json.load(_f)["lost_order"]
 
 
 def listed(cell: str, source=None) -> set:
@@ -49,6 +52,13 @@ def checks_of(lines: list) -> dict:
 def phase(lines: list, name: str) -> dict:
     return next(json.loads(ln) for ln in lines
                 if f'"phase": "{name}"' in ln)
+
+
+def lost_in_window(lines: list) -> list:
+    """The shards the window's repairs lost, in order (the first cycle of
+    the verify line is the warm-up's)."""
+    return [sid for cycle in phase(lines, "verify")["lost"][1:]
+            for sid in cycle]
 
 
 def test_the_new_cells_are_listed_where_they_report():
@@ -68,7 +78,8 @@ def test_the_new_cells_are_listed_where_they_report():
     with open(os.path.join(BENCH, "traffic",
                            "single-shard-repair.json")) as f:
         mix = json.load(f)
-    assert mix["lose"] == {"data": 1, "parity": 0}
+    assert "lose" not in mix
+    assert sorted(mix["lost_order"]) == list(range(10))
     assert 1 <= mix["repairs_per_seal"] <= 4
     for cell in (PB, FLAT):
         entry = next(w for w in bench["workloads"] if w["name"] == cell)
@@ -101,6 +112,8 @@ def test_cell_rehearsal_traced(cell, layout, route, limit, operands, terms):
         "reference_piggyback" if layout == "piggyback" else "reference")
     assert all(len(set(lost)) == len(lost) and max(lost, default=0) < 10
                for lost in verify["lost"])
+    assert lost_in_window(lines) == [ORDER[n % 10] for n in range(
+        len(lost_in_window(lines)))]
     # the roofline count is made from the operation's equation; the
     # operand the node replied with is printed beside it
     ops = phase(lines, "roofline")["ops"]
@@ -125,13 +138,23 @@ def test_cell_rehearsal_traced(cell, layout, route, limit, operands, terms):
         else 62.5 <= share <= 70.0
 
 
-@pytest.mark.parametrize("cell", [PB, FLAT])
-def test_cell_rehearsal_untraced(cell):
-    rc, lines, err = rehearse(cell, *WINDOW)
+@pytest.mark.parametrize("cell,seed", [(PB, "2147483659"), (FLAT, "5"),
+                                       (FLAT, "2147483659")])
+def test_cell_rehearsal_untraced(cell, seed):
+    """Whatever the seed, the warm-up repairs the order's first shard and
+    the window's n-th repair its n-th: two rehearsals on different seeds
+    emit the same `lost` sequence."""
+    rc, lines, err = rehearse(cell, *WINDOW, "--seed", seed)
     assert rc == 0, err[-3000:]
     last = last_line(lines)
     assert last["correct"] is True
     assert set(last["metrics"]) == {"encode_mbps", "rebuild_mbps", "setup_s"}
+    assert phase(lines, "verify")["lost"][0] == ORDER[:1]
+    lost = lost_in_window(lines)
+    assert len(lost) >= 3
+    assert lost == [ORDER[n % 10] for n in range(len(lost))]
+    host = phase(lines, "host")
+    assert host["ops"]["ec.rebuild"]["count"] == len(lost)
 
 
 @pytest.mark.parametrize("cell,control,failing", [

@@ -3,26 +3,28 @@
 
     chiprun --timeout 3000 -- python3 benchmarks/tools/chip_sets.py \\
         --workload <cell> --tag c1 --seeds 11,12,13,14,15,16 --sets 2 \\
-        --traced 21 --short 31,32,33,34,35,36 --control 41,42,43
+        --traced 21 --traced-short 22,23 --short 31,32,33 --control 41
 
 `--sets` full-length runs over the same seeds (`run_seconds` of
 BENCHMARK.json, --trace 0), then traced runs, short runs on further seeds
 (`correct` on a dozen seeds) and each of the traffic mix's controls, or
-those `--controls` names (every one has to end `correct: false`). This process never touches JAX: each run is a child
-that holds the chip alone. Every run's stdout is kept under
-chiprun_out/sets/<tag>/, its last line in chiprun_out/sets/<tag>.jsonl, and
-the spreads the bounds are set from are printed at the end: for each
-metric and set the distance between the quartiles
-(`statistics.quantiles(values, n=4)`) as a share of the median.
+those `--controls` names (every one has to end `correct: false`). This
+process never touches JAX: each run is a child that holds the chip alone.
+Every run's stdout is kept under chiprun_out/sets/<tag>/, its last line in
+chiprun_out/sets/<tag>.jsonl, and the spreads the bounds are set from are
+printed at the end, for each metric and set as `spreads.py` beside this
+file prints them (it reads the .jsonl again, so sets of several calls can
+be put side by side later).
 """
 
 import argparse
 import json
 import os
-import statistics
 import subprocess
 import sys
 import time
+
+import spreads
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -32,11 +34,6 @@ def seeds(text: str) -> list:
     return [int(s) for s in text.split(",") if s]
 
 
-def spread(values: list) -> float:
-    q = statistics.quantiles(values, n=4)
-    return (q[2] - q[0]) / statistics.median(values)
-
-
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -44,6 +41,9 @@ def main() -> int:
     ap.add_argument("--seeds", default="")
     ap.add_argument("--sets", type=int, default=2)
     ap.add_argument("--traced", default="")
+    ap.add_argument("--traced-short", default="",
+                    help="traced runs of --short-seconds: the trace covers "
+                         "the window's first cycle whatever its length")
     ap.add_argument("--short", default="")
     ap.add_argument("--control", default="")
     ap.add_argument("--controls", default="",
@@ -67,6 +67,9 @@ def main() -> int:
     plan += [("traced", seed, bench["run_seconds"], 1,
               ["--out", os.path.join(out_dir, f"trace-{seed}")])
              for seed in seeds(args.traced)]
+    plan += [("traced", seed, args.short_seconds, 1,
+              ["--out", os.path.join(out_dir, f"trace-{seed}")])
+             for seed in seeds(args.traced_short)]
     plan += [("short", seed, args.short_seconds, 0, [])
              for seed in seeds(args.short)]
     plan += [("control", seed, args.short_seconds, 0, ["--control", control])
@@ -89,6 +92,8 @@ def main() -> int:
                "control": extra[1] if kind == "control" else None,
                "checks": [json.loads(ln) for ln in lines
                           if ln.startswith('{') and '"check"' in ln],
+               "host": next((json.loads(ln) for ln in lines
+                             if '"phase": "host"' in ln), None),
                "took_s": round(time.time() - t0, 1), "last": last}
         rows.append(row)
         log.write(json.dumps(row) + "\n")
@@ -98,21 +103,8 @@ def main() -> int:
               None if last is None else (last["correct"], {
                   k: round(v["value"], 3)
                   for k, v in last["metrics"].items()}), flush=True)
-    sets = sorted({r["kind"] for r in rows if r["kind"].startswith("set")})
-    table = {}
-    for name in sets:
-        for r in rows:
-            if r["kind"] == name and r["last"]:
-                for metric, v in r["last"]["metrics"].items():
-                    table.setdefault(metric, {}).setdefault(
-                        name, []).append(v["value"])
-    for metric, by_set in sorted(table.items()):
-        for name, values in sorted(by_set.items()):
-            if len(values) >= 2:
-                print(f"{metric:>14} {name}: median "
-                      f"{statistics.median(values):.4f} spread "
-                      f"{100 * spread(values):.2f} %  first {values[0]:.4f}  "
-                      f"rest {[round(v, 4) for v in values[1:]]}")
+    log.close()
+    spreads.main([out_dir + ".jsonl"])
     bad = [r for r in rows if r["kind"] != "control" and
            not (r["last"] and r["last"]["correct"])]
     passed_control = [r for r in rows if r["kind"] == "control" and
