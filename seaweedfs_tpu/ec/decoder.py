@@ -101,32 +101,38 @@ def find_dat_file_size(base_name: str) -> int:
 def write_dat_file(base_name: str, dat_size: int,
                    large_block: int = LARGE_BLOCK_SIZE,
                    small_block: int = SMALL_BLOCK_SIZE,
-                   buf_size: int = 8 << 20):
-    """Interleave-copy .ec00-09 back into a .dat of dat_size bytes."""
+                   buf_size: int = 8 << 20,
+                   data_shards: Optional[int] = None):
+    """Interleave-copy the k data shards (.ec00-09 of a 10 + 4 volume)
+    back into a .dat of dat_size bytes; k is the volume's own (.vif)
+    unless the caller names it."""
+    if data_shards is None:
+        from .layout import volume_geometry
+        data_shards = volume_geometry(base_name)[0]
     with tracing.span("write", op="ec.to_volume", bytes=int(dat_size)):
         _write_dat_file(base_name, dat_size, large_block, small_block,
-                        buf_size)
+                        buf_size, data_shards)
 
 
 def _write_dat_file(base_name, dat_size, large_block, small_block,
-                    buf_size):
-    ins = [open(base_name + to_ext(i), "rb") for i in range(DATA_SHARDS)]
+                    buf_size, data_shards=DATA_SHARDS):
+    ins = [open(base_name + to_ext(i), "rb") for i in range(data_shards)]
     try:
         with open(base_name + ".dat", "wb") as dat:
             remaining = dat_size
-            large_row = large_block * DATA_SHARDS
+            large_row = large_block * data_shards
             block_row = 0
             while remaining > large_row:
-                for i in range(DATA_SHARDS):
+                for i in range(data_shards):
                     _copy_block(ins[i], block_row * large_block, large_block,
                                 dat, buf_size)
                 remaining -= large_row
                 block_row += 1
             large_rows = block_row
             small_row_idx = 0
-            small_row = small_block * DATA_SHARDS
+            small_row = small_block * data_shards
             while remaining > 0:
-                for i in range(DATA_SHARDS):
+                for i in range(data_shards):
                     want = min(remaining, small_block)
                     if want <= 0:
                         break
@@ -193,9 +199,9 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
     the partial shard file before propagating, so the caller can fall
     back to the full streaming gather with a clean slate."""
     from ..ops import telemetry
-    from ..ops.codec import combine_planes_to_bytes, get_codec
-    from .constants import PARITY_SHARDS
-    codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
+    from ..ops.codec import combine_planes_to_bytes
+    from .encoder import volume_codec
+    codec = codec or volume_codec(base_name)
     if pipelined is None:
         pipelined = codec.pipelined
     if lost_sid != plan.lost:
@@ -318,9 +324,9 @@ def rebuild_ec_file_piggyback(base_name: str, lost_sid: int, source,
     propagating, so the caller can fall back to the full decode with a
     clean slate."""
     from ..ops import telemetry
-    from ..ops.codec import get_codec, pb_merge
-    from .constants import PARITY_SHARDS
-    codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
+    from ..ops.codec import pb_merge
+    from .encoder import volume_codec
+    codec = codec or volume_codec(base_name)
     if pipelined is None:
         pipelined = codec.pipelined
     if lost_sid != rplan.lost:

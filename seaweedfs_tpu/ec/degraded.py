@@ -4,7 +4,7 @@ first-class data-plane path.
 When a shard holder dies, needle reads that land on the lost shard fall
 through to reconstruction. The legacy loop
 (``volume_server._reconstruct_shard_range``) paid three separate taxes
-per read: it fanned out to all ``TOTAL_SHARDS-1`` siblings when k
+per read: it fanned out to all ``k+m-1`` siblings when k
 survivors suffice, it decoded the full 14-row stripe to recover one row,
 and it did all of it once per request even when a hundred readers were
 asking for the same dead shard at once.
@@ -22,7 +22,7 @@ asking for the same dead shard at once.
   survivor column ranges (``ops/codec.decode_plan``) through the PR-4
   reader stack: ``LocalShardReader`` for shards on this server,
   ``RemoteShardReader`` (per-stripe round-robin, ``SW_EC_HEDGE_MS``
-  hedging, failover) for the rest. Never ``TOTAL_SHARDS-1`` siblings.
+  hedging, failover) for the rest. Never ``k+m-1`` siblings.
 * **One-row decode** — ``codec.lost_row_coeffs`` extracts the lost
   shard's single coefficient row from the cached decode plan, so the
   matmul output is (1, W), not (missing, W).
@@ -201,8 +201,9 @@ class DegradedReadEngine:
     the cached ``{sid: [holders]}`` map; ``loc_cache`` (optional) is the
     ``EcShardLocationCache`` to invalidate when a survivor gather dies;
     ``self_url`` (str or callable) is this server's own address, which
-    never counts as a remote holder; ``codec`` (callable) resolves the
-    RS codec lazily so the store's backend choice wins.
+    never counts as a remote holder; ``codec(ev)`` (callable) resolves
+    the RS codec of one mounted volume's own geometry lazily, so the
+    store's backend choice wins (storage/store.Store.ec_volume_codec).
     """
 
     def __init__(self, store, locations, codec,
@@ -398,8 +399,8 @@ class DegradedReadEngine:
                            nreq: int) -> Dict[int, bytes]:
         with tracing.span("ec.degraded", volume=vid, shard=sid,
                           slabs=len(idxs), requests=nreq) as root:
-            codec = self._codec()
             ev = self.store.find_ec_volume(vid)
+            codec = self._codec(ev)
             self_url = self._self_url() if callable(self._self_url) \
                 else self._self_url
             locations = self._locations(vid) or {}

@@ -23,8 +23,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 from ..storage.needle_map import bytes_to_entry
 from ..storage.types import (NEEDLE_ENTRY_SIZE, TOMBSTONE_FILE_SIZE,
                              needle_id_to_bytes)
-from .constants import (DATA_SHARDS, LARGE_BLOCK_SIZE, PARITY_SHARDS,
-                        SMALL_BLOCK_SIZE, TOTAL_SHARDS, to_ext)
+from .constants import (LARGE_BLOCK_SIZE, MAX_SHARDS, SMALL_BLOCK_SIZE,
+                        to_ext)
 from .locate import Interval, locate_data
 
 
@@ -175,6 +175,10 @@ class EcVolume:
         self.created_at = time.time()
         self.version = None
         self.offset_width = None
+        # the volume's own RS geometry, stamped at encode time; a .vif
+        # that names none (every volume encoded before it did) is 10 + 4
+        from .layout import volume_geometry
+        self.k, self.m = volume_geometry(self.base_name)
         vif = self.base_name + ".vif"
         if os.path.exists(vif):
             try:
@@ -214,6 +218,10 @@ class EcVolume:
                     self.base_name, ",".join(defaulted), self.version,
                     self.offset_width, self.base_name)
 
+    @property
+    def total(self) -> int:
+        return self.k + self.m
+
     # -- shard management --------------------------------------------------
     def add_shard(self, shard_id: int) -> bool:
         if shard_id in self.shards:
@@ -240,7 +248,8 @@ class EcVolume:
         from ..storage.needle import get_actual_size
         dat_size = self._dat_size_hint()
         intervals = locate_data(LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, dat_size,
-                                offset, get_actual_size(size, self.version))
+                                offset, get_actual_size(size, self.version),
+                                data_shards=self.k)
         return offset, size, intervals
 
     def _dat_size_hint(self) -> int:
@@ -257,7 +266,7 @@ class EcVolume:
             shard_size = s.size
             break
         if shard_size is None:
-            for i in range(TOTAL_SHARDS):
+            for i in range(self.total):
                 p = self.base_name + to_ext(i)
                 if os.path.exists(p):
                     shard_size = os.path.getsize(p)
@@ -267,8 +276,8 @@ class EcVolume:
         n_large = shard_size // LARGE_BLOCK_SIZE
         if n_large > 0 and shard_size % LARGE_BLOCK_SIZE == 0:
             n_large -= 1
-        return n_large * LARGE_BLOCK_SIZE * DATA_SHARDS + \
-            (shard_size - n_large * LARGE_BLOCK_SIZE) * DATA_SHARDS
+        return n_large * LARGE_BLOCK_SIZE * self.k + \
+            (shard_size - n_large * LARGE_BLOCK_SIZE) * self.k
 
     # -- reads -------------------------------------------------------------
     def read_interval(self, interval: Interval,
@@ -340,7 +349,7 @@ class EcVolume:
             p = self.base_name + ext
             if os.path.exists(p):
                 os.remove(p)
-        for i in range(TOTAL_SHARDS):
+        for i in range(MAX_SHARDS):
             p = self.base_name + to_ext(i)
             if os.path.exists(p):
                 os.remove(p)

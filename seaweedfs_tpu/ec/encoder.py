@@ -48,6 +48,14 @@ from .constants import (DATA_SHARDS, LARGE_BLOCK_SIZE, PARITY_SHARDS,
 DEFAULT_SLAB = 8 << 20  # bytes per shard per device call
 
 
+def volume_codec(base_name: str) -> ReedSolomonCodec:
+    """The codec of an encoded volume for a caller that brought none:
+    the geometry its ``.vif`` names (10 + 4 where it names none), on
+    the backend ``auto`` picks."""
+    from .layout import volume_geometry
+    return get_codec(*volume_geometry(base_name))
+
+
 def write_sorted_file_from_idx(base_name: str, ext: str = ".ecx"):
     """Build the sorted EC index next to the volume files. Record width
     follows the volume's offset width (superblock flag; 5-byte-offset
@@ -449,6 +457,8 @@ def write_ec_files_spread(base_name: str, sink,
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
         stats["operand"] = list(operand)
+        stats["k"], stats["m"] = codec.k, codec.m
+        stats["shards"] = codec.total
         stats["phases"] = {n: round(s, 6) for n, s in
                            _phases_from_timer(timer, pipelined).items()}
         # encode busy = stream wall minus the time the consumer spent
@@ -516,7 +526,7 @@ def rebuild_ec_files(base_name: str,
     volume goes through rebuild_ec_files_piggyback, the body the
     streaming rebuild runs too, with every survivor read from a local
     file."""
-    codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
+    codec = codec or volume_codec(base_name)
     k, total = codec.k, codec.total
     if pipelined is None:
         pipelined = codec.pipelined
@@ -643,6 +653,8 @@ def rebuild_ec_files(base_name: str,
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
         stats["operand"] = [len(missing), k]
+        stats["k"], stats["m"] = codec.k, codec.m
+        stats["lost"] = list(missing)
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
     return missing
 
@@ -679,7 +691,7 @@ def rebuild_ec_files_piggyback(base_name: str, present: List[bool],
     partial outputs: a caller gets whole shards or nothing."""
     from ..ops import codec as ops_codec
     from ..ops import telemetry
-    codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
+    codec = codec or volume_codec(base_name)
     if pipelined is None:
         pipelined = codec.pipelined
     if not missing:
@@ -785,6 +797,7 @@ def rebuild_ec_files_piggyback(base_name: str, present: List[bool],
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
         stats["operand"] = list(coeffs.shape)
+        stats["k"], stats["m"] = codec.k, codec.m
         stats["layout"] = "piggyback"
         stats["lost"] = list(missing)
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
@@ -816,7 +829,7 @@ def rebuild_ec_files_streaming(base_name: str,
     decode plan), not local files. On ANY failure the partially written
     missing-shard files are removed — callers either get complete
     rebuilt shards or nothing."""
-    codec = codec or get_codec(DATA_SHARDS, PARITY_SHARDS)
+    codec = codec or volume_codec(base_name)
     k, total = codec.k, codec.total
     if pipelined is None:
         pipelined = codec.pipelined
@@ -910,8 +923,16 @@ def rebuild_ec_files_streaming(base_name: str,
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
         stats["operand"] = list(coeffs.shape)
+        stats["k"], stats["m"] = codec.k, codec.m
+        stats["lost"] = list(missing)
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
         stats.update(gs.overlap(stream_s, phases["gather"]))
+        # the byte account the single-shard routes give: what the
+        # gather's readers received, of the k whole shards it is meant
+        # to pull
+        stats["repair_bytes"] = gs.bytes
+        stats["repair_remote_bytes"] = gs.remote_bytes
+        stats["repair_baseline_bytes"] = k * source.shard_size
     return list(missing)
 
 

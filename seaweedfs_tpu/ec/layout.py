@@ -24,6 +24,11 @@ The layout is recorded twice, redundantly:
 
 ``volume_layout`` resolves the two (``.vif`` wins) and is the single
 routing predicate for store/scrub/degraded/rebuild.
+
+The ``.vif`` also carries the volume's RS geometry beside the layout
+keys (``ec_data_shards`` / ``ec_parity_shards``, written at encode
+time; ``volume_geometry`` reads them). A ``.vif`` without them is a
+volume encoded before geometries were per volume: the default, 10 + 4.
 """
 
 from __future__ import annotations
@@ -64,6 +69,39 @@ class LayoutInfo:
     def __repr__(self):
         return (f"LayoutInfo({self.layout!r}, window={self.window}, "
                 f"pairs={self.pairs})")
+
+
+def parse_geometry(text) -> "tuple[int, int]":
+    """``"6,3"`` (the shell flag and the admin route's query) or a
+    ``[k, m]`` pair -> (k, m), refused by name where it is no RS
+    geometry the shard bitmap can hold."""
+    from .constants import MAX_SHARDS
+    try:
+        k, m = (int(part) for part in
+                (text.split(",") if isinstance(text, str) else text))
+    except (TypeError, ValueError):
+        raise ValueError(f"geometry {text!r}: want <data>,<parity> "
+                         f"shards, e.g. 6,3") from None
+    if k < 1 or m < 1 or k + m > MAX_SHARDS:
+        raise ValueError(f"geometry {k},{m}: data and parity shards "
+                         f"at least 1 each, at most {MAX_SHARDS} in all")
+    return k, m
+
+
+def volume_geometry(base_name: str, default=None) -> "tuple[int, int]":
+    """(k, m) of an EC volume from its ``.vif``; ``default`` (10 + 4
+    unless the caller has another) where the sidecar is missing or
+    names none — every volume encoded before the keys existed."""
+    from .constants import DATA_SHARDS, PARITY_SHARDS
+    try:
+        with open(base_name + ".vif") as f:
+            info = json.load(f) or {}
+        k, m = info.get("ec_data_shards"), info.get("ec_parity_shards")
+        if k and m:
+            return int(k), int(m)
+    except (OSError, ValueError, TypeError):
+        pass
+    return tuple(default) if default else (DATA_SHARDS, PARITY_SHARDS)
 
 
 def _default_geometry(k: int) -> "tuple[int, int]":

@@ -33,30 +33,32 @@ class Interval:
     size: int
     is_large_block: bool
     large_block_rows_count: int
+    data_shards: int = DATA_SHARDS      # the volume's k: blocks a row
 
     def to_shard_id_and_offset(self, large_block: int, small_block: int):
         offset = self.inner_block_offset
-        row = self.block_index // DATA_SHARDS
+        row = self.block_index // self.data_shards
         if self.is_large_block:
             offset += row * large_block
         else:
             offset += (self.large_block_rows_count * large_block
                        + row * small_block)
-        return self.block_index % DATA_SHARDS, offset
+        return self.block_index % self.data_shards, offset
 
 
-def n_large_rows_for(dat_size: int, large_block: int) -> int:
+def n_large_rows_for(dat_size: int, large_block: int,
+                     data_shards: int = DATA_SHARDS) -> int:
     """Number of large rows the encoder actually wrote: one per full
-    10*large_block row while STRICTLY more than a row remains."""
+    k*large_block row while STRICTLY more than a row remains."""
     if dat_size <= 0:
         return 0
-    return (dat_size - 1) // (large_block * DATA_SHARDS)
+    return (dat_size - 1) // (large_block * data_shards)
 
 
 def _locate_offset(large_block: int, small_block: int, dat_size: int,
-                   offset: int):
-    large_row = large_block * DATA_SHARDS
-    n_large_rows = n_large_rows_for(dat_size, large_block)
+                   offset: int, data_shards: int = DATA_SHARDS):
+    large_row = large_block * data_shards
+    n_large_rows = n_large_rows_for(dat_size, large_block, data_shards)
     if offset < n_large_rows * large_row:
         return offset // large_block, True, offset % large_block
     offset -= n_large_rows * large_row
@@ -64,22 +66,23 @@ def _locate_offset(large_block: int, small_block: int, dat_size: int,
 
 
 def locate_data(large_block: int, small_block: int, dat_size: int,
-                offset: int, size: int) -> List[Interval]:
+                offset: int, size: int,
+                data_shards: int = DATA_SHARDS) -> List[Interval]:
     block_index, is_large, inner = _locate_offset(
-        large_block, small_block, dat_size, offset)
-    n_large_rows = n_large_rows_for(dat_size, large_block)
+        large_block, small_block, dat_size, offset, data_shards)
+    n_large_rows = n_large_rows_for(dat_size, large_block, data_shards)
 
     intervals: List[Interval] = []
     while size > 0:
         block_remaining = (large_block if is_large else small_block) - inner
         take = min(size, block_remaining)
         intervals.append(Interval(block_index, inner, take, is_large,
-                                  n_large_rows))
+                                  n_large_rows, data_shards))
         size -= take
         if size <= 0:
             break
         block_index += 1
-        if is_large and block_index == n_large_rows * DATA_SHARDS:
+        if is_large and block_index == n_large_rows * data_shards:
             is_large = False
             block_index = 0
         inner = 0

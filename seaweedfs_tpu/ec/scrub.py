@@ -97,7 +97,7 @@ class ScrubEngine:
     """Paced background syndrome verification of every local EC volume."""
 
     def __init__(self, store, locations: Callable[[int], Dict[int, list]],
-                 codec: Callable[[], object],
+                 codec: Callable[[object], object],
                  self_url: Callable[[], str],
                  on_finding: Optional[Callable[[dict], bool]] = None,
                  rate_mbps: Optional[float] = None,
@@ -230,7 +230,7 @@ class ScrubEngine:
             self._set_volume_state(vid, skipped="not_owner")
             return {"volume": vid, "skipped": "not_owner"}
 
-        codec = self.codec()
+        codec = self.codec(ev)      # of the volume's own geometry
         # the volume's layout picks the parity-check rows: flat volumes
         # verify H·x=0 over raw shard bytes, piggyback volumes over the
         # sub-chunk rows ([E|I] from the coupled plan) of window-split
@@ -387,7 +387,7 @@ class ScrubEngine:
         sidecars (ec/layout.volume_layout)."""
         from ..storage.types import entry_size
         from .layout import volume_layout
-        codec = self.codec()
+        codec = self.codec(ev)
         width = getattr(ev, "offset_width", None) or 4
         return volume_layout(ev.base_name, codec.k,
                              record_size=entry_size(width))

@@ -19,7 +19,10 @@ class ShardBits(int):
         return bool(self & (1 << sid))
 
     def shard_ids(self):
-        return [i for i in range(TOTAL_SHARDS) if self.has_shard_id(i)]
+        """Every shard id set, whatever the volume's geometry (any
+        k + m up to the bitmap's 32 bits: RS(20,4) has 24)."""
+        return [i for i in range(self.bit_length())
+                if self.has_shard_id(i)]
 
     def shard_id_count(self) -> int:
         return bin(self).count("1")
@@ -30,8 +33,10 @@ class ShardBits(int):
     def minus(self, other: "ShardBits") -> "ShardBits":
         return ShardBits(self & ~other)
 
-    def minus_parity_shards(self) -> "ShardBits":
+    def minus_parity_shards(self, data_shards: int = DATA_SHARDS,
+                            total_shards: int = TOTAL_SHARDS
+                            ) -> "ShardBits":
         out = self
-        for sid in range(DATA_SHARDS, TOTAL_SHARDS):
+        for sid in range(data_shards, total_shards):
             out = out.remove_shard_id(sid)
         return out

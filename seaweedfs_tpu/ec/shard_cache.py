@@ -33,18 +33,25 @@ ENOUGH_SHARDS_TTL = 7 * 60.0   # >= k shards known
 class EcShardLocationCache:
     def __init__(self, fetch: Callable[[int], Dict[int, List[str]]],
                  data_shards: int = DATA_SHARDS,
-                 total_shards: int = TOTAL_SHARDS):
+                 total_shards: int = TOTAL_SHARDS,
+                 geometry: Callable[[int], tuple] = None):
+        """``geometry(vid)`` -> (k, k + m) of one volume, where the
+        owner knows it (a mounted volume's .vif); without it every
+        volume is held to ``data_shards`` / ``total_shards``."""
         self._fetch = fetch
         self._data_shards = data_shards
         self._total_shards = total_shards
+        self._geometry = geometry
         self._lock = make_lock("shard_cache._lock")
         self._entries: Dict[int, tuple] = {}  # vid -> (refresh_t, locations)
 
-    def _ttl(self, locations: Dict[int, List[str]]) -> float:
+    def _ttl(self, vid: int, locations: Dict[int, List[str]]) -> float:
+        k, total = self._geometry(vid) if self._geometry is not None \
+            else (self._data_shards, self._total_shards)
         known = sum(1 for urls in locations.values() if urls)
-        if known < self._data_shards:
+        if known < k:
             return FEW_SHARDS_TTL
-        if known >= self._total_shards:
+        if known >= total:
             return ALL_SHARDS_TTL
         return ENOUGH_SHARDS_TTL
 
@@ -53,7 +60,8 @@ class EcShardLocationCache:
             entry = self._entries.get(vid)
             if entry is not None:
                 refresh_t, locations = entry
-                if time.monotonic() - refresh_t < self._ttl(locations):
+                if time.monotonic() - refresh_t < \
+                        self._ttl(vid, locations):
                     return locations
         locations = self._fetch(vid) or {}
         with self._lock:

@@ -168,6 +168,8 @@ class ReedSolomonCodec:
         self.k = data_shards
         self.m = parity_shards
         self.total = data_shards + parity_shards
+        # the name dispatches, spans and replies count this geometry by
+        self.geometry = f"{data_shards}+{parity_shards}"
         self.matrix_kind = matrix_kind
         self.matrix = gf256.build_matrix(self.k, self.total, matrix_kind)
         self._decode_cache: dict = {}

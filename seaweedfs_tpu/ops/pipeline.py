@@ -168,8 +168,7 @@ class PipelinedMatmul:
                         timer.add("h2d", end - t0, padded.nbytes,
                                   interval=(t0, end))
                     up.nbytes = padded.nbytes
-                STATS.add("dispatches")
-                STATS.add("device_bytes", data.nbytes)
+                STATS.add_dispatch(self.codec.geometry, data.nbytes)
                 out = fn(const, dev)                 # async dispatch
                 fut = drain_pool.submit(fetch, out, self.r * bucket, w)
                 pending.append((meta, data, fut, w))

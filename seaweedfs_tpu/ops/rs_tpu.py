@@ -295,8 +295,7 @@ class DeviceCodec(ReedSolomonCodec):
                     padded = np.zeros((k, bucket), dtype=np.uint8)
                     padded[:, :w] = chunk
                     chunk = padded
-                STATS.add("dispatches")
-                STATS.add("device_bytes", w * k)
+                STATS.add_dispatch(self.geometry, w * k)
                 pending.append((off, end, fn(bitmat, put(chunk))))
         with tracing.span("drain", backend=self.backend,
                           bytes=int(n * r)):

@@ -36,6 +36,13 @@ the full decode as its own route and counts no fallback.
 `coupled_decodes` counts the full coupled decodes of piggyback volumes
 (ec/encoder.rebuild_ec_files_piggyback), local or streaming.
 
+`geometry_dispatches` counts every dispatch again under the RS
+geometry of the codec that issued it (`"10+4"`, `"6+3"`: a volume's
+geometry is its own, storage/store keeps a codec a geometry), at the
+two sites `dispatches` is counted: a call site that fell back to the
+default geometry's codec for a volume of another is bit-wrong or slow
+and shows nowhere else than in this map.
+
 `slab_fresh_bytes` counts the bytes of stripe-sized host blocks that
 were new memory (ec/transport._take_slab found its pool empty, or
 holding nothing large enough): what these hosts charge for is memory a
@@ -67,10 +74,20 @@ class DispatchStats:
             setattr(self, f, 0)
         self._mesh_device_bytes: Dict[str, int] = {}
         self._repair_route = dict.fromkeys(self.REPAIR_ROUTES, 0)
+        self._geometry_dispatches: Dict[str, int] = {}
 
     def add(self, field: str, n: int = 1):
         with self._lock:
             setattr(self, field, getattr(self, field) + n)
+
+    def add_dispatch(self, geometry: str, nbytes: int):
+        """One device dispatch of `nbytes` payload bytes, issued by a
+        codec of this geometry (`ReedSolomonCodec.geometry`: "10+4")."""
+        with self._lock:
+            self.dispatches += 1
+            self.device_bytes += nbytes
+            self._geometry_dispatches[geometry] = \
+                self._geometry_dispatches.get(geometry, 0) + 1
 
     def add_read(self, nbytes: int, busy_s: float, cpu_s: float):
         """One dispatch's slab left the `.dat` reader thread."""
@@ -95,6 +112,7 @@ class DispatchStats:
             snap = {f: getattr(self, f) for f in self._FIELDS}
             snap["mesh_device_bytes"] = dict(self._mesh_device_bytes)
             snap["repair_route"] = dict(self._repair_route)
+            snap["geometry_dispatches"] = dict(self._geometry_dispatches)
             return snap
 
 
@@ -120,6 +138,11 @@ def delta(before: dict) -> dict:
         if moved > 0:
             per_dev[dev] = moved
     out["mesh_device_bytes"] = per_dev
+    before_geo = before.get("geometry_dispatches", {})
+    moved_geo = {g: n - before_geo.get(g, 0)
+                 for g, n in now["geometry_dispatches"].items()}
+    out["geometry_dispatches"] = {g: n for g, n in moved_geo.items()
+                                  if n > 0}
     if per_dev:
         peak = max(per_dev.values())
         out["dispatch_width_devices"] = len(per_dev)

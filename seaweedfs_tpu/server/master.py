@@ -509,17 +509,20 @@ class MasterServer:
         via /cluster/scrub_report. Idempotent — repeat sightings
         collapse onto the open incident and keep its original
         detection time."""
-        from ..ec import TOTAL_SHARDS
         with self.topology.lock:
             shard_map = {vid: [[n.url for n in holders]
                                for holders in per_shard]
                          for vid, per_shard in
                          self.topology.ec_shard_map.items()}
+            totals = {vid: sum(self.topology.ec_geometry(vid))
+                      for vid in shard_map}
         for vid, per_shard in shard_map.items():
             if not any(per_shard):
                 continue  # fully unregistered volume, not a shard loss
-            present = sum(1 for holders in per_shard if holders)
-            if present == TOTAL_SHARDS:
+            # whole at the volume's own k + m (9, 14, 24 ...)
+            total = totals[vid]
+            present = sum(1 for holders in per_shard[:total] if holders)
+            if present == total:
                 self._repair_seen_complete.add(vid)
             # a hole is only a LOSS if the stripe was once whole: a
             # streaming encode registers shards incrementally, and
@@ -527,7 +530,7 @@ class MasterServer:
             # half-built volume
             if vid not in self._repair_seen_complete:
                 continue
-            for sid in range(TOTAL_SHARDS):
+            for sid in range(total):
                 holders = per_shard[sid] if sid < len(per_shard) else []
                 if holders:
                     self.repair_queue.resolve("lost_shard", volume=vid,
@@ -662,6 +665,11 @@ class MasterServer:
         ec_collections = {int(k): v
                           for k, v in
                           (hb.get("ec_collections") or {}).items()}
+        # each EC volume's own [k, m]; a holder that names none (an
+        # older volume server) leaves its volumes at the default
+        ec_geometries = {int(k): v
+                         for k, v in
+                         (hb.get("ec_geometries") or {}).items()}
         if hb.get("delta"):
             # incremental heartbeat (reference master_grpc_server.go:
             # 94-152): only new/changed/deleted volumes ride the wire.
@@ -673,7 +681,8 @@ class MasterServer:
                 deleted_volumes=[int(v) for v in
                                  hb.get("deleted_volumes", [])],
                 ec_shards=ec_shards, ec_collections=ec_collections,
-                max_file_key=int(hb.get("max_file_key", 0)))
+                max_file_key=int(hb.get("max_file_key", 0)),
+                ec_geometries=ec_geometries)
             if not applied:
                 return {"resync": True,
                         "volume_size_limit":
@@ -692,6 +701,7 @@ class MasterServer:
                 ec_shards=ec_shards,
                 ec_collections=ec_collections,
                 max_file_key=int(hb.get("max_file_key", 0)),
+                ec_geometries=ec_geometries,
             )
         out = {"volume_size_limit": self.topology.volume_size_limit,
                "leader": self.leader_url() or self.url}
@@ -898,7 +908,9 @@ class MasterServer:
         shards = self.topology.lookup_ec_shards(vid)
         if shards is None:
             raise HttpError(404, f"ec volume {vid} not found")
-        return {"volumeId": vid, "shards": shards}
+        k, m = self.topology.ec_geometry(vid)
+        return {"volumeId": vid, "shards": shards,
+                "data_shards": k, "parity_shards": m}
 
     def ec_status(self, req: Request):
         fwd = self._leader_forward(req)
@@ -909,6 +921,8 @@ class MasterServer:
             return {"volumes": {
                 str(vid): {
                     "collection": self.topology.ec_collections.get(vid, ""),
+                    **dict(zip(("data_shards", "parity_shards"),
+                               self.topology.ec_geometry(vid))),
                     "shards": {str(sid): [n.url for n in holders]
                                for sid, holders in enumerate(per_shard)
                                if holders},

@@ -355,11 +355,12 @@ struct VolumeRec {
 // from their files; a lost shard's bytes come from the reconstructed-slab
 // cache below — if every covering slab is resident the GET never leaves
 // the plane.
-constexpr int kDataShards = 10;   // ec/constants.py DATA_SHARDS
+constexpr int kDataShards = 10;   // ec/constants.py DATA_SHARDS: the default
 constexpr int kMaxEcShards = 32;  // data+parity ceiling (codec max)
 
 struct EcVolumeRec {
   int version = 3;
+  int data_shards = kDataShards;  // the volume's own k (its .vif)
   int64_t dat_size = 0;  // original .dat size (drives the row split)
   int64_t large_block = 0, small_block = 0;
   int64_t slab_bytes = 0;  // cache slab size (SW_EC_DEGRADED_SLAB_BYTES)
@@ -376,9 +377,9 @@ struct EcVolumeRec {
 };
 
 // encoder-exact large-row count (ec/locate.py n_large_rows_for)
-int64_t ec_n_large_rows(int64_t dat_size, int64_t large_block) {
+int64_t ec_n_large_rows(int64_t dat_size, int64_t large_block, int k) {
   if (dat_size <= 0) return 0;
-  return (dat_size - 1) / (large_block * kDataShards);
+  return (dat_size - 1) / (large_block * k);
 }
 
 // ------------------------------------------------------------ slab cache
@@ -1255,8 +1256,9 @@ void serve_ec_needle(Server* s, int fd, const Request& req,
     return;
   }
   std::vector<uint8_t> blob(static_cast<size_t>(want), 0);
-  int64_t large_row = ev->large_block * kDataShards;
-  int64_t n_large = ec_n_large_rows(ev->dat_size, ev->large_block);
+  const int k = ev->data_shards;
+  int64_t large_row = ev->large_block * k;
+  int64_t n_large = ec_n_large_rows(ev->dat_size, ev->large_block, k);
   int64_t block_index, inner;
   bool is_large;
   if (static_cast<int64_t>(offset) < n_large * large_row) {
@@ -1280,8 +1282,8 @@ void serve_ec_needle(Server* s, int fd, const Request& req,
     while (remaining > 0) {
       int64_t blk = is_large ? ev->large_block : ev->small_block;
       int64_t take = std::min(remaining, blk - inner);
-      int sid = static_cast<int>(block_index % kDataShards);
-      int64_t row = block_index / kDataShards;
+      int sid = static_cast<int>(block_index % k);
+      int64_t row = block_index / k;
       int64_t shard_off =
           inner + (is_large ? row * ev->large_block
                             : n_large * ev->large_block +
@@ -1308,7 +1310,7 @@ void serve_ec_needle(Server* s, int fd, const Request& req,
       remaining -= take;
       if (remaining <= 0) break;
       block_index++;
-      if (is_large && block_index == n_large * kDataShards) {
+      if (is_large && block_index == n_large * k) {
         is_large = false;
         block_index = 0;
       }
@@ -2524,6 +2526,20 @@ int swhp_ec_register(void* h, uint32_t vid, int version, int64_t dat_size,
   rec->slab_bytes = slab_bytes;
   std::unique_lock<std::shared_mutex> l(s->ec_mu);
   s->ec_vols[vid] = std::move(rec);
+  return 0;
+}
+
+// The volume's own data-shard count k (its .vif's geometry), for a
+// volume that is not the default 10 + 4: set right after
+// swhp_ec_register, before any shard is attached, so no request ever
+// locates a needle with another k than its shards were striped by.
+int swhp_ec_set_data_shards(void* h, uint32_t vid, int k) {
+  if (!h || k < 1 || k >= kMaxEcShards) return -1;
+  Server* s = static_cast<Server*>(h);
+  auto ev = s->find_ec(vid);
+  if (!ev) return -1;
+  std::unique_lock<std::shared_mutex> l(ev->mu);
+  ev->data_shards = k;
   return 0;
 }
 

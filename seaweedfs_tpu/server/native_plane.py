@@ -203,6 +203,10 @@ def _load() -> Optional[ctypes.CDLL]:
                 ctypes.c_void_p, ctypes.c_uint32, ctypes.c_int,
                 ctypes.c_char_p]
             lib.swhp_ec_set_shard.restype = ctypes.c_int
+            if hasattr(lib, "swhp_ec_set_data_shards"):
+                lib.swhp_ec_set_data_shards.argtypes = [
+                    ctypes.c_void_p, ctypes.c_uint32, ctypes.c_int]
+                lib.swhp_ec_set_data_shards.restype = ctypes.c_int
             lib.swhp_ec_put_bulk.argtypes = [
                 ctypes.c_void_p, ctypes.c_uint32, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64]
@@ -390,8 +394,8 @@ class NativeReadPlane:
         h = self._h
         if not h or not self._has_cache:
             return False
-        from ..ec.constants import (LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE,
-                                    TOTAL_SHARDS)
+        from ..ec.constants import (DATA_SHARDS, LARGE_BLOCK_SIZE,
+                                    SMALL_BLOCK_SIZE)
         try:
             dat_size = ev._dat_size_hint()
         except Exception:
@@ -401,7 +405,15 @@ class NativeReadPlane:
             SMALL_BLOCK_SIZE, int(slab_bytes))
         if rc != 0:
             return False
-        for sid in range(TOTAL_SHARDS):
+        if ev.k != DATA_SHARDS:
+            # the volume's own k; an overridden build that stripes by
+            # 10 only leaves the volume on the redirect path (Python
+            # serves it)
+            set_k = getattr(self._lib, "swhp_ec_set_data_shards", None)
+            if set_k is None or set_k(h, ev.vid, ev.k) != 0:
+                self._lib.swhp_ec_unregister(h, ev.vid)
+                return False
+        for sid in range(ev.total):
             shard = ev.shards.get(sid)
             self._lib.swhp_ec_set_shard(
                 h, ev.vid, sid,

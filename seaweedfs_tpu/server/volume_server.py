@@ -17,8 +17,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from ..ec.constants import DATA_SHARDS, TOTAL_SHARDS, to_ext
-from ..ops.codec import get_codec
+from ..ec.constants import MAX_SHARDS, to_ext
 from ..util import malloc_policy, tracing
 from ..storage.needle import Needle
 from ..storage.store import Store
@@ -152,15 +151,13 @@ class VolumeServer:
         self.pulse_seconds = _config.env_float("SW_PULSE_S") \
             if pulse_seconds is None else pulse_seconds
         self.read_redirect = read_redirect
-        codec = get_codec(DATA_SHARDS, 4, backend=ec_backend) \
-            if ec_backend != "auto" else None
         self.store = Store(
             directories or ["./data"],
             max_volume_counts=max_volume_counts,
             ip=host, port=self.port,
             public_url=public_url or f"{host}:{self.port}",
-            data_center=data_center, rack=rack, codec=codec,
-            index_kind=index_kind)
+            data_center=data_center, rack=rack,
+            index_kind=index_kind, ec_backend=ec_backend)
         self.volume_size_limit = 30 * 1024 * 1024 * 1024
         # shard_write's piece buffers, kept between requests (a fresh
         # 8 MiB block is page faults on these hosts): a handler takes
@@ -179,7 +176,8 @@ class VolumeServer:
         self._vid_map = shared_vid_map(self.master_url)
         from ..ec.shard_cache import EcShardLocationCache
         self._ec_loc_cache = EcShardLocationCache(
-            self._fetch_ec_shard_locations)
+            self._fetch_ec_shard_locations,
+            geometry=self._mounted_ec_geometry)
         # batched degraded-read serving tier: reconstruct-on-read with
         # request coalescing, exactly-k survivor gather and a
         # reconstructed-slab LRU (ec/degraded.py)
@@ -188,7 +186,7 @@ class VolumeServer:
         self.degraded = DegradedReadEngine(
             store=self.store,
             locations=self._ec_shard_locations,
-            codec=lambda: self.store.codec or get_codec(DATA_SHARDS, 4),
+            codec=self.store.ec_volume_codec,
             loc_cache=self._ec_loc_cache,
             self_url=lambda: self.url,
             on_read=lambda s: DEGRADED_READ_HISTOGRAM.observe(
@@ -206,7 +204,7 @@ class VolumeServer:
         self.scrub = ScrubEngine(
             store=self.store,
             locations=self._ec_shard_locations,
-            codec=lambda: self.store.codec or get_codec(DATA_SHARDS, 4),
+            codec=self.store.ec_volume_codec,
             self_url=lambda: self.url,
             on_finding=self._report_scrub_finding)
         self._stop = threading.Event()
@@ -696,10 +694,11 @@ class VolumeServer:
             # family (observe_mesh), not the flat kind counter
             if isinstance(total, (int, float)):
                 DEVICE_TELEMETRY_COUNTER.set_total(total, kind)
-            elif kind == "repair_route":
-                for route, n in total.items():
+            elif kind in ("repair_route", "geometry_dispatches"):
+                # repair_route.full, geometry_dispatches.6+3, ...
+                for name, n in total.items():
                     DEVICE_TELEMETRY_COUNTER.set_total(
-                        n, f"repair_route.{route}")
+                        n, f"{kind}.{name}")
         # connection-pool churn (process-global, same mirror pattern)
         from .http_util import pool_stats_snapshot
         for event, total in pool_stats_snapshot().items():
@@ -906,6 +905,9 @@ class VolumeServer:
         bound for remote holders never touch this disk."""
         vid = int(req.query["volume"])
         collection = req.query.get("collection", "")
+        # `ec.encode -geometry k,m`: the new volume's RS code; absent,
+        # the default (10 + 4)
+        geometry = req.query.get("geometry") or None
         try:
             body = req.json()
         except ValueError:
@@ -921,14 +923,16 @@ class VolumeServer:
                 spares=body.get("spares") or [],
                 window=int(body.get("window") or 0) or None,
                 stats=stats,
-                rate_mbps=float(body.get("rate_mbps") or 0.0))
+                rate_mbps=float(body.get("rate_mbps") or 0.0),
+                geometry=geometry)
             observe_spread(stats)
             observe_mesh(stats)
             return {"volume": vid, "base": os.path.basename(base),
                     "assignment": {str(s): u for s, u in final.items()},
                     "stats": stats,
                     "trace_id": tracing.current_trace_id()}
-        base = self.store.generate_ec_shards(vid, collection)
+        base = self.store.generate_ec_shards(vid, collection,
+                                             geometry=geometry)
         return {"volume": vid, "base": os.path.basename(base)}
 
     def _ec_stage_base(self, vid: int, collection: str) -> str:
@@ -936,7 +940,8 @@ class VolumeServer:
         holding this volume's EC files if any (staged ranges, finalized
         shards and the later sidecar copy must all land at ONE base or
         the mount won't see them), else a free location."""
-        exts = [to_ext(s) for s in range(TOTAL_SHARDS)] + [".ecx"]
+        # a stage arrives before the volume's .vif: any shard id
+        exts = [to_ext(s) for s in range(MAX_SHARDS)] + [".ecx"]
         for loc in self.store.locations:
             base = volume_file_prefix(loc.directory, collection, vid)
             if any(os.path.exists(base + e) or
@@ -970,7 +975,7 @@ class VolumeServer:
             removed = []
             for loc in self.store.locations:
                 base = volume_file_prefix(loc.directory, collection, vid)
-                for sid in range(TOTAL_SHARDS):
+                for sid in range(MAX_SHARDS):
                     p = base + to_ext(sid) + ".part"
                     if os.path.exists(p):
                         os.remove(p)
@@ -1226,7 +1231,7 @@ class VolumeServer:
                         if not p.endswith(".part"):
                             removed.append(sid)
             if not any(os.path.exists(base + to_ext(s))
-                       for s in range(TOTAL_SHARDS)):
+                       for s in range(MAX_SHARDS)):
                 for ext in (".ecx", ".ecj", ".vif", ".scrub"):
                     if os.path.exists(base + ext):
                         os.remove(base + ext)
@@ -1312,13 +1317,13 @@ class VolumeServer:
         ev = self.store.find_ec_volume(vid)
         if ev is None:
             raise HttpError(404, f"ec volume {vid} not mounted")
-        if len([s for s in ev.shard_ids() if s < DATA_SHARDS]) < DATA_SHARDS:
+        if len([s for s in ev.shard_ids() if s < ev.k]) < ev.k:
             raise HttpError(409, "need all data shards local to decode")
         base = ev.base_name
         dat_size = ec_decoder.find_dat_file_size(base)
-        ec_decoder.write_dat_file(base, dat_size)
+        ec_decoder.write_dat_file(base, dat_size, data_shards=ev.k)
         ec_decoder.write_idx_file_from_ec_index(base)
-        self.store.unmount_ec_shards(vid, list(range(TOTAL_SHARDS)))
+        self.store.unmount_ec_shards(vid, list(range(ev.total)))
         self._fast_ec_sync(vid)  # decoded back to a plain volume
         for loc in self.store.locations:
             if os.path.dirname(base) == loc.directory:
@@ -1975,6 +1980,25 @@ class VolumeServer:
         except HttpError:
             return {}
 
+    def _mounted_ec_geometry(self, vid: int) -> tuple:
+        """(k, k + m) of a volume mounted here, for the location
+        cache's freshness tiers; the default for one that is not."""
+        ev = self.store.find_ec_volume(vid)
+        k, m = (ev.k, ev.m) if ev is not None \
+            else self.store.default_geometry
+        return k, k + m
+
+    def _ec_geometry(self, vid: int) -> tuple:
+        """(k, m) of an EC volume no shard of which is here: the
+        master's, from its holders' heartbeats; the default where the
+        master cannot say."""
+        try:
+            out = get_json(f"http://{self.master_url}/cluster/ec_lookup"
+                           f"?volumeId={vid}", timeout=10)
+            return (int(out["data_shards"]), int(out["parity_shards"]))
+        except (HttpError, KeyError, TypeError, ValueError):
+            return self.store.default_geometry
+
     def _ec_shard_locations(self, vid: int) -> Dict[int, List[str]]:
         """Cached with tiered freshness + invalidate-on-failure
         (reference store_ec.go:218-259); raw master hits only on expiry."""
@@ -2025,13 +2049,16 @@ class VolumeServer:
                                        size) -> bytes:
         """Per-read fallback. Still fixed relative to the original loop:
         fetches only the first-k survivors the decode plan needs (never
-        all TOTAL_SHARDS-1 siblings) and decodes only the lost shard's
+        all k+m-1 siblings) and decodes only the lost shard's
         row (codec.lost_row_coeffs) instead of regenerating the full
         stripe with codec.reconstruct."""
         from ..util.fanout import fan_out
         ev = self.store.find_ec_volume(vid)
         locations = self._ec_shard_locations(vid)
-        codec = self.store.codec or get_codec(DATA_SHARDS, 4)
+        # the volume's own geometry; a server that holds no shard of
+        # it asks the master, which has it from the holders' heartbeats
+        codec = self.store.ec_volume_codec(ev) if ev is not None \
+            else self.store.codec_for(*self._ec_geometry(vid))
 
         present = []
         for other in range(codec.total):
@@ -2042,7 +2069,7 @@ class VolumeServer:
             else:
                 present.append(any(h != self.url
                                    for h in locations.get(other, [])))
-        if sum(present) < DATA_SHARDS:
+        if sum(present) < codec.k:
             raise HttpError(
                 503, f"cannot reconstruct {vid}.{sid}: "
                      f"{sum(present)} shards")

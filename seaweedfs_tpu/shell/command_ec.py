@@ -10,7 +10,7 @@ from __future__ import annotations
 
 from typing import Dict, List
 
-from ..ec.constants import DATA_SHARDS, TOTAL_SHARDS
+from ..ec.constants import DATA_SHARDS, MAX_SHARDS, PARITY_SHARDS
 from ..server.http_util import HttpError
 from .command_env import CommandEnv, command, parse_flags
 
@@ -23,20 +23,32 @@ def _volume_replicas(env: CommandEnv, vid: int) -> List[dict]:
     return env.all_volumes().get(str(vid), [])
 
 
-def balanced_ec_distribution(nodes: List[dict]) -> List[str]:
-    """Assign 14 shards round-robin by free slots (reference
-    balancedEcDistribution command_ec_encode.go:237-253)."""
+def _geometry_of(info: dict) -> tuple:
+    """(k, m) of an EC volume as the master reports it (from its
+    holders' heartbeats); the default where it names none."""
+    return (int(info.get("data_shards") or DATA_SHARDS),
+            int(info.get("parity_shards") or PARITY_SHARDS))
+
+
+def balanced_ec_distribution(nodes: List[dict],
+                             geometry: tuple = (DATA_SHARDS,
+                                                PARITY_SHARDS)
+                             ) -> List[str]:
+    """Assign the k + m shards of one volume (14 by default) round-robin
+    by free slots (reference balancedEcDistribution
+    command_ec_encode.go:237-253)."""
     if not nodes:
         raise ValueError("no volume servers")
+    k, m = geometry
     # plain round-robin over servers that still have free EC slots (one
-    # volume slot = 10 shard slots)
+    # volume slot = k shard slots: 10 by default)
     picked: Dict[str, int] = {n["url"]: 0 for n in nodes}
-    free_slots = {n["url"]: max(n.get("free", 0), 0) * 10 for n in nodes}
+    free_slots = {n["url"]: max(n.get("free", 0), 0) * k for n in nodes}
     urls = [n["url"] for n in nodes]
     out: List[str] = []
     i = 0
     spins = 0
-    while len(out) < TOTAL_SHARDS:
+    while len(out) < k + m:
         url = urls[i % len(urls)]
         i += 1
         if free_slots[url] - picked[url] >= 1:
@@ -78,10 +90,13 @@ def collect_volume_ids_for_ec_encode(env: CommandEnv, collection: str,
 
 @command("ec.encode",
          "-volumeId <id> | -collection <name> [-fullPercent 0.95] "
-         "[-mode stream|copy] : erasure-code volumes and spread 14 "
-         "shards across the cluster (stream = push shard ranges to "
-         "holders while later slabs encode; copy = legacy "
-         "generate-then-pull)")
+         "[-mode stream|copy] [-geometry <data>,<parity>] : erasure-code "
+         "volumes and spread their shards across the cluster (geometry "
+         "= the RS code of the new EC volume, e.g. 6,3 for nine shards; "
+         "10,4 and 14 shards without the flag; it is stamped into the "
+         "volume's .vif and every later command reads it from there; "
+         "stream = push shard ranges to holders while later slabs "
+         "encode; copy = legacy generate-then-pull)")
 def ec_encode(env: CommandEnv, args: List[str]):
     flags = parse_flags(args)
     if "volumeId" in flags:
@@ -93,12 +108,17 @@ def ec_encode(env: CommandEnv, args: List[str]):
     else:
         env.write("usage: ec.encode -volumeId <id> | -collection <name>")
         return
+    geometry = None
+    if "geometry" in flags:
+        from ..ec.layout import parse_geometry
+        geometry = parse_geometry(flags["geometry"])
     for vid in vids:
-        do_ec_encode(env, vid, mode=flags.get("mode"))
+        do_ec_encode(env, vid, mode=flags.get("mode"), geometry=geometry)
 
 
 def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
-                 timings: Dict = None, rate_mbps: float = 0.0):
+                 timings: Dict = None, rate_mbps: float = 0.0,
+                 geometry: tuple = None):
     """Freeze -> encode+spread -> mount -> drop originals.
 
     mode: "stream" (default; `SW_EC_SPREAD_MODE` overrides) sends the
@@ -117,7 +137,9 @@ def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
     ``timings``, when given, records encode/spread busy seconds,
     ``overlap_frac``, and the spread counters for bench. ``rate_mbps``
     > 0 paces the streaming spread (the tierer's background cap);
-    copy mode ignores it."""
+    copy mode ignores it. ``geometry`` (k, m) is the new EC volume's RS
+    code, passed on to the source's ``/admin/ec/generate``; None leaves
+    the node at its default, 10 + 4."""
     from ..util import config as _config
     from ..util import tracing
     mode = (mode or _config.env_str("SW_EC_SPREAD_MODE") or
@@ -142,7 +164,8 @@ def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
                     r["url"], f"/admin/volume/readonly?volume={vid}")
                 if not (out or {}).get("was_readonly"):
                     froze.append(r["url"])
-        assignment = balanced_ec_distribution(_free_nodes(env))
+        assignment = balanced_ec_distribution(
+            _free_nodes(env), geometry or (DATA_SHARDS, PARITY_SHARDS))
         by_node: Dict[str, List[int]] = {}
         for sid, url in enumerate(assignment):
             by_node.setdefault(url, []).append(sid)
@@ -150,12 +173,13 @@ def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
             # 2+3. encode + spread + mount
             if mode == "copy":
                 _encode_spread_copy(env, vid, collection, source,
-                                    by_node, timings)
+                                    by_node, timings, geometry)
             else:
                 try:
                     _encode_spread_streaming(env, vid, collection,
                                              source, assignment,
-                                             timings, rate_mbps)
+                                             timings, rate_mbps,
+                                             geometry)
                 except HttpError as e:
                     env.write(f"volume {vid}: streaming encode failed "
                               f"({e.status}); falling back to copy mode")
@@ -163,7 +187,7 @@ def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
                     _cleanup_partial_encode(env, vid, collection,
                                             set(assignment) | {source})
                     _encode_spread_copy(env, vid, collection, source,
-                                        by_node, timings)
+                                        by_node, timings, geometry)
         except BaseException as e:
             _cleanup_partial_encode(env, vid, collection,
                                     set(assignment) | {source})
@@ -191,8 +215,9 @@ def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
 def _cleanup_partial_encode(env: CommandEnv, vid: int, collection: str,
                             nodes):
     """Best-effort removal of every shard file and ``.part`` stage a
-    failed encode may have left on any involved node."""
-    all_shards = ",".join(map(str, range(TOTAL_SHARDS)))
+    failed encode may have left on any involved node (of whatever
+    geometry: every shard id a volume can have)."""
+    all_shards = ",".join(map(str, range(MAX_SHARDS)))
     for url in nodes:
         try:
             env.node_post(url, f"/admin/ec/delete_shards?volume={vid}"
@@ -205,7 +230,8 @@ def _cleanup_partial_encode(env: CommandEnv, vid: int, collection: str,
 def _encode_spread_streaming(env: CommandEnv, vid: int, collection: str,
                              source: str, assignment: List[str],
                              timings: Dict = None,
-                             rate_mbps: float = 0.0):
+                             rate_mbps: float = 0.0,
+                             geometry: tuple = None):
     """One POST: the source encodes and pushes each shard's slab ranges
     to its assigned holder while later slabs encode. Afterwards only
     the KB-scale index sidecars (.ecx/.vif) are copied to remote
@@ -217,7 +243,7 @@ def _encode_spread_streaming(env: CommandEnv, vid: int, collection: str,
     t0 = _time.perf_counter()
     out = env.node_post(
         source, f"/admin/ec/generate?volume={vid}"
-                f"&collection={collection}",
+                f"&collection={collection}{_geometry_query(geometry)}",
         body={"assignment": {str(s): u
                              for s, u in enumerate(assignment)},
               "spares": spares,
@@ -270,10 +296,14 @@ def _encode_spread_streaming(env: CommandEnv, vid: int, collection: str,
         _merge_rebuild_stats(timings, out)
 
 
+def _geometry_query(geometry) -> str:
+    return f"&geometry={geometry[0]},{geometry[1]}" if geometry else ""
+
+
 def _encode_spread_copy(env: CommandEnv, vid: int, collection: str,
                         source: str, by_node: Dict[str, List[int]],
-                        timings: Dict = None):
-    """Legacy two-phase flow: generate all 14 shards on the source,
+                        timings: Dict = None, geometry: tuple = None):
+    """Legacy two-phase flow: generate all k + m shards on the source,
     then every target pulls + mounts its shards concurrently (reference
     parallelCopyEcShardsFromSource, command_ec_encode.go:200-235:
     goroutine per target server)."""
@@ -281,9 +311,11 @@ def _encode_spread_copy(env: CommandEnv, vid: int, collection: str,
     from ..util.fanout import fan_out_must_succeed
     t0 = _time.perf_counter()
     env.node_post(source, f"/admin/ec/generate?volume={vid}"
-                          f"&collection={collection}")
+                          f"&collection={collection}"
+                          f"{_geometry_query(geometry)}")
     t1 = _time.perf_counter()
-    env.write(f"volume {vid}: generated {TOTAL_SHARDS} shards on "
+    total = sum(len(held) for held in by_node.values())
+    env.write(f"volume {vid}: generated {total} shards on "
               f"{source}")
 
     def spread(target):
@@ -305,7 +337,7 @@ def _encode_spread_copy(env: CommandEnv, vid: int, collection: str,
         env.write(f"volume {vid}: shards {s} -> {url}")
     # 4. delete source's unassigned shard files
     source_keeps = set(by_node.get(source, []))
-    extra = [s for s in range(TOTAL_SHARDS) if s not in source_keeps]
+    extra = [s for s in range(total) if s not in source_keeps]
     if extra:
         env.node_post(source, f"/admin/ec/delete_shards?volume={vid}"
                               f"&collection={collection}"
@@ -338,10 +370,11 @@ def ec_rebuild(env: CommandEnv, args: List[str]):
         if "collection" in flags and collection != flags["collection"]:
             continue
         shards = {int(s): urls for s, urls in info["shards"].items()}
-        missing = [s for s in range(TOTAL_SHARDS) if s not in shards]
+        k, m = _geometry_of(info)
+        missing = [s for s in range(k + m) if s not in shards]
         if not missing:
             continue
-        if len(shards) < DATA_SHARDS:
+        if len(shards) < k:
             env.write(f"volume {vid}: only {len(shards)} shards left, "
                       f"cannot rebuild")
             continue
@@ -364,6 +397,8 @@ def _merge_rebuild_stats(timings: Dict, out: dict):
             agg = timings.setdefault(key, {})
             for holder, n in val.items():
                 agg[holder] = agg.get(holder, 0) + n
+        elif key in ("k", "m", "shards"):
+            timings[key] = val      # the volume's geometry: no sum
         elif isinstance(val, (int, float)):
             timings[key] = timings.get(key, 0) + val
         else:
@@ -556,8 +591,9 @@ def ec_decode(env: CommandEnv, args: List[str]):
         if "collection" in flags and collection != flags["collection"]:
             continue
         shards = {int(s): urls for s, urls in info["shards"].items()}
-        data_shards = {s: u for s, u in shards.items() if s < DATA_SHARDS}
-        if len(data_shards) < DATA_SHARDS:
+        k, m = _geometry_of(info)
+        data_shards = {s: u for s, u in shards.items() if s < k}
+        if len(data_shards) < k:
             env.write(f"volume {vid}: missing data shards; run ec.rebuild "
                       f"first")
             continue
@@ -578,11 +614,11 @@ def ec_decode(env: CommandEnv, args: List[str]):
         env.node_post(target, f"/admin/ec/mount?volume={vid}"
                               f"&collection={collection}"
                               f"&shards="
-                              f"{','.join(str(s) for s in range(DATA_SHARDS))}")
+                              f"{','.join(str(s) for s in range(k))}")
         env.node_post(target, f"/admin/ec/to_volume?volume={vid}"
                               f"&collection={collection}")
         # remove EC shards cluster-wide
-        all_shards = ",".join(map(str, range(TOTAL_SHARDS)))
+        all_shards = ",".join(map(str, range(k + m)))
         holders = {u for urls in shards.values() for u in urls} | {target}
         for u in holders:
             env.node_post(u, f"/admin/ec/delete_shards?volume={vid}"

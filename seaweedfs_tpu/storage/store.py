@@ -13,7 +13,8 @@ from ..util.locks import make_rlock
 from typing import Dict, List, Optional
 
 from ..ec import encoder as ec_encoder
-from ..ec.constants import DATA_SHARDS, TOTAL_SHARDS, to_ext
+from ..ec.constants import (DATA_SHARDS, MAX_SHARDS, PARITY_SHARDS,
+                            to_ext)
 from ..ec.ec_volume import EcVolume, ec_offset_width, rebuild_ecx_file
 from ..ops.codec import ReedSolomonCodec
 from .disk_location import DiskLocation
@@ -27,7 +28,7 @@ class Store:
                  ip: str = "127.0.0.1", port: int = 8080,
                  public_url: str = "", data_center: str = "",
                  rack: str = "", codec: Optional[ReedSolomonCodec] = None,
-                 index_kind: str = "memory"):
+                 index_kind: str = "memory", ec_backend: str = "auto"):
         if isinstance(directories, str):
             directories = [directories]
         max_volume_counts = max_volume_counts or [7] * len(directories)
@@ -38,7 +39,18 @@ class Store:
         self.public_url = public_url or f"{ip}:{port}"
         self.data_center = data_center
         self.rack = rack
-        self.codec = codec
+        # one codec a geometry, built on first use on the configured
+        # backend: a volume's (k, m) is its own (.vif), and volumes of
+        # several geometries live here at once. A codec handed in is
+        # its geometry's, and names the geometry of a volume whose
+        # sidecars name none (10 + 4 unless a caller brought another)
+        self.ec_backend = ec_backend
+        self.default_geometry = (codec.k, codec.m) if codec is not None \
+            else (DATA_SHARDS, PARITY_SHARDS)
+        self._codecs: Dict[tuple, ReedSolomonCodec] = {}
+        self._codec_like = codec
+        if codec is not None:
+            self._codecs[self.default_geometry] = codec
         # fired after any volume create/delete or EC shard mount/unmount
         # (reference store.go:40-64 NewVolumesChan/DeletedVolumesChan/
         # NewEcShardsChan/DeletedEcShardsChan): lets the volume server
@@ -53,6 +65,46 @@ class Store:
         for loc in self.locations:
             loc.load_existing_volumes()
             loc.load_all_ec_shards()
+
+    # -- codecs ------------------------------------------------------------
+    def codec_for(self, k: int, m: int) -> ReedSolomonCodec:
+        """The codec of one geometry, built the first time a volume of
+        it is touched: the configured backend, or the class and matrix
+        kind of the codec this store was handed."""
+        key = (int(k), int(m))
+        with self.lock:
+            codec = self._codecs.get(key)
+            if codec is None:
+                like = self._codec_like
+                if like is not None:
+                    codec = type(like)(*key, like.matrix_kind)
+                else:
+                    from ..ops.codec import get_codec
+                    codec = get_codec(*key, backend=self.ec_backend)
+                self._codecs[key] = codec
+            return codec
+
+    @property
+    def codec(self) -> ReedSolomonCodec:
+        """The default geometry's codec (volumes whose .vif names no
+        geometry, and callers that ask for no volume in particular)."""
+        return self.codec_for(*self.default_geometry)
+
+    def volume_geometry(self, base: str) -> tuple:
+        """(k, m) of the EC volume at `base`, from its .vif."""
+        from ..ec import layout as ec_layout
+        return ec_layout.volume_geometry(base, self.default_geometry)
+
+    def volume_codec(self, base: str) -> ReedSolomonCodec:
+        return self.codec_for(*self.volume_geometry(base))
+
+    def ec_volume_codec(self, ev: Optional[EcVolume]
+                        ) -> ReedSolomonCodec:
+        """What the degraded-read and scrub engines ask with: the codec
+        of a mounted volume's own geometry (no volume: the default)."""
+        if ev is None:
+            return self.codec
+        return self.codec_for(ev.k, ev.m)
 
     # -- lookup ------------------------------------------------------------
     def find_volume(self, vid: int) -> Optional[Volume]:
@@ -70,12 +122,14 @@ class Store:
         return None
 
     def find_free_location(self) -> Optional[DiskLocation]:
-        """Location with a free slot; EC shards count as 1/10 volume
-        (reference store.go:99-112)."""
+        """Location with a free slot; an EC shard counts as 1/k of a
+        volume, k the volume's own (1/10 by default; reference
+        store.go:99-112)."""
         best, best_free = None, 0.0
         for loc in self.locations:
-            ec_shards = sum(len(ev.shards) for ev in loc.ec_volumes.values())
-            free = loc.max_volume_count - len(loc.volumes) - ec_shards / 10.0
+            ec_slots = sum(len(ev.shards) / ev.k
+                           for ev in loc.ec_volumes.values())
+            free = loc.max_volume_count - len(loc.volumes) - ec_slots
             if free >= 1 and free > best_free:
                 best, best_free = loc, free
         return best
@@ -144,13 +198,14 @@ class Store:
         return v.delete_needle(n)
 
     # -- EC lifecycle (reference volume_grpc_erasure_coding.go) ------------
-    def _encode_layout(self):
-        """(layout name, plan, window) for NEW ec volumes, from
-        SW_EC_LAYOUT. Unsupported geometries (m < 2) raise rather than
-        silently downgrading an operator's explicit piggyback choice."""
+    def _encode_layout(self, codec: ReedSolomonCodec):
+        """(layout name, plan, window) for a NEW ec volume of `codec`'s
+        geometry, from SW_EC_LAYOUT. A geometry the piggyback
+        construction does not cover (m < 2) raises, by name and before
+        anything is written, rather than silently downgrading an
+        operator's explicit piggyback choice."""
         from ..ec import layout as ec_layout
-        from ..ec.constants import (LARGE_BLOCK_SIZE, PARITY_SHARDS,
-                                    SMALL_BLOCK_SIZE)
+        from ..ec.constants import LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE
         from ..ops import codec as ops_codec
         from ..util import config as _config
         name = (_config.env_str("SW_EC_LAYOUT") or
@@ -159,16 +214,24 @@ class Store:
             return ec_layout.LAYOUT_FLAT, None, None
         if name != ec_layout.LAYOUT_PIGGYBACK:
             raise VolumeError(f"unknown SW_EC_LAYOUT {name!r}")
-        k = self.codec.k if self.codec is not None else DATA_SHARDS
-        m = (self.codec.m if self.codec is not None else PARITY_SHARDS)
-        if not ops_codec.piggyback_supported(k, m):
+        if not ops_codec.piggyback_supported(codec.k, codec.m):
             raise VolumeError(
-                f"SW_EC_LAYOUT=piggyback unsupported for RS({k},{m})")
-        from ..ops.codec import get_codec
-        codec = self.codec or get_codec(k, m)
+                f"SW_EC_LAYOUT=piggyback unsupported for "
+                f"RS({codec.k},{codec.m})")
         pplan, window = ec_encoder.piggyback_geometry(
             codec, None, LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE)
         return ec_layout.LAYOUT_PIGGYBACK, pplan, window
+
+    def _encode_codec(self, geometry) -> ReedSolomonCodec:
+        """The codec a new EC volume is coded with: the geometry
+        `ec.encode -geometry k,m` named, else the default."""
+        if not geometry:
+            return self.codec
+        from ..ec import layout as ec_layout
+        try:
+            return self.codec_for(*ec_layout.parse_geometry(geometry))
+        except ValueError as e:
+            raise VolumeError(str(e)) from None
 
     def _volume_layout(self, base):
         """Resolve an existing volume's on-disk layout from its
@@ -176,7 +239,7 @@ class Store:
         every layout-sensitive path below."""
         from ..ec import layout as ec_layout
         from .types import entry_size
-        k = self.codec.k if self.codec is not None else DATA_SHARDS
+        k = self.volume_geometry(base)[0]
         try:
             width = ec_offset_width(base)
         except Exception:  # noqa: BLE001 - no sidecars at all: flat
@@ -184,11 +247,13 @@ class Store:
         return ec_layout.volume_layout(base, k,
                                        record_size=entry_size(width))
 
-    def _write_layout_sidecars(self, base, v, layout, pplan, window):
-        """Record the volume metadata AND layout in one .vif/.ecx-tag
-        write (ec/layout). offset_width must ride along: a shard
-        receiver holding only parity shards has no .ec00 superblock to
-        infer the .ecx record width from."""
+    def _write_layout_sidecars(self, base, v, layout, pplan, window,
+                               codec):
+        """Record the volume metadata, its RS geometry AND layout in
+        one .vif/.ecx-tag write (ec/layout). offset_width must ride
+        along: a shard receiver holding only parity shards has no .ec00
+        superblock to infer the .ecx record width from; the geometry
+        travels with the .vif wherever the index files are copied."""
         from ..ec import layout as ec_layout
         from .types import entry_size
         ec_layout.write_layout_sidecars(
@@ -196,10 +261,14 @@ class Store:
             window=window,
             pairs=(pplan.npairs if pplan is not None else None),
             record_size=entry_size(v.offset_width),
-            version=v.version, offset_width=v.offset_width)
+            version=v.version, offset_width=v.offset_width,
+            ec_data_shards=codec.k, ec_parity_shards=codec.m)
 
-    def generate_ec_shards(self, vid: int, collection: str = "") -> str:
-        """Volume .dat/.idx -> .ec00-13 + .ecx + .vif on the same disk.
+    def generate_ec_shards(self, vid: int, collection: str = "",
+                           geometry=None) -> str:
+        """Volume .dat/.idx -> .ec00-13 + .ecx + .vif on the same disk
+        (k + m shard files of the `geometry` asked for, 10 + 4 by
+        default).
         SW_EC_LAYOUT picks the parity layout for the new shards; the
         choice is stamped into the sidecars so every later reader
         (scrub, degraded reads, rebuild) routes by the volume, not the
@@ -210,13 +279,14 @@ class Store:
         if not v.readonly:
             raise VolumeError(f"volume {vid} must be readonly for ec encode")
         base = v.file_name()
-        layout, pplan, window = self._encode_layout()
+        codec = self._encode_codec(geometry)
+        layout, pplan, window = self._encode_layout(codec)
         from ..util import tracing
-        with tracing.span("ec.encode.local", volume=vid, layout=layout):
+        with tracing.span("ec.encode.local", volume=vid, layout=layout,
+                          k=codec.k, m=codec.m):
             ec_encoder.write_sorted_file_from_idx(base)
-            ec_encoder.write_ec_files(base, codec=self.codec,
-                                      layout=layout)
-        self._write_layout_sidecars(base, v, layout, pplan, window)
+            ec_encoder.write_ec_files(base, codec=codec, layout=layout)
+        self._write_layout_sidecars(base, v, layout, pplan, window, codec)
         return base
 
     def generate_ec_shards_streaming(self, vid: int, collection: str = "",
@@ -224,7 +294,8 @@ class Store:
                                      spares: List[str] = None,
                                      window: Optional[int] = None,
                                      stats: dict = None,
-                                     rate_mbps: float = 0.0):
+                                     rate_mbps: float = 0.0,
+                                     geometry=None):
         """Streaming encode+spread: encode the readonly volume and push
         each shard's slab ranges to its assigned holder while later
         slabs are still encoding (ec/spread.py). ``assignment`` maps
@@ -249,17 +320,18 @@ class Store:
         base = v.file_name()
         assignment = {int(s): u for s, u in (assignment or {}).items()}
         sstats = spread.SpreadStats()
-        total = self.codec.total if self.codec is not None else TOTAL_SHARDS
+        codec = self._encode_codec(geometry)
+        total = codec.total
         # same slab policy as the streaming gather: shrink the stripe
         # so even a near-slab-sized shard gives the spread several
         # stripes to overlap with the encode (slab only batches device
         # columns — shard bytes are invariant under it)
         from ..ec.gather import auto_slab
         slab = auto_slab(ec_encoder.ec_shard_base_size(
-            os.path.getsize(base + ".dat")))
-        layout, pplan, pb_window = self._encode_layout()
+            os.path.getsize(base + ".dat"), data_shards=codec.k))
+        layout, pplan, pb_window = self._encode_layout(codec)
         with tracing.span("ec.encode.stream", volume=vid,
-                          layout=layout) as root:
+                          layout=layout, k=codec.k, m=codec.m) as root:
             ec_encoder.write_sorted_file_from_idx(base)
             sink = spread.StripedSpreadSink(
                 vid, base, assignment, total, collection=collection,
@@ -268,7 +340,7 @@ class Store:
                 rate_mbps=rate_mbps)
             try:
                 ec_encoder.write_ec_files_spread(
-                    base, sink, codec=self.codec, slab=slab, stats=stats,
+                    base, sink, codec=codec, slab=slab, stats=stats,
                     layout=layout)
             except BaseException:
                 # the sink already aborted every holder's stage; drop
@@ -284,7 +356,8 @@ class Store:
                 except OSError:
                     pass
                 raise
-            self._write_layout_sidecars(base, v, layout, pplan, pb_window)
+            self._write_layout_sidecars(base, v, layout, pplan, pb_window,
+                                        codec)
         observe_transport("push", sstats, window=sink.window)
         return base, sink.assignment()
 
@@ -347,10 +420,11 @@ class Store:
             base = volume_file_prefix(loc.directory, collection, vid)
             if os.path.exists(base + ".ecx"):
                 li = self._volume_layout(base)
+                codec = self.volume_codec(base)
                 with tracing.span("ec.rebuild.local", volume=vid,
-                                  layout=li.layout):
+                                  layout=li.layout, k=codec.k, m=codec.m):
                     rebuilt = ec_encoder.rebuild_ec_files(
-                        base, codec=self.codec, stats=stats,
+                        base, codec=codec, stats=stats,
                         layout=(li if li.piggyback else None))
                     from ..ec.decoder import read_ec_volume_superblock
                     t0 = _time.perf_counter()
@@ -415,18 +489,20 @@ class Store:
             base = volume_file_prefix(cand.directory, collection, vid)
             if os.path.exists(base + ".ecx") or any(
                     os.path.exists(base + to_ext(i))
-                    for i in range(TOTAL_SHARDS)):
+                    for i in range(MAX_SHARDS)):
                 loc = cand
                 break
         if loc is None:
             loc = self.find_free_location() or self.locations[0]
         base = volume_file_prefix(loc.directory, collection, vid)
-        k = self.codec.k if self.codec is not None else DATA_SHARDS
-        total = self.codec.total if self.codec is not None \
-            else TOTAL_SHARDS
         with tracing.span("ec.rebuild.stream", volume=vid) as root:
             if holders:
                 gather.fetch_index_files(base, holders)
+            # the .vif is local now (fetched above when remote): the
+            # volume's own geometry, and the codec of that geometry
+            codec = self.volume_codec(base)
+            k, total = codec.k, codec.total
+            root.tags["k"], root.tags["m"] = codec.k, codec.m
             local = [os.path.exists(base + to_ext(i))
                      for i in range(total)]
             present = [local[i] or i in sources for i in range(total)]
@@ -485,12 +561,12 @@ class Store:
                     rebuilt = self._rebuild_streaming_piggyback(
                         vid, base, local, present, missing, sources,
                         sized, stats, slab, window, hedge_ms, root,
-                        mode, li)
+                        mode, li, codec)
                 else:
                     rebuilt = self._rebuild_streaming_trace(
                         vid, base, local, present, missing, sources,
                         sized, stats, slab, window, hedge_ms, root,
-                        mode)
+                        mode, codec)
             full = rebuilt is None
             if full and li.piggyback:
                 # full coupled decode: the body plans, then asks for
@@ -513,7 +589,7 @@ class Store:
 
                 rebuilt = ec_encoder.rebuild_ec_files_piggyback(
                     base, present, missing, li, coupled_source,
-                    codec=self.codec, stats=stats)
+                    codec=codec, stats=stats)
                 from ..stats.metrics import observe_transport
                 observe_transport("pull", gstats,
                                   window=window or gather.gather_window())
@@ -541,7 +617,7 @@ class Store:
                     window=window, stats=gstats, parent_span=root)
                 rebuilt = ec_encoder.rebuild_ec_files_streaming(
                     base, gather_present, missing, source,
-                    codec=self.codec, slab=eff_slab, stats=stats)
+                    codec=codec, slab=eff_slab, stats=stats)
                 from ..stats.metrics import observe_transport
                 observe_transport("pull", gstats, window=source.window)
                 if stats is not None:
@@ -595,7 +671,7 @@ class Store:
     def _rebuild_streaming_piggyback(self, vid, base, local, present,
                                      missing, sources, sized, stats,
                                      slab, window, hedge_ms, root, mode,
-                                     li):
+                                     li, codec=None):
         """Attempt the half-plane piggyback repair; returns the rebuilt
         shard list or None to signal 'use the full coupled decode
         instead'. A loss the route was never meant for (more than one
@@ -624,19 +700,13 @@ class Store:
             return refuse(
                 f"{len(missing)} shards lost, piggyback repairs one")
         lost = missing[0]
-        k = self.codec.k if self.codec is not None else DATA_SHARDS
-        m = (self.codec.m if self.codec is not None
-             else TOTAL_SHARDS - DATA_SHARDS)
+        codec = codec or self.codec
+        k, m = codec.k, codec.m
         with tracing.Stage("ec.rebuild.plan", root) as planning:
             try:
                 pplan = ops_codec.piggyback_plan(
-                    k, m,
-                    matrix_kind=(self.codec.matrix_kind
-                                 if self.codec is not None
-                                 else "vandermonde"),
-                    matrix=(self.codec.matrix
-                            if self.codec is not None else None),
-                    pairs=li.pairs)
+                    k, m, matrix_kind=codec.matrix_kind,
+                    matrix=codec.matrix, pairs=li.pairs)
             except ValueError as e:
                 return bail(f"no piggyback scheme: {e}")
         if lost >= pplan.coupled:
@@ -652,9 +722,7 @@ class Store:
             rplan = ops_codec.piggyback_repair_plan(
                 k, m, lost, parity_sids=tuple(par[:2]),
                 matrix_kind=pplan.matrix_kind,
-                matrix=(self.codec.matrix
-                        if self.codec is not None else None),
-                pairs=li.pairs)
+                matrix=codec.matrix, pairs=li.pairs)
         except ValueError as e:
             return bail(f"no repair plan: {e}")
         shard_size = sized(rplan.helpers)
@@ -682,7 +750,7 @@ class Store:
         rstats: dict = {}
         try:
             rebuilt = ec_decoder.rebuild_ec_file_piggyback(
-                base, lost, source, rplan, li.window, codec=self.codec,
+                base, lost, source, rplan, li.window, codec=codec,
                 slab=source.slab, stats=rstats)
         except HttpError as e:
             if e.status in (404, 405, 501):
@@ -700,7 +768,8 @@ class Store:
 
     def _rebuild_streaming_trace(self, vid, base, local, present,
                                  missing, sources, sized, stats, slab,
-                                 window, hedge_ms, root, mode):
+                                 window, hedge_ms, root, mode,
+                                 codec=None):
         """Attempt the trace-repair path; returns the rebuilt shard list
         or None to signal 'use the full streaming gather instead'. More
         than one lost shard was never this route's: the full gather is
@@ -727,9 +796,8 @@ class Store:
         if len(missing) != 1:
             return refuse(f"{len(missing)} shards lost, trace repairs one")
         lost = missing[0]
-        k = self.codec.k if self.codec is not None else DATA_SHARDS
-        m = (self.codec.m if self.codec is not None
-             else TOTAL_SHARDS - DATA_SHARDS)
+        codec = codec or self.codec
+        k, m = codec.k, codec.m
         helpers = [i for i, p in enumerate(present) if p and i != lost]
         # a scheme search of ~0.5 s the first time a (lost, helpers)
         # pair is seen, a cache hit after: a stage of the stream
@@ -737,11 +805,7 @@ class Store:
             try:
                 plan = ops_codec.repair_plan(
                     k, m, lost, survivors=helpers,
-                    matrix_kind=(self.codec.matrix_kind
-                                 if self.codec is not None
-                                 else "vandermonde"),
-                    matrix=(self.codec.matrix
-                            if self.codec is not None else None))
+                    matrix_kind=codec.matrix_kind, matrix=codec.matrix)
             except ValueError as e:
                 return bail(f"no repair scheme: {e}")
         if mode == "auto" and plan.frac >= 1.0:
@@ -765,7 +829,7 @@ class Store:
         rstats: dict = {}
         try:
             rebuilt = ec_decoder.rebuild_ec_file_repair(
-                base, lost, source, plan, codec=self.codec,
+                base, lost, source, plan, codec=codec,
                 slab=eff_slab, stats=rstats)
         except HttpError as e:
             if e.status in (404, 405, 501):
@@ -786,6 +850,7 @@ class Store:
         volumes = []
         ec_shards: Dict[int, int] = {}
         ec_collections: Dict[int, str] = {}
+        ec_geometries: Dict[int, List[int]] = {}
         max_file_key = 0
         max_volume_count = 0
         for loc in self.locations:
@@ -813,6 +878,7 @@ class Store:
                     bits |= 1 << sid
                 ec_shards[vid] = bits
                 ec_collections[vid] = ev.collection
+                ec_geometries[vid] = [ev.k, ev.m]
         return {
             "ip": self.ip, "port": self.port, "public_url": self.public_url,
             "data_center": self.data_center, "rack": self.rack,
@@ -821,6 +887,9 @@ class Store:
             "volumes": volumes,
             "ec_shards": ec_shards,
             "ec_collections": ec_collections,
+            # each EC volume's own RS geometry [k, m] (its .vif): the
+            # master knows a volume whole at k + m shards
+            "ec_geometries": ec_geometries,
         }
 
     def status(self) -> dict:

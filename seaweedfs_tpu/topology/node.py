@@ -11,7 +11,7 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional
 
-from ..ec.constants import DATA_SHARDS
+from ..ec.constants import DATA_SHARDS, PARITY_SHARDS
 from ..ec.shard_bits import ShardBits
 
 
@@ -68,6 +68,9 @@ class DataNode:
         self.volumes: Dict[int, VolumeInfo] = {}
         self.ec_shards: Dict[int, ShardBits] = {}  # vid -> bits
         self.ec_shard_collections: Dict[int, str] = {}
+        # vid -> (k, m) as the holder's heartbeat names it; a volume
+        # it names none for is the default geometry's
+        self.ec_shard_geometries: Dict[int, tuple] = {}
         self.last_seen = time.time()
         self.rack: Optional["Rack"] = None
 
@@ -88,7 +91,12 @@ class DataNode:
         """Free volume slots, EC shards counted fractionally
         (reference store.go:99-112 FindFreeLocation)."""
         return self.max_volume_count - len(self.volumes) \
-            - self.ec_shard_count() / DATA_SHARDS
+            - sum(bits.shard_id_count() / self.ec_geometry(vid)[0]
+                  for vid, bits in self.ec_shards.items())
+
+    def ec_geometry(self, vid: int) -> tuple:
+        return self.ec_shard_geometries.get(
+            vid, (DATA_SHARDS, PARITY_SHARDS))
 
     def update_volumes(self, infos: List[VolumeInfo]) -> None:
         self.volumes = {vi.id: vi for vi in infos}
@@ -102,10 +110,14 @@ class DataNode:
         self.volumes.pop(vid, None)
 
     def update_ec_shards(self, shards: Dict[int, int],
-                         collections: Dict[int, str]) -> None:
+                         collections: Dict[int, str],
+                         geometries: Dict[int, tuple] = None) -> None:
         self.ec_shards = {vid: ShardBits(bits)
                           for vid, bits in shards.items() if bits}
         self.ec_shard_collections = dict(collections)
+        self.ec_shard_geometries = {
+            vid: (int(g[0]), int(g[1]))
+            for vid, g in (geometries or {}).items()}
 
     def to_dict(self) -> dict:
         rack = self.rack

@@ -261,6 +261,8 @@ class Topology:
         # vid -> shard_id -> [DataNode] (reference topology_ec.go ecShardMap)
         self.ec_shard_map: Dict[int, List[List[DataNode]]] = {}
         self.ec_collections: Dict[int, str] = {}
+        # vid -> (k, m), from the holders' heartbeats (ec_geometries)
+        self.ec_geometries: Dict[int, Tuple[int, int]] = {}
         self.max_volume_id = 0
         # optional ("new"|"deleted", vid, url, public_url) callback — the
         # master wires its watch hub here to push location deltas
@@ -306,7 +308,9 @@ class Topology:
                            ec_shards: Dict[int, int] = None,
                            ec_collections: Dict[int, str] = None,
                            max_file_key: int = 0,
-                           fast_url: str = "") -> DataNode:
+                           fast_url: str = "",
+                           ec_geometries: Dict[int, tuple] = None
+                           ) -> DataNode:
         with self.lock:
             dc = self.get_or_create_dc(dc_id or "DefaultDataCenter")
             rack = dc.get_or_create_rack(rack_id or "DefaultRack")
@@ -341,7 +345,8 @@ class Topology:
                                            node.fast_url)
 
             if ec_shards is not None:
-                node.update_ec_shards(ec_shards, ec_collections or {})
+                node.update_ec_shards(ec_shards, ec_collections or {},
+                                      ec_geometries)
                 self._sync_ec_shards(node)
             return node
 
@@ -349,7 +354,9 @@ class Topology:
                               deleted_volumes: List[int],
                               ec_shards: Dict[int, int] = None,
                               ec_collections: Dict[int, str] = None,
-                              max_file_key: int = 0) -> bool:
+                              max_file_key: int = 0,
+                              ec_geometries: Dict[int, tuple] = None
+                              ) -> bool:
         """Incremental registration (reference master_grpc_server.go
         IncrementalHeartbeat path). Returns False when the node is
         unknown — the caller must then request a full resync."""
@@ -383,7 +390,8 @@ class Topology:
                                            node.public_url,
                                            node.fast_url)
             if ec_shards is not None:
-                node.update_ec_shards(ec_shards, ec_collections or {})
+                node.update_ec_shards(ec_shards, ec_collections or {},
+                                      ec_geometries)
                 self._sync_ec_shards(node)
             return True
 
@@ -394,10 +402,18 @@ class Topology:
                 if node in holders:
                     holders.remove(node)
         self._drop_empty_ec_volumes()
-        from ..ec.constants import TOTAL_SHARDS
         for vid, bits in node.ec_shards.items():
-            per_shard = self.ec_shard_map.setdefault(
-                vid, [[] for _ in range(TOTAL_SHARDS)])
+            # a holder that names the volume's geometry wins over one
+            # that names none (an older volume server)
+            if vid in node.ec_shard_geometries or \
+                    vid not in self.ec_geometries:
+                self.ec_geometries[vid] = node.ec_geometry(vid)
+            k, m = self.ec_geometries[vid]
+            per_shard = self.ec_shard_map.setdefault(vid, [])
+            # one holder list a shard of the volume's own k + m (and of
+            # any id a holder reports beyond it)
+            while len(per_shard) < max(k + m, bits.bit_length()):
+                per_shard.append([])
             self.ec_collections[vid] = \
                 node.ec_shard_collections.get(vid, "")
             self.max_volume_id = max(self.max_volume_id, vid)
@@ -410,6 +426,7 @@ class Topology:
                     if not any(per_shard)]:
             del self.ec_shard_map[vid]
             self.ec_collections.pop(vid, None)
+            self.ec_geometries.pop(vid, None)
 
     def unregister_node(self, node: DataNode):
         """Heartbeat stream broke: drop the node and its volumes."""
@@ -478,6 +495,12 @@ class Topology:
                         nodes.append(n)
             return nodes or None
         return None
+
+    def ec_geometry(self, vid: int) -> Tuple[int, int]:
+        """(k, m) of an EC volume as its holders report it; the default
+        10 + 4 for one no heartbeat has named a geometry for."""
+        from ..ec.constants import DATA_SHARDS, PARITY_SHARDS
+        return self.ec_geometries.get(vid, (DATA_SHARDS, PARITY_SHARDS))
 
     def lookup_ec_shards(self, vid: int) -> Optional[dict]:
         with self.lock:

@@ -95,7 +95,7 @@ def _engine(tmp_path, codec, lost=3, keep=None, slab=4096, batch_ms=0.0,
     ev = _FakeEv({i: _FakeShard(paths[i]) for i in survivors})
     eng = DegradedReadEngine(
         store=_FakeStore(ev), locations=lambda vid: {},
-        codec=lambda: codec, slab=slab, batch_ms=batch_ms,
+        codec=lambda ev: codec, slab=slab, batch_ms=batch_ms,
         cache_bytes=cache_bytes)
     return eng, shards, lost
 

@@ -1245,7 +1245,8 @@ PLANE_ABI = (
     "swhp_served", "swhp_redirected", "swhp_written",
     "swhp_stats_len", "swhp_stats", "swhp_lat_bounds",
     "swhp_set_stats_enabled", "swhp_set_slow_us", "swhp_slow_ring",
-    "swhp_ec_register", "swhp_ec_set_shard", "swhp_ec_put_bulk",
+    "swhp_ec_register", "swhp_ec_set_data_shards", "swhp_ec_set_shard",
+    "swhp_ec_put_bulk",
     "swhp_ec_delete", "swhp_ec_unregister",
     "swhp_cache_configure", "swhp_cache_put", "swhp_cache_invalidate",
     "swhp_cache_stats_len", "swhp_cache_stats",

@@ -126,7 +126,7 @@ def _engine(tmp_path, codec, slab=8192, w=40_000, local=None,
              base_name=str(tmp_path / "1"))
     eng = ScrubEngine(
         store=_Store(ev), locations=locations or (lambda vid: {}),
-        codec=lambda: codec, self_url=lambda: "me:8080",
+        codec=lambda ev: codec, self_url=lambda: "me:8080",
         on_finding=on_finding, rate_mbps=rate_mbps, idle_s=0,
         slab=slab)
     return eng, ev, paths
@@ -247,7 +247,7 @@ def test_scrub_env_knobs(monkeypatch):
     assert scrub_slab_bytes() == 4096            # floored
     # idle_s <= 0 means start() must not spawn the loop thread
     eng = ScrubEngine(store=None, locations=lambda v: {},
-                      codec=lambda: None, self_url=lambda: "",
+                      codec=lambda ev: None, self_url=lambda: "",
                       idle_s=0)
     eng.start()
     assert eng._thread is None

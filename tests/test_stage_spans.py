@@ -238,7 +238,7 @@ def test_counters_at_the_stage_boundaries(cycle):
     # transfers and survivor fetches are not doubled in telemetry: the
     # spans carry the bytes the transport's own stats already count
     assert set(enc) - {"mesh_device_bytes", "dispatch_width_devices",
-                       "device_byte_share"} == {
+                       "device_byte_share", "geometry_dispatches"} == {
         "dispatches", "bitmat_uploads", "host_fallbacks", "device_bytes",
         "mesh_dispatches", "read_bytes", "read_busy_us", "read_cpu_us",
         "repair_fallbacks", "coupled_decodes", "slab_fresh_bytes"}

@@ -126,6 +126,28 @@ def test_fused_coupled_decode_operands_compile(one_chip, lost):
     _compile_fused(one_chip, k, r, DEFAULT_SLAB // 32)
 
 
+@pytest.mark.parametrize("lost", [[0, 3, 6], [1, 4, 7], [2, 5, 8]],
+                         ids=["holder-A", "holder-B", "holder-C"])
+def test_fused_rs6_3_holder_decode_operand_compiles(one_chip, lost):
+    """A lost holder of an RS(6,3) volume on three servers (two data
+    shards and a parity shard): a dense (3, 6) block of the inverse,
+    lifted to the (24, 48) bit operand the encode of that geometry runs
+    too, against (6, 8 MiB) stripes: 48 MiB a dispatch where RS(10,4)
+    sends 80."""
+    import numpy as np
+    from seaweedfs_tpu.ec.encoder import DEFAULT_SLAB
+    from seaweedfs_tpu.ops.codec import NumpyCodec
+    from seaweedfs_tpu.ops.rs_pallas import fuse_bitmat
+    src, missing, coeffs = NumpyCodec(6, 3).decode_plan(
+        tuple(i not in lost for i in range(9)))
+    assert missing == lost and len(src) == 6
+    assert coeffs.shape == (3, 6) and np.all(coeffs != 0)   # dense
+    assert fuse_bitmat(coeffs).shape == (24, 48)
+    mem = _compile_fused(one_chip, 6, 3, DEFAULT_SLAB).memory_analysis()
+    assert mem.argument_size_in_bytes >= 6 * DEFAULT_SLAB
+    assert mem.temp_size_in_bytes < DEFAULT_SLAB
+
+
 @pytest.mark.parametrize("rows_in,rows_out,n", [
     (10, 4, 8 * MIB), (10, 4, 32 * MIB), (320, 128, MIB)],
     ids=["slab-8MiB", "chunk-32MiB", "piggyback-1MiB"])
