@@ -321,7 +321,7 @@ def write_ec_files(base_name: str, codec: Optional[ReedSolomonCodec] = None,
         [open(base_name + to_ext(i), "wb") for i in range(k + m)]
     # device-parallel compute feeding holder-parallel network: with a
     # piecewise-draining codec (mesh) and a sink, each device shard's
-    # parity piece is routed to the per-target send queues the moment
+    # parity piece is routed to the lanes' send queues the moment
     # its d2h lands — the host never stages the full (m, slab) output.
     # The piggyback transform is window-interleaved, so its parity must
     # merge whole slabs: no pieces.
@@ -463,7 +463,7 @@ def write_ec_files_spread(base_name: str, sink,
                            _phases_from_timer(timer, pipelined).items()}
         # encode busy = stream wall minus the time the consumer spent
         # blocked on full send windows; spread busy = the union of send
-        # intervals across all target workers. The overlap fraction is
+        # intervals across every target's lanes. The overlap fraction is
         # the same clamped serialized-vs-wall estimate the streaming
         # rebuild reports for gather/compute.
         spread_busy = ss.busy_s()

@@ -10,13 +10,14 @@ encode (each stripe is one slab-aligned ``[off, off+w)`` range of every
 shard) and pushes each shard's ranges straight to its assigned holder
 via the ``/admin/ec/shard_write`` endpoint while later slabs are still
 encoding: a run of a shard's contiguous ranges is one POST whose body
-is views of the stripes' rows, on the connection the target's worker
-keeps open, and the holder streams it from the socket into the
+is views of the stripes' rows, on the connection that shard's lane
+keeps open (a target's shards ride two lanes, so a holder takes two
+runs at once), and the holder streams it from the socket into the
 ``.part`` stage. Shards bound for remote holders never touch the
 source disk, and no shard byte is copied in Python on its way.
 
-All of the transport — the bounded ``SW_EC_SPREAD_WINDOW`` per-target
-window with peak-buffer and blocked-time accounting, contiguous-run
+All of the transport — the lanes, the bounded ``SW_EC_SPREAD_WINDOW``
+window of each with peak-buffer and blocked-time accounting, contiguous-run
 merging, retry/failover onto spares, first-run ``SW_EC_HEDGE_MS``
 hedging, the ``.part``-stage/atomic-finalize discipline — lives in
 ``ec/transport.py``, shared byte-for-byte with the gather pull side.
@@ -44,8 +45,8 @@ def spread_window() -> int:
 
 class StripedSpreadSink(StripedPush):
     """The placement stream: ``write_stripe`` routes each shard row of
-    the arriving stripe to its holder's bounded send queue; per-target
-    workers push the ranges while the encode produces the next stripes.
+    the arriving stripe to the bounded send queue of its shard's lane;
+    the lanes push the ranges while the encode produces the next stripes.
     ``assignment`` maps shard id -> holder url; shards mapped to
     ``local_url`` (or unmapped) take the local-writer fast path and are
     staged next to ``base_name``. Everything after writer construction

@@ -7,10 +7,11 @@ failover, ``SW_EC_HEDGE_MS`` hedging with loser-drain health
 attribution, contiguous-run merging and local fast paths. This module
 is that transport, once — a *pull* side (``StripedPull``: stripe
 readers fan out over a pool, stripes yield strictly in order) and a
-*push* side (``StripedPush``: per-target workers drain bounded send
-queues, merging contiguous runs). Gather, spread, scrub and the tier
-demotion pipeline are thin clients; hedging and health routing are
-therefore available on the write path too, not just the read path.
+*push* side (``StripedPush``: a target's shards ride on lanes, worker
+threads that drain bounded send queues and merge contiguous runs).
+Gather, spread, scrub and the tier demotion pipeline are thin clients;
+hedging and health routing are therefore available on the write path
+too, not just the read path.
 
 Shape of the stream on both sides: a *stripe* is one slab-aligned byte
 range ``[off, off+w)`` of every shard. The pull side materializes it as
@@ -121,11 +122,13 @@ class SpreadError(Exception):
 # flight. An encode: the one being read, the pipeline's read-ahead (3)
 # and depth (4), the one being written, one in the producer's hand —
 # and, since the spread queues views of a slab's rows and not copies of
-# them (PR 30), the stripes its workers have not had acknowledged: a
-# window in the queues, a window in the workers' hands, the one being
-# routed (19). A gather: a window (4) the readers are filling, one in
-# the producer's hand, the read-ahead (3) and depth (4), the one whose
-# rebuilt rows are being appended: 13 of the 19.
+# them (PR 30), the stripes its lanes have not had acknowledged: a
+# window in the slowest lane's queue, a window in its hand, the one
+# being routed (19; a lane's window counts stripes of its own shards,
+# so more lanes a target hold no more stripes). A gather: a window (4)
+# the readers are filling, one in the producer's hand, the read-ahead
+# (3) and depth (4), the one whose rebuilt rows are being appended: 13
+# of the 19.
 _SLAB_POOL: "deque[np.ndarray]" = deque(
     maxlen=10 + 2 * DEFAULT_WINDOW + 1)
 
@@ -181,6 +184,7 @@ class TransportStats:
         self.fetches = 0
         self.sends = 0
         self.connects = 0
+        self.lanes = 0
         self.bytes = 0
         self.remote_bytes = 0
         self.hedges_fired = 0
@@ -221,7 +225,7 @@ class TransportStats:
                     self.holder_fetches.get(holder, 0) + 1
 
     def add_connects(self, n: int):
-        """Connections a remote writer opened: one a push worker and
+        """Connections a remote writer opened: one a push lane and
         holder when nothing fails, one more a retry or failover."""
         if n:
             with self._lock:
@@ -334,13 +338,21 @@ class GatherStats(TransportStats):
 class SpreadStats(TransportStats):
     """Push-side role of the shared stats: snapshot keys are
     ``spread_*`` (what ``observe_spread`` and the encode stats dicts
-    have always carried)."""
+    have always carried). ``spread_send_s`` is the SUM of the send
+    intervals whose union is the busy time: over ``spread_busy_s`` it
+    is the mean number of runs in flight while the spread is busy.
+    ``spread_lanes``: the lanes that carried at least one run."""
 
     stage = "spread"
+
+    def send_s(self) -> float:
+        return self.timer.totals.get(self.stage, 0.0)
 
     def snapshot(self) -> Dict[str, float]:
         out = super().snapshot()
         out["spread_connects"] = self.connects
+        out["spread_lanes"] = self.lanes
+        out["spread_send_s"] = round(self.send_s(), 3)
         return out
 
 
@@ -798,7 +810,7 @@ class RemoteShardWriter:
     (append-at-expected-offset, 409 on mismatch) whose body is the
     chunks themselves — row views of the encode's slabs under a
     Content-Length, written to the socket as they lie — on the
-    connection its push worker keeps to that holder (``link``, an
+    connection its push lane keeps to that holder (``link``, an
     http_util.KeptConnection; without one, a connection for this run
     alone: the hedged duplicates). It carries the caller span's
     traceparent so the holder's spans join the trace. Every send feeds
@@ -826,7 +838,7 @@ class RemoteShardWriter:
         return f"http://{holder}{self._target(query)}"
 
     def _headers(self) -> Optional[dict]:
-        # target worker threads don't inherit the tracing contextvar —
+        # the lanes' threads don't inherit the tracing contextvar —
         # carry the caller span's traceparent explicitly
         if self.span is None:
             return None
@@ -885,48 +897,104 @@ class RemoteShardWriter:
             pass
 
 
-class TargetWorker(threading.Thread):
-    """Drains one target's bounded send queue: pops queued
-    ``(sid, off, chunk, stripe)`` items, merges per-shard contiguous
-    runs, and sends each run as one POST on the connection it keeps to
-    its holder. A chunk is a view of its stripe's rows, held until the
-    run it went out in is acknowledged; then the stripe hears of it
-    (``StripedPush._row_done``). Owns the target url so failover
-    (re-assigning every shard of a dead target to a spare) is a
-    single-variable swap. The FIRST run to a remote target may be
-    hedged: past the ``SW_EC_HEDGE_MS`` deadline the same run races a
-    duplicate stage on a spare, the first ack wins the shard set, and
-    the loser's stage is aborted once its send drains."""
+# lanes a target's shards are divided between (a target with fewer
+# shards has a lane a shard). Two: while a holder's handler writes one
+# lane's run to its ``.part`` the other lane's run arrives, and the
+# sender's steps between an acknowledgement and the next request run
+# beside a send. A lane a shard was tried on the chip host and kept
+# out (PERF.md, PR 39).
+LANES = 2
 
-    def __init__(self, sink: "StripedPush", url: Optional[str],
-                 sids: List[int], window: int):
-        name = url or "local"
-        super().__init__(daemon=True, name=f"ec-push-{name}")
-        self.sink = sink
+
+class PushTarget:
+    """One holder of a push and what its lanes share: the url, so that
+    failover and the first-run hedge (re-assigning every shard of a
+    dead or slow target to a spare) stay a single-variable swap
+    whichever lane makes it, and the bytes it has acknowledged. The
+    target's FIRST run goes out on one lane alone — whichever claims it
+    — and only that run may be hedged or failed over; the other lanes
+    send once it is acknowledged, to whichever holder won, so a dead
+    holder's shards never land on two spares."""
+
+    def __init__(self, url: Optional[str], sids: Sequence[int]):
         self.url = url
+        self.sids = list(sids)
+        self.acked = 0
+        self._lock = make_lock("transport.PushTarget._lock")
+        self._claimed = False
+        self.opened = threading.Event()     # the first run is in
+
+    def claim_first_run(self) -> bool:
+        """True for the one lane that sends the target's first run."""
+        with self._lock:
+            first, self._claimed = not self._claimed, True
+        return first
+
+    def add_acked(self, n: int):
+        with self._lock:
+            self.acked += n
+        self.opened.set()
+
+
+class TargetWorker(threading.Thread):
+    """One lane of a target: drains its bounded send queue — pops
+    queued ``(sid, off, chunk, stripe)`` items of the shards it
+    carries, merges per-shard contiguous runs, and sends each run as
+    one POST on the connection it keeps to the target's holder. A
+    shard's rows always ride the same lane, so its runs reach the
+    holder in ascending offsets on one connection. A chunk is a view of
+    its stripe's rows, held until the run it went out in is
+    acknowledged; then the stripe hears of it
+    (``StripedPush._row_done``). The url and the acknowledged bytes are
+    the target's (``PushTarget``), shared with its other lanes. The
+    FIRST run to a remote target may be hedged: past the
+    ``SW_EC_HEDGE_MS`` deadline the same run races a duplicate stage on
+    a spare, the first ack wins the target's shard set, and the loser's
+    stage is aborted once its send drains."""
+
+    def __init__(self, sink: "StripedPush", target: PushTarget,
+                 lane: int, sids: List[int], window: int):
+        name = target.url or "local"
+        super().__init__(daemon=True, name=f"ec-push-{name}-{lane}")
+        self.sink = sink
+        self.target = target
+        self.lane = lane
         self.sids = list(sids)
         self.max_batch = max(1, window * len(sids))
         self.q: queue.Queue = queue.Queue(maxsize=self.max_batch)
-        self.acked = 0
+        self.runs = 0
         self.error: Optional[BaseException] = None
+        self._opened = False  # this lane may send: the first run is in
         self._link = None    # the kept connection, and to which holder
 
     def link(self):
-        """The connection this worker keeps to its holder: opened by
-        the first run, closed by a failed one (the retry opens the
-        next) and when the holder changes."""
+        """The connection this lane keeps to its holder: opened by the
+        first run, closed by a failed one (the retry opens the next)
+        and when the holder changes."""
         from ..server.http_util import KeptConnection
-        if self.url is None:
+        url = self.target.url
+        if url is None:
             return None
-        if self._link is None or self._link.netloc != self.url:
+        if self._link is None or self._link.netloc != url:
             self._close_link()
-            self._link = KeptConnection(self.url)
+            self._link = KeptConnection(url)
         return self._link
 
     def _close_link(self):
         link, self._link = self._link, None
         if link is not None:
             link.close()
+
+    def _wait_turn(self) -> bool:
+        """Hold this lane's first run until the target's first run —
+        another lane's, unless this one claims it — is acknowledged.
+        False: the spread failed meanwhile."""
+        if self.target.claim_first_run():
+            return True
+        while not self.target.opened.wait(0.05):
+            if self.sink.failed is not None:
+                return False
+        return True
 
     def run(self):
         try:
@@ -952,14 +1020,20 @@ class TargetWorker(threading.Thread):
                         break
                 if not batch:
                     break
+                if not self._opened:
+                    self._opened = self._wait_turn()
+                if self.sink.failed is not None:
+                    return      # another lane's failure ended the spread
                 # one stage per drained batch (span ``ec.spread.send``):
                 # its merged runs go out back to back on this thread,
                 # to the holder it names
                 with tracing.Stage(self.sink.send_span,
                                    self.sink.parent_span,
-                                   target=self.url or "local") as st:
+                                   target=self.target.url or "local",
+                                   lane=self.lane) as st:
                     for sid, off, chunks, stripes in merge_runs(batch):
                         n = self._send_run(sid, off, chunks)
+                        self.runs += 1
                         for chunk, stripe in zip(chunks, stripes):
                             self.sink._row_done(stripe, len(chunk))
                         st.nbytes += n
@@ -972,9 +1046,10 @@ class TargetWorker(threading.Thread):
 
     def _send_run(self, sid: int, off: int, chunks) -> int:
         writer = self.sink.writers[sid]
+        target = self.target
         n = sum(len(c) for c in chunks)
-        if (self.sink.hedge_s > 0 and self.url is not None
-                and self.acked == 0 and off == 0):
+        if (self.sink.hedge_s > 0 and target.url is not None
+                and target.acked == 0 and off == 0):
             if self._send_run_hedged(writer, off, chunks, n):
                 return n
         while True:
@@ -983,21 +1058,21 @@ class TargetWorker(threading.Thread):
                 if attempt:
                     self.sink.stats.add_retry()
                 try:
-                    writer.send(self.url, off, chunks, self.link())
-                    self.acked += n
+                    writer.send(target.url, off, chunks, self.link())
+                    target.add_acked(n)
                     return n
                 except BaseException as e:  # noqa: BLE001 - retry/failover
                     last = e
-            if self.acked > 0 or off != 0 or self.url is None:
+            if target.acked > 0 or off != 0 or target.url is None:
                 # bytes already landed on this target (or it's the local
                 # disk): the dead holder's prefix is unreplayable — the
                 # stripe stream never kept it. Abort; the caller falls
                 # back to the copy flow.
                 raise last
-            spare = self.sink._take_spare(self.url)
+            spare = self.sink._take_spare(target.url)
             if spare is None:
                 raise last
-            dead, self.url = self.url, spare
+            dead, target.url = target.url, spare
             self.sink.stats.add_failover()
             writer.abort(dead)
 
@@ -1006,28 +1081,29 @@ class TargetWorker(threading.Thread):
         """Hedge the first run of this target: if the leading holder
         has not acked within the deadline, race the same run against a
         spare's stage. Returns True when the run landed (possibly after
-        swapping ``self.url`` to the winning spare); False hands the
+        swapping the target's url to the winning spare); False hands the
         run to the plain retry/failover path — a duplicate re-send is
         safe because the holder's 409 ``staged=`` reply identifies a
         delivered-but-unacked run."""
+        target = self.target
         ex = hedge_pool()
-        primary = ex.submit(writer.send, self.url, off, chunks)
+        primary = ex.submit(writer.send, target.url, off, chunks)
         try:
             primary.result(timeout=self.sink.hedge_s)
-            self.acked += n
+            target.add_acked(n)
             return True
         except _FutureTimeout:
             pass
         except Exception:  # noqa: BLE001 - fast failure: plain failover
             return False
-        spare = self.sink._take_spare(self.url)
+        spare = self.sink._take_spare(target.url)
         if spare is None:
             # no rival to race: wait the slow send out
             try:
                 primary.result()
             except Exception:  # noqa: BLE001 - plain path owns retries
                 return False
-            self.acked += n
+            target.add_acked(n)
             return True
         self.sink.stats.add_hedge_fired()
         secondary = ex.submit(writer.send, spare, off, chunks)
@@ -1039,30 +1115,32 @@ class TargetWorker(threading.Thread):
                     continue
                 self.sink.stats.add_hedge_lost()
                 if f is secondary:
-                    # the spare won: it owns this worker's shard set
-                    # from here on; the slow holder's stage is aborted
-                    # once its duplicate drains (the send is idempotent
-                    # there — nothing else references the stage)
-                    slow, self.url = self.url, spare
+                    # the spare won: it owns the target's shard set
+                    # (every lane's) from here on; the slow holder's
+                    # stage is aborted once its duplicate drains (the
+                    # send is idempotent there — nothing else
+                    # references the stage)
+                    slow, target.url = target.url, spare
                     self.sink.stats.add_hedge_won()
                     self.sink.stats.add_failover()
                     _health.BOARD.record_hedge_loss(slow, spare)
                     primary.add_done_callback(
                         lambda _f, dead=slow: writer.abort(dead))
                 else:
-                    _health.BOARD.record_hedge_loss(spare, self.url)
+                    _health.BOARD.record_hedge_loss(spare, target.url)
 
                     def _cleanup(_f, spare=spare):
                         writer.abort(spare)
                         self.sink._return_spare(spare)
 
                     secondary.add_done_callback(_cleanup)
-                self.acked += n
+                target.add_acked(n)
                 return True
         # both failed: the plain path retries and fails over; give the
         # consumed spare back first so failover can still reach it
         self.sink._return_spare(spare)
         return False
+
 
 def merge_runs(batch):
     """Merge a drained batch of ``(sid, off, chunk, stripe)`` items into
@@ -1086,16 +1164,28 @@ def merge_runs(batch):
 
 class StripedPush:
     """The push pump: ``write_stripe`` routes each shard row of the
-    arriving stripe to its holder's bounded send queue; per-target
-    workers push the ranges while the producer makes the next stripes.
+    arriving stripe to the bounded send queue of its shard's lane; the
+    lanes push the ranges while the producer makes the next stripes. A
+    target's shards are divided between ``LANES`` lanes in shard order,
+    alternately (5 -> 3 + 2, 3 -> 2 + 1, a lone shard one lane), each a
+    worker thread with a queue and a kept connection of its own, so a
+    holder serves a target's runs on as many handler threads and one
+    run's file write overlaps another's arrival; the local target is
+    laned the same way. A lane's window is ``window`` stripes of its
+    own shards, so the stripes outstanding do not grow with the lanes.
     Subclasses build the ``writers`` list (one endpoint per shard) and
     the ``by_target`` grouping; everything else — window accounting,
     blocked-time, failover spares, hedging, finalize/abort discipline,
     optional MB/s pacing — lives here."""
 
-    # one span per batch a target worker drains from its queue and
-    # sends as merged runs (TargetWorker.run)
+    # one span per batch a lane drains from its queue and sends as
+    # merged runs (TargetWorker.run), tagged with target and lane
     send_span = "ec.spread.send"
+    # finish(): the wait for every lane to drain and join, then the
+    # finalize of every shard — the tail of an encode after its last
+    # stripe is queued
+    finish_span = "ec.spread.finish"
+    finalize_span = "ec.spread.finalize"
 
     def __init__(self, writers: List, by_target: Dict[Optional[str],
                                                       List[int]],
@@ -1128,13 +1218,21 @@ class StripedPush:
         self.stats.remote_shards = sum(
             1 for w in self.writers if w.remote)
         self.stats.local_shards = self.total - self.stats.remote_shards
-        self.workers = [
-            TargetWorker(self, url, sids, self.window)
-            for url, sids in by_target.items()]
-        self._worker_of = {}
-        for w in self.workers:
-            for sid in w.sids:
-                self._worker_of[sid] = w
+        self.targets = [PushTarget(url, sids)
+                        for url, sids in by_target.items()]
+        self.workers: List[TargetWorker] = []
+        self._target_of: Dict[int, PushTarget] = {}
+        self._worker_of: Dict[int, TargetWorker] = {}
+        for t in self.targets:
+            lanes = min(LANES, len(t.sids))
+            for lane in range(lanes):
+                w = TargetWorker(self, t, lane, t.sids[lane::lanes],
+                                 self.window)
+                self.workers.append(w)
+                for sid in w.sids:
+                    self._worker_of[sid] = w
+            for sid in t.sids:
+                self._target_of[sid] = t
         self.blocked_s = 0.0     # producer time lost to full windows
         for w in self.workers:
             w.start()
@@ -1172,7 +1270,7 @@ class StripedPush:
     def assignment(self) -> Dict[int, str]:
         """Final shard placement (post-failover): sid -> holder url,
         '' for shards kept locally."""
-        return {sid: (self._worker_of[sid].url or "")
+        return {sid: (self._target_of[sid].url or "")
                 for sid in range(self.total)}
 
     def _put(self, worker: TargetWorker, item):
@@ -1216,11 +1314,12 @@ class StripedPush:
     # -- the stream ---------------------------------------------------------
     def write_stripe(self, data, parity, done=None):
         """Route one stripe: row i of ``data``/``parity`` is the next
-        ``w`` bytes of shard i / shard k+i. The rows are queued as
-        views, not copies: the stripe's arrays belong to the sink until
-        every row's run is acknowledged, and ``done()`` is called then
-        (from a worker thread) — the caller's leave to write into them
-        again. A stripe of a failed spread is never done."""
+        ``w`` bytes of shard i / shard k+i, queued on its shard's lane.
+        The rows are queued as views, not copies: the stripe's arrays
+        belong to the sink until every row's run is acknowledged, and
+        ``done()`` is called then (from a lane's thread) — the caller's
+        leave to write into them again. A stripe of a failed spread is
+        never done."""
         k = data.shape[0]
         w = data.shape[1]
         off = self.offset
@@ -1247,24 +1346,27 @@ class StripedPush:
             stripe[1]()
 
     def finish(self):
-        """Drain every window, join the workers, then finalize all
-        shards (atomic ``.part`` -> shard rename on every holder).
-        Raises if any push or finalize failed."""
-        t0 = time.perf_counter()
-        for w in self.workers:
-            self._put(w, _SENTINEL)
-        for w in self.workers:
-            w.join()
-        self.blocked_s += time.perf_counter() - t0
+        """Drain every window, join every lane of every target, then
+        finalize all shards (atomic ``.part`` -> shard rename on every
+        holder, in shard order). Raises if any push or finalize failed:
+        no shard is renamed unless every lane ended clean."""
+        with tracing.Stage(self.finish_span, self.parent_span) as st:
+            for w in self.workers:
+                self._put(w, _SENTINEL)
+            for w in self.workers:
+                w.join()
+        self.blocked_s += st.t1 - st.t0
+        self.stats.lanes = sum(1 for w in self.workers if w.runs)
         if self.failed is not None:
             raise SpreadError(
                 f"shard spread failed: {self.failed!r}") from self.failed
-        for sid in range(self.total):
-            self.writers[sid].finalize(self._worker_of[sid].url,
-                                       self.offset)
+        with tracing.Stage(self.finalize_span, self.parent_span):
+            for sid in range(self.total):
+                self.writers[sid].finalize(self._target_of[sid].url,
+                                           self.offset)
 
     def abort(self):
-        """Stop the workers and leave no partial shards: best-effort
+        """Stop the lanes and leave no partial shards: best-effort
         ``.part`` cleanup on every holder and on the local disk."""
         self._fail(SpreadError("spread aborted"))
         for w in self.workers:
@@ -1276,6 +1378,6 @@ class StripedPush:
             w.join(timeout=10.0)
         for sid in range(self.total):
             try:
-                self.writers[sid].abort(self._worker_of[sid].url)
+                self.writers[sid].abort(self._target_of[sid].url)
             except Exception:  # noqa: BLE001 - best-effort cleanup
                 pass

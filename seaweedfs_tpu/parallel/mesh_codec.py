@@ -177,7 +177,7 @@ class MeshCodec(DeviceCodec):
         """Host pieces of a device output in width order: list of
         (col_offset, (r, piece_w) np.ndarray) covering [0, w). Sharded
         outputs drain one piece per device shard — consumers (the
-        spread sink's per-target workers, rebuild shard writes) start
+        spread sink's lanes, rebuild shard writes) start
         on the first device's stripes without staging the full slab on
         the host; single-device outputs come back as one piece."""
         shards = getattr(out_dev, "addressable_shards", None) or []

@@ -1063,7 +1063,7 @@ def post_chunked(url: str, chunks, headers: Optional[dict] = None,
 class KeptConnection:
     """One connection to one cluster peer that its owner keeps open
     across POSTs whose bodies are buffers the owner still holds (the EC
-    spread: one a push worker and holder, ec/transport.py). A body goes
+    spread: one a push lane and holder, ec/transport.py). A body goes
     out under a Content-Length as the buffers it is made of — row views
     of a slab are written to the socket as they lie, never joined or
     framed into new bytes — and is therefore replayable: any failure

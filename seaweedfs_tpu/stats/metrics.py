@@ -911,6 +911,15 @@ VOLUME_EC_SPREAD_SECONDS = VOLUME_SERVER_GATHER.counter(
     "SeaweedFS_volumeServer_ec_spread_seconds_total",
     "Cumulative spread busy time (union of in-flight send intervals) "
     "across streaming encodes.")
+VOLUME_EC_SPREAD_SEND_SECONDS = VOLUME_SERVER_GATHER.counter(
+    "SeaweedFS_volumeServer_ec_spread_send_seconds_total",
+    "Cumulative spread send time (SUM of the send intervals whose "
+    "union is ec_spread_seconds_total): over it, the mean number of "
+    "runs in flight while a spread is busy.")
+VOLUME_EC_SPREAD_LANES_GAUGE = VOLUME_SERVER_GATHER.gauge(
+    "SeaweedFS_volumeServer_ec_spread_lanes",
+    "Push lanes (worker thread + kept connection) that carried at "
+    "least one run in the last streaming encode.")
 VOLUME_EC_SPREAD_MBPS_GAUGE = VOLUME_SERVER_GATHER.gauge(
     "SeaweedFS_volumeServer_ec_spread_mbps",
     "Effective shard placement bandwidth of the last streaming encode "
@@ -937,6 +946,11 @@ def observe_spread(stats: Dict):
     busy = stats.get("spread_busy_s")
     if busy:
         VOLUME_EC_SPREAD_SECONDS.inc(amount=busy)
+    send = stats.get("spread_send_s")
+    if send:
+        VOLUME_EC_SPREAD_SEND_SECONDS.inc(amount=send)
+    if "spread_lanes" in stats:
+        VOLUME_EC_SPREAD_LANES_GAUGE.set(stats["spread_lanes"])
     if "spread_mbps" in stats:
         VOLUME_EC_SPREAD_MBPS_GAUGE.set(stats["spread_mbps"])
     if "overlap_frac" in stats:

@@ -200,6 +200,47 @@ def test_span_count_is_per_dispatch_not_per_block(cycle):
         count("ec.rebuild.fetch.local", rtid) == 10 * n_reb
 
 
+@pytest.mark.parametrize("name", ["ec.spread.finish",
+                                  "ec.spread.finalize"])
+def test_the_spreads_tail_has_its_spans(cycle, name):
+    """PR 39: `sink.finish()` is two stages on the consumer under the
+    stream's root — the wait for every lane to join, then the finalize
+    of every shard — once an encode, in that order, and no send ends
+    after the join does."""
+    tid = cycle["encode"]["trace_id"]
+    span = _one(cycle, name, tid)
+    stream = _one(cycle, "ec.encode.stream", tid)
+    assert span["parent_id"] == stream["span_id"]
+    assert _inside(span, stream)
+    assert not span["tags"]["thread"].startswith(WORKER_PREFIXES)
+    join = _one(cycle, "ec.spread.finish", tid)
+    join_end = join["start"] + join["duration_s"]
+    sends = [s for s in _named(cycle, "ec.spread.send")
+             if s["trace_id"] == tid]
+    assert max(s["start"] + s["duration_s"] for s in sends) \
+        <= join_end + 1e-6
+    assert _one(cycle, "ec.spread.finalize", tid)["start"] \
+        >= join_end - 1e-6
+
+
+def test_a_send_names_its_target_and_its_lane(cycle):
+    """5 + 5 + 4 over three servers: two lanes a target, each on a
+    thread of its own; the reply counts them and the sends' sum."""
+    tid = cycle["encode"]["trace_id"]
+    sends = [s for s in _named(cycle, "ec.spread.send")
+             if s["trace_id"] == tid]
+    lanes = {(s["tags"]["target"], s["tags"]["lane"]) for s in sends}
+    assert len(lanes) == 6 and {lane for _, lane in lanes} == {0, 1}
+    assert len({s["tags"]["thread"] for s in sends}) == 6
+    enc = cycle["encode"]
+    assert enc["spread_lanes"] == 6 and enc["spread_connects"] == 4
+    assert enc["spread_send_s"] >= enc["spread_busy_s"]
+    # the reply's sum is the sends' own intervals (the spans hold whole
+    # batches, so they are no shorter)
+    assert enc["spread_send_s"] <= \
+        sum(s["duration_s"] for s in sends) + 1e-3
+
+
 def test_phases_keep_their_keys_and_their_sum(cycle):
     reb = cycle["rebuild"]
     assert set(reb["phases"]) == {"gather", "plan", "dispatch", "drain",

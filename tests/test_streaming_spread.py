@@ -57,6 +57,7 @@ class FakeTarget:
         self.appends = 0
         self.finalized = 0
         self.aborted = 0
+        self.seen = []              # (connection, shard, offset) an append
         self._lock = threading.Lock()
         router = Router()
         router.add("POST", "/admin/ec/shard_write", self._shard_write)
@@ -97,6 +98,10 @@ class FakeTarget:
         with self._lock:
             self.appends += 1
             n_seen = self.appends
+            self.seen.append((req.handler.client_address,
+                              int(req.query["shard"]),
+                              int(req.query.get("offset", "0"))))
+        self._arrived(n_seen)
         if self.fail or (self.fail_after is not None
                          and n_seen > self.fail_after):
             _ = req.body
@@ -112,6 +117,16 @@ class FakeTarget:
             f.write(data)
             staged = f.tell()
         return {"volume": vid, "shard": sid, "staged": staged}
+
+    def _arrived(self, n_seen):
+        """Hook: append number ``n_seen`` is at the door."""
+
+    def connections(self):
+        """{connection: the shards whose appends it carried}."""
+        out = {}
+        for conn, sid, _ in self.seen:
+            out.setdefault(conn, set()).add(sid)
+        return out
 
     def stop(self):
         self.server.stop()
@@ -353,14 +368,17 @@ def test_observe_spread_metrics():
         "spread_bytes": 1 << 20, "spread_sends": 9, "spread_stripes": 3,
         "spread_retries": 1, "spread_failovers": 1,
         "spread_busy_s": 0.5, "spread_mbps": 88.5,
+        "spread_send_s": 1.75, "spread_lanes": 6,
         "overlap_frac": 0.61})
     assert metrics.VOLUME_EC_SPREAD_COUNTER.value("bytes") - before \
         == 1 << 20
     assert metrics.VOLUME_EC_ENCODE_OVERLAP_FRAC_GAUGE.value() == 0.61
     assert metrics.VOLUME_EC_SPREAD_MBPS_GAUGE.value() == 88.5
+    assert metrics.VOLUME_EC_SPREAD_LANES_GAUGE.value() == 6
     render = metrics.VOLUME_SERVER_GATHER.render()
     assert 'ec_spread_total{kind="bytes"}' in render
     assert "ec_encode_overlap_frac" in render
+    assert "ec_spread_send_seconds_total" in render
 
 
 # -- end-to-end: streaming ec.encode over a live cluster ---------------------
@@ -559,9 +577,10 @@ def _encode_through(tmp_path, codec, targets, remote, window=2,
 
 
 def test_one_connection_a_worker_and_holder(tmp_path):
-    """`spread_connects`: a clean encode opens one connection to each
-    remote target however many runs it sends; a retry after a run cut in
-    the middle opens one more, and so does a failover to a spare."""
+    """`spread_connects`: a clean encode opens one connection a lane to
+    each remote target (two lanes a target of two shards or more)
+    however many runs it sends; a retry after a run cut in the middle
+    opens one more, and so does a failover to a spare."""
     codec = NumpyCodec(6, 3)
     dirs = [tmp_path / n for n in ("a", "b", "cut", "dead", "spare")]
     for d in dirs:
@@ -575,7 +594,8 @@ def test_one_connection_a_worker_and_holder(tmp_path):
         clean, _ = _encode_through(
             tmp_path / "clean", codec, targets,
             {1: a.url, 4: a.url, 7: a.url, 2: b.url, 8: b.url})
-        assert clean["spread_connects"] == 2
+        assert clean["spread_connects"] == 4
+        assert len(a.connections()) == len(b.connections()) == 2
         assert clean["spread_sends"] > 2 * clean["spread_connects"]
         assert clean["spread_retries"] == 0
         assert clean["holder_fetches"][a.url] == a.appends >= 2
@@ -586,16 +606,18 @@ def test_one_connection_a_worker_and_holder(tmp_path):
             tmp_path / "retried", codec, targets,
             {1: a.url, 4: a.url, 2: cut.url, 8: cut.url})
         assert retried["spread_retries"] == 1
-        assert retried["spread_connects"] == 2 + 1
+        assert retried["spread_connects"] == 4 + 1
 
         # failover: the dead holder answered 503 on its one connection
-        # (both attempts), the spare gets the worker's next
+        # (both attempts of the one lane that had the first run), the
+        # spare gets that lane's next and the other lane's only one
         moved, sink = _encode_through(
             tmp_path / "moved", codec, targets,
             {1: a.url, 7: dead.url, 8: dead.url}, spares=[spare.url])
         assert moved["spread_failovers"] == 1
-        assert sink.assignment()[7] == spare.url
-        assert moved["spread_connects"] == 2 + 1
+        assert sink.assignment()[7] == sink.assignment()[8] == spare.url
+        assert moved["spread_connects"] == 3 + 1
+        assert len(dead.connections()) == 1
     finally:
         for t in targets.values():
             t.stop()
@@ -835,8 +857,226 @@ def _slab_case(tmp_path, monkeypatch, codec, layout="flat", enc=ENC):
         # piggyback's are recycled like the flat layout's since PR 33
         assert reused, "no recycled slab was read into again"
         assert len(encoder._SLAB_POOL) <= encoder._SLAB_POOL.maxlen
-        assert stats["spread_connects"] == 1
+        # one connection a lane: the target's shards ride two
+        assert stats["spread_connects"] == 2
     finally:
         tgt.stop()
         encoder._SLAB_POOL.clear()
     return pieces
+
+
+# -- PR 39: a target's shards ride two lanes ----------------------------------
+# A lane is a worker thread with a queue and a kept connection of its own;
+# a target's shards are dealt to its lanes alternately in shard order, the
+# url and the first run are the target's.
+
+def _holders(tmp_path, names, cls=FakeTarget):
+    out = []
+    for n in names:
+        (tmp_path / n).mkdir()
+        out.append(cls(str(tmp_path / n)))
+    return out
+
+
+@pytest.mark.parametrize("held, lanes", [
+    (5, [[0, 2, 4], [1, 3]]), (4, [[0, 2], [1, 3]]),
+    (3, [[0, 2], [1]]), (1, [[0]])])
+def test_a_holders_shards_are_dealt_to_its_lanes(tmp_path, held, lanes):
+    """3 + 2 / 2 + 2 / 2 + 1 / 1: every shard's appends arrive on one
+    connection, in ascending contiguous offsets, and a connection
+    carries exactly one lane's shards."""
+    codec = NumpyCodec(6, 3)
+    tgt, = _holders(tmp_path, ["t"])
+    try:
+        remote = {sid: tgt.url for sid in range(held)}
+        stats, sink = _encode_through(tmp_path / "run", codec,
+                                      {tgt.url: tgt}, remote, window=1)
+        assert sorted(sorted(sids) for sids in
+                      tgt.connections().values()) == sorted(lanes)
+        by_shard = {}
+        for conn, sid, off in tgt.seen:
+            by_shard.setdefault(sid, []).append((conn, off))
+        for sid, appends in by_shard.items():
+            assert len({conn for conn, _ in appends}) == 1
+            offs = [off for _, off in appends]
+            assert offs == sorted(offs) and offs[0] == 0 and len(offs) > 1
+        assert stats["spread_connects"] == len(lanes)
+        # the local target's shards are laned the same way
+        assert stats["spread_lanes"] == len(lanes) + min(2, 9 - held)
+        assert [sorted(w.sids) for w in sink.workers
+                if w.target.url == tgt.url] == lanes
+    finally:
+        tgt.stop()
+
+
+class MeetingTarget(FakeTarget):
+    """A holder whose second append (the first after the target's first
+    run) waits at the door for another append to arrive beside it: a
+    sender with one run in flight a holder never brings the second, the
+    barrier times out and the append fails."""
+
+    def __init__(self, directory):
+        super().__init__(directory)
+        self.door = threading.Barrier(2, timeout=10)
+        self.met = []
+
+    def _arrived(self, n_seen):
+        if n_seen in (2, 3):
+            try:
+                self.door.wait()
+            except threading.BrokenBarrierError:
+                raise HttpError(500, "no second run arrived beside "
+                                     "this one") from None
+            with self._lock:
+                self.met.append(self.seen[n_seen - 1][0])
+
+
+def test_two_runs_to_one_holder_are_in_flight_at_once(tmp_path):
+    """Shown with a barrier, not a clock: the holder lets neither of
+    two appends in until both are there, on two connections."""
+    codec = NumpyCodec(6, 3)
+    tgt, = _holders(tmp_path, ["t"], MeetingTarget)
+    try:
+        stats, _ = _encode_through(
+            tmp_path / "run", codec, {tgt.url: tgt},
+            {1: tgt.url, 4: tgt.url, 7: tgt.url}, window=1)
+        assert len(tgt.met) == 2 and tgt.met[0] != tgt.met[1]
+        assert stats["spread_retries"] == 0
+        # the target's first run went out alone, on one of the two
+        assert tgt.seen[0][2] == 0
+    finally:
+        tgt.stop()
+
+
+def test_failover_moves_every_lane_to_one_spare(tmp_path):
+    """A holder dead at first contact: the lane that had the target's
+    first run fails over, every shard of the target lands on the one
+    spare, and the other lane never opens a connection to the dead
+    holder (it sends only once the first run is acknowledged)."""
+    codec = NumpyCodec(6, 3)
+    dead, s1, s2 = _holders(tmp_path, ["dead", "s1", "s2"])
+    dead.fail = True
+    targets = {t.url: t for t in (dead, s1, s2)}
+    try:
+        remote = {sid: dead.url for sid in (1, 3, 4, 7, 8)}
+        stats, sink = _encode_through(
+            tmp_path / "run", codec, targets, remote,
+            spares=[s1.url, s2.url])
+        final = sink.assignment()
+        assert len({final[sid] for sid in remote}) == 1
+        spare = targets[final[1]]
+        assert spare is not dead
+        assert stats["spread_failovers"] == 1
+        assert len(dead.connections()) == 1
+        assert sorted(sorted(c) for c in spare.connections().values()) \
+            == [[1, 4, 8], [3, 7]]
+        other = s2 if spare is s1 else s1
+        assert other.appends == 0 and not os.listdir(other.dir)
+        assert not os.listdir(dead.dir)
+    finally:
+        for t in targets.values():
+            t.stop()
+
+
+def test_a_hedge_won_by_the_spare_gives_it_every_lane(tmp_path,
+                                                      monkeypatch):
+    codec = NumpyCodec(6, 3)
+    slow, fast = _holders(tmp_path, ["slow", "fast"])
+    slow.delay = 0.6
+    monkeypatch.setenv("SW_EC_HEDGE_MS", "60")
+    targets = {slow.url: slow, fast.url: fast}
+    try:
+        remote = {sid: slow.url for sid in (2, 5, 8)}
+        stats, sink = _encode_through(tmp_path / "run", codec, targets,
+                                      remote, spares=[fast.url])
+        assert stats["hedges_won"] == 1 and stats["spread_failovers"] == 1
+        assert {sink.assignment()[sid] for sid in remote} == {fast.url}
+        # the slow holder saw the first run and nothing else, and its
+        # stage is aborted once that duplicate has drained
+        from conftest import wait_until
+        assert wait_until(lambda: slow.aborted == 1, timeout=5)
+        assert slow.appends == 1 and not os.listdir(slow.dir)
+    finally:
+        slow.stop()
+        fast.stop()
+
+
+class ShardFailingTarget(FakeTarget):
+    """Fails every append of one shard from a given offset on."""
+
+    fail_shard, fail_from = None, 0
+
+    def _arrived(self, n_seen):
+        _, sid, off = self.seen[n_seen - 1]
+        if sid == self.fail_shard and off >= self.fail_from:
+            raise HttpError(503, "injected lane failure")
+
+
+@pytest.mark.parametrize("lane", [0, 1])
+def test_a_failed_lane_finalizes_nothing_and_leaves_no_part(tmp_path,
+                                                            lane):
+    """After the target's first acknowledgement a failed run ends the
+    spread: `finish()` raises with every lane joined and not one shard
+    renamed into place — on the holder whose other lane ended clean, on
+    the healthy holder, locally — and `abort()` leaves no `.part`."""
+    from seaweedfs_tpu.ec.transport import (LocalShardWriter,
+                                            RemoteShardWriter, StripedPush)
+    bad, = _holders(tmp_path, ["bad"], ShardFailingTarget)
+    good, = _holders(tmp_path, ["good"])
+    w = 4096
+    bad.fail_shard, bad.fail_from = (1, 3)[lane], 2 * w
+    (tmp_path / "src").mkdir()
+    local = [str(tmp_path / "src" / f"1{to_ext(sid)}") for sid in (0, 5)]
+    writers = [LocalShardWriter(local[0]), RemoteShardWriter(1, 1),
+               RemoteShardWriter(1, 2), RemoteShardWriter(1, 3),
+               RemoteShardWriter(1, 4), LocalShardWriter(local[1])]
+    try:
+        sink = StripedPush(writers, {None: [0, 5], bad.url: [1, 3],
+                                     good.url: [2, 4]}, window=1)
+        rows = np.arange(6 * w, dtype=np.uint8).reshape(6, w)
+        # the producer may see the failure before `finish()` does
+        with pytest.raises(SpreadError):
+            for _ in range(6):
+                sink.write_stripe(rows[:4], rows[4:])
+            sink.finish()
+        assert bad.finalized == good.finalized == 0
+        for d in (bad.dir, good.dir, str(tmp_path / "src")):
+            assert not [f for f in os.listdir(d)
+                        if not f.endswith(".part")], d
+        sink.abort()
+        assert not [t for t in sink.workers if t.is_alive()]
+        for d in (bad.dir, good.dir, str(tmp_path / "src")):
+            assert os.listdir(d) == [], d
+        assert sink.stats.failovers == 0
+    finally:
+        bad.stop()
+        good.stop()
+
+
+@pytest.mark.parametrize("k, m, servers, lanes, connects", [
+    (10, 4, 3, 6, 4), (6, 3, 3, 6, 4), (10, 4, 4, 8, 6)])
+def test_laned_spread_is_bit_identical(tmp_path, k, m, servers, lanes,
+                                       connects):
+    """RS(10,4) 5+5+4, RS(6,3) 3+3+3 and RS(10,4) 4+4+3+3, round-robin
+    as the shell lays them (the encoding node one of the holders): every
+    shard file equals the copy flow's, and the stats name the lanes."""
+    codec = NumpyCodec(k, m)
+    others = _holders(tmp_path, [f"h{i}" for i in range(1, servers)])
+    urls = [LOCAL] + [t.url for t in others]
+    try:
+        remote = {sid: urls[sid % servers] for sid in range(k + m)
+                  if sid % servers}
+        stats, sink = _encode_through(
+            tmp_path / "run", codec, {t.url: t for t in others}, remote,
+            nbytes=k * (64 << 10) + 70_001)
+        assert stats["spread_lanes"] == lanes == len(sink.workers)
+        assert stats["spread_connects"] == connects
+        assert stats["spread_send_s"] >= stats["spread_busy_s"] > 0
+        assert stats["spread_bytes"] == stats["shard_size"] * (k + m)
+        for t in others:
+            assert t.finalized == len(
+                [s for s, u in remote.items() if u == t.url])
+            assert len(t.connections()) == 2
+    finally:
+        for t in others:
+            t.stop()

@@ -240,6 +240,39 @@ def test_rate_zero_means_unpaced(tmp_path):
     assert time.perf_counter() - t0 < 1.0
 
 
+def test_lanes_lose_no_update_of_what_they_share(tmp_path, monkeypatch):
+    """Eight lanes of one target (more threads than this box has
+    cores) under a shortened switch interval: the target's acknowledged
+    bytes, the stats and every stripe's countdown come out exact."""
+    import sys
+    total, w, n_stripes = 16, 512, 300
+    monkeypatch.setattr(transport, "LANES", 8)
+    writers = [transport.LocalShardWriter(str(tmp_path / f"s{to_ext(i)}"))
+               for i in range(total)]
+    stats = transport.SpreadStats()
+    done = []
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        sink = transport.StripedPush(writers, {None: list(range(total))},
+                                     window=2, stats=stats)
+        rows = np.arange(total * w, dtype=np.uint8).reshape(total, w)
+        for i in range(n_stripes):
+            sink.write_stripe(rows[:10], rows[10:],
+                              done=lambda i=i: done.append(i))
+        sink.finish()
+    finally:
+        sys.setswitchinterval(interval)
+    assert not [t for t in sink.workers if t.is_alive()]
+    assert len(sink.workers) == 8 == stats.lanes
+    assert sink.targets[0].acked == stats.bytes == total * n_stripes * w
+    assert sorted(done) == list(range(n_stripes))
+    assert sink._buffered == 0
+    for i in range(total):
+        with open(str(tmp_path / f"s{to_ext(i)}"), "rb") as f:
+            assert f.read() == rows[i].tobytes() * n_stripes
+
+
 # -- pull -> push round trip: bit-identical through both halves --------------
 
 def test_pull_push_roundtrip_bit_identical(tmp_path):
@@ -324,7 +357,8 @@ def test_push_over_tls_real_holders(tmp_path):
             d = hdir if sid in remote else str(src)
             assert _digest(os.path.join(d, f"1{to_ext(sid)}")) \
                 == oracle[sid], f"shard {sid} diverged"
-        assert stats["spread_connects"] == 1
+        # one connection a lane and holder: three shards ride two lanes
+        assert stats["spread_connects"] == 2
         assert stats["spread_retries"] == 0
     finally:
         for s in (holder, master):
