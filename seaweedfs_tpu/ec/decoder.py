@@ -286,6 +286,7 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
         stats["stream_s"] = round(stream_s, 3)
         stats["backend"] = codec.backend
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
+        stats["stage_max_s"] = {**timer.max_s(), **gs.timer.max_s()}
         stats.update(gs.overlap(stream_s, phases["gather"]))
         # the repair story: symbol bytes moved vs the k*shard baseline
         # the full-RS gather would have pulled for the same rebuild
@@ -405,6 +406,7 @@ def rebuild_ec_file_piggyback(base_name: str, lost_sid: int, source,
         stats["backend"] = codec.backend
         stats["layout"] = "piggyback"
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
+        stats["stage_max_s"] = {**timer.max_s(), **gs.timer.max_s()}
         stats.update(gs.overlap(stream_s, phases["gather"]))
         # the repair story: half-plane bytes moved vs the k*shard
         # baseline the full-RS gather would have pulled

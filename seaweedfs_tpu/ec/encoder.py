@@ -461,6 +461,7 @@ def write_ec_files_spread(base_name: str, sink,
         stats["shards"] = codec.total
         stats["phases"] = {n: round(s, 6) for n, s in
                            _phases_from_timer(timer, pipelined).items()}
+        stats["stage_max_s"] = {**timer.max_s(), **ss.timer.max_s()}
         # encode busy = stream wall minus the time the consumer spent
         # blocked on full send windows; spread busy = the union of send
         # intervals across every target's lanes. The overlap fraction is
@@ -579,6 +580,7 @@ def rebuild_ec_files(base_name: str,
     before = telemetry.STATS.snapshot()
     phases = {"gather": 0.0, "plan": 0.0, "dispatch": 0.0,
               "drain": 0.0, "write": 0.0}
+    ptimer = StageTimer(root=tracing.current_span())
     t_stream = time.perf_counter()
     try:
         if pipelined:
@@ -586,7 +588,6 @@ def rebuild_ec_files(base_name: str,
             t0 = time.perf_counter()
             coeffs = _rebuild_coeffs(codec, present, missing)
             phases["plan"] = time.perf_counter() - t0
-            ptimer = StageTimer(root=tracing.current_span())
             # pieces: device-shard outputs drain and append to the
             # missing-shard files per device, no full-slab host staging
             pm = PipelinedMatmul(coeffs, max_width=slab, codec=codec,
@@ -656,6 +657,7 @@ def rebuild_ec_files(base_name: str,
         stats["k"], stats["m"] = codec.k, codec.m
         stats["lost"] = list(missing)
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
+        stats["stage_max_s"] = ptimer.max_s()
     return missing
 
 
@@ -801,6 +803,7 @@ def rebuild_ec_files_piggyback(base_name: str, present: List[bool],
         stats["layout"] = "piggyback"
         stats["lost"] = list(missing)
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
+        stats["stage_max_s"] = {**timer.max_s(), **gs.timer.max_s()}
         stats.update(gs.overlap(stream_s, phases["gather"]))
         # the byte account the single-shard routes give: k whole shards
         # gathered of the k a full gather pulls
@@ -847,12 +850,12 @@ def rebuild_ec_files_streaming(base_name: str,
     phases["plan"] = time.perf_counter() - t0
     outs = {i: open(base_name + to_ext(i), "wb") for i in missing}
     rebuilt_bytes = 0
+    # the stream's root span (ec.rebuild.stream, current here)
+    ptimer = StageTimer(root=tracing.current_span())
     t_stream = time.perf_counter()
     try:
         if pipelined:
             from ..ops.pipeline import PipelinedMatmul
-            # the stream's root span (ec.rebuild.stream, current here)
-            ptimer = StageTimer(root=tracing.current_span())
             # pieces, same as rebuild_ec_files: the sharded decode's
             # per-device outputs append as they land
             pm = PipelinedMatmul(coeffs, max_width=slab, codec=codec,
@@ -926,6 +929,7 @@ def rebuild_ec_files_streaming(base_name: str,
         stats["k"], stats["m"] = codec.k, codec.m
         stats["lost"] = list(missing)
         stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
+        stats["stage_max_s"] = {**ptimer.max_s(), **gs.timer.max_s()}
         stats.update(gs.overlap(stream_s, phases["gather"]))
         # the byte account the single-shard routes give: what the
         # gather's readers received, of the k whole shards it is meant

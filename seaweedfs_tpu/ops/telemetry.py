@@ -65,7 +65,12 @@ class DispatchStats:
                "device_bytes", "mesh_dispatches",
                "read_bytes", "read_busy_us", "read_cpu_us",
                "repair_fallbacks", "coupled_decodes",
-               "slab_fresh_bytes")
+               "slab_fresh_bytes",
+               "holder_runs", "holder_bytes", "holder_us",
+               "holder_recv_us", "holder_write_us", "holder_cpu_us",
+               "lock_probe_samples", "lock_probe_elapsed_us",
+               "lock_probe_late_us", "lock_probe_stalls",
+               "lock_probe_stall_us")
     REPAIR_ROUTES = ("piggyback", "trace", "full")
 
     def __init__(self):
@@ -95,6 +100,30 @@ class DispatchStats:
             self.read_bytes += nbytes
             self.read_busy_us += int(busy_s * 1e6)
             self.read_cpu_us += int(cpu_s * 1e6)
+
+    def add_holder_run(self, nbytes: int, wall_s: float, recv_s: float,
+                       write_s: float, cpu_s: float):
+        """One run a holder moved from the socket to its stage file
+        (also one that ended short and was rolled back: its interval
+        and the bytes that did arrive)."""
+        with self._lock:
+            self.holder_runs += 1
+            self.holder_bytes += nbytes
+            self.holder_us += int(wall_s * 1e6)
+            self.holder_recv_us += int(recv_s * 1e6)
+            self.holder_write_us += int(write_s * 1e6)
+            self.holder_cpu_us += int(cpu_s * 1e6)
+
+    def add_probe_sample(self, elapsed_s: float, late_s: float,
+                         stalled: bool):
+        """One wake-up of the interpreter-lock probe."""
+        with self._lock:
+            self.lock_probe_samples += 1
+            self.lock_probe_elapsed_us += int(elapsed_s * 1e6)
+            self.lock_probe_late_us += int(late_s * 1e6)
+            if stalled:
+                self.lock_probe_stalls += 1
+                self.lock_probe_stall_us += int(late_s * 1e6)
 
     def add_repair_route(self, route: str):
         """One streaming rebuild finished on this route."""

@@ -18,6 +18,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..ec.constants import MAX_SHARDS, to_ext
+from ..ops import telemetry
 from ..util import malloc_policy, tracing
 from ..storage.needle import Needle
 from ..storage.store import Store
@@ -45,14 +46,20 @@ def _tag_holder_read(bytes_read: int, bytes_sent: int):
 SHARD_WRITE_PIECE = 8 << 20
 
 
-def _tag_shard_write(nbytes: int, pieces: int):
-    """On the server span of a holder's shard_write append: the bytes
-    it moved from the socket to the stage file, and in how many pieces
-    of its buffer (also when the run ended short and was rolled back)."""
+def _count_shard_write(nbytes: int, pieces: int, wall_s: float,
+                       recv_s: float, write_s: float, cpu_s: float):
+    """One run of a holder's shard_write append, also when it ended
+    short and was rolled back: onto the server span the bytes it moved
+    from the socket to the stage file, in how many pieces of its buffer,
+    the seconds inside the socket reads (``recv_s``) and inside
+    ``os.write`` (``write_s``: the rest of the span is the interpreter
+    between them) and the handler thread's CPU time (``cpu_s``); into
+    ``ops/telemetry.STATS`` the same as the ``holder_*`` counters."""
     span = tracing.current_span()
     if span is not None:
-        span.tags["bytes"] = int(nbytes)
-        span.tags["pieces"] = int(pieces)
+        span.tags.update(bytes=int(nbytes), pieces=int(pieces),
+                         recv_s=recv_s, write_s=write_s, cpu_s=cpu_s)
+    telemetry.STATS.add_holder_run(nbytes, wall_s, recv_s, write_s, cpu_s)
 
 
 class VolumeServer:
@@ -208,6 +215,7 @@ class VolumeServer:
             self_url=lambda: self.url,
             on_finding=self._report_scrub_finding)
         self._stop = threading.Event()
+        self._probing = False
         # immediate delta-push (reference store.go:40-64 change channels,
         # consumed by volume_grpc_client_to_master.go:57-185): volume
         # create/delete and EC shard mount/unmount wake the heartbeat
@@ -253,6 +261,10 @@ class VolumeServer:
     def start(self):
         # the EC streams this server runs live on recycled buffers
         malloc_policy.keep_freed_memory()
+        # one probe of the interpreter lock a process, however many
+        # servers it holds: the last stop() ends it
+        tracing.start_lock_probe(telemetry.STATS.add_probe_sample)
+        self._probing = True
         self.server.start()
         try:
             self.heartbeat_once()
@@ -284,6 +296,9 @@ class VolumeServer:
             push.stop_event.set()
         self.server.stop()
         self.store.close()
+        if self._probing:
+            self._probing = False
+            tracing.stop_lock_probe()
 
     @property
     def url(self) -> str:
@@ -686,7 +701,6 @@ class VolumeServer:
         # device-codec telemetry (process-global monotonic counters)
         # mirrors onto the scrape so dispatches / bitmat uploads / host
         # fallbacks are visible without running a rebuild through bench
-        from ..ops import telemetry
         from ..stats.metrics import (DEVICE_TELEMETRY_COUNTER,
                                      HTTP_POOL_CHURN_COUNTER)
         for kind, total in telemetry.STATS.snapshot().items():
@@ -1019,18 +1033,35 @@ class VolumeServer:
             fd = os.open(part, os.O_WRONLY | os.O_CREAT | os.O_APPEND
                          | (os.O_TRUNC if off == 0 else 0), 0o644)
             nbytes = pieces = 0
+            recv_s = write_s = 0.0
+            body = req.body_pieces(buf)
+            # where the run blocks: a clock pair around each fill of
+            # the buffer from the socket and each write of it to the
+            # stage; what they leave of the run is the interpreter
+            clock = time.perf_counter
+            cpu_s = time.thread_time()
+            t_run = clock()
             try:
-                for piece in req.body_pieces(buf):
+                while True:
+                    t = clock()
+                    piece = next(body, None)
+                    recv_s += clock() - t
+                    if piece is None:
+                        break
                     nbytes += len(piece)
                     pieces += 1
+                    t = clock()
                     while piece:
                         piece = piece[os.write(fd, piece):]
+                    write_s += clock() - t
             except BaseException:
                 os.ftruncate(fd, off)   # whole or not at all
                 raise
             finally:
+                wall_s = clock() - t_run
                 os.close(fd)
-                _tag_shard_write(nbytes, pieces)
+                _count_shard_write(nbytes, pieces, wall_s, recv_s,
+                                   write_s, time.thread_time() - cpu_s)
         finally:
             self._shard_write_bufs.append(buf)
         return {"volume": vid, "shard": sid, "staged": off + nbytes}

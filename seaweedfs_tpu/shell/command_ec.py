@@ -386,12 +386,17 @@ def ec_rebuild(env: CommandEnv, args: List[str]):
 def _merge_rebuild_stats(timings: Dict, out: dict):
     """Fold the rebuilder's stats dict into the shell timings: numbers
     sum across volumes, dict-valued breakdowns (per-phase seconds,
-    per-holder fetch/error counts) merge per key."""
+    per-holder fetch/error counts) merge per key, a stage's longest
+    interval stays the longest."""
     for key, val in (out.get("stats") or {}).items():
         if key == "phases" and isinstance(val, dict):
             agg = timings.setdefault("phases", {})
             for ph, secs in val.items():
                 agg[ph] = round(agg.get(ph, 0.0) + secs, 6)
+        elif key == "stage_max_s" and isinstance(val, dict):
+            agg = timings.setdefault("stage_max_s", {})
+            for stage, secs in val.items():
+                agg[stage] = max(agg.get(stage, 0.0), secs)
         elif key in ("holder_fetches", "holder_errors") and \
                 isinstance(val, dict):
             agg = timings.setdefault(key, {})

@@ -4,22 +4,19 @@ The reference wires Go pprof behind -cpuprofile/-memprofile flags
 (reference weed/command/volume.go:71-72, weed/util/pprof.go). The TPU
 build's equivalents:
 
-  * ``cpu_profile(path)`` — cProfile for single-threaded host code
-    (offline tools, kernels).
   * ``SamplingProfiler`` — an all-thread stack sampler for the servers
     (cProfile only sees the calling thread, useless for a threaded
     server): samples ``sys._current_frames()`` on an interval and dumps
     a collapsed-stack report (flamegraph.pl / speedscope compatible).
-    Wired behind ``-cpuprofile`` on the server/benchmark CLIs.
-
-All are no-ops unless explicitly enabled, so they can stay in the
-serving path.
+    Wired behind ``-cpuprofile`` on the server CLIs and
+    ``POST /admin/profile``. Off unless asked for.
+  * ``StageTimer`` — the EC streams' per-stage totals, busy unions and
+    longest intervals; always on.
 """
 
 from __future__ import annotations
 
 import contextlib
-import cProfile
 import threading
 from . import tracing
 from .locks import make_lock
@@ -35,21 +32,6 @@ def mirror_stages_to_profiler():
     tracing.set_stage_mirror(TraceAnnotation)
 
 
-@contextlib.contextmanager
-def cpu_profile(path: Optional[str]):
-    """cProfile the enclosed block into ``path`` (pstats format)."""
-    if not path:
-        yield
-        return
-    prof = cProfile.Profile()
-    prof.enable()
-    try:
-        yield
-    finally:
-        prof.disable()
-        prof.dump_stats(path)
-
-
 class SamplingProfiler:
     """All-thread wall-clock stack sampler.
 
@@ -58,7 +40,9 @@ class SamplingProfiler:
     collapsed stacks. ``stop()`` writes one ``frame;frame;... count``
     line per distinct stack — the folded format flamegraph.pl and
     speedscope ingest directly. Overhead is one GIL-held walk per
-    sample (~10-50us), fine at the default 10ms period.
+    sample (~10-50us), fine at the default 10ms period. A stack says
+    where a thread stands, not whether it holds the interpreter lock or
+    waits for it: that is ``tracing.LockProbe``'s to say.
     """
 
     def __init__(self, path: Optional[str], interval: float = 0.01):
@@ -134,15 +118,17 @@ class StageTimer:
     def __init__(self, root: Optional[tracing.Span] = None):
         self.root = root
         self.totals: Dict[str, float] = {}
+        self.maxes: Dict[str, float] = {}
         self.bytes: Dict[str, int] = {}
         self.intervals: Dict[str, List[Tuple[float, float]]] = {}
-        self._t0 = time.perf_counter()
         self._lock = make_lock("profiling._lock")  # stages report from worker threads
 
     def add(self, stage: str, dt: float, nbytes: int = 0,
             interval: Optional[Tuple[float, float]] = None):
         with self._lock:
             self.totals[stage] = self.totals.get(stage, 0.0) + dt
+            if dt > self.maxes.get(stage, 0.0):
+                self.maxes[stage] = dt
             if nbytes:
                 self.bytes[stage] = self.bytes.get(stage, 0) + nbytes
             if interval is not None:
@@ -165,6 +151,14 @@ class StageTimer:
             self.add(name, st.t1 - st.t0, st.nbytes,
                      interval=(st.t0, st.t1))
 
+    def max_s(self) -> Dict[str, float]:
+        """The longest single interval of each stage: the one slow fetch
+        or drain that a stage's total averages away (a reply's
+        ``stage_max_s``)."""
+        with self._lock:
+            return {stage: round(dt, 6)
+                    for stage, dt in self.maxes.items()}
+
     def busy_time(self, stage: str) -> float:
         """Union length of the stage's intervals (overlaps collapsed)."""
         ivs = sorted(self.intervals.get(stage, []))
@@ -179,23 +173,3 @@ class StageTimer:
         if cur_end is not None:
             total += cur_end - cur_start
         return total
-
-    def rate_mbps(self, stage: str, use_busy: bool = False) -> float:
-        t = self.busy_time(stage) if use_busy else self.totals.get(stage, 0.0)
-        if t <= 0:
-            return 0.0
-        return self.bytes.get(stage, 0) / t / 1e6
-
-    def summary(self) -> str:
-        wall = time.perf_counter() - self._t0
-        parts = [f"wall {wall:.1f}s"]
-        for name in sorted(self.totals):
-            line = f"{name} {self.totals[name]:.1f}s"
-            if name in self.intervals:
-                busy = self.busy_time(name)
-                if abs(busy - self.totals[name]) > 0.05:
-                    line += f" (busy {busy:.1f}s)"
-            if self.bytes.get(name):
-                line += f" @{self.rate_mbps(name, name in self.intervals):.0f}MB/s"
-            parts.append(line)
-        return ", ".join(parts)

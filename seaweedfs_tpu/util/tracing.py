@@ -15,6 +15,11 @@ imports JAX has handed over its annotation (``set_stage_mirror``).
 The five consumer-side phase sums, which interleave across many
 intervals, stay plain seconds materialized with ``record_span``.
 
+What no span of a stage can say is how long a thread that came back
+from a blocking call waited to run again. ``LockProbe`` measures that
+from outside: one thread a process that sleeps ``PROBE_PERIOD`` and
+counts how late it woke (``start_lock_probe`` / ``stop_lock_probe``).
+
 Finished spans fan out three ways (see ``_export``):
 
 * a bounded in-memory ring of recent traces (``RING``), served as JSON
@@ -287,6 +292,88 @@ class Stage:
             "tags": tags,
         })
         return False
+
+
+# the probe's sleep, and the lateness from which a sample is a stall of
+# the whole process (it leaves a `process.stall` span of its own). The
+# host's wake-up latency is inside every sample's lateness and grows with
+# the period (PERF.md section 5 has the idle baseline of this value)
+PROBE_PERIOD = 0.020
+PROBE_STALL = 0.050
+
+
+class LockProbe:
+    """A thread that does nothing but sleep ``PROBE_PERIOD`` and read how
+    late it came back. A thread that returns from ``sleep`` takes the
+    interpreter lock back as a handler returning from a ``recv`` or a
+    ``write`` does, so the lateness is the host's wake-up latency (read
+    it in an idle process) plus that wait: an upper bound of it, since
+    the probe always queues behind whoever holds the lock. It is also
+    what tells a stall: the probe allocates nothing and touches no file,
+    so where the host or a C call that holds the interpreter stopped the
+    process it stops with the others, and where only threads that fault
+    memory in are slow it keeps ticking.
+    ``count(elapsed_s, late_s, stalled)`` takes every sample
+    (``ops/telemetry.STATS.add_probe_sample``: this module imports
+    nothing of the package); a sample later than ``PROBE_STALL`` also
+    leaves the finished span ``process.stall``, a trace of its own in
+    the ring, from when it should have woken. ``clock`` and ``sleep``
+    are the test's to replace."""
+
+    def __init__(self, count: Callable[[float, float, bool], None],
+                 clock: Callable[[], float] = time.perf_counter,
+                 sleep: Callable[[float], None] = time.sleep):
+        self.count = count
+        self.clock = clock
+        self.sleep = sleep
+        self._stop = False
+        self.thread = threading.Thread(target=self._run, daemon=True,
+                                       name="lock-probe")
+
+    def _run(self):
+        while not self._stop:
+            self.sample()
+
+    def sample(self):
+        t = self.clock()
+        self.sleep(PROBE_PERIOD)
+        elapsed = self.clock() - t
+        late = max(elapsed - PROBE_PERIOD, 0.0)
+        stalled = late > PROBE_STALL
+        self.count(elapsed, late, stalled)
+        if stalled:
+            record_span("process.stall", late)
+
+    def stop(self):
+        self._stop = True
+        self.thread.join(timeout=5)
+
+
+_probe: Optional[LockProbe] = None
+_probe_users = 0
+_probe_lock = make_lock("tracing._probe_lock")
+
+
+def start_lock_probe(count: Callable[[float, float, bool], None]):
+    """One more user of the process's probe; the first starts it."""
+    global _probe, _probe_users
+    with _probe_lock:
+        _probe_users += 1
+        if _probe is None:
+            _probe = LockProbe(count)
+            _probe.thread.start()
+
+
+def stop_lock_probe():
+    """One user fewer; the last one's call ends the thread."""
+    global _probe, _probe_users
+    with _probe_lock:
+        _probe_users = max(_probe_users - 1, 0)
+        probe = None
+        if _probe_users == 0:
+            probe, _probe = _probe, None
+    if probe is not None:
+        probe.stop()
 
 
 class TraceRing:

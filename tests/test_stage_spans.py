@@ -169,6 +169,77 @@ def test_shell_orchestration_stage(cycle, name):
     assert got[0]["tags"]["thread"] == threading.current_thread().name
 
 
+# command, the timer's stage, the spans taken over the same intervals
+STAGE_MAXES = [
+    ("encode", "disk_read", ("ec.encode.read",)),
+    ("encode", "d2h+mxu", ("ec.d2h",)),
+    ("encode", "shard_write", ("ec.encode.write",)),
+    ("rebuild", "gather", ("ec.rebuild.fetch.remote",
+                           "ec.rebuild.fetch.local")),
+    ("rebuild", "d2h+mxu", ("ec.d2h",)),
+    ("rebuild", "shard_write", ("ec.rebuild.write",)),
+]
+
+
+@pytest.mark.parametrize("command,stage,names", STAGE_MAXES,
+                         ids=[f"{c}-{s}" for c, s, _ in STAGE_MAXES])
+def test_reply_names_the_longest_interval_of_a_stage(cycle, command, stage,
+                                                     names):
+    """`stage_max_s` beside `phases`: the one slow fetch or drain that a
+    stage's sum averages away, from the interval the span was cut from."""
+    reply = cycle[command]
+    took = [s["duration_s"] for s in cycle["spans"]
+            if s["name"] in names and s["trace_id"] == reply["trace_id"]]
+    assert len(took) >= 2
+    longest = reply["stage_max_s"][stage]
+    assert longest == pytest.approx(max(took), abs=2e-6)
+    assert sum(took) / len(took) <= longest <= sum(took)
+
+
+def test_every_stage_of_a_reply_has_its_longest_interval(cycle):
+    enc, reb = cycle["encode"], cycle["rebuild"]
+    assert {"disk_read", "h2d", "d2h+mxu", "shard_write", "read_wait",
+            "spread"} <= set(enc["stage_max_s"])
+    assert {"gather", "h2d", "d2h+mxu", "shard_write", "read_wait"} \
+        <= set(reb["stage_max_s"])
+    for reply in (enc, reb):
+        assert all(0 < v <= reply["stream_s"] + 1e-3
+                   for v in reply["stage_max_s"].values())
+    # a run's send is no longer than all of them, no shorter than their
+    # mean (the reply rounds the sum to the millisecond)
+    assert enc["spread_send_s"] / enc["spread_sends"] - 1e-3 \
+        <= enc["stage_max_s"]["spread"] <= enc["spread_send_s"] + 1e-3
+    # and `phases`, which the gather shares read, is what it was
+    assert set(enc["phases"]) == {"gather", "dispatch", "drain", "write"}
+    assert set(reb["phases"]) == set(tracing.PHASES)
+
+
+def test_a_holders_appends_are_split_and_counted(cycle):
+    """Every remote run of the encode's spread: `recv_s`, `write_s`,
+    `cpu_s` on the holder's server span, and the `holder_*` counters of
+    the process (all three servers are its) moved by the same."""
+    enc = cycle["encode_counters"]
+    tid = cycle["encode"]["trace_id"]
+    runs = [s for s in _named(cycle, "POST /admin/ec/shard_write")
+            if s["trace_id"] == tid and "bytes" in s["tags"]]
+    assert runs
+    for span in runs:
+        tags = span["tags"]
+        assert tags["recv_s"] > 0 and tags["write_s"] > 0
+        assert tags["recv_s"] + tags["write_s"] <= span["duration_s"]
+        assert 0 <= tags["cpu_s"] <= span["duration_s"]
+    assert enc["holder_runs"] == len(runs)
+    assert enc["holder_bytes"] == sum(s["tags"]["bytes"] for s in runs) \
+        == cycle["encode"]["spread_remote_shards"] \
+        * cycle["encode"]["shard_size"]
+    assert enc["holder_recv_us"] + enc["holder_write_us"] \
+        <= enc["holder_us"] <= 1e6 * sum(s["duration_s"] for s in runs)
+    assert cycle["rebuild_counters"]["holder_runs"] == 0
+    # the probe of the process ticked through both commands
+    assert enc["lock_probe_samples"] > 0
+    assert cycle["rebuild_counters"]["lock_probe_samples"] > 0
+
+
 def test_span_count_is_per_dispatch_not_per_block(cycle):
     enc, reb = cycle["encode_counters"], cycle["rebuild_counters"]
     n_enc, n_reb = enc["dispatches"], reb["dispatches"]
@@ -282,7 +353,12 @@ def test_counters_at_the_stage_boundaries(cycle):
                        "device_byte_share", "geometry_dispatches"} == {
         "dispatches", "bitmat_uploads", "host_fallbacks", "device_bytes",
         "mesh_dispatches", "read_bytes", "read_busy_us", "read_cpu_us",
-        "repair_fallbacks", "coupled_decodes", "slab_fresh_bytes"}
+        "repair_fallbacks", "coupled_decodes", "slab_fresh_bytes",
+        # PR 40: what a thread waits for (tests/test_wait_probe.py)
+        "holder_runs", "holder_bytes", "holder_us", "holder_recv_us",
+        "holder_write_us", "holder_cpu_us", "lock_probe_samples",
+        "lock_probe_elapsed_us", "lock_probe_late_us",
+        "lock_probe_stalls", "lock_probe_stall_us"}
     fetched = sum(s["tags"]["bytes"] for s in cycle["spans"]
                   if s["name"].startswith("ec.rebuild.fetch."))
     assert fetched == cycle["rebuild"]["survivor_bytes"] > 0
