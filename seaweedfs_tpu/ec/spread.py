@@ -49,9 +49,10 @@ class StripedSpreadSink(StripedPush):
     the lanes push the ranges while the encode produces the next stripes.
     ``assignment`` maps shard id -> holder url; shards mapped to
     ``local_url`` (or unmapped) take the local-writer fast path and are
-    staged next to ``base_name``. Everything after writer construction
-    — windows, runs, failover, hedging, pacing, finalize/abort — is
-    ``StripedPush``."""
+    staged next to ``base_name``. ``slab`` is the stripe width of the
+    stream the sink is handed: a lane's window is ``window`` stripes of
+    it, in bytes. Everything after writer construction — windows, runs,
+    failover, hedging, pacing, finalize/abort — is ``StripedPush``."""
 
     def __init__(self, vid: int, base_name: str,
                  assignment: Dict[int, str], total: int,
@@ -61,7 +62,8 @@ class StripedSpreadSink(StripedPush):
                  window: Optional[int] = None,
                  stats: Optional[TransportStats] = None,
                  parent_span=None,
-                 rate_mbps: float = 0.0):
+                 rate_mbps: float = 0.0,
+                 slab: int = 8 << 20):
         from .constants import to_ext
         self.vid = vid
         self.base_name = base_name
@@ -79,4 +81,5 @@ class StripedSpreadSink(StripedPush):
             by_target.setdefault(url or None, []).append(sid)
         super().__init__(writers, by_target, spares=spares,
                          window=window, stats=stats,
-                         parent_span=parent_span, rate_mbps=rate_mbps)
+                         parent_span=parent_span, rate_mbps=rate_mbps,
+                         slab=slab)

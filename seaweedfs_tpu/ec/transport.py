@@ -45,7 +45,6 @@ Straggler defenses (shared):
 from __future__ import annotations
 
 import os
-import queue
 import re
 import threading
 import time
@@ -124,8 +123,9 @@ class SpreadError(Exception):
 # and, since the spread queues views of a slab's rows and not copies of
 # them (PR 30), the stripes its lanes have not had acknowledged: a
 # window in the slowest lane's queue, a window in its hand, the one
-# being routed (19; a lane's window counts stripes of its own shards,
-# so more lanes a target hold no more stripes). A gather: a window (4)
+# being routed (19; a lane's window counts the bytes of its own shards'
+# rows, a window of slab-wide stripes' worth, so neither more lanes a
+# target nor narrower rows hold more). A gather: a window (4)
 # the readers are filling, one in the producer's hand, the read-ahead
 # (3) and depth (4), the one whose rebuilt rows are being appended: 13
 # of the 19.
@@ -953,15 +953,20 @@ class TargetWorker(threading.Thread):
     stage is aborted once its send drains."""
 
     def __init__(self, sink: "StripedPush", target: PushTarget,
-                 lane: int, sids: List[int], window: int):
+                 lane: int, sids: List[int], shard_room: int):
         name = target.url or "local"
         super().__init__(daemon=True, name=f"ec-push-{name}-{lane}")
         self.sink = sink
         self.target = target
         self.lane = lane
         self.sids = list(sids)
-        self.max_batch = max(1, window * len(sids))
-        self.q: queue.Queue = queue.Queue(maxsize=self.max_batch)
+        # the lane's window, in bytes: what it may hold queued, and so
+        # the most a drained batch carries (``shard_room`` a shard)
+        self.room = shard_room * len(sids)
+        self._queued: list = []     # rows and sentinels, in order
+        self._held = 0              # bytes of the rows in _queued
+        self._cv = threading.Condition(
+            make_lock("transport.TargetWorker._cv"))
         self.runs = 0
         self.error: Optional[BaseException] = None
         self._opened = False  # this lane may send: the first run is in
@@ -985,6 +990,32 @@ class TargetWorker(threading.Thread):
         if link is not None:
             link.close()
 
+    def offer(self, item, nbytes: int = 0,
+              timeout: Optional[float] = None) -> bool:
+        """Queue ``item``, a row of ``nbytes`` bytes, once the window
+        has room for it or the lane holds nothing (so no width can
+        deadlock); False when ``timeout`` passed first. A sentinel
+        weighs nothing and is never held up."""
+        with self._cv:
+            if nbytes and not self._cv.wait_for(
+                    lambda: not self._held
+                    or self._held + nbytes <= self.room, timeout):
+                return False
+            self._queued.append(item)
+            self._held += nbytes
+            self._cv.notify_all()
+        return True
+
+    def _drain(self, timeout: float) -> list:
+        """Everything queued, in order, once there is anything (else
+        [] after ``timeout``): a window's bytes at most, by what
+        ``offer`` admits."""
+        with self._cv:
+            self._cv.wait_for(lambda: self._queued, timeout)
+            batch, self._queued, self._held = self._queued, [], 0
+            self._cv.notify_all()
+        return batch
+
     def _wait_turn(self) -> bool:
         """Hold this lane's first run until the target's first run —
         another lane's, unless this one claims it — is acknowledged.
@@ -1000,23 +1031,15 @@ class TargetWorker(threading.Thread):
         try:
             stop = False
             while not stop:
-                try:
-                    item = self.q.get(timeout=0.1)
-                except queue.Empty:
+                batch = self._drain(0.1)
+                if not batch:
                     if self.sink.failed is not None:
                         return
                     continue
-                batch = []
-                while True:
+                for i, item in enumerate(batch):
                     if item is _SENTINEL:
                         stop = True
-                        break
-                    batch.append(item)
-                    if len(batch) >= self.max_batch:
-                        break
-                    try:
-                        item = self.q.get_nowait()
-                    except queue.Empty:
+                        del batch[i:]
                         break
                 if not batch:
                     break
@@ -1171,8 +1194,11 @@ class StripedPush:
     worker thread with a queue and a kept connection of its own, so a
     holder serves a target's runs on as many handler threads and one
     run's file write overlaps another's arrival; the local target is
-    laned the same way. A lane's window is ``window`` stripes of its
-    own shards, so the stripes outstanding do not grow with the lanes.
+    laned the same way. A lane's window is ``window`` stripes of
+    ``slab`` width (the stream's own) of its own shards, counted in
+    bytes: what is outstanding grows neither with the lanes nor where
+    a stream hands its stripes over in narrower pieces (the mesh drains
+    a dispatch a device), and a run is as long there as anywhere.
     Subclasses build the ``writers`` list (one endpoint per shard) and
     the ``by_target`` grouping; everything else — window accounting,
     blocked-time, failover spares, hedging, finalize/abort discipline,
@@ -1193,9 +1219,10 @@ class StripedPush:
                  window: Optional[int] = None,
                  stats: Optional[TransportStats] = None,
                  parent_span=None, hedge_ms: Optional[float] = None,
-                 rate_mbps: float = 0.0):
+                 rate_mbps: float = 0.0, slab: int = 8 << 20):
         self.total = len(writers)
         self.window = max(1, int(window) if window else push_window())
+        self.slab = int(slab)
         self.stats = stats if stats is not None else SpreadStats()
         self.parent_span = parent_span
         self.hedge_s = (default_hedge_ms() if hedge_ms is None
@@ -1227,7 +1254,7 @@ class StripedPush:
             lanes = min(LANES, len(t.sids))
             for lane in range(lanes):
                 w = TargetWorker(self, t, lane, t.sids[lane::lanes],
-                                 self.window)
+                                 self.window * self.slab)
                 self.workers.append(w)
                 for sid in w.sids:
                     self._worker_of[sid] = w
@@ -1273,7 +1300,7 @@ class StripedPush:
         return {sid: (self._target_of[sid].url or "")
                 for sid in range(self.total)}
 
-    def _put(self, worker: TargetWorker, item):
+    def _put(self, worker: TargetWorker, item, nbytes: int = 0):
         t0 = time.perf_counter()
         waited = False
         while True:
@@ -1281,11 +1308,9 @@ class StripedPush:
                 raise SpreadError(
                     f"shard spread failed: {self.failed!r}") \
                     from self.failed
-            try:
-                worker.q.put(item, timeout=0.05)
+            if worker.offer(item, nbytes, timeout=0.05):
                 break
-            except queue.Full:
-                waited = True
+            waited = True
         if waited:
             self.blocked_s += time.perf_counter() - t0
 
@@ -1330,7 +1355,8 @@ class StripedPush:
                 data[sid] if sid < k else parity[sid - k]).cast("B")
             stripe_bytes += len(row)
             self._note_buffered(len(row))
-            self._put(self._worker_of[sid], (sid, off, row, stripe))
+            self._put(self._worker_of[sid], (sid, off, row, stripe),
+                      len(row))
         self.offset = off + w
         with self._lock:
             self.stats.stripes += 1
@@ -1370,10 +1396,7 @@ class StripedPush:
         ``.part`` cleanup on every holder and on the local disk."""
         self._fail(SpreadError("spread aborted"))
         for w in self.workers:
-            try:
-                w.q.put_nowait(_SENTINEL)
-            except queue.Full:
-                pass
+            w.offer(_SENTINEL)
         for w in self.workers:
             w.join(timeout=10.0)
         for sid in range(self.total):

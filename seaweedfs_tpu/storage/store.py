@@ -337,7 +337,7 @@ class Store:
                 vid, base, assignment, total, collection=collection,
                 local_url=self.public_url, spares=spares,
                 window=window, stats=sstats, parent_span=root,
-                rate_mbps=rate_mbps)
+                rate_mbps=rate_mbps, slab=slab)
             try:
                 ec_encoder.write_ec_files_spread(
                     base, sink, codec=codec, slab=slab, stats=stats,

@@ -114,8 +114,8 @@ _knob("SW_EC_HEDGE_MS", "float", 0.0,
       "Hedge a duplicate survivor range read after this many ms; 0 "
       "disables hedging.")
 _knob("SW_EC_SPREAD_WINDOW", "int", 4,
-      "Bounded per-lane send-queue window (stripes) for streaming "
-      "encode spread.")
+      "Bounded per-lane send-queue window for streaming encode "
+      "spread: stripes of the stream's slab width, counted in bytes.")
 _knob("SW_EC_SPREAD_MODE", "str", "stream",
       "ec.encode default transfer mode: stream or copy.")
 _knob("SW_EC_REPAIR_MODE", "str", "auto",

@@ -58,6 +58,7 @@ class FakeTarget:
         self.finalized = 0
         self.aborted = 0
         self.seen = []              # (connection, shard, offset) an append
+        self.sizes = []             # bytes of every append staged
         self._lock = threading.Lock()
         router = Router()
         router.add("POST", "/admin/ec/shard_write", self._shard_write)
@@ -116,6 +117,8 @@ class FakeTarget:
         with open(part, "wb" if off == 0 else "ab") as f:
             f.write(data)
             staged = f.tell()
+        with self._lock:
+            self.sizes.append(len(data))
         return {"volume": vid, "shard": sid, "staged": staged}
 
     def _arrived(self, n_seen):
@@ -184,7 +187,8 @@ def test_stream_vs_copy_bit_identical(tmp_path, backend):
         assignment = {sid: remote.get(sid, LOCAL) for sid in range(k + m)}
         stats = {}
         sink = StripedSpreadSink(1, base, assignment, k + m,
-                                 local_url=LOCAL, window=2)
+                                 local_url=LOCAL, window=2,
+                                 slab=ENC["slab"])
         write_ec_files_spread(base, sink, codec=codec, stats=stats,
                               **ENC)
         # every shard bit-identical to the copy-mode oracle, each at its
@@ -211,12 +215,19 @@ def test_stream_vs_copy_bit_identical(tmp_path, backend):
 
 # -- bounded send window (satellite: memory stays O(window*slab)) ------------
 
-def test_bounded_send_window(tmp_path):
+# The window counts bytes: ``window`` stripes of the sink's ``slab`` a
+# shard. A stream that hands over slab-wide rows (one chip) queues as
+# many rows as the window says; one that hands over quarter-slab rows
+# (the mesh's piece-wise drain) queues four times as many, sends runs
+# as long, and holds no more bytes.
+
+@pytest.mark.parametrize("rows_a_slab", [1, 4])
+def test_bounded_send_window(tmp_path, rows_a_slab):
     k, m, window = 6, 3, 1
     codec = NumpyCodec(k, m)
     src = tmp_path / "src"
     src.mkdir()
-    n_stripes = 10
+    n_stripes = 10 * rows_a_slab
     base, oracle = _seed_oracle(src, codec, k * (16 << 10) * n_stripes)
     tdir = tmp_path / "t"
     tdir.mkdir()
@@ -225,8 +236,10 @@ def test_bounded_send_window(tmp_path):
     try:
         assignment = {sid: tgt.url for sid in range(k + m)}
         stats = {}
+        slab = rows_a_slab * ENC["slab"]    # the sink's; a row is ENC's
         sink = StripedSpreadSink(1, base, assignment, k + m,
-                                 local_url=LOCAL, window=window)
+                                 local_url=LOCAL, window=window,
+                                 slab=slab)
         write_ec_files_spread(base, sink, codec=codec, stats=stats,
                               **ENC)
         for sid in range(k + m):
@@ -234,11 +247,20 @@ def test_bounded_send_window(tmp_path):
                 == oracle[sid]
         # queued + in-hand batch + the stripe being routed — never the
         # whole volume (which is n_stripes windows deep)
-        slab = ENC["slab"]
         assert stats["peak_spread_buffer"] <= \
             (2 * window + 1) * (k + m) * slab
         assert stats["peak_spread_buffer"] < stats["spread_bytes"] // 2
         assert stats["spread_stripes"] == n_stripes
+        # a run is a window of bytes a shard at most: one row where a
+        # row is slab wide (a run a row, as a window of 1 always sent),
+        # up to four where it is a quarter — and so fewer sends
+        rows = (k + m) * n_stripes
+        assert max(tgt.sizes) == window * slab
+        assert stats["spread_sends"] == len(tgt.sizes)
+        if rows_a_slab == 1:
+            assert stats["spread_sends"] == rows
+        else:
+            assert rows // rows_a_slab <= stats["spread_sends"] <= rows // 2
         # a stalled spread shows up as encode-side blocked time, not as
         # phantom encode work: busy encode <= wall
         assert sink.blocked_s > 0
@@ -262,7 +284,8 @@ def test_midstream_failure_leaves_no_partials(tmp_path):
         assignment = {sid: tgt.url if sid in (3, 5) else LOCAL
                       for sid in range(k + m)}
         sink = StripedSpreadSink(1, base, assignment, k + m,
-                                 local_url=LOCAL, window=1)
+                                 local_url=LOCAL, window=1,
+                                 slab=ENC["slab"])
         with pytest.raises(SpreadError):
             write_ec_files_spread(base, sink, codec=codec, **ENC)
         # no finalized shards and no .part stages anywhere — the failed
@@ -295,7 +318,8 @@ def test_failover_reassigns_dead_target(tmp_path):
         stats = {}
         sink = StripedSpreadSink(1, base, assignment, k + m,
                                  local_url=LOCAL,
-                                 spares=[spare.url], window=2)
+                                 spares=[spare.url], window=2,
+                                 slab=ENC["slab"])
         write_ec_files_spread(base, sink, codec=codec, stats=stats,
                               **ENC)
         # the dead target's shards landed complete on the spare, and the
@@ -559,7 +583,8 @@ class CuttingTarget(FakeTarget):
 
 
 def _encode_through(tmp_path, codec, targets, remote, window=2,
-                    spares=None, nbytes=6 * (64 << 10) + 70_001):
+                    spares=None, nbytes=6 * (64 << 10) + 70_001,
+                    slab=ENC["slab"]):
     src = tmp_path / "src"
     src.mkdir(parents=True)
     base, oracle = _seed_oracle(src, codec, nbytes)
@@ -567,7 +592,7 @@ def _encode_through(tmp_path, codec, targets, remote, window=2,
     assignment = {sid: remote.get(sid, LOCAL) for sid in range(total)}
     stats = {}
     sink = StripedSpreadSink(1, base, assignment, total, local_url=LOCAL,
-                             window=window, spares=spares)
+                             window=window, spares=spares, slab=slab)
     write_ec_files_spread(base, sink, codec=codec, stats=stats, **ENC)
     for sid, url in sink.assignment().items():
         holder = targets[url].dir if url else str(src)
@@ -777,9 +802,12 @@ def test_slab_goes_back_only_after_its_last_row_is_acknowledged(
             codec = MeshCodec(6, 3, mesh=make_codec_mesh(width_devices=4),
                               mesh_shard_min_bytes=0,
                               small_dispatch_bytes=0)
-            pieces = _slab_case(tmp_path, monkeypatch, codec)
+            pieces, sizes = _slab_case(tmp_path, monkeypatch, codec)
         # four pieces a dispatch, each a stripe of its own
         assert pieces.count((16 << 10) // 4) >= 4 * 24
+        # ... and a run is as long as a whole stripe's would be: the
+        # window of 1 is a slab of bytes a shard, four pieces
+        assert max(sizes) == 16 << 10 and sizes.count(16 << 10) >= 8
     else:
         # slab 2048: whole windows, every stripe is the reader's slab;
         # 3000: the window re-cut makes the stripes of copies
@@ -843,7 +871,7 @@ def _slab_case(tmp_path, monkeypatch, codec, layout="flat", enc=ENC):
                       for sid in range(k + m)}
         stats = {}
         sink = NotingSink(1, base, assignment, k + m, local_url=LOCAL,
-                          window=1)
+                          window=1, slab=enc["slab"])
         write_ec_files_spread(base, sink, codec=codec, stats=stats,
                               layout=layout, **enc)
         for sid in range(k + m):
@@ -862,7 +890,7 @@ def _slab_case(tmp_path, monkeypatch, codec, layout="flat", enc=ENC):
     finally:
         tgt.stop()
         encoder._SLAB_POOL.clear()
-    return pieces
+    return pieces, tgt.sizes
 
 
 # -- PR 39: a target's shards ride two lanes ----------------------------------
@@ -1032,7 +1060,8 @@ def test_a_failed_lane_finalizes_nothing_and_leaves_no_part(tmp_path,
                RemoteShardWriter(1, 4), LocalShardWriter(local[1])]
     try:
         sink = StripedPush(writers, {None: [0, 5], bad.url: [1, 3],
-                                     good.url: [2, 4]}, window=1)
+                                     good.url: [2, 4]}, window=1,
+                           slab=w)
         rows = np.arange(6 * w, dtype=np.uint8).reshape(6, w)
         # the producer may see the failure before `finish()` does
         with pytest.raises(SpreadError):

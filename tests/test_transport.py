@@ -10,6 +10,7 @@ shard bytes bit-identical through both halves of the transport."""
 
 import hashlib
 import os
+import threading
 import time
 
 import numpy as np
@@ -97,7 +98,8 @@ def test_stall_fails_over_on_both_sides(tmp_path):
         push_stats = transport.SpreadStats()
         sink = StripedSpreadSink(1, pbase, assignment, k + m,
                                  local_url=LOCAL, spares=[spare_t.url],
-                                 window=2, stats=push_stats)
+                                 window=2, stats=push_stats,
+                                 slab=ENC["slab"])
         write_ec_files_spread(pbase, sink, codec=codec, **ENC)
         assert _digest(os.path.join(str(sdir), f"1{to_ext(7)}")) \
             == oracle[7]
@@ -111,7 +113,12 @@ def test_stall_fails_over_on_both_sides(tmp_path):
 
 # -- bounded in-flight window on both sides ----------------------------------
 
-def test_bounded_window_both_sides(tmp_path):
+@pytest.mark.parametrize("rows_a_slab", [1, 4])
+def test_bounded_window_both_sides(tmp_path, rows_a_slab):
+    """``rows_a_slab``: the push side's stream hands its sink rows a
+    slab wide (one chip) or a quarter of it (the mesh's pieces): the
+    window counts bytes, so the bound is the same stripes of slab
+    width."""
     window, k, slab, n_stripes = 2, 4, 8 << 10, 12
 
     class SlowReader:
@@ -137,7 +144,8 @@ def test_bounded_window_both_sides(tmp_path):
     codec = NumpyCodec(k, 2)
     sdir = tmp_path / "src"
     sdir.mkdir()
-    base, _ = _seed_oracle(sdir, codec, k * (16 << 10) * 10)
+    base, oracle = _seed_oracle(sdir, codec,
+                                k * (16 << 10) * 10 * rows_a_slab)
     tdir = tmp_path / "tgt"
     tdir.mkdir()
     tgt = FakeTarget(str(tdir))
@@ -145,15 +153,20 @@ def test_bounded_window_both_sides(tmp_path):
     try:
         assignment = {sid: tgt.url for sid in range(codec.total)}
         push_stats = transport.SpreadStats()
+        push_slab = rows_a_slab * ENC["slab"]
         sink = StripedSpreadSink(1, base, assignment, codec.total,
                                  local_url=LOCAL, window=window,
-                                 stats=push_stats)
+                                 stats=push_stats, slab=push_slab)
         write_ec_files_spread(base, sink, codec=codec, **ENC)
         # queued + in-hand batch + the stripe being routed — never the
         # whole volume
         assert push_stats.peak_buffered <= \
-            (2 * window + 1) * codec.total * ENC["slab"]
+            (2 * window + 1) * codec.total * push_slab
         assert push_stats.peak_buffered < push_stats.bytes // 2
+        assert max(tgt.sizes) <= window * push_slab
+        for sid in range(codec.total):
+            assert _digest(os.path.join(str(tdir), f"1{to_ext(sid)}")) \
+                == oracle[sid]
     finally:
         tgt.stop()
 
@@ -178,7 +191,8 @@ def test_push_hedge_spare_wins(tmp_path, monkeypatch):
         stats = transport.SpreadStats()
         sink = StripedSpreadSink(1, base, assignment, k + m,
                                  local_url=LOCAL, spares=[fast.url],
-                                 window=2, stats=stats)
+                                 window=2, stats=stats,
+                                 slab=ENC["slab"])
         t0 = time.perf_counter()
         write_ec_files_spread(base, sink, codec=codec, **ENC)
         wall = time.perf_counter() - t0
@@ -201,6 +215,183 @@ def test_push_hedge_spare_wins(tmp_path, monkeypatch):
     finally:
         slow.stop()
         fast.stop()
+
+
+# -- PR 41: the push window counts bytes ---------------------------------------
+# A lane admits rows while what it holds queued is under ``window`` stripes
+# of the sink's ``slab`` a shard, whatever the width of the rows it is
+# handed: slab wide (one chip) or a quarter of it (the mesh's pieces).
+
+class GatedWriter(transport.LocalShardWriter):
+    """A local shard whose appends wait at a gate: notes when the first
+    is there and every run's bytes, and fails on request."""
+
+    def __init__(self, path, gate=None):
+        super().__init__(path)
+        self.gate = gate or threading.Event()
+        self.entered = threading.Event()
+        self.fail = False
+        self.runs = []
+
+    def send(self, url, off, chunks, link=None):
+        self.entered.set()
+        assert self.gate.wait(10), "the test never opened the gate"
+        if self.fail:
+            raise transport.SpreadError("injected lane failure")
+        n = super().send(url, off, chunks, link)
+        self.runs.append(n)
+        return n
+
+
+def _gated_sink(tmp_path, total, window, slab):
+    writers = [GatedWriter(str(tmp_path / f"s{to_ext(i)}"))
+               for i in range(total)]
+    stats = transport.SpreadStats()
+    sink = transport.StripedPush(writers, {None: list(range(total))},
+                                 window=window, stats=stats, slab=slab)
+    return writers, stats, sink
+
+
+def _stripes(n, total, w, seed=41):
+    return np.random.default_rng(seed).integers(
+        0, 256, (n, total, w), dtype=np.uint8)
+
+
+def _writer_thread(sink, stripes, k, finish=False):
+    """Route ``stripes`` on a thread of its own: (thread, how many it
+    has routed so far, what it raised)."""
+    routed, raised = [], []
+
+    def run():
+        try:
+            for st in stripes:
+                sink.write_stripe(st[:k], st[k:])
+                routed.append(1)
+            if finish:
+                sink.finish()
+        except BaseException as e:  # noqa: BLE001 - the test reads it
+            raised.append(e)
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    return t, routed, raised
+
+
+def _stalled_at(routed, n):
+    from conftest import wait_until
+    if not wait_until(lambda: len(routed) >= n, timeout=5):
+        return False
+    time.sleep(0.2)             # four of _put's looks at the window
+    return len(routed) == n
+
+
+def _shard_bytes(stripes, sid):
+    return b"".join(st[sid].tobytes() for st in stripes)
+
+
+@pytest.mark.parametrize("rows_a_slab", [1, 4])
+def test_a_lane_admits_a_window_of_bytes(tmp_path, rows_a_slab):
+    """Slab-wide rows: the producer blocks at the stripe it always
+    blocked at (``window`` queued behind the one in the lane's hand).
+    Quarter-slab rows: four times the rows are admitted, the next run
+    is the whole window — ``window`` x ``slab`` bytes of the shard —
+    and the bytes held stay under the same bound."""
+    window, slab = 2, 4096
+    w = slab // rows_a_slab
+    (gw,), stats, sink = _gated_sink(tmp_path, 1, window, slab)
+    stripes = _stripes(6 * window * rows_a_slab, 1, w)
+    sink.write_stripe(stripes[0][:1], stripes[0][1:])
+    assert gw.entered.wait(5)       # the first row is in the lane's hand
+    t, routed, raised = _writer_thread(sink, stripes[1:], 1)
+    assert _stalled_at(routed, window * rows_a_slab)
+    # in hand + a window queued + the row being routed
+    assert sink._buffered == w + window * slab + w
+    gw.gate.set()
+    t.join(5)
+    assert not t.is_alive() and not raised
+    sink.finish()
+    assert gw.runs[:2] == [w, window * slab]
+    assert max(gw.runs) == window * slab
+    assert stats.sends == len(gw.runs) and stats.bytes == stripes.size
+    assert stats.peak_buffered <= (2 * window + 1) * slab
+    assert sink.blocked_s > 0 and sink._buffered == 0
+    with open(gw.path, "rb") as f:
+        assert f.read() == _shard_bytes(stripes, 0)
+
+
+@pytest.mark.parametrize("window", [1, 2])
+def test_a_row_wider_than_the_window_passes_an_empty_lane(tmp_path,
+                                                          window):
+    """No width can deadlock: a row that no window has room for is
+    admitted when the lane holds nothing, one at a time."""
+    slab = 4096
+    w = window * slab + slab // 2
+    (gw,), stats, sink = _gated_sink(tmp_path, 1, window, slab)
+    stripes = _stripes(5, 1, w)
+    sink.write_stripe(stripes[0][:1], stripes[0][1:])
+    assert gw.entered.wait(5)
+    t, routed, raised = _writer_thread(sink, stripes[1:], 1, finish=True)
+    # the second is queued behind the one in hand, the third waits
+    assert _stalled_at(routed, 1)
+    gw.gate.set()
+    t.join(5)
+    assert not t.is_alive() and not raised
+    assert gw.runs == [w] * 5 and stats.peak_buffered == 3 * w
+    with open(gw.path, "rb") as f:
+        assert f.read() == _shard_bytes(stripes, 0)
+
+
+@pytest.mark.parametrize("rows_a_slab", [1, 4])
+@pytest.mark.parametrize("what", ["finish", "abort", "failure"])
+def test_full_windows_hold_up_no_ending(tmp_path, what, rows_a_slab):
+    """Every lane's window full to the byte and every holder stuck:
+    ``finish()`` queues its sentinels behind them and returns as soon
+    as the holders move, ``abort()`` likewise, and a producer waiting
+    at a full lane hears of another lane's failure at its next look."""
+    window, slab, total = 1, 4096, 2    # a shard a holder and lane
+    w = slab // rows_a_slab
+    gates = [threading.Event(), threading.Event()]
+    writers = [GatedWriter(str(tmp_path / f"s{to_ext(i)}"), gates[i])
+               for i in range(total)]
+    stats = transport.SpreadStats()
+    sink = transport.StripedPush(writers, {"holder-a": [0],
+                                           "holder-b": [1]},
+                                 window=window, stats=stats, slab=slab)
+    stripes = _stripes(2 + window * rows_a_slab, total, w)
+    sink.write_stripe(stripes[0][:1], stripes[0][1:])
+    assert all(gw.entered.wait(5) for gw in writers)
+    for st in stripes[1:-1]:
+        sink.write_stripe(st[:1], st[1:])
+    assert sink.blocked_s == 0          # all of it fitted, to the byte
+    assert [lane._held for lane in sink.workers] == [lane.room for lane
+                                                     in sink.workers]
+    opener = threading.Timer(0.3, lambda: [g.set() for g in gates])
+    t0 = time.perf_counter()
+    if what == "finish":
+        opener.start()
+        sink.finish()
+        for i, gw in enumerate(writers):
+            assert gw.runs == [w, window * slab]
+            with open(gw.path, "rb") as f:
+                assert f.read() == _shard_bytes(stripes[:-1], i)
+    elif what == "abort":
+        opener.start()
+        sink.abort()
+        assert os.listdir(str(tmp_path)) == []
+    else:
+        t, routed, raised = _writer_thread(sink, stripes[-1:], 1)
+        assert _stalled_at(routed, 0) and t.is_alive()
+        writers[1].fail = True
+        gates[1].set()                  # lane 1 fails; lane 0 stays stuck
+        t.join(2)
+        assert not t.is_alive() and not routed
+        assert isinstance(raised[0], transport.SpreadError)
+        assert not gates[0].is_set()
+        opener.start()
+        sink.abort()
+        assert os.listdir(str(tmp_path)) == []
+    assert time.perf_counter() - t0 < 3.0
+    assert not [lane for lane in sink.workers if lane.is_alive()]
 
 
 # -- producer pacing: the tier demotion's MB/s cap ---------------------------
@@ -349,7 +540,8 @@ def test_push_over_tls_real_holders(tmp_path):
                       for sid in range(k + m)}
         stats = {}
         sink = StripedSpreadSink(1, base, assignment, k + m,
-                                 local_url=LOCAL, window=2)
+                                 local_url=LOCAL, window=2,
+                                 slab=ENC["slab"])
         write_ec_files_spread(base, sink, codec=codec, stats=stats,
                               **ENC)
         hdir = holder.store.locations[0].directory
