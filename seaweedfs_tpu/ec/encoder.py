@@ -56,10 +56,27 @@ def volume_codec(base_name: str) -> ReedSolomonCodec:
     return get_codec(*volume_geometry(base_name))
 
 
-def write_sorted_file_from_idx(base_name: str, ext: str = ".ecx"):
+def write_sorted_file_from_idx(base_name: str, ext: str = ".ecx",
+                               timer: Optional[StageTimer] = None):
     """Build the sorted EC index next to the volume files. Record width
     follows the volume's offset width (superblock flag; 5-byte-offset
-    volumes have 17B .idx/.ecx records)."""
+    volumes have 17B .idx/.ecx records).
+
+    One Python iteration an entry to parse, one to sort and pack: on a
+    volume of small needles it is a stage of its own, so a stream's
+    ``timer`` takes it as ``index`` (span ``ec.encode.index`` under the
+    timer's root, tagged with what it read and wrote) and the process
+    counts it (ops/telemetry ``index_entries``, ``index_us``) whoever
+    called."""
+    from ..ops import telemetry
+    stage = (timer or StageTimer()).stage("index", span="ec.encode.index")
+    with stage as st:
+        db, st.nbytes = _sorted_file_from_idx(base_name, ext)
+        st.tags["entries"], st.tags["tombstones"] = len(db), db.tombstones
+    telemetry.STATS.add_index(len(db), st.t1 - st.t0)
+
+
+def _sorted_file_from_idx(base_name: str, ext: str):
     width = 4
     try:
         from ..storage.super_block import SUPER_BLOCK_SIZE, SuperBlock
@@ -69,7 +86,7 @@ def write_sorted_file_from_idx(base_name: str, ext: str = ".ecx"):
     except Exception:  # noqa: BLE001 - no/short .dat: default width
         pass
     db = MemDb.load_from_idx(base_name + ".idx", width)
-    db.save_to_idx(base_name + ext)
+    return db, db.save_to_idx(base_name + ext)
 
 
 def _dispatch_plan(dat_size: int, k: int, large_block: int, small_block: int,
@@ -415,7 +432,8 @@ def write_ec_files_spread(base_name: str, sink,
                           slab: int = DEFAULT_SLAB,
                           pipelined: Optional[bool] = None,
                           stats: Optional[dict] = None,
-                          layout: str = "flat"):
+                          layout: str = "flat",
+                          index: bool = False):
     """Streaming encode+spread: tee write_ec_files' stripe stream into
     ``sink`` (an ec.spread.StripedSpreadSink) so each shard's slab
     ranges reach its holder while later slabs are still encoding —
@@ -426,6 +444,11 @@ def write_ec_files_spread(base_name: str, sink,
     On ANY failure the sink is aborted (``.part`` cleanup on every
     holder) before the exception propagates — callers either get a
     complete finalized shard set or nothing.
+
+    ``index`` builds the volume's ``.ecx`` from its ``.idx`` first, as
+    the stage ``index`` of this stream's account (``phases.index``,
+    ``stage_max_s.index``, span ``ec.encode.index``); the stream's own
+    wall (``stream_s``, ``encode_busy_s``) starts after it.
 
     ``stats``, when given, is filled with the spread counters plus
     ``encode_busy_s`` / ``spread_busy_s`` / ``overlap_frac`` — the
@@ -438,8 +461,10 @@ def write_ec_files_spread(base_name: str, sink,
     # the stream's root span (ec.encode.stream, current here): the
     # reader, drain and write stages hang under it as real spans
     timer = StageTimer(root=tracing.current_span())
-    t_stream = time.perf_counter()
     try:
+        if index:
+            write_sorted_file_from_idx(base_name, timer=timer)
+        t_stream = time.perf_counter()
         operand = write_ec_files(
             base_name, codec=codec, large_block=large_block,
             small_block=small_block, slab=slab, pipelined=pipelined,
@@ -461,6 +486,9 @@ def write_ec_files_spread(base_name: str, sink,
         stats["shards"] = codec.total
         stats["phases"] = {n: round(s, 6) for n, s in
                            _phases_from_timer(timer, pipelined).items()}
+        if index:
+            # before the stream, on the thread that then consumes it
+            stats["phases"]["index"] = round(timer.totals["index"], 6)
         stats["stage_max_s"] = {**timer.max_s(), **ss.timer.max_s()}
         # encode busy = stream wall minus the time the consumer spent
         # blocked on full send windows; spread busy = the union of send

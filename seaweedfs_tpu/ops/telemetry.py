@@ -43,6 +43,13 @@ two sites `dispatches` is counted: a call site that fell back to the
 default geometry's codec for a volume of another is bit-wrong or slow
 and shows nowhere else than in this map.
 
+`index_entries` and `index_us` count the `.ecx` builds
+(ec/encoder.write_sorted_file_from_idx): the live entries written and
+the microseconds from the first `.idx` record read to the last `.ecx`
+record written. A volume of 1 MiB needles has a thousand entries and
+the build shows nowhere; one of 4 KB needles has thirty thousand, built
+an entry a Python iteration on the thread that then runs the stream.
+
 `slab_fresh_bytes` counts the bytes of stripe-sized host blocks that
 were new memory (ec/transport._take_slab found its pool empty, or
 holding nothing large enough): what these hosts charge for is memory a
@@ -65,7 +72,7 @@ class DispatchStats:
                "device_bytes", "mesh_dispatches",
                "read_bytes", "read_busy_us", "read_cpu_us",
                "repair_fallbacks", "coupled_decodes",
-               "slab_fresh_bytes",
+               "slab_fresh_bytes", "index_entries", "index_us",
                "holder_runs", "holder_bytes", "holder_us",
                "holder_recv_us", "holder_write_us", "holder_cpu_us",
                "lock_probe_samples", "lock_probe_elapsed_us",
@@ -100,6 +107,12 @@ class DispatchStats:
             self.read_bytes += nbytes
             self.read_busy_us += int(busy_s * 1e6)
             self.read_cpu_us += int(cpu_s * 1e6)
+
+    def add_index(self, entries: int, wall_s: float):
+        """One `.ecx` built from a volume's `.idx`."""
+        with self._lock:
+            self.index_entries += entries
+            self.index_us += int(wall_s * 1e6)
 
     def add_holder_run(self, nbytes: int, wall_s: float, recv_s: float,
                        write_s: float, cpu_s: float):

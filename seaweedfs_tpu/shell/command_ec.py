@@ -19,6 +19,27 @@ def _free_nodes(env: CommandEnv) -> List[dict]:
     return sorted(env.cluster_nodes(), key=lambda n: -n.get("free", 0))
 
 
+def pick_rebuilder(nodes: List[dict], shards: Dict[int, List[str]]) -> str:
+    """The node that rebuilds a volume's lost shards: among the nodes
+    with a free slot, the one that holds fewest of THIS volume's shards,
+    then the freest (reference command_ec_rebuild.go picks by free slots
+    alone and leaves the stacking to ec.balance). The free count is the
+    whole server's: after a holder's loss a command rebuilds volume
+    after volume onto the emptied server, which fills as it goes, and
+    where the freest node then is one that already holds m of a volume's
+    shards the rebuilt ones stacked on it put it above m — a second
+    loss the volume does not survive. ``nodes`` as the master lists
+    them (`/cluster/status`), ``shards`` the volume's survivors by
+    holder (`/cluster/ec_status`)."""
+    held: Dict[str, int] = {}
+    for urls in shards.values():
+        for url in urls:
+            held[url] = held.get(url, 0) + 1
+    with_room = [n for n in nodes if n.get("free", 0) > 0] or nodes
+    return min(with_room, key=lambda n: (held.get(n["url"], 0),
+                                         -n.get("free", 0)))["url"]
+
+
 def _volume_replicas(env: CommandEnv, vid: int) -> List[dict]:
     return env.all_volumes().get(str(vid), [])
 
@@ -65,16 +86,18 @@ def balanced_ec_distribution(nodes: List[dict],
 def collect_volume_ids_for_ec_encode(env: CommandEnv, collection: str,
                                      full_percent: float = 0.95,
                                      quiet_seconds: float = 3600,
-                                     size_limit: int = None) -> List[int]:
-    """Quiet & nearly-full volumes (reference
-    collectVolumeIdsForEcEncode command_ec_encode.go:255-287)."""
+                                     size_limit: int = None
+                                     ) -> Dict[int, int]:
+    """Quiet & nearly-full volumes and their sizes, by volume id
+    (reference collectVolumeIdsForEcEncode
+    command_ec_encode.go:255-287)."""
     import time
     if size_limit is None:
         status = env.master_get("/dir/status")
         size_limit = status.get("volumeSizeLimit") \
             or 30 * 1024 * 1024 * 1024
     now = time.time()
-    out = []
+    out = {}
     for vid_s, replicas in env.all_volumes().items():
         vi = replicas[0]
         if vi.get("collection", "") != collection:
@@ -84,7 +107,7 @@ def collect_volume_ids_for_ec_encode(env: CommandEnv, collection: str,
         modified = vi.get("modified_at", 0)
         if modified and now - modified < quiet_seconds:
             continue
-        out.append(int(vid_s))
+        out[int(vid_s)] = int(vi.get("size", 0))
     return out
 
 
@@ -98,27 +121,39 @@ def collect_volume_ids_for_ec_encode(env: CommandEnv, collection: str,
          "stream = push shard ranges to holders while later slabs "
          "encode; copy = legacy generate-then-pull)")
 def ec_encode(env: CommandEnv, args: List[str]):
+    from ..util import tracing
     flags = parse_flags(args)
-    if "volumeId" in flags:
-        vids = [int(flags["volumeId"])]
-    elif "collection" in flags:
-        vids = collect_volume_ids_for_ec_encode(
-            env, flags["collection"], float(flags.get("fullPercent", 0.95)),
-            quiet_seconds=float(flags.get("quietFor", 3600)))
-    else:
-        env.write("usage: ec.encode -volumeId <id> | -collection <name>")
-        return
     geometry = None
     if "geometry" in flags:
         from ..ec.layout import parse_geometry
         geometry = parse_geometry(flags["geometry"])
-    for vid in vids:
-        do_ec_encode(env, vid, mode=flags.get("mode"), geometry=geometry)
+    if "volumeId" in flags:
+        do_ec_encode(env, int(flags["volumeId"]), mode=flags.get("mode"),
+                     geometry=geometry)
+        return
+    if "collection" not in flags:
+        env.write("usage: ec.encode -volumeId <id> | -collection <name>")
+        return
+    sizes = collect_volume_ids_for_ec_encode(
+        env, flags["collection"], float(flags.get("fullPercent", 0.95)),
+        quiet_seconds=float(flags.get("quietFor", 3600)))
+    # the whole command under one span of its own trace; each volume's
+    # ec.encode stays the root of its own and names this one
+    whole = tracing.Span("ec.encode.collection", tags={
+        "collection": flags["collection"], "volumes": 0, "bytes": 0})
+    try:
+        for vid, size in sizes.items():
+            do_ec_encode(env, vid, mode=flags.get("mode"),
+                         geometry=geometry, command=whole.trace_id)
+            whole.tags["volumes"] += 1
+            whole.tags["bytes"] += size
+    finally:
+        tracing.finish_span(whole)
 
 
 def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
                  timings: Dict = None, rate_mbps: float = 0.0,
-                 geometry: tuple = None):
+                 geometry: tuple = None, command: str = None):
     """Freeze -> encode+spread -> mount -> drop originals.
 
     mode: "stream" (default; `SW_EC_SPREAD_MODE` overrides) sends the
@@ -139,7 +174,8 @@ def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
     > 0 paces the streaming spread (the tierer's background cap);
     copy mode ignores it. ``geometry`` (k, m) is the new EC volume's RS
     code, passed on to the source's ``/admin/ec/generate``; None leaves
-    the node at its default, 10 + 4."""
+    the node at its default, 10 + 4. ``command`` is the trace id of the
+    `ec.encode -collection` span this volume is one of, kept as a tag."""
     from ..util import config as _config
     from ..util import tracing
     mode = (mode or _config.env_str("SW_EC_SPREAD_MODE") or
@@ -151,6 +187,8 @@ def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
     collection = replicas[0].get("collection", "")
     source = replicas[0]["url"]
     root = tracing.start_span("ec.encode", volume=vid, mode=mode)
+    if command:
+        root.tags["command"] = command
     if timings is not None:
         timings["mode"] = mode
     try:
@@ -363,24 +401,37 @@ def _encode_spread_copy(env: CommandEnv, vid: int, collection: str,
          "half-shard planes on piggyback-layout volumes, full pulls k "
          "whole ranges, auto picks by the volume's layout)")
 def ec_rebuild(env: CommandEnv, args: List[str]):
+    from ..util import tracing
     flags = parse_flags(args)
-    for vid_s, info in env.ec_volumes().items():
-        vid = int(vid_s)
-        collection = info.get("collection", "")
-        if "collection" in flags and collection != flags["collection"]:
-            continue
-        shards = {int(s): urls for s, urls in info["shards"].items()}
-        k, m = _geometry_of(info)
-        missing = [s for s in range(k + m) if s not in shards]
-        if not missing:
-            continue
-        if len(shards) < k:
-            env.write(f"volume {vid}: only {len(shards)} shards left, "
-                      f"cannot rebuild")
-            continue
-        do_ec_rebuild(env, vid, collection, shards, missing,
-                      mode=flags.get("mode"),
-                      repair=flags.get("repair"))
+    # the whole command under one span of its own trace; each volume's
+    # ec.rebuild stays the root of its own and names this one
+    whole = tracing.Span("ec.rebuild.collection", tags={
+        "collection": flags.get("collection", ""), "volumes": 0,
+        "bytes": 0})
+    try:
+        for vid_s, info in env.ec_volumes().items():
+            vid = int(vid_s)
+            collection = info.get("collection", "")
+            if "collection" in flags and collection != flags["collection"]:
+                continue
+            shards = {int(s): urls for s, urls in info["shards"].items()}
+            k, m = _geometry_of(info)
+            missing = [s for s in range(k + m) if s not in shards]
+            if not missing:
+                continue
+            if len(shards) < k:
+                env.write(f"volume {vid}: only {len(shards)} shards left, "
+                          f"cannot rebuild")
+                continue
+            timings: Dict = {}
+            do_ec_rebuild(env, vid, collection, shards, missing,
+                          timings=timings, mode=flags.get("mode"),
+                          repair=flags.get("repair"),
+                          command=whole.trace_id)
+            whole.tags["volumes"] += 1
+            whole.tags["bytes"] += timings.get("rebuilt_bytes", 0)
+    finally:
+        tracing.finish_span(whole)
 
 
 def _merge_rebuild_stats(timings: Dict, out: dict):
@@ -413,7 +464,7 @@ def _merge_rebuild_stats(timings: Dict, out: dict):
 def do_ec_rebuild(env: CommandEnv, vid: int, collection: str,
                   shards: Dict[int, List[str]], missing: List[int],
                   timings: Dict[str, float] = None, mode: str = None,
-                  repair: str = None):
+                  repair: str = None, command: str = None):
     """`timings`, when given, records the phase walls plus the
     rebuilder's stats (gather/compute busy time, overlap_frac, dispatch
     telemetry) — the benchmark's overlap accounting.
@@ -431,7 +482,8 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str,
     survivors) on flat volumes, plane repair (half-shard planes from
     k+1 helpers) on piggyback volumes. "trace"/"piggyback" force the
     matching strategy and error on the other layout; "full" forces the
-    k-survivor gather on either. Stream mode only."""
+    k-survivor gather on either. Stream mode only. ``command`` is the
+    trace id of the `ec.rebuild` command's span, kept as a tag."""
     from ..util import config as _config
     from ..util import tracing
     mode = (mode or _config.env_str("SW_EC_GATHER_MODE") or
@@ -442,10 +494,10 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str,
     # rebuild, mount — carries its traceparent: ONE trace per operation
     root = tracing.start_span("ec.rebuild", volume=vid, mode=mode,
                               repair=repair)
+    if command:
+        root.tags["command"] = command
     try:
-        # pick the node with most free slots as rebuilder (reference
-        # command_ec_rebuild.go: pick by free slot count)
-        rebuilder = _free_nodes(env)[0]["url"]
+        rebuilder = pick_rebuilder(env.cluster_nodes(), shards)
         if mode == "copy":
             rebuilt = _rebuild_via_copy(env, vid, collection, shards,
                                         rebuilder, root, timings)

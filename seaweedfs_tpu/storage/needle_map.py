@@ -158,6 +158,11 @@ class MemDb:
     def __init__(self, offset_width: int = OFFSET_SIZE):
         self._m: dict = {}
         self.offset_width = offset_width
+        # .idx records load_from_idx dropped: deletes and zero offsets
+        self.tombstones = 0
+
+    def __len__(self) -> int:
+        return len(self._m)
 
     def set(self, nid: int, offset: int, size: int):
         self._m[nid] = (offset, size)
@@ -182,13 +187,16 @@ class MemDb:
                 db.set(nid, offset, size)
             else:
                 db.delete(nid)
+                db.tombstones += 1
         return db
 
-    def save_to_idx(self, path: str):
+    def save_to_idx(self, path: str) -> int:
+        """Write the live entries ascending by key; returns the bytes."""
         with open(path, "wb") as f:
             for nid, offset, size in self.ascending_visit():
                 f.write(entry_to_bytes(nid, offset, size,
                                        self.offset_width))
+            return f.tell()
 
 
 def walk_index_file(idx_path: str, offset_width: int = OFFSET_SIZE):

@@ -332,7 +332,6 @@ class Store:
         layout, pplan, pb_window = self._encode_layout(codec)
         with tracing.span("ec.encode.stream", volume=vid,
                           layout=layout, k=codec.k, m=codec.m) as root:
-            ec_encoder.write_sorted_file_from_idx(base)
             sink = spread.StripedSpreadSink(
                 vid, base, assignment, total, collection=collection,
                 local_url=self.public_url, spares=spares,
@@ -341,7 +340,7 @@ class Store:
             try:
                 ec_encoder.write_ec_files_spread(
                     base, sink, codec=codec, slab=slab, stats=stats,
-                    layout=layout)
+                    layout=layout, index=True)
             except BaseException:
                 # the sink already aborted every holder's stage; drop
                 # anything the local fast path finalized plus the index
@@ -497,7 +496,13 @@ class Store:
         base = volume_file_prefix(loc.directory, collection, vid)
         with tracing.span("ec.rebuild.stream", volume=vid) as root:
             if holders:
-                gather.fetch_index_files(base, holders)
+                # the sidecars this rebuilder lacks (.ecx by the entry,
+                # so by the needle: 0.5 MB for a volume of 4 KB needles)
+                with tracing.Stage("ec.rebuild.index", root) as pulled:
+                    pulled.tags["files"] = gather.fetch_index_files(
+                        base, holders)
+                    pulled.nbytes = sum(os.path.getsize(base + ext)
+                                        for ext in pulled.tags["files"])
             # the .vif is local now (fetched above when remote): the
             # volume's own geometry, and the codec of that geometry
             codec = self.volume_codec(base)

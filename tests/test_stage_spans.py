@@ -209,8 +209,12 @@ def test_every_stage_of_a_reply_has_its_longest_interval(cycle):
     # mean (the reply rounds the sum to the millisecond)
     assert enc["spread_send_s"] / enc["spread_sends"] - 1e-3 \
         <= enc["stage_max_s"]["spread"] <= enc["spread_send_s"] + 1e-3
-    # and `phases`, which the gather shares read, is what it was
-    assert set(enc["phases"]) == {"gather", "dispatch", "drain", "write"}
+    # and `phases`, which the gather shares read, is what it was, with
+    # the `.ecx` build before the stream as a phase of its own (PR 42)
+    assert set(enc["phases"]) == {"gather", "dispatch", "drain", "write",
+                                  "index"}
+    assert enc["phases"]["index"] == pytest.approx(
+        enc["stage_max_s"]["index"], abs=2e-6)
     assert set(reb["phases"]) == set(tracing.PHASES)
 
 
@@ -322,13 +326,16 @@ def test_phases_keep_their_keys_and_their_sum(cycle):
     writes = sum(s["duration_s"] for s in _named(cycle, "ec.rebuild.write"))
     assert reb["phases"]["write"] >= writes * 0.99
     enc = cycle["encode"]
-    assert set(enc["phases"]) == {"gather", "dispatch", "drain", "write"}
+    assert set(enc["phases"]) == {"gather", "dispatch", "drain", "write",
+                                  "index"}
     writes = sum(s["duration_s"] for s in _named(cycle, "ec.encode.write"))
     # (the reply rounds a phase to the microsecond, and since PR 30 a
     # stripe's write is queueing 14 views: tens of microseconds)
     assert enc["phases"]["write"] == pytest.approx(writes, rel=1e-3,
                                                    abs=1e-6)
-    assert sum(enc["phases"].values()) <= enc["stream_s"] * 1.01
+    # (the .ecx build comes before the stream: `stream_s` starts after it)
+    assert sum(enc["phases"].values()) - enc["phases"]["index"] \
+        <= enc["stream_s"] * 1.01
     # the phase spans themselves are still there, one of each a command
     tid = enc["trace_id"]
     for phase in ("gather", "dispatch", "write"):
@@ -354,6 +361,8 @@ def test_counters_at_the_stage_boundaries(cycle):
         "dispatches", "bitmat_uploads", "host_fallbacks", "device_bytes",
         "mesh_dispatches", "read_bytes", "read_busy_us", "read_cpu_us",
         "repair_fallbacks", "coupled_decodes", "slab_fresh_bytes",
+        # PR 42: the .ecx build (tests/test_collection_served.py)
+        "index_entries", "index_us",
         # PR 40: what a thread waits for (tests/test_wait_probe.py)
         "holder_runs", "holder_bytes", "holder_us", "holder_recv_us",
         "holder_write_us", "holder_cpu_us", "lock_probe_samples",

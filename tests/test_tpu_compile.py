@@ -99,6 +99,24 @@ def test_fused_decode_and_syndrome_compile(one_chip, k, r):
     _compile_fused(one_chip, k, r, MIB)
 
 
+@pytest.mark.parametrize("r", [4, 3], ids=["encode-or-4-lost", "3-lost"])
+def test_fused_compiles_at_a_small_volumes_stripe_width(one_chip, r):
+    """A 123 MiB volume of small needles (BASELINE config 3) has 13 MiB
+    shards, which `ec/gather.auto_slab` cuts into four stripes of
+    3.25 MiB: the encode's three-row dispatches are padded to that width
+    (`width_bucket` caps the 4 MiB bucket at the stream's slab) and the
+    rebuild's stripes have it. Not a power of two, and no multiple of
+    the tile `pick_tile` gives it."""
+    from seaweedfs_tpu.ec.gather import auto_slab
+    from seaweedfs_tpu.ops.rs_pallas import pick_tile
+    from seaweedfs_tpu.ops.rs_tpu import width_bucket
+    n = auto_slab(13 * MIB)
+    assert n == 13 * MIB // 4 == 3407872
+    assert width_bucket(3 * MIB, n) == n and width_bucket(MIB, n) == MIB
+    assert n % pick_tile(10, r, n) != 0
+    _compile_fused(one_chip, 10, r, n)
+
+
 def test_fused_piggyback_encode_matrix_compiles(one_chip):
     """The piggyback layout's sub-chunk encode matrix is (m*alpha,
     k*alpha) = (128, 320): the widest contraction the kernel serves, at
