@@ -50,6 +50,16 @@ record written. A volume of 1 MiB needles has a thousand entries and
 the build shows nowhere; one of 4 KB needles has thirty thousand, built
 an entry a Python iteration on the thread that then runs the stream.
 
+`mirror_entries` and `mirror_us` count the indexes a holder loads:
+the records of a `.idx` replayed into a needle map
+(storage/needle_map.NeedleMap.load: every volume load, mount and
+freeze) and the entries pushed into the native plane's mirrors
+(server/native_plane: a volume's live set, an EC volume's `.ecx`), with
+the wall microseconds of each load. They are read as arrays
+(storage/idx_array); `mirror_loop_entries` counts those that still went
+an entry a Python iteration, which a map that offers no columns (the
+compact, sorted-file and disk maps under the plane) costs.
+
 `slab_fresh_bytes` counts the bytes of stripe-sized host blocks that
 were new memory (ec/transport._take_slab found its pool empty, or
 holding nothing large enough): what these hosts charge for is memory a
@@ -77,7 +87,8 @@ class DispatchStats:
                "holder_recv_us", "holder_write_us", "holder_cpu_us",
                "lock_probe_samples", "lock_probe_elapsed_us",
                "lock_probe_late_us", "lock_probe_stalls",
-               "lock_probe_stall_us")
+               "lock_probe_stall_us",
+               "mirror_entries", "mirror_us", "mirror_loop_entries")
     REPAIR_ROUTES = ("piggyback", "trace", "full")
 
     def __init__(self):
@@ -113,6 +124,14 @@ class DispatchStats:
         with self._lock:
             self.index_entries += entries
             self.index_us += int(wall_s * 1e6)
+
+    def add_mirror(self, entries: int, wall_s: float,
+                   loop_entries: int = 0):
+        """One index loaded into a needle map or a plane's mirror."""
+        with self._lock:
+            self.mirror_entries += entries
+            self.mirror_us += int(wall_s * 1e6)
+            self.mirror_loop_entries += loop_entries
 
     def add_holder_run(self, nbytes: int, wall_s: float, recv_s: float,
                        write_s: float, cpu_s: float):

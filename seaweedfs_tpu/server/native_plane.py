@@ -22,6 +22,8 @@ import ctypes
 import json
 import os
 import threading
+import time
+from ..ops import telemetry
 from ..util import config
 from ..util.locks import make_lock
 from typing import Optional
@@ -306,36 +308,53 @@ class NativeReadPlane:
             h, volume.id, volume.dat_path.encode(), volume.version)
         if rc != 0:
             return False
-        from ..storage.compact_map import snapshot_live_items
-        with volume.lock:
-            entries = snapshot_live_items(volume.nm)
-        with entries:
-            return self._bulk_load(volume, entries)
+        t0 = time.perf_counter()
+        live_columns = getattr(volume.nm, "live_columns", None)
+        looped = 0
+        if live_columns is not None:
+            with volume.lock:
+                keys, offsets, sizes = live_columns()
+            pushed = self._put_columns(self._lib.swhp_put_bulk, volume.id,
+                                       keys, offsets, sizes)
+        else:
+            from ..storage.compact_map import snapshot_live_items
+            with volume.lock:
+                entries = snapshot_live_items(volume.nm)
+            with entries:
+                pushed = looped = self._bulk_load(volume, entries)
+        telemetry.STATS.add_mirror(pushed, time.perf_counter() - t0, looped)
+        return True
 
-    def _bulk_load(self, volume, entries) -> bool:
+    def _put_columns(self, put_bulk, vid: int, keys, offsets, sizes) -> int:
+        """Hand a mirror its entries as three native arrays (uint64
+        keys, uint64 byte offsets, uint32 sizes), at most 2^20 a call."""
         import numpy as np
+        for lo in range(0, len(keys), 1 << 20):
+            chunk = slice(lo, lo + (1 << 20))
+            ka = np.ascontiguousarray(keys[chunk], np.uint64)
+            oa = np.ascontiguousarray(offsets[chunk], np.uint64)
+            sa = np.ascontiguousarray(sizes[chunk], np.uint32)
+            put_bulk(self._h, vid,
+                     ka.ctypes.data_as(ctypes.c_void_p),
+                     oa.ctypes.data_as(ctypes.c_void_p),
+                     sa.ctypes.data_as(ctypes.c_void_p), len(ka))
+        return len(keys)
 
-        def put_chunk(keys, offsets, sizes):
-            ka = np.asarray(keys, dtype=np.uint64)
-            oa = np.asarray(offsets, dtype=np.uint64)
-            sa = np.asarray(sizes, dtype=np.uint32)
-            self._lib.swhp_put_bulk(
-                self._h, volume.id,
-                ka.ctypes.data_as(ctypes.c_void_p),
-                oa.ctypes.data_as(ctypes.c_void_p),
-                sa.ctypes.data_as(ctypes.c_void_p), len(keys))
-
+    def _bulk_load(self, volume, entries) -> int:
+        """A map that offers no columns (compact, sorted-file, disk):
+        its snapshot an entry an iteration, staged in bounded lists."""
         keys, offsets, sizes = [], [], []
+        pushed = 0
         for key, nv in entries:
             keys.append(key)
             offsets.append(nv.offset)
             sizes.append(nv.size)
-            if len(keys) >= (1 << 20):   # bound the staging lists
-                put_chunk(keys, offsets, sizes)
+            if len(keys) >= (1 << 20):
+                pushed += self._put_columns(
+                    self._lib.swhp_put_bulk, volume.id, keys, offsets, sizes)
                 keys, offsets, sizes = [], [], []
-        if keys:
-            put_chunk(keys, offsets, sizes)
-        return True
+        return pushed + self._put_columns(
+            self._lib.swhp_put_bulk, volume.id, keys, offsets, sizes)
 
     def unregister_volume(self, vid: int):
         h = self._h
@@ -423,36 +442,19 @@ class NativeReadPlane:
     def _bulk_load_ecx(self, ev) -> bool:
         """Snapshot the .ecx under its lock and push every entry —
         tombstones included, so a deleted needle redirects (Python
-        404s) instead of being resurrected by a re-sync."""
-        import numpy as np
-        from ..storage.needle_map import bytes_to_entry
-        from ..storage.types import entry_size
-        rec_size = entry_size(ev.offset_width)
+        404s) instead of being resurrected by a re-sync. The snapshot
+        is viewed as the record array it is (storage/idx_array): no
+        entry passes through a Python object."""
+        from ..storage import idx_array
+        t0 = time.perf_counter()
         with ev.ecx_lock:
             ev.ecx_file.seek(0)
             raw = ev.ecx_file.read(ev.ecx_size)
-        keys, offsets, sizes = [], [], []
-
-        def put_chunk():
-            ka = np.asarray(keys, dtype=np.uint64)
-            oa = np.asarray(offsets, dtype=np.uint64)
-            sa = np.asarray(sizes, dtype=np.uint32)
-            self._lib.swhp_ec_put_bulk(
-                self._h, ev.vid,
-                ka.ctypes.data_as(ctypes.c_void_p),
-                oa.ctypes.data_as(ctypes.c_void_p),
-                sa.ctypes.data_as(ctypes.c_void_p), len(keys))
-
-        for pos in range(0, len(raw) - rec_size + 1, rec_size):
-            key, offset, size = bytes_to_entry(raw[pos:pos + rec_size])
-            keys.append(key)
-            offsets.append(offset)
-            sizes.append(size)
-            if len(keys) >= (1 << 20):  # bound the staging lists
-                put_chunk()
-                keys, offsets, sizes = [], [], []
-        if keys:
-            put_chunk()
+        keys, offsets, sizes = idx_array.columns(
+            idx_array.records_of(raw, ev.offset_width))
+        pushed = self._put_columns(
+            self._lib.swhp_ec_put_bulk, ev.vid, keys, offsets, sizes)
+        telemetry.STATS.add_mirror(pushed, time.perf_counter() - t0)
         return True
 
     def unregister_ec_volume(self, vid: int):

@@ -28,44 +28,11 @@ from typing import Iterator, Optional, Tuple
 
 import numpy as np
 
+from .idx_array import IDX_DTYPE, read_idx_records, replay_idx
 from .needle_map import NeedleValue, entry_to_bytes
-from .types import (NEEDLE_ENTRY_SIZE, NEEDLE_PADDING_SIZE,
-                    TOMBSTONE_FILE_SIZE)
+from .types import NEEDLE_PADDING_SIZE, TOMBSTONE_FILE_SIZE
 
-# .idx record layout; "off" is in STORED units (real byte offset / 8,
-# reference types/needle_types.go) — converted at the get/put boundary
-IDX_DTYPE = np.dtype([("nid", ">u8"), ("off", ">u4"), ("size", ">u4")])
 _DELETED = NeedleValue(0, TOMBSTONE_FILE_SIZE)  # overflow tombstone marker
-
-
-def _replay_idx_vectorized(idx_path: str):
-    """One-pass vectorized .idx replay: returns (live_records sorted by
-    nid, counters dict). Last event per needle wins; counters match the
-    dict map's event-tally semantics exactly:
-      deletion_counter = puts - live,  deletion_bytes = put_bytes - live_bytes
-    (every non-final put is superseded exactly once; deletes of dead
-    needles tally nothing — same as NeedleMap._apply)."""
-    counters = {"file_counter": 0, "file_byte_counter": 0,
-                "deletion_counter": 0, "deletion_byte_counter": 0,
-                "maximum_file_key": 0}
-    if not os.path.exists(idx_path) or os.path.getsize(idx_path) == 0:
-        return np.empty(0, dtype=IDX_DTYPE), counters
-    raw = np.fromfile(idx_path, dtype=np.uint8)
-    n = len(raw) // NEEDLE_ENTRY_SIZE
-    arr = raw[:n * NEEDLE_ENTRY_SIZE].view(IDX_DTYPE)
-    puts = (arr["size"] != TOMBSTONE_FILE_SIZE) & (arr["off"] != 0)
-    counters["maximum_file_key"] = int(arr["nid"].max()) if n else 0
-    counters["file_counter"] = int(puts.sum())
-    counters["file_byte_counter"] = int(arr["size"][puts].sum())
-    # last event per nid: first occurrence in the reversed stream
-    _, idx_rev = np.unique(arr["nid"][::-1], return_index=True)
-    last_idx = n - 1 - idx_rev  # ascending nid order (np.unique sorts)
-    live = arr[last_idx][puts[last_idx]]
-    counters["deletion_counter"] = \
-        counters["file_counter"] - len(live)
-    counters["deletion_byte_counter"] = \
-        counters["file_byte_counter"] - int(live["size"].sum())
-    return live, counters
 
 
 class _SortedBase:
@@ -205,7 +172,7 @@ class CompactNeedleMap(_SortedBase):
     def load(cls, idx_path: str) -> "CompactNeedleMap":
         nm = cls.__new__(cls)
         _SortedBase.__init__(nm, None)
-        live, counters = _replay_idx_vectorized(idx_path)
+        live, counters = replay_idx(read_idx_records(idx_path))
         nm._base = live
         nm.__dict__.update(counters)
         nm.idx_path = idx_path
@@ -281,7 +248,7 @@ class SortedFileNeedleMap(_SortedBase):
                       "maximum_file_key"):
                 setattr(nm, k, int(meta.get(k, 0)))
         else:
-            live, counters = _replay_idx_vectorized(idx_path)
+            live, counters = replay_idx(read_idx_records(idx_path))
             nm.__dict__.update(counters)
             live.tofile(sdx_path)
         if os.path.getsize(sdx_path) if os.path.exists(sdx_path) else 0:

@@ -18,10 +18,15 @@ consumer: ec/ec_volume.search_needle_from_sorted_index.)
 
 from __future__ import annotations
 
+import operator
 import os
 import struct
+import time
 from typing import Iterator, Optional, Tuple
 
+import numpy as np
+
+from .idx_array import columns, read_idx_records, replay_idx
 from .types import (NEEDLE_ENTRY_SIZE, OFFSET_SIZE, TOMBSTONE_FILE_SIZE,
                     bytes_to_offset, bytes_to_needle_id, entry_size,
                     needle_id_to_bytes, offset_to_bytes)
@@ -47,6 +52,10 @@ class NeedleValue:
         self.size = size
 
 
+_OFFSET_OF = operator.attrgetter("offset")
+_SIZE_OF = operator.attrgetter("size")
+
+
 class NeedleMap:
     """Write-through needle map: in-memory dict + append-only .idx log."""
 
@@ -68,18 +77,24 @@ class NeedleMap:
     @classmethod
     def load(cls, idx_path: str,
              offset_width: int = OFFSET_SIZE) -> "NeedleMap":
+        """Replay the .idx as one record array (idx_array.replay_idx:
+        the last record of a key wins, the counters are _apply's event
+        tally). The dict comes out ascending by key, not in log order;
+        every caller that needs an order asks snapshot_live_items(...,
+        by_offset=True) for it."""
+        from ..ops import telemetry
+        t0 = time.perf_counter()
         nm = cls.__new__(cls)
-        nm._m = {}
         nm.idx_path = idx_path
         nm.offset_width = offset_width
-        nm.file_counter = nm.file_byte_counter = 0
-        nm.deletion_counter = nm.deletion_byte_counter = 0
-        nm.maximum_file_key = 0
-        if os.path.exists(idx_path):
-            for nid, offset, size in walk_index_file(idx_path,
-                                                     offset_width):
-                nm._apply(nid, offset, size)
+        records = read_idx_records(idx_path, offset_width)
+        live, counters = replay_idx(records)
+        nm.__dict__.update(counters)
+        keys, offsets, sizes = columns(live)
+        nm._m = dict(zip(keys.tolist(),
+                         map(NeedleValue, offsets.tolist(), sizes.tolist())))
         nm._idx_file = open(idx_path, "ab")
+        telemetry.STATS.add_mirror(len(records), time.perf_counter() - t0)
         return nm
 
     def _apply(self, nid: int, offset: int, size: int):
@@ -130,6 +145,17 @@ class NeedleMap:
 
     def items(self) -> Iterator[Tuple[int, NeedleValue]]:
         return iter(self._m.items())
+
+    def live_columns(self):
+        """The live set as (keys uint64, byte offsets uint64, sizes
+        uint32) arrays, each filled in one C-level pass over the dict:
+        what the native plane's mirror takes (call under the volume
+        lock — the dict mutates under writes)."""
+        n = len(self._m)
+        values = self._m.values()
+        return (np.fromiter(self._m, np.uint64, n),
+                np.fromiter(map(_OFFSET_OF, values), np.uint64, n),
+                np.fromiter(map(_SIZE_OF, values), np.uint32, n))
 
     @property
     def content_size(self) -> int:

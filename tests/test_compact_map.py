@@ -167,7 +167,7 @@ def test_sorted_file_fast_reload_skips_replay(tmp_path, monkeypatch):
     def boom(_):
         raise AssertionError("full .idx replay on a fresh .sdx")
 
-    monkeypatch.setattr(cm, "_replay_idx_vectorized", boom)
+    monkeypatch.setattr(cm, "replay_idx", boom)
     again = SortedFileNeedleMap.load(path)
     assert again.get(42) is None and again.get(41).size == 75
     assert (again.file_counter, again.deletion_counter,

@@ -9,6 +9,7 @@ transparently for GET/HEAD).
 
 import json
 import time
+from types import SimpleNamespace
 
 import pytest
 
@@ -1231,6 +1232,119 @@ class TestPlaneDegradedServing:
         assert not wrong, wrong
         assert not errors, errors[:5]
         assert hits[0] > 100, (hits, misses)
+
+
+class TestMirrorsLoadFromArrays:
+    """The plane's mirrors are filled from record arrays
+    (storage/idx_array), not an entry a Python iteration: a freeze and
+    an EC mount count what they loaded, and the mirror a mount filled
+    answers a live, a deleted and an absent key as the loop's did."""
+
+    @pytest.fixture
+    def sealed(self, tmp_path):
+        """Three servers, one collection volume of needles in shard 0's
+        first row: two deleted before the seal, the rest live."""
+        import io
+        import os
+        from seaweedfs_tpu.client import operation as op
+        from seaweedfs_tpu.ops import telemetry
+        from seaweedfs_tpu.shell.command_env import (CommandEnv,
+                                                     run_command)
+        master = MasterServer(port=0, pulse_seconds=1).start()
+        servers = [
+            VolumeServer(port=0, directories=[str(tmp_path / f"m{i}")],
+                         master_url=master.url, pulse_seconds=1,
+                         max_volume_counts=[30],
+                         ec_backend="numpy").start()
+            for i in range(3)]
+        try:
+            payloads = {}
+            for i in range(40):
+                data = bytes([i]) * (3000 + i)
+                payloads[op.upload_data(master.url, data, filename=f"m{i}",
+                                        collection="mc")] = data
+            by_vid = {}
+            for f in payloads:
+                by_vid.setdefault(int(f.split(",")[0]), []).append(f)
+            vid = max(by_vid, key=lambda v: len(by_vid[v]))
+            fids = by_vid[vid]
+            owner = next(vs for vs in servers
+                         if vs.store.find_volume(vid) is not None)
+            for f in fids[:2]:
+                http_call("DELETE", f"http://{owner.url}/{f}")
+            live = {f: payloads[f] for f in fids[2:]}
+            assert len(live) >= 3
+            idx_records = os.path.getsize(
+                owner.store.find_volume(vid).idx_path) // 16
+            assert idx_records == len(fids) + 2
+
+            # -- the freeze: the lease comes back, the needle map is
+            # replayed from the .idx and the plane's mirror refilled
+            before = telemetry.STATS.snapshot()
+            out = post_json(f"http://{owner.url}/admin/volume/readonly"
+                            f"?volume={vid}", {})
+            assert out["was_readonly"] is False
+            freeze = telemetry.delta(before)
+
+            before = telemetry.STATS.snapshot()
+            env = CommandEnv(master.url, out=io.StringIO())
+            assert run_command(env, f"ec.encode -volumeId {vid}")
+            encode = telemetry.delta(before)
+            yield SimpleNamespace(
+                master=master, servers=servers, vid=vid, live=live,
+                deleted_before=fids[:2], idx_records=idx_records,
+                freeze=freeze, encode=encode)
+        finally:
+            for vs in servers:
+                vs.stop()
+            master.stop()
+
+    def test_a_freeze_and_the_mounts_count_what_they_loaded(self, sealed):
+        live = len(sealed.live)
+        # NeedleMap.load read every record of the log; register_volume
+        # pushed the live set
+        assert sealed.freeze["mirror_entries"] == sealed.idx_records + live
+        assert sealed.freeze["mirror_us"] > 0
+        assert sealed.freeze["mirror_loop_entries"] == 0
+        # ec.encode found the volume frozen (no second replay); each of
+        # the three holders' mounts pushed the .ecx's entries
+        holders = [vs for vs in sealed.servers
+                   if vs.store.find_ec_volume(sealed.vid) is not None]
+        assert len(holders) == 3
+        assert sealed.encode["mirror_entries"] == 3 * live
+        assert sealed.encode["mirror_loop_entries"] == 0
+        body, _ = http_get_with_headers(f"http://{holders[0].url}/metrics")
+        for kind in ("mirror_entries", "mirror_us", "mirror_loop_entries"):
+            assert f'ec_device_telemetry_total{{kind="{kind}"}}' in \
+                body.decode()
+
+    def test_mounted_mirror_answers_live_deleted_absent(self, sealed):
+        vid = sealed.vid
+        serving = next(vs for vs in sealed.servers
+                       if (ev := vs.store.find_ec_volume(vid)) is not None
+                       and 0 in ev.shards)
+        fids = list(sealed.live)
+        gone, kept = fids[0], fids[1:]
+        # a delete after the seal: a tombstone in the .ecx, which a
+        # re-sync must push too or the needle comes back
+        http_call("DELETE", f"http://{serving.url}/{gone}")
+        mine = sorted(serving.store.find_ec_volume(vid).shards)
+        for verb in ("unmount", "mount"):
+            post_json(f"http://{serving.url}/admin/ec/{verb}?volume={vid}"
+                      f"&collection=mc&shards={mine[-1]}", {})
+        base = serving.fast_plane.cache_stats()
+        for f in kept:      # live: served in the plane from shard 0
+            st, _, body = raw_get(serving.fast_url, f"/{f}")
+            assert st == 200 and body == sealed.live[f]
+        snap = serving.fast_plane.cache_stats()
+        assert snap["ec_local_served"] - base["ec_local_served"] == len(kept)
+        absent = f"{vid},{'%x' % 0xabcdef01}00000000"
+        for f in (gone, sealed.deleted_before[0], absent):
+            st, _, _ = raw_get(serving.fast_url, f"/{f}")
+            assert st == 307, f
+            with pytest.raises(HttpError) as e:
+                http_call("GET", f"http://{serving.url}/{f}")
+            assert e.value.status == 404
 
 
 # Frozen ABI manifest: every symbol http_plane.cc exports. Adding an

@@ -367,7 +367,9 @@ def test_counters_at_the_stage_boundaries(cycle):
         "holder_runs", "holder_bytes", "holder_us", "holder_recv_us",
         "holder_write_us", "holder_cpu_us", "lock_probe_samples",
         "lock_probe_elapsed_us", "lock_probe_late_us",
-        "lock_probe_stalls", "lock_probe_stall_us"}
+        "lock_probe_stalls", "lock_probe_stall_us",
+        # PR 43: indexes loaded as arrays (tests/test_idx_array.py)
+        "mirror_entries", "mirror_us", "mirror_loop_entries"}
     fetched = sum(s["tags"]["bytes"] for s in cycle["spans"]
                   if s["name"].startswith("ec.rebuild.fetch."))
     assert fetched == cycle["rebuild"]["survivor_bytes"] > 0
