@@ -14,6 +14,18 @@ import sys
 import threading
 import time
 
+from ..ops.codec import BACKENDS as EC_BACKENDS
+
+EC_BACKEND_HELP = (
+    "erasure-coding codec: auto (the TPU where JAX computes on one, "
+    "else native, else numpy), numpy, native, tpu (JAX's default "
+    "device), mesh (every dispatch sharded over all local chips), "
+    "tpu-own (one chip of the host's several: the process's volume "
+    "servers take the local chips in turn, first server chip 0, second "
+    "chip 1, ... modulo their count, and each reports its chip in "
+    "/status and its heartbeat so that ec.encode -collection and "
+    "ec.rebuild keep one volume in flight a distinct chip; a process "
+    "a server with one visible chip gets index 0)")
 
 def _security_cfg(args):
     """security.toml/json + WEED_* env, loaded once per process and
@@ -910,7 +922,7 @@ def build_parser() -> argparse.ArgumentParser:
     v.add_argument("-rack", default="")
     v.add_argument("-pulseSeconds", type=int, default=5)
     v.add_argument("-ec.backend", dest="ec_backend", default="auto",
-                   choices=["auto", "numpy", "native", "tpu", "mesh"])
+                   choices=list(EC_BACKENDS), help=EC_BACKEND_HELP)
     v.add_argument("-fastPort", type=int, default=0,
                    help="native C++ read plane port (0 = auto-pick, "
                         "-1 = disabled); plain needle GETs are served "
@@ -977,7 +989,7 @@ def build_parser() -> argparse.ArgumentParser:
     s.add_argument("-webdav", action="store_true")
     s.add_argument("-webdavPort", type=int, default=7333)
     s.add_argument("-ec.backend", dest="ec_backend", default="auto",
-                   choices=["auto", "numpy", "native", "tpu", "mesh"])
+                   choices=list(EC_BACKENDS), help=EC_BACKEND_HELP)
     s.add_argument("-fastPort", type=int, default=0,
                    help="native C++ read plane port (0 = auto-pick, "
                         "-1 = disabled)")

@@ -848,7 +848,8 @@ def rebuild_ec_files_streaming(base_name: str,
                                codec: Optional[ReedSolomonCodec] = None,
                                slab: int = DEFAULT_SLAB,
                                pipelined: Optional[bool] = None,
-                               stats: Optional[dict] = None) -> List[int]:
+                               stats: Optional[dict] = None,
+                               sink=None) -> List[int]:
     """Streaming variant of rebuild_ec_files: the survivor bytes arrive
     from ``source`` (an ec.gather.StripedGatherSource — local files and
     remote holders mixed) instead of whole shard files on local disk,
@@ -859,7 +860,16 @@ def rebuild_ec_files_streaming(base_name: str,
     ``present``/``missing`` describe the cluster-wide shard state (the
     decode plan), not local files. On ANY failure the partially written
     missing-shard files are removed — callers either get complete
-    rebuilt shards or nothing."""
+    rebuilt shards or nothing.
+
+    ``sink`` (an ec.spread.RebuiltShardSink) takes the rebuilt rows in
+    place of local files: the decode runs here and the shards land on
+    another node's disk, pushed as the encode's spread pushes a remote
+    shard. The stage is ``deliver`` (span ``ec.rebuild.deliver``: the
+    time the consumer spent handing rows to the sink — blocked on its
+    windows — and waiting for its finish) where a local rebuild has
+    ``shard_write``; the reply carries it as ``phases.deliver``. A
+    failure aborts the sink: no partial shard stays on the target."""
     codec = codec or volume_codec(base_name)
     k, total = codec.k, codec.total
     if pipelined is None:
@@ -876,7 +886,11 @@ def rebuild_ec_files_streaming(base_name: str,
     t0 = time.perf_counter()
     coeffs = _rebuild_coeffs(codec, present, missing)
     phases["plan"] = time.perf_counter() - t0
-    outs = {i: open(base_name + to_ext(i), "wb") for i in missing}
+    outs = {} if sink is not None else \
+        {i: open(base_name + to_ext(i), "wb") for i in missing}
+    # where the rebuilt rows go: the local shard files, or the sink
+    out_stage, out_span = ("shard_write", "ec.rebuild.write") \
+        if sink is None else ("deliver", "ec.rebuild.deliver")
     rebuilt_bytes = 0
     # the stream's root span (ec.rebuild.stream, current here)
     ptimer = StageTimer(root=tracing.current_span())
@@ -891,14 +905,17 @@ def rebuild_ec_files_streaming(base_name: str,
             for _, data, parts in pm.stream(source.slabs()):
                 # its output is drained: the gather may fill it again
                 _give_slab(data)
-                with ptimer.stage("shard_write",
-                                  span="ec.rebuild.write") as st:
+                with ptimer.stage(out_stage, span=out_span) as st:
                     for _, piece in parts:
+                        if sink is not None:
+                            sink.write_rows(piece)    # views: no copy
+                            st.nbytes += piece.nbytes
+                            continue
                         for r, i in enumerate(missing):
                             outs[i].write(piece[r])   # a view: no copy
                             st.nbytes += piece[r].nbytes
             phases["write"] = ptimer.totals.get("shard_write", 0.0)
-            rebuilt_bytes = ptimer.bytes.get("shard_write", 0)
+            rebuilt_bytes = ptimer.bytes.get(out_stage, 0)
             # consumer-side accounting, same discipline as
             # rebuild_ec_files: read_wait is the time this thread spent
             # blocked on stripes still in flight — the UNOVERLAPPED
@@ -918,15 +935,25 @@ def rebuild_ec_files_streaming(base_name: str,
                 out = codec._matmul(coeffs, data)
                 t2 = time.perf_counter()
                 _give_slab(data)
-                for r, i in enumerate(missing):
-                    outs[i].write(np.ascontiguousarray(out[r],
-                                                       dtype=np.uint8))
-                    rebuilt_bytes += data.shape[1]
-                t3 = time.perf_counter()
+                out = np.ascontiguousarray(out, dtype=np.uint8)
+                rebuilt_bytes += out.nbytes
+                if sink is not None:
+                    with ptimer.stage(out_stage, out.nbytes,
+                                      span=out_span):
+                        sink.write_rows(out)
+                else:
+                    for r, i in enumerate(missing):
+                        outs[i].write(out[r])
+                    phases["write"] += time.perf_counter() - t2
                 phases["gather"] += t1 - t0
                 phases["dispatch"] += t2 - t1
-                phases["write"] += t3 - t2
+        if sink is not None:
+            # the lanes drained, every shard finalized on the target
+            with ptimer.stage(out_stage, span=out_span):
+                sink.finish()
     except BaseException:
+        if sink is not None:
+            sink.abort()
         for i, h in outs.items():
             h.close()
             try:
@@ -938,6 +965,8 @@ def rebuild_ec_files_streaming(base_name: str,
         for h in outs.values():
             h.close()
     stream_s = time.perf_counter() - t_stream
+    if sink is not None:
+        phases["deliver"] = ptimer.totals.get("deliver", 0.0)
     residual = stream_s - (sum(phases.values()) - phases["plan"])
     if residual > 0:
         phases["dispatch"] += residual
@@ -945,10 +974,15 @@ def rebuild_ec_files_streaming(base_name: str,
         if secs > 0:
             tracing.record_span(name, secs, op="ec.rebuild",
                                 backend=codec.backend, streaming=True)
+    telemetry.STATS.add("rebuild_local_bytes" if sink is None
+                        else "rebuild_delivered_bytes", rebuilt_bytes)
     if stats is not None:
         gs = source.stats
         stats.update(telemetry.delta(before))
         stats.update(gs.snapshot())
+        if sink is not None:
+            stats["delivered_to"] = sink.target
+            stats["deliver_blocked_s"] = round(sink.blocked_s, 6)
         stats["survivor_bytes"] = source.shard_size * k
         stats["rebuilt_bytes"] = rebuilt_bytes
         stats["stream_s"] = round(stream_s, 3)

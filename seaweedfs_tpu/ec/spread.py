@@ -83,3 +83,35 @@ class StripedSpreadSink(StripedPush):
                          window=window, stats=stats,
                          parent_span=parent_span, rate_mbps=rate_mbps,
                          slab=slab)
+
+
+class RebuiltShardSink(StripedPush):
+    """The sink of a rebuild whose decode runs on another node than the
+    one placement names for the rebuilt shards (shell/command_ec: one
+    volume in flight a chip; f4's rebuilder nodes, apart from its
+    storage nodes). Row r of every decoded stripe is the next range of
+    shard ``missing[r]``, pushed to ``target``'s
+    ``/admin/ec/shard_write`` exactly as the encode's spread pushes a
+    remote shard: the same lanes, windows, runs, ``.part`` stage and
+    atomic finalize, and ``abort`` leaves nothing of them on the
+    target. No spare: the shards of a rebuild belong on the node the
+    shell chose, and a failed delivery is the shell's to retry there."""
+
+    def __init__(self, vid: int, missing: Sequence[int], target: str,
+                 collection: str = "", window: Optional[int] = None,
+                 stats: Optional[TransportStats] = None,
+                 parent_span=None, slab: int = 8 << 20):
+        self.vid = vid
+        self.missing = list(missing)
+        self.target = target
+        writers = [RemoteShardWriter(vid, sid, collection)
+                   for sid in self.missing]
+        super().__init__(writers, {target: list(range(len(writers)))},
+                         window=window, stats=stats,
+                         parent_span=parent_span, slab=slab)
+
+    def write_rows(self, rows):
+        """One decoded stripe: ``rows[r]`` continues shard
+        ``missing[r]``. The rows are queued as views (StripedPush.
+        write_stripe): the array is the sink's until they are sent."""
+        self.write_stripe(rows, rows[:0])

@@ -366,9 +366,16 @@ def _auto_backend() -> str:
     return _AUTO_CHOICE
 
 
+#: `-ec.backend` values a server can be started with
+BACKENDS = ("auto", "numpy", "native", "tpu", "tpu-own", "mesh")
+
+
 def get_codec(data_shards: int, parity_shards: int,
               backend: str = "auto",
-              matrix_kind: str = "vandermonde") -> ReedSolomonCodec:
+              matrix_kind: str = "vandermonde",
+              device_ordinal: int = 0) -> ReedSolomonCodec:
+    """``device_ordinal`` matters to `tpu-own` alone: which of the
+    process's local chips the codec computes on (rs_tpu.local_device)."""
     if backend == "auto":
         backend = _auto_backend()
     if backend == "numpy":
@@ -379,6 +386,10 @@ def get_codec(data_shards: int, parity_shards: int,
     if backend == "tpu":
         from .rs_tpu import TpuCodec
         return TpuCodec(data_shards, parity_shards, matrix_kind)
+    if backend == "tpu-own":
+        from .rs_tpu import OwnDeviceCodec
+        return OwnDeviceCodec(data_shards, parity_shards, matrix_kind,
+                              ordinal=device_ordinal)
     if backend == "mesh":
         # SPMD over every visible device (multi-chip hosts)
         from ..parallel.mesh_codec import MeshCodec

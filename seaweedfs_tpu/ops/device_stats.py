@@ -96,19 +96,32 @@ class DeviceStats:
 
     # -- hot path ------------------------------------------------------
 
-    def tick(self, entry: str):
+    def tick(self, entry: str, chip: Optional[str] = None):
         """Count one dispatch: the ONLY per-dispatch cost — one lock,
-        one dict increment, no clock reads."""
+        one dict increment, no clock reads. A program that belongs to
+        one chip of several (`-ec.backend tpu-own`) counts it a second
+        time under its ``chip`` (``dev<i>``), beside the entry points
+        in the same table: `tpu` and `mesh` name no chip and count
+        nothing there."""
         with self._lock:
             self.dispatches[entry] = self.dispatches.get(entry, 0) + 1
+            if chip is not None:
+                self.dispatches[chip] = self.dispatches.get(chip, 0) + 1
 
     # -- slow-path events ----------------------------------------------
 
-    def note_compile(self, entry: str, bucket_key, seconds: float):
+    def note_compile(self, entry: str, bucket_key, seconds: float,
+                     chip: Optional[str] = None):
+        """``chip`` (``dev<i>``): the chip the executable belongs to,
+        where the program is one chip's of several. The same (entry,
+        bucket) on another chip is a compile of its own, not a
+        recompile."""
         with self._lock:
             self.compiles[entry] = self.compiles.get(entry, 0) + 1
             self.compile_seconds[entry] = \
                 self.compile_seconds.get(entry, 0.0) + seconds
+            if chip is not None:
+                bucket_key = (chip,) + tuple(bucket_key)
             key = (entry, bucket_key)
             seen = self._bucket_compiles.get(key, 0) + 1
             self._bucket_compiles[key] = seen
@@ -185,14 +198,33 @@ class InstrumentedJit:
     trailing width through canonical_width(), so per-bucket compiles
     are idempotent and exact-width churn latches."""
 
-    __slots__ = ("_jit", "entry", "_stats", "_compiled", "_lock")
+    __slots__ = ("_jit", "entry", "_stats", "_compiled", "_lock",
+                 "device", "_chip", "_on")
 
-    def __init__(self, jfn, entry: str, stats: Optional[DeviceStats] = None):
+    def __init__(self, jfn, entry: str, stats: Optional[DeviceStats] = None,
+                 device: Optional[int] = None):
         self._jit = jfn
         self.entry = entry
         self._stats = stats if stats is not None else DEVICE_STATS
         self._compiled: Dict[Any, Callable] = {}
         self._lock = make_lock(f"device_stats.wrap[{entry}]")
+        # the one chip this instance's executables are compiled for and
+        # counted under; None: wherever JAX puts them (`tpu`, `mesh`)
+        self.device = device
+        self._chip = None if device is None else f"dev{device}"
+        self._on: Dict[int, "InstrumentedJit"] = {}
+
+    def on_device(self, index: int) -> "InstrumentedJit":
+        """The same jitted program as one chip's own: a sibling with its
+        own executables (an AOT executable is bound to the devices of
+        the arguments it was lowered with), counted under the same entry
+        point and under ``dev<index>``."""
+        with self._lock:
+            sibling = self._on.get(index)
+            if sibling is None:
+                sibling = self._on[index] = InstrumentedJit(
+                    self._jit, self.entry, self._stats, device=index)
+            return sibling
 
     @property
     def raw_jit(self):
@@ -232,7 +264,8 @@ class InstrumentedJit:
                 exe = self._jit
             dt = _perf_counter() - t0
             self._compiled[sig] = exe
-        self._stats.note_compile(self.entry, self._bucket_key(sig), dt)
+        self._stats.note_compile(self.entry, self._bucket_key(sig), dt,
+                                 self._chip)
         return exe
 
     def __call__(self, *args):
@@ -240,7 +273,7 @@ class InstrumentedJit:
         exe = self._compiled.get(sig)
         if exe is None:
             exe = self._compile(sig, args)
-        self._stats.tick(self.entry)
+        self._stats.tick(self.entry, self._chip)
         return exe(*args)
 
 

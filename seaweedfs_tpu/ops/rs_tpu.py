@@ -194,24 +194,30 @@ def on_tpu() -> bool:
     return require_tpu("tpu|mesh") == "tpu"
 
 
-def fn_and_bitmat(coeffs: np.ndarray, n: int):
+def fn_and_bitmat(coeffs: np.ndarray, n: int,
+                  device: Optional[int] = None):
     """Pick the device kernel for this platform: the fused Pallas kernel
     on the TPU (ops/rs_pallas — unpack/matmul/pack in VMEM, no HBM
     temporaries), the packed AND/popcount XLA program where the CPU was
     asked for (the test mesh, where the 8x bit-plane gemm is the
     bottleneck and Pallas would have to interpret). Returns (jitted fn,
     host constant — fused bitmat on TPU, packed uint32 bitmat off it)
-    with matching layouts; both are bit-identical to the numpy oracle."""
+    with matching layouts; both are bit-identical to the numpy oracle.
+    ``device``: the index of the one local chip the caller computes on
+    (OwnDeviceCodec); the fn is then that chip's own instance of the
+    same program (device_stats.InstrumentedJit.on_device)."""
     coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
     r, k = coeffs.shape
     if on_tpu():
         from .rs_pallas import _fused_fn, fuse_bitmat, pick_tile
         with _factory_lock:
             fn = _fused_fn(k, r, n, pick_tile(k, r, n), False)
-        return fn, fuse_bitmat(coeffs)
-    with _factory_lock:
-        fn = _packed_fn(k, r, n)
-    return fn, _packed_bitmat(coeffs.tobytes(), r, k)
+        const = fuse_bitmat(coeffs)
+    else:
+        with _factory_lock:
+            fn = _packed_fn(k, r, n)
+        const = _packed_bitmat(coeffs.tobytes(), r, k)
+    return (fn if device is None else fn.on_device(device)), const
 
 
 def width_bucket(n: int, cap: Optional[int]) -> int:
@@ -316,3 +322,52 @@ class TpuCodec(DeviceCodec):
         if n > self.chunk_bytes:
             return self.chunk_bytes
         return super()._chunk_bucket(w, n)
+
+
+def local_device(ordinal: int):
+    """(index, device) of the local chip a store with this ordinal
+    computes on: the process's local devices taken in turn, modulo their
+    count. First JAX touch of a `tpu-own` server (the same platform rule
+    as `tpu`: the TPU, or the CPU where JAX_PLATFORMS asks for it)."""
+    from ..util.jax_platform import require_tpu
+    jax, _ = _jax()
+    require_tpu("tpu-own")
+    devices = jax.local_devices()
+    index = int(ordinal) % len(devices)
+    return index, devices[index]
+
+
+class OwnDeviceCodec(TpuCodec):
+    """JAX backend on ONE chip of a host that has several
+    (`-ec.backend tpu-own`): the single-chip programs and dispatch of
+    `tpu`, with operands, constants and compiled executables placed on
+    the local device the codec's store was given (``ordinal``: the
+    store's place among the process's stores; the chip is that modulo
+    the local device count). Four volume servers in one process on a
+    four-chip host compute on four chips; a process a server with one
+    visible chip gets index 0, which is where `tpu` computes too."""
+
+    backend = "tpu-own"
+
+    def __init__(self, data_shards: int, parity_shards: int,
+                 matrix_kind: str = "vandermonde", ordinal: int = 0,
+                 **kwargs):
+        super().__init__(data_shards, parity_shards, matrix_kind, **kwargs)
+        self.ordinal = int(ordinal)
+        self._device = None
+
+    @property
+    def device(self):
+        """(index, jax device), resolved at the first device touch."""
+        if self._device is None:
+            self._device = local_device(self.ordinal)
+        return self._device
+
+    def device_fn(self, coeffs: np.ndarray, width: int):
+        import jax
+        coeffs = np.ascontiguousarray(coeffs, dtype=np.uint8)
+        index, device = self.device
+        fn, const_host = fn_and_bitmat(coeffs, width, device=index)
+        const_dev = self._consts.get(
+            coeffs.tobytes(), lambda: jax.device_put(const_host, device))
+        return fn, const_dev, lambda slab: jax.device_put(slab, device)

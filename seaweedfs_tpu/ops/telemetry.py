@@ -60,6 +60,12 @@ the wall microseconds of each load. They are read as arrays
 an entry a Python iteration, which a map that offers no columns (the
 compact, sorted-file and disk maps under the plane) costs.
 
+`rebuild_delivered_bytes` and `rebuild_local_bytes` count the bytes of
+rebuilt shards a full-gather rebuild produced, by where they went: sent
+to another node's disk through the spread's sink (the node that decoded
+is not the node placement names, shell/command_ec's one volume in
+flight a chip) or written to this node's own files.
+
 `slab_fresh_bytes` counts the bytes of stripe-sized host blocks that
 were new memory (ec/transport._take_slab found its pool empty, or
 holding nothing large enough): what these hosts charge for is memory a
@@ -88,7 +94,8 @@ class DispatchStats:
                "lock_probe_samples", "lock_probe_elapsed_us",
                "lock_probe_late_us", "lock_probe_stalls",
                "lock_probe_stall_us",
-               "mirror_entries", "mirror_us", "mirror_loop_entries")
+               "mirror_entries", "mirror_us", "mirror_loop_entries",
+               "rebuild_delivered_bytes", "rebuild_local_bytes")
     REPAIR_ROUTES = ("piggyback", "trace", "full")
 
     def __init__(self):

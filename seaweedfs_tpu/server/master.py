@@ -670,41 +670,64 @@ class MasterServer:
         ec_geometries = {int(k): v
                          for k, v in
                          (hb.get("ec_geometries") or {}).items()}
-        if hb.get("delta"):
-            # incremental heartbeat (reference master_grpc_server.go:
-            # 94-152): only new/changed/deleted volumes ride the wire.
-            # An unknown node means we lost its registration (restart,
-            # failover) — ask for a full resync instead of guessing.
-            applied = self.topology.apply_heartbeat_delta(
-                url=f"{hb.get('ip', '127.0.0.1')}:{hb.get('port', 0)}",
-                new_volumes=hb.get("new_volumes", []),
-                deleted_volumes=[int(v) for v in
-                                 hb.get("deleted_volumes", [])],
-                ec_shards=ec_shards, ec_collections=ec_collections,
-                max_file_key=int(hb.get("max_file_key", 0)),
-                ec_geometries=ec_geometries)
-            if not applied:
-                return {"resync": True,
+        url = f"{hb.get('ip', '127.0.0.1')}:{hb.get('port', 0)}"
+        seq = int(hb.get("seq") or 0)
+        # asked before the topology's lock is taken: raft's lock is
+        # above it in the order
+        leader = self.leader_url() or self.url
+        # a server's heartbeats may pass each other on the way here (its
+        # pulse thread and a handler that pushes a change post side by
+        # side): one collected before the state last applied is dropped,
+        # check and apply one step under the topology's lock
+        with self.topology.lock:
+            node = self.topology.find_node(url)
+            if node is not None and seq and seq < node.hb_seq:
+                return {"stale": True,
                         "volume_size_limit":
                         self.topology.volume_size_limit,
-                        "leader": self.leader_url() or self.url}
-        else:
-            self.topology.register_heartbeat(
-                dc_id=hb.get("data_center", ""),
-                rack_id=hb.get("rack", ""),
-                ip=hb.get("ip", "127.0.0.1"),
-                port=int(hb.get("port", 0)),
-                public_url=hb.get("public_url", ""),
-                fast_url=hb.get("fast_url", ""),
-                max_volume_count=int(hb.get("max_volume_count", 7)),
-                volumes=hb.get("volumes", []),
-                ec_shards=ec_shards,
-                ec_collections=ec_collections,
-                max_file_key=int(hb.get("max_file_key", 0)),
-                ec_geometries=ec_geometries,
-            )
+                        "leader": leader}
+            if hb.get("delta"):
+                # incremental heartbeat (reference master_grpc_server.go:
+                # 94-152): only new/changed/deleted volumes ride the wire.
+                # An unknown node means we lost its registration (restart,
+                # failover) — ask for a full resync instead of guessing.
+                applied = self.topology.apply_heartbeat_delta(
+                    url=url,
+                    new_volumes=hb.get("new_volumes", []),
+                    deleted_volumes=[int(v) for v in
+                                     hb.get("deleted_volumes", [])],
+                    ec_shards=ec_shards, ec_collections=ec_collections,
+                    max_file_key=int(hb.get("max_file_key", 0)),
+                    ec_geometries=ec_geometries)
+                if not applied:
+                    return {"resync": True,
+                            "volume_size_limit":
+                            self.topology.volume_size_limit,
+                            "leader": leader}
+            else:
+                self.topology.register_heartbeat(
+                    dc_id=hb.get("data_center", ""),
+                    rack_id=hb.get("rack", ""),
+                    ip=hb.get("ip", "127.0.0.1"),
+                    port=int(hb.get("port", 0)),
+                    public_url=hb.get("public_url", ""),
+                    fast_url=hb.get("fast_url", ""),
+                    max_volume_count=int(hb.get("max_volume_count", 7)),
+                    volumes=hb.get("volumes", []),
+                    ec_shards=ec_shards,
+                    ec_collections=ec_collections,
+                    max_file_key=int(hb.get("max_file_key", 0)),
+                    ec_geometries=ec_geometries,
+                )
+            node = self.topology.find_node(url)
+            if node is not None:
+                node.hb_seq = max(node.hb_seq, seq)
+                if hb.get("device"):
+                    # the chip of a `-ec.backend tpu-own` server (no
+                    # other names one): /cluster/status passes it on
+                    node.device = hb["device"]
         out = {"volume_size_limit": self.topology.volume_size_limit,
-               "leader": self.leader_url() or self.url}
+               "leader": leader}
         if self.metrics_address:
             # reference master_grpc_server.go:75-77: the master decides
             # where and how often servers push metrics

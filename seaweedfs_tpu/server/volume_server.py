@@ -20,6 +20,7 @@ import numpy as np
 from ..ec.constants import MAX_SHARDS, to_ext
 from ..ops import telemetry
 from ..util import malloc_policy, tracing
+from ..util.locks import make_lock
 from ..storage.needle import Needle
 from ..storage.store import Store
 from ..storage.types import parse_file_id
@@ -251,6 +252,10 @@ class VolumeServer:
                 glog.V(0).infof("native read plane unavailable: %s", e)
                 self.fast_plane = None
         # delta-heartbeat state: last volume set acked, and by whom
+        self._hb_lock = make_lock("volume_server.heartbeat")
+        # orders this server's heartbeats at the master; the clock's
+        # nanoseconds at start, so a restarted server's come after
+        self._hb_seq = time.time_ns()
         self._hb_acked_master = None
         self._hb_acked_volumes = None
         self._hb_thread = threading.Thread(target=self._heartbeat_loop,
@@ -474,7 +479,9 @@ class VolumeServer:
             # the full state immediately
             resp = post_json(f"http://{target}/cluster/heartbeat", hb,
                              timeout=10)
-        if not resp.get("not_leader"):
+        if not resp.get("not_leader") and not resp.get("stale"):
+            # (a state the master dropped as overtaken acknowledges
+            # nothing: the newer one it holds set these)
             self._hb_acked_master = target
             self._hb_acked_volumes = {v["id"]: v for v in hb["volumes"]}
         return resp
@@ -482,8 +489,19 @@ class VolumeServer:
     def heartbeat_once(self):
         """Heartbeat the current master, trying every seed before
         giving up — startup must not die because the first listed seed
-        happens to be the down one."""
-        hb = self.store.collect_heartbeat()
+        happens to be the down one. The pulse thread and the handlers
+        that push a change (mount, unmount, delete_shards) all come
+        through here, side by side: each collected state takes the next
+        ``seq`` under ``_hb_lock`` (held for the collect only, never for
+        the POST), and the master drops a state older than the one it
+        last applied from this server. So a state collected before a
+        change cannot overwrite the one collected behind it (the master
+        would take dropped shards for held again until the next pulse,
+        and an `ec.rebuild` typed then finds nothing lost)."""
+        with self._hb_lock:
+            hb = self.store.collect_heartbeat()
+            self._hb_seq += 1
+            hb["seq"] = self._hb_seq
         if self.fast_plane is not None:
             hb["fast_url"] = self.fast_url
         last = None
@@ -765,7 +783,11 @@ class VolumeServer:
         inventory.initialized=false (the chip belongs to one process; a
         status question must not grab it)."""
         from ..ops import device_stats as _ds
-        return _ds.admin_snapshot()
+        out = _ds.admin_snapshot()
+        # the store's own chip among the inventory's (`tpu-own`), None
+        # where the backend names none
+        out["own"] = self.store.device()
+        return out
 
     def admin_plane_cache(self, req: Request):
         """Native-plane reconstructed-slab cache counters + EC serving
@@ -1096,7 +1118,14 @@ class VolumeServer:
         """Local rebuild from whole shard files (legacy, query-only), or
         — when the POST body carries ``sources`` ({shard: [holders]}) —
         the streaming striped gather: survivor ranges are pulled and
-        decoded in overlapped slabs, never landing whole on disk."""
+        decoded in overlapped slabs, never landing whole on disk.
+        ``target`` in the body names the server that keeps the rebuilt
+        shards where that is another one: this server decodes on its
+        chip and the rows go to the target's ``/admin/ec/shard_write``
+        (the flat full gather only; the caller then has the target pull
+        the sidecars and mount). The reply's stats name it
+        (``delivered_to``), and the reply the chip that decoded
+        (``device``: the store's own, "" where the backend names none)."""
         from ..stats.metrics import (observe_gather, observe_mesh,
                                      observe_repair)
         from ..util import tracing
@@ -1115,7 +1144,8 @@ class VolumeServer:
                 window=int(body.get("window") or 0) or None,
                 hedge_ms=float(hedge_ms) if hedge_ms is not None
                 else None,
-                repair=str(body.get("repair") or "auto"))
+                repair=str(body.get("repair") or "auto"),
+                deliver_to=body.get("target") or None)
             observe_gather(stats)
             observe_repair(stats)
             observe_mesh(stats)
@@ -1127,6 +1157,7 @@ class VolumeServer:
             # of them (engine LRU + plane slabs) are dead weight
             self._invalidate_reconstructions(vid, rebuilt)
         return {"volume": vid, "rebuilt": rebuilt, "stats": stats,
+                "device": (self.store.device() or {}).get("chip", ""),
                 "trace_id": tracing.current_trace_id()}
 
     def admin_ec_scrub(self, req: Request):

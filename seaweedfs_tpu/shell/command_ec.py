@@ -12,6 +12,7 @@ from typing import Dict, List
 
 from ..ec.constants import DATA_SHARDS, MAX_SHARDS, PARITY_SHARDS
 from ..server.http_util import HttpError
+from ..util.locks import make_lock
 from .command_env import CommandEnv, command, parse_flags
 
 
@@ -38,6 +39,86 @@ def pick_rebuilder(nodes: List[dict], shards: Dict[int, List[str]]) -> str:
     with_room = [n for n in nodes if n.get("free", 0) > 0] or nodes
     return min(with_room, key=lambda n: (held.get(n["url"], 0),
                                          -n.get("free", 0)))["url"]
+
+
+def chips_of(nodes: List[dict]) -> Dict[str, str]:
+    """url -> the chip that node's codecs compute on, as the master
+    passes on what the node's heartbeat names (`-ec.backend tpu-own`:
+    ``device.chip``, a name no other chip has). A node that names none
+    is taken to share one with every other such node: how many chips a
+    command may use is read from the cluster, never typed, and where
+    nothing is known it is one."""
+    return {n["url"]: (n.get("device") or {}).get("chip", "")
+            for n in nodes}
+
+
+def lanes_of(chips: Dict[str, str]) -> int:
+    """How many volumes a collection command keeps in flight: the
+    distinct chips among the cluster's nodes."""
+    return len(set(chips.values()))
+
+
+def run_in_lanes(jobs: list, chips: Dict[str, str], place, run):
+    """A collection's volumes over the cluster's chips: ONE volume in
+    flight per distinct chip, the jobs taken in order. ``place(job,
+    busy)`` is asked, with the volumes in flight by chip, where a job
+    would compute now: it returns (node url, whatever ``run`` needs
+    beside) or None while the job has to wait for a chip; the first job
+    of the order that has a place starts, on a thread of its own, and
+    ``run(job, placement)`` does the work. Where the cluster names one
+    chip (every deployment before `tpu-own`, and every server of one
+    process on `tpu`) nothing is placed and no thread is started:
+    ``run(job, None)`` in order on the caller's thread, which is what
+    the commands did before there were lanes. A job that raises stops
+    new ones from starting; those in flight finish, and the first
+    error in job order is raised."""
+    import threading
+    if lanes_of(chips) <= 1:
+        for job in jobs:
+            run(job, None)
+        return
+    busy = dict.fromkeys(chips.values(), 0)
+    freed = threading.Condition()
+    errors: Dict[int, BaseException] = {}
+    threads = []
+    pending = list(enumerate(jobs))
+
+    def work(n, job, placement):
+        try:
+            run(job, placement)
+        except BaseException as e:  # noqa: BLE001 - raised by the caller
+            errors[n] = e
+        finally:
+            with freed:
+                busy[chips[placement[0]]] -= 1
+                freed.notify()
+
+    while pending and not errors:
+        with freed:
+            snapshot = dict(busy)
+        start = None
+        # only this thread adds to `busy`: a job placed by the snapshot
+        # finds its chip no busier when it starts
+        for n, job in pending:
+            placement = place(job, snapshot)
+            if placement is not None:
+                start = (n, job, placement)
+                break
+        with freed:
+            if start is None:
+                if busy == snapshot:
+                    freed.wait()
+                continue
+            busy[chips[start[2][0]]] += 1
+        pending.remove(start[:2])
+        t = threading.Thread(target=work, args=start, daemon=True,
+                             name=f"ec-volume-{start[0]}")
+        threads.append(t)
+        t.start()
+    for t in threads:
+        t.join()
+    if errors:
+        raise errors[min(errors)]
 
 
 def _volume_replicas(env: CommandEnv, vid: int) -> List[dict]:
@@ -87,9 +168,9 @@ def collect_volume_ids_for_ec_encode(env: CommandEnv, collection: str,
                                      full_percent: float = 0.95,
                                      quiet_seconds: float = 3600,
                                      size_limit: int = None
-                                     ) -> Dict[int, int]:
-    """Quiet & nearly-full volumes and their sizes, by volume id
-    (reference collectVolumeIdsForEcEncode
+                                     ) -> Dict[int, tuple]:
+    """Quiet & nearly-full volumes by volume id: (size, the url of the
+    server that holds it) (reference collectVolumeIdsForEcEncode
     command_ec_encode.go:255-287)."""
     import time
     if size_limit is None:
@@ -107,7 +188,7 @@ def collect_volume_ids_for_ec_encode(env: CommandEnv, collection: str,
         modified = vi.get("modified_at", 0)
         if modified and now - modified < quiet_seconds:
             continue
-        out[int(vid_s)] = int(vi.get("size", 0))
+        out[int(vid_s)] = (int(vi.get("size", 0)), vi.get("url"))
     return out
 
 
@@ -134,19 +215,35 @@ def ec_encode(env: CommandEnv, args: List[str]):
     if "collection" not in flags:
         env.write("usage: ec.encode -volumeId <id> | -collection <name>")
         return
-    sizes = collect_volume_ids_for_ec_encode(
+    found = collect_volume_ids_for_ec_encode(
         env, flags["collection"], float(flags.get("fullPercent", 0.95)),
         quiet_seconds=float(flags.get("quietFor", 3600)))
     # the whole command under one span of its own trace; each volume's
     # ec.encode stays the root of its own and names this one
     whole = tracing.Span("ec.encode.collection", tags={
         "collection": flags["collection"], "volumes": 0, "bytes": 0})
-    try:
-        for vid, size in sizes.items():
-            do_ec_encode(env, vid, mode=flags.get("mode"),
-                         geometry=geometry, command=whole.trace_id)
+
+    counted = make_lock("command_ec.collection_span")
+
+    def encode(vid, _placement):
+        do_ec_encode(env, vid, mode=flags.get("mode"),
+                     geometry=geometry, command=whole.trace_id)
+        with counted:       # volumes in lanes finish on their threads
             whole.tags["volumes"] += 1
-            whole.tags["bytes"] += size
+            whole.tags["bytes"] += found[vid][0]
+
+    chips = chips_of(env.cluster_nodes())
+
+    def home_if_free(vid, busy):
+        # a volume is coded where its .dat lies: it starts when that
+        # node's chip has nothing in flight (asked only where there are
+        # lanes; a volume whose holder the cluster does not list raises)
+        home = found[vid][1]
+        return None if busy[chips[home]] else (home,)
+
+    try:
+        run_in_lanes(sorted(found) if lanes_of(chips) > 1 else list(found),
+                     chips, home_if_free, encode)
     finally:
         tracing.finish_span(whole)
 
@@ -408,7 +505,21 @@ def ec_rebuild(env: CommandEnv, args: List[str]):
     whole = tracing.Span("ec.rebuild.collection", tags={
         "collection": flags.get("collection", ""), "volumes": 0,
         "bytes": 0})
+    counted = make_lock("command_ec.collection_span")
+
+    def rebuild(job, placement):
+        vid, collection, shards, missing = job
+        timings: Dict = {}
+        do_ec_rebuild(env, vid, collection, shards, missing,
+                      timings=timings, mode=flags.get("mode"),
+                      repair=flags.get("repair"),
+                      command=whole.trace_id, placement=placement)
+        with counted:       # volumes in lanes finish on their threads
+            whole.tags["volumes"] += 1
+            whole.tags["bytes"] += timings.get("rebuilt_bytes", 0)
+
     try:
+        jobs = []
         for vid_s, info in env.ec_volumes().items():
             vid = int(vid_s)
             collection = info.get("collection", "")
@@ -423,15 +534,48 @@ def ec_rebuild(env: CommandEnv, args: List[str]):
                 env.write(f"volume {vid}: only {len(shards)} shards left, "
                           f"cannot rebuild")
                 continue
-            timings: Dict = {}
-            do_ec_rebuild(env, vid, collection, shards, missing,
-                          timings=timings, mode=flags.get("mode"),
-                          repair=flags.get("repair"),
-                          command=whole.trace_id)
-            whole.tags["volumes"] += 1
-            whole.tags["bytes"] += timings.get("rebuilt_bytes", 0)
+            jobs.append((vid, collection, shards, missing))
+        chips = chips_of(env.cluster_nodes()) if jobs else {}
+        if lanes_of(chips) > 1:
+            jobs.sort(key=lambda job: job[0])
+
+        def where(job, busy):
+            if all(busy.values()):
+                return None     # no chip free: ask the master nothing
+            return place_rebuild(env.cluster_nodes(), chips, busy,
+                                 job[2], job[3])
+
+        run_in_lanes(jobs, chips, where, rebuild)
     finally:
         tracing.finish_span(whole)
+
+
+def place_rebuild(nodes: List[dict], chips: Dict[str, str],
+                  busy: Dict[str, int], shards: Dict[int, List[str]],
+                  missing: List[int]):
+    """(computing node, target) of one volume's rebuild, or None while
+    it has to wait. The target is placement's
+    (`pick_rebuilder`: fewest of this volume's shards, then freest) and
+    keeps the rebuilt shards; the node that gathers and decodes is the
+    target where its chip has nothing in flight from this command, else
+    a node on the chip with least in flight, of those the one that holds
+    most of the volume's survivors (least to gather) — f4's rebuilder
+    nodes, apart from its storage nodes. Only a loss of several shards
+    (the flat full gather) is decoded off its target; a single shard's
+    routes (trace, half-planes) read and write on the target, and wait
+    for its chip."""
+    target = pick_rebuilder(nodes, shards)
+    if not busy[chips[target]]:
+        return target, target
+    if len(missing) < 2:
+        return None
+    held: Dict[str, int] = {}
+    for urls in shards.values():
+        for url in urls:
+            held[url] = held.get(url, 0) + 1
+    node = min((n["url"] for n in nodes if n["url"] in chips),
+               key=lambda url: (busy[chips[url]], -held.get(url, 0)))
+    return node, target
 
 
 def _merge_rebuild_stats(timings: Dict, out: dict):
@@ -464,7 +608,8 @@ def _merge_rebuild_stats(timings: Dict, out: dict):
 def do_ec_rebuild(env: CommandEnv, vid: int, collection: str,
                   shards: Dict[int, List[str]], missing: List[int],
                   timings: Dict[str, float] = None, mode: str = None,
-                  repair: str = None, command: str = None):
+                  repair: str = None, command: str = None,
+                  placement: tuple = None):
     """`timings`, when given, records the phase walls plus the
     rebuilder's stats (gather/compute busy time, overlap_frac, dispatch
     telemetry) — the benchmark's overlap accounting.
@@ -483,7 +628,16 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str,
     k+1 helpers) on piggyback volumes. "trace"/"piggyback" force the
     matching strategy and error on the other layout; "full" forces the
     k-survivor gather on either. Stream mode only. ``command`` is the
-    trace id of the `ec.rebuild` command's span, kept as a tag."""
+    trace id of the `ec.rebuild` command's span, kept as a tag.
+
+    ``placement`` is (computing node, target) where the command runs
+    its volumes in lanes (`place_rebuild`); None: the target is picked
+    here and computes, as it always did. Where the two differ the
+    computing node decodes and delivers the rebuilt shards to the
+    target's disk, the target pulls the sidecars and mounts; if that
+    fails the target is cleaned of every partial shard and the volume
+    is rebuilt on the target itself. The span's ``device`` tag is the
+    chip the node that decoded names in its reply."""
     from ..util import config as _config
     from ..util import tracing
     mode = (mode or _config.env_str("SW_EC_GATHER_MODE") or
@@ -497,15 +651,35 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str,
     if command:
         root.tags["command"] = command
     try:
-        rebuilder = pick_rebuilder(env.cluster_nodes(), shards)
+        node, rebuilder = placement or (None, None)
+        if rebuilder is None:
+            rebuilder = node = pick_rebuilder(env.cluster_nodes(), shards)
+        if mode == "copy":
+            node = rebuilder
+        root.tags["target"], root.tags["computed_on"] = rebuilder, node
+        root.tags["device"] = ""    # the node that decodes names it
         if mode == "copy":
             rebuilt = _rebuild_via_copy(env, vid, collection, shards,
                                         rebuilder, root, timings)
         else:
             try:
-                rebuilt = _rebuild_streaming(env, vid, collection,
-                                             shards, rebuilder, root,
-                                             timings, repair=repair)
+                if node != rebuilder:
+                    try:
+                        rebuilt = _rebuild_streaming(
+                            env, vid, collection, shards, rebuilder, root,
+                            timings, repair=repair, node=node)
+                    except HttpError as e:
+                        env.write(f"volume {vid}: rebuild on {node} for "
+                                  f"{rebuilder} failed ({e}); rebuilding "
+                                  f"on {rebuilder}")
+                        root.tags["fallback"] = "target"
+                        root.tags["computed_on"] = node = rebuilder
+                        _cleanup_partial_rebuild(env, vid, collection,
+                                                 rebuilder, missing)
+                if node == rebuilder:
+                    rebuilt = _rebuild_streaming(env, vid, collection,
+                                                 shards, rebuilder, root,
+                                                 timings, repair=repair)
             except HttpError as e:
                 env.write(f"volume {vid}: streaming rebuild failed "
                           f"({e.status}); falling back to copy mode")
@@ -523,25 +697,58 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str,
     env.write(f"volume {vid}: rebuilt shards {rebuilt} on {rebuilder}")
 
 
+def _cleanup_partial_rebuild(env: CommandEnv, vid: int, collection: str,
+                             target: str, missing: List[int]):
+    """What a delivery that died may have left on the target: the
+    `.part` stages of the shards it was sending, and a shard it had
+    already finalized. None of them is mounted (the mount comes after
+    the last); all go, as `_cleanup_partial_encode` clears a failed
+    spread, and the survivors the target holds stay."""
+    try:
+        env.node_post(target, f"/admin/ec/delete_shards?volume={vid}"
+                              f"&collection={collection}"
+                              f"&shards={','.join(map(str, missing))}")
+    except HttpError:
+        pass
+
+
 def _rebuild_streaming(env: CommandEnv, vid: int, collection: str,
                        shards: Dict[int, List[str]], rebuilder: str,
                        root, timings: Dict = None,
-                       repair: str = "auto") -> List[int]:
+                       repair: str = "auto",
+                       node: str = None) -> List[int]:
     """One POST: the rebuilder pulls slab-aligned survivor ranges from
     the holder map and feeds them straight into the pipelined decode
     (or, single-shard loss with ``repair`` auto/trace/piggyback, pulls
     projected repair symbols or half-shard planes from the helpers the
-    volume's layout prescribes)."""
+    volume's layout prescribes). ``node``, where given and another
+    server than ``rebuilder``, does the gather and the decode in its
+    place and sends the rebuilt shards to the rebuilder's disk; the
+    rebuilder then pulls the sidecars from a holder, as a target of the
+    encode's spread does, and mounts."""
     import time as _time
+    node = node or rebuilder
     sources = {str(sid): urls for sid, urls in shards.items()
-               if rebuilder not in urls}
+               if node not in urls}
+    body = {"sources": sources, "repair": repair}
+    if node != rebuilder:
+        body["target"] = rebuilder
     t0 = _time.perf_counter()
     out = env.node_post(
-        rebuilder,
+        node,
         f"/admin/ec/rebuild?volume={vid}&collection={collection}",
-        body={"sources": sources, "repair": repair})
+        body=body)
     t1 = _time.perf_counter()
+    root.tags["device"] = out.get("device", "")
     rebuilt = out.get("rebuilt", [])
+    if rebuilt and node != rebuilder and \
+            not any(rebuilder in urls for urls in shards.values()):
+        # the shard bytes are there; the index a mount needs is not
+        holder = next(urls[0] for urls in shards.values() if urls)
+        env.node_post(rebuilder, f"/admin/ec/copy?volume={vid}"
+                                 f"&collection={collection}"
+                                 f"&source={holder}&shards="
+                                 f"&copy_ecx=true")
     if timings is not None:
         stats = out.get("stats") or {}
         # stream mode has no serialized gather wall: report the busy

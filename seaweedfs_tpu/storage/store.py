@@ -7,6 +7,8 @@ volumes, and hosts the EC lifecycle operations (generate/mount/rebuild).
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import os
 import threading
 from ..util.locks import make_rlock
@@ -21,6 +23,19 @@ from .disk_location import DiskLocation
 from .needle import Needle
 from .types import TTL, ReplicaPlacement
 from .volume import Volume, VolumeError, volume_file_prefix
+
+
+def _remove_quietly(path: str):
+    try:
+        os.remove(path)
+    except OSError:
+        pass
+
+
+#: `-ec.backend tpu-own`: the process's stores take its local chips in
+#: turn, in the order they are built (the chip is the ordinal modulo the
+#: local device count, ops/rs_tpu.local_device)
+_OWN_DEVICE_ORDINALS = itertools.count()
 
 
 class Store:
@@ -45,6 +60,11 @@ class Store:
         # its geometry's, and names the geometry of a volume whose
         # sidecars name none (10 + 4 unless a caller brought another)
         self.ec_backend = ec_backend
+        # a chip of its own among the process's (`tpu-own`); None: the
+        # codecs compute wherever their backend does
+        self.device_ordinal = next(_OWN_DEVICE_ORDINALS) \
+            if ec_backend == "tpu-own" else None
+        self._device: Optional[dict] = None
         self.default_geometry = (codec.k, codec.m) if codec is not None \
             else (DATA_SHARDS, PARITY_SHARDS)
         self._codecs: Dict[tuple, ReedSolomonCodec] = {}
@@ -80,9 +100,35 @@ class Store:
                     codec = type(like)(*key, like.matrix_kind)
                 else:
                     from ..ops.codec import get_codec
-                    codec = get_codec(*key, backend=self.ec_backend)
+                    codec = get_codec(*key, backend=self.ec_backend,
+                                      device_ordinal=self.device_ordinal
+                                      or 0)
                 self._codecs[key] = codec
             return codec
+
+    def device(self) -> Optional[dict]:
+        """The chip this store's codecs compute on, where it has one of
+        its own (`tpu-own`): platform, index among the process's local
+        devices, kind, and ``chip``, a name no other chip of the cluster
+        has (host, process and index: two servers share a chip exactly
+        where they name the same one). None on every other backend: the
+        server then says nothing of a device, and whoever asks takes
+        such servers to share one. Resolving it is the store's first
+        JAX touch (a `tpu-own` server asked for a chip; it is taken when
+        the server starts, not at a status question of a process that
+        has none yet)."""
+        if self.device_ordinal is None:
+            return None
+        if self._device is None:
+            import socket
+            from ..ops.rs_tpu import local_device
+            index, dev = local_device(self.device_ordinal)
+            self._device = {
+                "platform": dev.platform, "index": index,
+                "kind": getattr(dev, "device_kind", "unknown"),
+                "chip": f"{socket.gethostname()}/{os.getpid()}/"
+                        f"{dev.platform}{index}"}
+        return self._device
 
     @property
     def codec(self) -> ReedSolomonCodec:
@@ -443,7 +489,8 @@ class Store:
                                     slab: Optional[int] = None,
                                     window: Optional[int] = None,
                                     hedge_ms: Optional[float] = None,
-                                    repair: str = "auto"
+                                    repair: str = "auto",
+                                    deliver_to: Optional[str] = None
                                     ) -> List[int]:
         """Rebuild missing shards by streaming slab ranges of remote
         survivors straight into the decode — no whole-shard copies on
@@ -470,7 +517,15 @@ class Store:
         ``telemetry.repair_fallbacks`` and named under
         ``repair_fallback``. Forcing ``trace`` on a piggyback volume
         (or ``piggyback`` on flat) is an error: the modes read parity
-        bytes the other layout does not have."""
+        bytes the other layout does not have.
+
+        ``deliver_to`` names another server as the home of the rebuilt
+        shards: this one gathers and decodes (on its chip), and the rows
+        leave through the encode's sink to that server's disk
+        (ec/spread.RebuiltShardSink; nothing of them is written here,
+        and sidecars this server pulled for the decode alone are dropped
+        again). The flat full gather only: a piggyback volume is refused
+        (VolumeError), and a single-shard loss is decoded in full."""
         import time as _time
         from ..ec import gather
         from ..util import tracing
@@ -494,7 +549,10 @@ class Store:
         if loc is None:
             loc = self.find_free_location() or self.locations[0]
         base = volume_file_prefix(loc.directory, collection, vid)
-        with tracing.span("ec.rebuild.stream", volume=vid) as root:
+        if deliver_to == self.public_url:
+            deliver_to = None
+        with tracing.span("ec.rebuild.stream", volume=vid) as root, \
+                contextlib.ExitStack() as borrowed:
             if holders:
                 # the sidecars this rebuilder lacks (.ecx by the entry,
                 # so by the needle: 0.5 MB for a volume of 4 KB needles)
@@ -503,6 +561,13 @@ class Store:
                         base, holders)
                     pulled.nbytes = sum(os.path.getsize(base + ext)
                                         for ext in pulled.tags["files"])
+                if deliver_to and not any(
+                        os.path.exists(base + to_ext(i))
+                        for i in range(MAX_SHARDS)):
+                    # nothing of the volume lives here and nothing will:
+                    # what was pulled is for this decode alone
+                    for ext in pulled.tags["files"]:
+                        borrowed.callback(_remove_quietly, base + ext)
             # the .vif is local now (fetched above when remote): the
             # volume's own geometry, and the codec of that geometry
             codec = self.volume_codec(base)
@@ -524,6 +589,14 @@ class Store:
             # sidecars are local now (fetched above when remote): the
             # volume's layout routes every path below
             li = self._volume_layout(base)
+            if deliver_to:
+                if li.piggyback:
+                    raise VolumeError(
+                        "a rebuild delivered to another node is the flat "
+                        "full gather's; a piggyback volume is rebuilt on "
+                        "the node that keeps the shards")
+                mode = "full"
+                root.tags["deliver_to"] = deliver_to
             if mode == "trace" and li.piggyback:
                 raise VolumeError(
                     "-repair trace: volume has the piggyback layout "
@@ -620,9 +693,15 @@ class Store:
                 source = gather.StripedGatherSource(
                     readers, shard_size, slab=eff_slab,
                     window=window, stats=gstats, parent_span=root)
+                sink = None
+                if deliver_to:
+                    from ..ec import spread
+                    sink = spread.RebuiltShardSink(
+                        vid, missing, deliver_to, collection=collection,
+                        parent_span=root, slab=eff_slab)
                 rebuilt = ec_encoder.rebuild_ec_files_streaming(
                     base, gather_present, missing, source,
-                    codec=codec, slab=eff_slab, stats=stats)
+                    codec=codec, slab=eff_slab, stats=stats, sink=sink)
                 from ..stats.metrics import observe_transport
                 observe_transport("pull", gstats, window=source.window)
                 if stats is not None:
@@ -884,7 +963,7 @@ class Store:
                 ec_shards[vid] = bits
                 ec_collections[vid] = ev.collection
                 ec_geometries[vid] = [ev.k, ev.m]
-        return {
+        hb = {
             "ip": self.ip, "port": self.port, "public_url": self.public_url,
             "data_center": self.data_center, "rack": self.rack,
             "max_volume_count": max_volume_count,
@@ -896,6 +975,12 @@ class Store:
             # master knows a volume whole at k + m shards
             "ec_geometries": ec_geometries,
         }
+        if self.device_ordinal is not None:
+            # the chip of a `tpu-own` server: the master passes it on
+            # (/cluster/status), the shell keeps one volume in flight a
+            # distinct chip
+            hb["device"] = self.device()
+        return hb
 
     def status(self) -> dict:
         hb = self.collect_heartbeat()

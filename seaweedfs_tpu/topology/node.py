@@ -64,6 +64,11 @@ class DataNode:
         # native read plane, when the server advertises one (empty
         # otherwise); read paths prefer it for plain needle GETs
         self.fast_url = ""
+        # the chip the server's codecs compute on, as its heartbeat
+        # names it (`-ec.backend tpu-own`); None: it names none
+        self.device: Optional[dict] = None
+        # `seq` of the heartbeat last applied (0: the server sends none)
+        self.hb_seq = 0
         self.max_volume_count = max_volume_count
         self.volumes: Dict[int, VolumeInfo] = {}
         self.ec_shards: Dict[int, ShardBits] = {}  # vid -> bits
@@ -133,6 +138,7 @@ class DataNode:
             "rack": rack.id if rack else "",
             "dataCenter": rack.data_center.id
             if rack and rack.data_center else "",
+            **({"device": self.device} if self.device else {}),
         }
 
 

@@ -411,3 +411,40 @@ def test_a_single_volume_encode_leaves_no_command_span(served):
     # every span of that name here came from the one -collection command)
     assert len([s for s in served["spans"]
                 if s["name"] == "ec.encode.collection"]) == 1
+
+
+# -- servers that share a chip: one volume in flight (PR 45) -----------------
+
+def _volumes_of(served, name: str) -> list:
+    commands = {s["trace_id"] for s in served["spans"]
+                if s["name"] == name + ".collection"}
+    return sorted((s for s in served["spans"] if s["name"] == name
+                   and s["tags"].get("command") in commands),
+                  key=lambda s: s["start"])
+
+
+@pytest.mark.parametrize("name", ["ec.encode", "ec.rebuild"])
+def test_on_one_chip_a_volume_starts_when_the_last_has_ended(served, name):
+    """The four servers are one process on `-ec.backend tpu`: none names a
+    chip, the shell reads one lane from the cluster, and a collection's
+    volumes follow each other as they always did."""
+    volumes = _volumes_of(served, name)
+    assert len(volumes) == VOLUMES * (1 if name == "ec.encode" else 4)
+    for before, after in zip(volumes, volumes[1:]):
+        assert before["start"] + before["duration_s"] <= \
+            after["start"] + 1e-6
+
+
+def test_on_one_chip_the_target_decodes_for_itself(served):
+    for span in _volumes_of(served, "ec.rebuild"):
+        assert span["tags"]["computed_on"] == span["tags"]["target"]
+        assert span["tags"]["device"] == ""
+    assert not [s for s in served["spans"]
+                if s["name"] == "ec.rebuild.deliver"]
+    for loss in served["losses"]:
+        assert all("delivered_to" not in r
+                   for r in _replies(loss, "/admin/ec/rebuild"))
+    # the two counters are on the scrape, beside the other telemetry
+    for kind in ("rebuild_delivered_bytes", "rebuild_local_bytes"):
+        assert f'ec_device_telemetry_total{{kind="{kind}"}}' in \
+            served["scrape"]

@@ -92,6 +92,49 @@ class TestCompileExecuteSplit:
         assert moved["recompiles_total"] == 0
 
 
+class TestAProgramOfOneChip:
+    """`-ec.backend tpu-own`: the same jitted program as each chip's
+    own, its executables and its counts apart (PR 45)."""
+
+    def test_a_sibling_a_chip_compiles_once_each_and_never_latches(self):
+        import jax
+        stats = DeviceStats()
+        fn = wrap(_jit_scale(), "t.own", stats=stats)
+        devices = jax.local_devices()[:3]
+        for index, dev in enumerate(devices):
+            on = fn.on_device(index)
+            assert on is fn.on_device(index) and on.device == index
+            assert on.raw_jit is fn.raw_jit and on.entry == "t.own"
+            for _ in range(index + 1):
+                out = on(jax.device_put(_const(), dev),
+                         jax.device_put(_data(512), dev))
+            assert out.devices() == {dev}
+            assert (np.asarray(out) == 3).all()
+        snap = stats.snapshot()
+        # a chip's first use of a (entry, bucket) is a compile, not a
+        # recompile of the chip before it
+        assert snap["compiles"] == {"t.own": 3}
+        assert snap["recompiles"] == {} and snap["sentinel"] is False
+        assert snap["dispatches"] == {"t.own": 6, "dev0": 1, "dev1": 2,
+                                      "dev2": 3}
+
+    def test_the_same_chip_twice_is_still_a_recompile(self):
+        stats = DeviceStats()
+        stats.note_compile("t.own", ("sig", 512), 0.1, chip="dev1")
+        stats.note_compile("t.own", ("sig", 512), 0.1, chip="dev2")
+        assert stats.sentinel is False
+        stats.note_compile("t.own", ("sig", 512), 0.1, chip="dev1")
+        assert stats.sentinel is True and stats.recompiles == {"t.own": 1}
+        assert stats.offenders[0].startswith("t.own:('dev1'")
+
+    def test_a_program_of_no_chip_counts_under_no_chip(self):
+        stats = DeviceStats()
+        fn = wrap(_jit_scale(), "t.anywhere", stats=stats)
+        assert fn.device is None
+        fn(_const(), _data(512))
+        assert stats.snapshot()["dispatches"] == {"t.anywhere": 1}
+
+
 class TestRecompileSentinel:
     def test_shape_churn_latches_while_bucketed_stays_zero(self):
         stats = DeviceStats()

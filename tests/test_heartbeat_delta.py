@@ -91,6 +91,40 @@ def test_master_amnesia_forces_resync(cluster):
     assert vid in master.topology.find_node(vs.url).volumes
 
 
+def test_an_overtaken_heartbeat_is_dropped(cluster):
+    """A server's heartbeats are posted side by side (the pulse thread,
+    a handler that pushes a change): the master applies a state only if
+    none collected after it was applied before, whichever arrives
+    first, and the sender takes a dropped one for no acknowledgement."""
+    master, vs = cluster
+    a = op.assign(master.url)
+    vid = int(a["fid"].split(",")[0])
+    vs.heartbeat_once()
+    node = master.topology.find_node(vs.url)
+    first = node.hb_seq
+    assert first == vs._hb_seq > 0
+    vs.heartbeat_once()
+    assert node.hb_seq == vs._hb_seq == first + 1
+    # the state collected BEFORE the volume existed arrives after it
+    old = vs.store.collect_heartbeat()
+    old["volumes"], old["seq"] = [], first
+    acked = dict(vs._hb_acked_volumes)
+    resp = vs._post_heartbeat(old, vs.master_url)
+    assert resp.get("stale") is True
+    assert vid in node.volumes and node.hb_seq == first + 1
+    assert vs._hb_acked_volumes == acked
+    # equal or newer is applied; a server that sends no seq is as before
+    old["seq"] = first + 1
+    assert not post_json(f"http://{master.url}/cluster/heartbeat",
+                         old).get("stale")
+    assert vid not in node.volumes
+    del old["seq"]
+    old["volumes"] = [hb_volume(vid)]
+    assert not post_json(f"http://{master.url}/cluster/heartbeat",
+                         old).get("stale")
+    assert vid in node.volumes and node.hb_seq == first + 1
+
+
 def test_immediate_push_beats_the_pulse(tmp_path):
     """Volume create and EC shard mount must reach the master within
     milliseconds via the store change hook (reference store.go:40-64

@@ -369,7 +369,9 @@ def test_counters_at_the_stage_boundaries(cycle):
         "lock_probe_elapsed_us", "lock_probe_late_us",
         "lock_probe_stalls", "lock_probe_stall_us",
         # PR 43: indexes loaded as arrays (tests/test_idx_array.py)
-        "mirror_entries", "mirror_us", "mirror_loop_entries"}
+        "mirror_entries", "mirror_us", "mirror_loop_entries",
+        # PR 45: decode apart from storage (tests/test_fanned_served.py)
+        "rebuild_delivered_bytes", "rebuild_local_bytes"}
     fetched = sum(s["tags"]["bytes"] for s in cycle["spans"]
                   if s["name"].startswith("ec.rebuild.fetch."))
     assert fetched == cycle["rebuild"]["survivor_bytes"] > 0
@@ -498,3 +500,141 @@ def test_ring_counts_the_spans_it_drops():
     assert ring.dropped_of("a") == 0 and ring.dropped == 2
     ring.clear()
     assert ring.dropped == 0
+
+
+# -- a rebuild decoded apart from where it is stored (PR 45) -----------------
+
+@pytest.fixture(scope="module")
+def delivered(tmp_path_factory):
+    """Three servers on `tpu-own` (a host device each); one volume coded,
+    two shards of one holder lost, and the rebuild placed by hand: a
+    survivor holder computes, the holder that lost them is the target."""
+    from seaweedfs_tpu.client import operation as op
+    from seaweedfs_tpu.ec.constants import TOTAL_SHARDS
+    from seaweedfs_tpu.ops import device_stats
+    from seaweedfs_tpu.server.http_util import get_json, post_json
+    from seaweedfs_tpu.server.master import MasterServer
+    from seaweedfs_tpu.server.volume_server import VolumeServer
+    from seaweedfs_tpu.shell.command_ec import (chips_of, do_ec_encode,
+                                                do_ec_rebuild)
+    from seaweedfs_tpu.shell.command_env import CommandEnv
+
+    tmp = tmp_path_factory.mktemp("delivered")
+    master = MasterServer(port=0, volume_size_limit_mb=64,
+                          pulse_seconds=1, growth_counts={1: 1}).start()
+    servers = [VolumeServer(
+        port=0, directories=[str(tmp / f"v{i}")], master_url=master.url,
+        pulse_seconds=1, max_volume_counts=[20],
+        ec_backend="tpu-own").start() for i in range(3)]
+    spans, out = [], {}
+    try:
+        env = CommandEnv(master.url, out=io.StringIO())
+        assert wait_until(lambda: len(env.cluster_nodes()) == 3)
+        a = op.assign(master.url, collection="dl")
+        vid = int(a["fid"].split(",")[0])
+        rng = np.random.default_rng(45)
+        for i in range(10):
+            op.upload(a["url"], f"{vid},{i + 1:x}00000001",
+                      rng.integers(0, 256, 2_000_000).astype(
+                          np.uint8).tobytes(), filename=f"f{i}")
+
+        def lookup():
+            ec = get_json(f"http://{master.url}/cluster/ec_lookup"
+                          f"?volumeId={vid}")
+            return {int(s): u for s, u in ec.get("shards", {}).items()
+                    if u}
+
+        do_ec_encode(env, vid)
+        assert wait_until(lambda: len(lookup()) == TOTAL_SHARDS)
+        by_holder = {}
+        for sid, urls in lookup().items():
+            by_holder.setdefault(urls[0], []).append(sid)
+        target, held = min(by_holder.items(), key=lambda kv: len(kv[1]))
+        node = next(u for u in by_holder if u != target)
+        lost = sorted(held)[:2]
+        post_json(f"http://{target}/admin/ec/delete_shards?volume={vid}"
+                  f"&collection=dl&shards={','.join(map(str, lost))}")
+        assert wait_until(lambda: not set(lost) & set(lookup()))
+        chips = chips_of(env.cluster_nodes())
+        tracing.add_finish_hook(spans.append)
+        before = telemetry.STATS.snapshot()
+        jit = device_stats.DEVICE_STATS.snapshot()["dispatches"]
+        timings = {}
+        do_ec_rebuild(env, vid, "dl", lookup(), lost, timings=timings,
+                      placement=(node, target))
+        out.update(
+            reply=dict(timings), counters=telemetry.delta(before),
+            jit={e: n - jit.get(e, 0) for e, n in
+                 device_stats.DEVICE_STATS.snapshot()["dispatches"].items()
+                 if n - jit.get(e, 0)},
+            node=node, target=target, lost=lost, chips=chips,
+            index={vs.url: vs.store.device()["index"] for vs in servers})
+        assert wait_until(lambda: len(lookup()) == TOTAL_SHARDS)
+        out["holders"] = lookup()
+    finally:
+        tracing.remove_finish_hook(spans.append)
+        for vs in servers:
+            vs.stop()
+        master.stop()
+    out["spans"] = spans
+    return out
+
+
+def test_the_deliver_stage_is_the_consumers_under_the_stream(delivered):
+    tid = delivered["reply"]["trace_id"]
+    mine = [s for s in delivered["spans"] if s["trace_id"] == tid]
+    stream = [s for s in mine if s["name"] == "ec.rebuild.stream"]
+    assert len(stream) == 1
+    assert stream[0]["tags"]["deliver_to"] == delivered["target"]
+    delivers = [s for s in mine if s["name"] == "ec.rebuild.deliver"]
+    assert delivers and all(s["parent_id"] == stream[0]["span_id"] and
+                            _inside(s, stream[0]) for s in delivers)
+    # where a local rebuild has ec.rebuild.write, and on the same thread
+    assert not [s for s in mine if s["name"] == "ec.rebuild.write"]
+    threads = {s["tags"]["thread"] for s in delivers}
+    assert threads == {s["tags"]["thread"] for s in mine
+                       if s["name"] == "ec.h2d"}
+    reply = delivered["reply"]
+    assert sum(s["tags"]["bytes"] for s in delivers) == \
+        reply["rebuilt_bytes"] == 2 * reply["survivor_bytes"] // 10
+    assert sum(s["duration_s"] for s in delivers) == \
+        pytest.approx(reply["phases"]["deliver"], abs=1e-4)
+    assert reply["delivered_to"] == delivered["target"]
+    assert 0 <= reply["deliver_blocked_s"] <= reply["phases"]["deliver"] + 1e-3
+    assert reply["stage_max_s"]["deliver"] > 0
+
+
+def test_the_targets_appends_join_the_rebuilds_trace(delivered):
+    tid = delivered["reply"]["trace_id"]
+    appends = [s for s in delivered["spans"] if s["trace_id"] == tid
+               and s["name"] == "POST /admin/ec/shard_write"]
+    # two shards: at least an append and a finalize each
+    assert len(appends) >= 4
+    sends = [s for s in delivered["spans"] if s["trace_id"] == tid
+             and s["name"] == "ec.spread.send"]
+    assert sends and {s["tags"]["target"] for s in sends} == \
+        {delivered["target"]}
+    pulls = [s for s in delivered["spans"] if s["trace_id"] == tid
+             and s["name"] == "POST /admin/ec/copy"]
+    assert not pulls        # the target kept survivors: its index is there
+    for sid in delivered["lost"]:
+        assert delivered["holders"][sid] == [delivered["target"]]
+
+
+def test_the_volumes_span_names_who_computed_for_whom(delivered):
+    tid = delivered["reply"]["trace_id"]
+    root = next(s for s in delivered["spans"] if s["trace_id"] == tid
+                and s["name"] == "ec.rebuild")
+    assert root["tags"]["computed_on"] == delivered["node"]
+    assert root["tags"]["target"] == delivered["target"]
+    assert root["tags"]["device"] == delivered["chips"][delivered["node"]]
+    assert "fallback" not in root["tags"]
+    # counted by where the bytes went, and by the chip that dispatched
+    counters = delivered["counters"]
+    assert counters["rebuild_delivered_bytes"] == \
+        delivered["reply"]["rebuilt_bytes"]
+    assert counters["rebuild_local_bytes"] == 0
+    dev = f"dev{delivered['index'][delivered['node']]}"
+    assert {e for e in delivered["jit"] if e.startswith("dev")} == {dev}
+    assert delivered["jit"][dev] == delivered["jit"]["rs_tpu._packed_fn"] \
+        == counters["dispatches"]

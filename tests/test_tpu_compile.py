@@ -202,3 +202,32 @@ def test_mesh_program_compiles_on_four_chips(topo, rows_in, rows_out, n):
     per_device = compiled.memory_analysis().argument_size_in_bytes \
         - rows_in * 8 * rows_out * 8
     assert quarter <= per_device <= 1.7 * quarter
+
+
+@pytest.mark.parametrize("r", [4, 3], ids=["holder-of-4", "holder-of-3"])
+def test_fused_decode_compiles_for_each_chip_of_the_host(topo, r):
+    """`-ec.backend tpu-own`: a server's codec lowers the same jitted
+    program with operands committed to its own chip, so the fanned
+    rebuild's warm-up compiles the (lost, 10) decode at the 8 MiB slab
+    once a chip. For the described host's four devices: four compiles of
+    one program, each through Mosaic, each for its one device."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from seaweedfs_tpu.ec.encoder import DEFAULT_SLAB
+    from seaweedfs_tpu.ops.rs_pallas import _fused_fn, pick_tile
+    k, n = 10, DEFAULT_SLAB
+    assert len(topo.devices) == 4
+    fn = _fused_fn(k, r, n, pick_tile(k, r, n), False)
+    for index, device in enumerate(topo.devices):
+        own = fn.on_device(index)
+        assert own.raw_jit is fn.raw_jit and own.device == index
+        chip = SingleDeviceSharding(device)
+        compiled = own.raw_jit.lower(
+            jax.ShapeDtypeStruct((8 * r, 8 * k), jnp.int8, sharding=chip),
+            jax.ShapeDtypeStruct((k, n), jnp.uint8, sharding=chip)
+        ).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        (const_at, data_at), _ = compiled.input_shardings
+        assert const_at.device_set == data_at.device_set == {device}
+        assert compiled.output_shardings.device_set == {device}
