@@ -62,12 +62,13 @@ def write_sorted_file_from_idx(base_name: str, ext: str = ".ecx",
     follows the volume's offset width (superblock flag; 5-byte-offset
     volumes have 17B .idx/.ecx records).
 
-    One Python iteration an entry to parse, one to sort and pack: on a
-    volume of small needles it is a stage of its own, so a stream's
-    ``timer`` takes it as ``index`` (span ``ec.encode.index`` under the
-    timer's root, tagged with what it read and wrote) and the process
-    counts it (ops/telemetry ``index_entries``, ``index_us``) whoever
-    called."""
+    The log is read as one record array and sorted once
+    (needle_map.MemDb.load_from_idx), a ``delete`` a key its log leaves
+    dead. On a volume of small needles it is still a stage of its own,
+    so a stream's ``timer`` takes it as ``index`` (span
+    ``ec.encode.index`` under the timer's root, tagged with what it read
+    and wrote) and the process counts it (ops/telemetry
+    ``index_entries``, ``index_us``) whoever called."""
     from ..ops import telemetry
     stage = (timer or StageTimer()).stage("index", span="ec.encode.index")
     with stage as st:

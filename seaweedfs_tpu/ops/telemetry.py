@@ -47,8 +47,9 @@ and shows nowhere else than in this map.
 (ec/encoder.write_sorted_file_from_idx): the live entries written and
 the microseconds from the first `.idx` record read to the last `.ecx`
 record written. A volume of 1 MiB needles has a thousand entries and
-the build shows nowhere; one of 4 KB needles has thirty thousand, built
-an entry a Python iteration on the thread that then runs the stream.
+the build shows nowhere; one of 4 KB needles has thirty thousand, read
+as one record array and sorted once (storage/needle_map.MemDb) on the
+thread that then runs the stream.
 
 `mirror_entries` and `mirror_us` count the indexes a holder loads:
 the records of a `.idx` replayed into a needle map

@@ -4,8 +4,9 @@ A `.idx` log and a sorted `.ecx` are packed big-endian records: an
 8-byte key, the STORED offset (real byte offset / 8, reference
 types/needle_types.go) in 4 or 5 bytes, a 4-byte size. The file
 already is the array; the dtype of its record width views it, and
-whoever loads an index (a needle map, the native plane's mirror) takes
-columns from that view instead of a record a Python iteration
+whoever loads an index (a needle map, the native plane's mirror, the
+`.ecx` build of a sealing volume: needle_map.MemDb) takes columns from
+that view instead of a record a Python iteration
 (needle_map.walk_index_file, which stays for the callers that stream).
 
 The width is the volume's own (`offset_width`, from its superblock):
@@ -61,6 +62,27 @@ def columns(records: np.ndarray):
             records["size"].astype(np.uint32))
 
 
+def is_put(records: np.ndarray) -> np.ndarray:
+    """Mask of the records that name a needle: neither a tombstone size
+    nor a zero offset (NeedleMap._apply's rule)."""
+    return (records["size"] != TOMBSTONE_FILE_SIZE) & \
+        (stored_offsets(records) != 0)
+
+
+def last_per_key(records: np.ndarray) -> np.ndarray:
+    """The last record of every key, ascending by key. One unstable
+    sort of the keys (a stable one takes five times as long): a key's
+    records come out side by side in any order, and the last of them is
+    the largest position of the run."""
+    if not len(records):
+        return records
+    keys = records["nid"].astype(np.uint64)
+    order = np.argsort(keys)
+    keys = keys[order]
+    starts = np.flatnonzero(np.concatenate(([True], keys[1:] != keys[:-1])))
+    return records[np.maximum.reduceat(order, starts)]
+
+
 def replay_idx(records: np.ndarray):
     """One-pass replay of a `.idx` log: returns (live records sorted by
     key, counters dict). Last event per needle wins; counters match the
@@ -74,16 +96,13 @@ def replay_idx(records: np.ndarray):
     n = len(records)
     if n == 0:
         return records, counters
-    puts = (records["size"] != TOMBSTONE_FILE_SIZE) & \
-        (stored_offsets(records) != 0)
+    puts = is_put(records)
     counters["maximum_file_key"] = int(records["nid"].max())
     counters["file_counter"] = int(puts.sum())
     counters["file_byte_counter"] = int(
         records["size"][puts].sum(dtype=np.uint64))
-    # last event per nid: first occurrence in the reversed stream
-    _, idx_rev = np.unique(records["nid"][::-1], return_index=True)
-    last_idx = n - 1 - idx_rev  # ascending nid order (np.unique sorts)
-    live = records[last_idx][puts[last_idx]]
+    last = last_per_key(records)
+    live = last[is_put(last)]
     counters["deletion_counter"] = \
         counters["file_counter"] - len(live)
     counters["deletion_byte_counter"] = \

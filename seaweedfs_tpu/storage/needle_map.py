@@ -10,7 +10,9 @@ MemDb used for EC index sorting. Here:
                        the same append-to-.idx write-through discipline
                        (reference needle_map.go:51 baseNeedleMapper).
   * MemDb            — sorted in-memory db for .idx -> .ecx sorting
-                       (reference needle_map/memdb.go).
+                       (reference needle_map/memdb.go): a record array
+                       ascending by key, loaded from the log in one sort
+                       (idx_array), not a record a Python iteration.
 
 (The sorted-file binary search over 16B records lives with its only
 consumer: ec/ec_volume.search_needle_from_sorted_index.)
@@ -26,7 +28,8 @@ from typing import Iterator, Optional, Tuple
 
 import numpy as np
 
-from .idx_array import columns, read_idx_records, replay_idx
+from .idx_array import (columns, is_put, last_per_key, read_idx_records,
+                        records_of, replay_idx)
 from .types import (NEEDLE_ENTRY_SIZE, OFFSET_SIZE, TOMBSTONE_FILE_SIZE,
                     bytes_to_offset, bytes_to_needle_id, entry_size,
                     needle_id_to_bytes, offset_to_bytes)
@@ -179,50 +182,54 @@ class NeedleMap:
 
 
 class MemDb:
-    """Sorted needle db for building .ecx files (reference memdb.go)."""
+    """Sorted needle db for building .ecx files (reference memdb.go):
+    the last put of every key of a log as one record array ascending by
+    key, less the keys deleted since."""
 
     def __init__(self, offset_width: int = OFFSET_SIZE):
-        self._m: dict = {}
         self.offset_width = offset_width
+        self._records = records_of(b"", offset_width)
+        self._deleted: set = set()
         # .idx records load_from_idx dropped: deletes and zero offsets
         self.tombstones = 0
 
     def __len__(self) -> int:
-        return len(self._m)
-
-    def set(self, nid: int, offset: int, size: int):
-        self._m[nid] = (offset, size)
+        return len(self._live())
 
     def delete(self, nid: int):
-        self._m.pop(nid, None)
+        self._deleted.add(nid)
 
-    def get(self, nid: int) -> Optional[Tuple[int, int]]:
-        return self._m.get(nid)
-
-    def ascending_visit(self):
-        for nid in sorted(self._m):
-            offset, size = self._m[nid]
-            yield nid, offset, size
+    def _live(self) -> np.ndarray:
+        if self._deleted:
+            gone = np.fromiter(self._deleted, np.uint64, len(self._deleted))
+            self._records = self._records[
+                ~np.isin(self._records["nid"], gone)]
+            self._deleted = set()
+        return self._records
 
     @classmethod
     def load_from_idx(cls, idx_path: str,
                       offset_width: int = OFFSET_SIZE) -> "MemDb":
+        """The log's replay, no record a Python iteration: the last put
+        of every key as one array, then `delete` once for each key whose
+        last record is a tombstone or a zero offset."""
         db = cls(offset_width)
-        for nid, offset, size in walk_index_file(idx_path, offset_width):
-            if size != TOMBSTONE_FILE_SIZE and offset != 0:
-                db.set(nid, offset, size)
-            else:
-                db.delete(nid)
-                db.tombstones += 1
+        with open(idx_path, "rb") as f:
+            records = records_of(f.read(), offset_width)
+        puts = is_put(records)
+        db._records = last_per_key(records[puts])
+        db.tombstones = len(records) - int(puts.sum())
+        last = last_per_key(records)
+        for nid in last["nid"][~is_put(last)].tolist():
+            db.delete(nid)
         return db
 
     def save_to_idx(self, path: str) -> int:
         """Write the live entries ascending by key; returns the bytes."""
+        live = self._live()
         with open(path, "wb") as f:
-            for nid, offset, size in self.ascending_visit():
-                f.write(entry_to_bytes(nid, offset, size,
-                                       self.offset_width))
-            return f.tell()
+            live.tofile(f)
+        return live.nbytes
 
 
 def walk_index_file(idx_path: str, offset_width: int = OFFSET_SIZE):

@@ -1,7 +1,9 @@
 """An index read as one record array (storage/idx_array) equals the
 record loop it replaced, which stays here as the plain reference:
-NeedleMap.load entry for entry and counter for counter, and the three
-arrays the native plane's .ecx mirror is handed."""
+NeedleMap.load entry for entry and counter for counter, the three
+arrays the native plane's .ecx mirror is handed, and the .ecx a sealing
+volume's log leaves (ec/encoder.write_sorted_file_from_idx through
+needle_map.MemDb), against a replay written here."""
 
 import ctypes
 import io
@@ -13,13 +15,19 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
+from seaweedfs_tpu.ec.encoder import write_sorted_file_from_idx
 from seaweedfs_tpu.ops import telemetry
 from seaweedfs_tpu.server.native_plane import NativeReadPlane
-from seaweedfs_tpu.storage import idx_array
-from seaweedfs_tpu.storage.needle_map import (NeedleMap, bytes_to_entry,
+from seaweedfs_tpu.storage import idx_array, needle_map
+from seaweedfs_tpu.storage.needle_map import (MemDb, NeedleMap,
+                                              bytes_to_entry,
                                               entry_to_bytes,
                                               walk_index_file)
+from seaweedfs_tpu.storage.super_block import (FLAG_5_BYTE_OFFSETS,
+                                               SuperBlock)
 from seaweedfs_tpu.storage.types import TOMBSTONE_FILE_SIZE, entry_size
+from seaweedfs_tpu.util import tracing
+from seaweedfs_tpu.util.profiling import StageTimer
 
 COUNTERS = ("file_counter", "file_byte_counter", "deletion_counter",
             "deletion_byte_counter", "maximum_file_key")
@@ -199,3 +207,167 @@ def test_ecx_mirror_goes_in_chunks_of_at_most_2_pow_20():
     assert lib.calls[1][2] == [(i % 1000 + 1) * 8
                                for i in range(1 << 20, n)]
     assert lib.calls[1][3] == [i % 77 for i in range(1 << 20, n)]
+
+
+# -- the .ecx of a sealing volume ------------------------------------------
+
+def replay_by_record_loop(log: bytes, offset_width: int, deletes=True):
+    """(.ecx bytes, records dropped) a log must leave, read a record at
+    a time from the bytes alone: a later record of a key replaces the
+    earlier, a tombstone size or a zero offset removes the key — or,
+    with ``deletes`` off, is skipped, as a builder that forgot what a
+    delete means would."""
+    rec = 8 + offset_width + 4
+    live, dropped = {}, 0
+    for at in range(0, len(log) - rec + 1, rec):
+        key = int.from_bytes(log[at:at + 8], "big")
+        stored = int.from_bytes(log[at + 8:at + 8 + offset_width], "big")
+        size = int.from_bytes(log[at + rec - 4:at + rec], "big")
+        if size == TOMBSTONE_FILE_SIZE or stored == 0:
+            dropped += 1
+            if deletes:
+                live.pop(key, None)
+        else:
+            live[key] = log[at:at + rec]
+    return b"".join(live[key] for key in sorted(live)), dropped
+
+
+def tombstone(key: int, w: int) -> bytes:
+    return entry_to_bytes(key, 0, TOMBSTONE_FILE_SIZE, w)
+
+
+ECX_LOGS = {
+    "empty": lambda w: b"",
+    "keys-out-of-order": lambda w: b"".join(
+        entry_to_bytes(key, 8 * (at + 1), key % 7, w)
+        for at, key in enumerate((9, 2, 1 << 63, 5, 1, 70000))),
+    "key-put-twice": lambda w: b"".join((
+        entry_to_bytes(4, 8, 1, w), entry_to_bytes(6, 16, 2, w),
+        entry_to_bytes(4, 24, 3, w))),
+    "put-delete": lambda w: b"".join((
+        entry_to_bytes(6, 16, 2, w), entry_to_bytes(4, 8, 1, w),
+        tombstone(6, w))),
+    "put-delete-put": LOGS["put-delete-put"],
+    "delete-of-absent": lambda w: b"".join((
+        tombstone(5, w), entry_to_bytes(4, 8, 1, w))),
+    "zero-offset": lambda w: b"".join((
+        entry_to_bytes(4, 8, 1, w), entry_to_bytes(6, 16, 2, w),
+        entry_to_bytes(4, 0, 77, w))),
+    "only-tombstones": lambda w: b"".join((
+        tombstone(5, w), entry_to_bytes(2, 0, 3, w), tombstone(5, w))),
+    "trailing-partial": LOGS["trailing-partial"],
+    "top-offset": lambda w: entry_to_bytes(
+        1, ((1 << (8 * w)) - 1) * 8, TOMBSTONE_FILE_SIZE - 1, w),
+    "seed-2": LOGS["seed-2"],
+    "seed-4": LOGS["seed-4"],
+}
+
+
+def sealing_volume(tmp_path, log: bytes, offset_width: int) -> str:
+    """A volume's base name with the two files the build reads: the
+    superblock that names the width, and the log."""
+    base = str(tmp_path / "7")
+    flags = FLAG_5_BYTE_OFFSETS if offset_width == 5 else 0
+    with open(base + ".dat", "wb") as f:
+        f.write(SuperBlock(flags=flags).to_bytes())
+    with open(base + ".idx", "wb") as f:
+        f.write(log)
+    return base
+
+
+def build_ecx(base: str):
+    """write_sorted_file_from_idx under a stream's timer: (the .ecx, its
+    span, what the process counted)."""
+    got = []
+    tracing.add_finish_hook(got.append)
+    before = telemetry.STATS.snapshot()
+    try:
+        with tracing.span("ec.encode.stream") as root:
+            timer = StageTimer(root=root)
+            write_sorted_file_from_idx(base, timer=timer)
+    finally:
+        tracing.remove_finish_hook(got.append)
+    span, = (s for s in got if s["name"] == "ec.encode.index")
+    assert timer.totals["index"] == pytest.approx(span["duration_s"])
+    with open(base + ".ecx", "rb") as f:
+        return f.read(), span, telemetry.delta(before)
+
+
+@pytest.mark.parametrize("offset_width", [4, 5])
+@pytest.mark.parametrize("log", list(ECX_LOGS))
+def test_the_ecx_is_the_logs_replay(tmp_path, log, offset_width):
+    raw = ECX_LOGS[log](offset_width)
+    want, dropped = replay_by_record_loop(raw, offset_width)
+    ecx, span, moved = build_ecx(sealing_volume(tmp_path, raw, offset_width))
+    assert ecx == want
+    entries = len(want) // entry_size(offset_width)
+    assert span["tags"]["entries"] == moved["index_entries"] == entries
+    assert span["tags"]["tombstones"] == dropped
+    assert span["tags"]["bytes"] == len(want)
+    assert moved["index_us"] == pytest.approx(span["duration_s"] * 1e6,
+                                              abs=1)
+
+
+def test_a_volume_without_its_log_builds_no_ecx(tmp_path):
+    base = sealing_volume(tmp_path, b"", 4)
+    os.remove(base + ".idx")
+    with pytest.raises(FileNotFoundError):
+        write_sorted_file_from_idx(base)
+    assert not os.path.exists(base + ".ecx")
+
+
+@pytest.mark.parametrize("offset_width", [4, 5])
+def test_a_delete_reaches_the_ecx_through_memdb_delete(
+        tmp_path, monkeypatch, offset_width):
+    """The seam the benchmark's control `keep_tombstones_in_ecx` breaks
+    the program at: with MemDb.delete a no-op every deleted key stays in
+    the .ecx with the offset and size of its last put."""
+    raw = b"".join((
+        random_log(5, offset_width, 900),   # its keys are all over 0
+        entry_to_bytes(0, 16, 2, offset_width),
+        entry_to_bytes(1 << 63, 24, 3, offset_width),
+        entry_to_bytes(0, 32, 5, offset_width),
+        tombstone(0, offset_width),
+        entry_to_bytes(1 << 63, 0, 3, offset_width),
+        tombstone((1 << 63) + 1, offset_width)))
+    base = sealing_volume(tmp_path, raw, offset_width)
+    sound, _, _ = build_ecx(base)
+    assert sound == replay_by_record_loop(raw, offset_width)[0]
+    monkeypatch.setattr(needle_map.MemDb, "delete", lambda self, nid: None)
+    broken, span, _ = build_ecx(base)
+    want, dropped = replay_by_record_loop(raw, offset_width, deletes=False)
+    assert broken == want != sound
+    kept = {nid: (offset, size) for nid, offset, size
+            in walk_index_file(base + ".ecx", offset_width)}
+    assert kept[0] == (32, 5) and kept[1 << 63] == (24, 3)
+    assert (1 << 63) + 1 not in kept
+    assert span["tags"]["tombstones"] == dropped
+    assert span["tags"]["entries"] == len(kept)
+
+
+@pytest.mark.parametrize("offset_width", [4, 5])
+def test_memdb_drops_the_keys_deleted_after_a_load(tmp_path, offset_width):
+    raw = random_log(9, offset_width, 600)
+    path = str(tmp_path / "v.idx")
+    with open(path, "wb") as f:
+        f.write(raw)
+    db = MemDb.load_from_idx(path, offset_width)
+    loaded, _ = replay_by_record_loop(raw, offset_width)
+    rec = entry_size(offset_width)
+    assert len(db) == len(loaded) // rec
+    first, second = (int.from_bytes(loaded[at:at + 8], "big")
+                     for at in (0, rec))
+    db.delete(second)
+    db.delete(12345678901)          # never put
+    db.delete(first)
+    db.delete(first)                # twice
+    want, _ = replay_by_record_loop(
+        raw + tombstone(first, offset_width) + tombstone(second,
+                                                         offset_width),
+        offset_width)
+    assert want == loaded[2 * rec:]
+    assert len(db) == len(want) // rec
+    out = str(tmp_path / "v.ecx")
+    assert db.save_to_idx(out) == len(want)
+    with open(out, "rb") as f:
+        assert f.read() == want

@@ -6,7 +6,9 @@ import numpy as np
 import pytest
 
 from seaweedfs_tpu.storage.needle import Needle
-from seaweedfs_tpu.storage.needle_map import NeedleMap, MemDb, walk_index_file
+from seaweedfs_tpu.storage.needle_map import (MemDb, NeedleMap,
+                                              entry_to_bytes,
+                                              walk_index_file)
 from seaweedfs_tpu.storage.types import TOMBSTONE_FILE_SIZE
 from seaweedfs_tpu.storage.volume import NotFound, Volume
 
@@ -171,14 +173,19 @@ def test_needle_map_counters(tmp_path):
 
 
 def test_memdb_sorted(tmp_path):
-    db = MemDb()
-    for nid in (5, 1, 9, 3):
-        db.set(nid, nid * 8, 10)
-    assert [e[0] for e in db.ascending_visit()] == [1, 3, 5, 9]
+    log = str(tmp_path / "log.idx")
+    with open(log, "wb") as f:
+        for nid in (5, 1, 9, 3, 7):
+            f.write(entry_to_bytes(nid, nid * 8, 10))
+    db = MemDb.load_from_idx(log)
+    assert len(db) == 5 and db.tombstones == 0
+    db.delete(7)
+    assert len(db) == 4
     p = str(tmp_path / "sorted.idx")
-    db.save_to_idx(p)
-    ids = [nid for nid, _, _ in walk_index_file(p)]
-    assert ids == [1, 3, 5, 9]
+    assert db.save_to_idx(p) == 4 * 16
+    assert list(walk_index_file(p)) == [(nid, nid * 8, 10)
+                                        for nid in (1, 3, 5, 9)]
+    assert MemDb().save_to_idx(p) == 0 and os.path.getsize(p) == 0
 
 
 def test_volume_scan(tmp_path):
