@@ -946,7 +946,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "memory dict, 16B/needle compact arrays, "
                         "mmap'd sorted file, or a disk-backed writable "
                         "map for indexes larger than RAM (reference "
-                        "-index leveldb)")
+                        "-index leveldb). A read-only volume of a memory "
+                        "index holds the compact arrays until it is "
+                        "made writable again")
     v.add_argument("-cpuprofile", default="",
                    help="write an all-thread collapsed-stack CPU "
                         "profile here on shutdown (flamegraph.pl/"

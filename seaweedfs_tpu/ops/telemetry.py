@@ -53,13 +53,16 @@ thread that then runs the stream.
 
 `mirror_entries` and `mirror_us` count the indexes a holder loads:
 the records of a `.idx` replayed into a needle map
-(storage/needle_map.NeedleMap.load: every volume load, mount and
-freeze) and the entries pushed into the native plane's mirrors
-(server/native_plane: a volume's live set, an EC volume's `.ecx`), with
-the wall microseconds of each load. They are read as arrays
+(storage/needle_map.NeedleMap.load, compact_map.CompactNeedleMap.load:
+every volume load, mount and freeze) and the entries pushed into the
+native plane's mirrors (server/native_plane: a volume's live set, an EC
+volume's `.ecx`), with the wall microseconds of each load. They are read as arrays
 (storage/idx_array); `mirror_loop_entries` counts those that still went
 an entry a Python iteration, which a map that offers no columns (the
-compact, sorted-file and disk maps under the plane) costs.
+disk map under the plane) costs. `frozen_array_maps` counts the reloads
+that chose the array map for a frozen volume of an in-memory index
+(storage/volume.Volume._reload_kind): one a freeze whose lease came
+back, so an `ec.encode -collection` of 32 volumes moves it by 32.
 
 `rebuild_delivered_bytes` and `rebuild_local_bytes` count the bytes of
 rebuilt shards a full-gather rebuild produced, by where they went: sent
@@ -96,7 +99,8 @@ class DispatchStats:
                "lock_probe_late_us", "lock_probe_stalls",
                "lock_probe_stall_us",
                "mirror_entries", "mirror_us", "mirror_loop_entries",
-               "rebuild_delivered_bytes", "rebuild_local_bytes")
+               "rebuild_delivered_bytes", "rebuild_local_bytes",
+               "frozen_array_maps")
     REPAIR_ROUTES = ("piggyback", "trace", "full")
 
     def __init__(self):

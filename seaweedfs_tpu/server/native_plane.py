@@ -341,8 +341,8 @@ class NativeReadPlane:
         return len(keys)
 
     def _bulk_load(self, volume, entries) -> int:
-        """A map that offers no columns (compact, sorted-file, disk):
-        its snapshot an entry an iteration, staged in bounded lists."""
+        """A map that offers no columns (disk): its snapshot an entry
+        an iteration, staged in bounded lists."""
         keys, offsets, sizes = [], [], []
         pushed = 0
         for key, nv in entries:

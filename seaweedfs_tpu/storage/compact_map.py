@@ -24,11 +24,12 @@ entries), so Volume can swap them per its -index flag.
 from __future__ import annotations
 
 import os
+import time
 from typing import Iterator, Optional, Tuple
 
 import numpy as np
 
-from .idx_array import IDX_DTYPE, read_idx_records, replay_idx
+from .idx_array import IDX_DTYPE, columns, read_idx_records, replay_idx
 from .needle_map import NeedleValue, entry_to_bytes
 from .types import NEEDLE_PADDING_SIZE, TOMBSTONE_FILE_SIZE
 
@@ -111,6 +112,31 @@ class _SortedBase:
             if ov is not _DELETED:
                 yield nid, ov
 
+    def _live_records(self) -> np.ndarray:
+        """The live set as one record array: the base's live records
+        and, where the overflow holds anything, its live entries in
+        place of the base records they shadow, ascending by key."""
+        live = self._base[self._live_mask()]
+        if not self._overflow:
+            return live
+        recent = self._overflow
+        shadowed = np.isin(live["nid"].astype(np.uint64),
+                           np.fromiter(recent, np.uint64, len(recent)))
+        more = np.array([(nid, nv.offset // NEEDLE_PADDING_SIZE, nv.size)
+                         for nid, nv in recent.items()
+                         if nv is not _DELETED], dtype=IDX_DTYPE)
+        merged = np.concatenate((live[~shadowed], more))
+        merged.sort(order="nid")
+        return merged
+
+    def live_columns(self):
+        """The live set as (keys uint64, byte offsets uint64, sizes
+        uint32) arrays, NeedleMap.live_columns's contract: what the
+        native plane's mirror takes in one bulk put (call under the
+        volume lock). Straight after a load the overflow is empty and
+        these are the base's own columns."""
+        return columns(self._live_records())
+
     # -- mutations ---------------------------------------------------------
     def put(self, nid: int, offset: int, size: int):
         old = self.get(nid)
@@ -170,33 +196,23 @@ class CompactNeedleMap(_SortedBase):
 
     @classmethod
     def load(cls, idx_path: str) -> "CompactNeedleMap":
+        from ..ops import telemetry
+        t0 = time.perf_counter()
         nm = cls.__new__(cls)
         _SortedBase.__init__(nm, None)
-        live, counters = replay_idx(read_idx_records(idx_path))
+        records = read_idx_records(idx_path)
+        live, counters = replay_idx(records)
         nm._base = live
         nm.__dict__.update(counters)
         nm.idx_path = idx_path
         nm._idx_file = open(idx_path, "ab")
+        telemetry.STATS.add_mirror(len(records), time.perf_counter() - t0)
         return nm
 
     def _maybe_merge(self):
-        if len(self._overflow) < self.MERGE_THRESHOLD:
-            return
-        keep = np.ones(len(self._base), dtype=bool)
-        if len(self._base):
-            keep &= self._live_mask()
-            ov_keys = np.fromiter(self._overflow.keys(), dtype=np.uint64,
-                                  count=len(self._overflow))
-            keep &= ~np.isin(self._base["nid"].astype(np.uint64), ov_keys)
-        extra = [(nid, ov.offset // NEEDLE_PADDING_SIZE, ov.size)
-                 for nid, ov in self._overflow.items() if ov is not _DELETED]
-        merged = np.empty(int(keep.sum()) + len(extra), dtype=IDX_DTYPE)
-        merged[:int(keep.sum())] = self._base[keep]
-        for j, (nid, off, size) in enumerate(extra):
-            merged[int(keep.sum()) + j] = (nid, off, size)
-        merged.sort(order="nid")
-        self._base = merged
-        self._overflow = {}
+        if len(self._overflow) >= self.MERGE_THRESHOLD:
+            self._base = self._live_records()
+            self._overflow = {}
 
     @property
     def index_nbytes(self) -> int:
@@ -233,6 +249,11 @@ class SortedFileNeedleMap(_SortedBase):
         nm.meta_path = meta_path
         idx_size = os.path.getsize(idx_path) \
             if os.path.exists(idx_path) else 0
+        # the .idx bytes this map has seen: what it loaded plus its own
+        # appends, not the file's size — under the native plane's write
+        # lease the log grows behind the map, and a watermark taken from
+        # the file would vouch for records the .sdx never saw
+        nm._idx_seen = idx_size
         meta = None
         if os.path.exists(meta_path) and os.path.exists(sdx_path):
             try:
@@ -273,7 +294,7 @@ class SortedFileNeedleMap(_SortedBase):
             # crash resurrects the needle on the no-replay fast path
             self._base.flush()
         self._idx_file.flush()
-        state = {"idx_size": os.path.getsize(self.idx_path),
+        state = {"idx_size": self._idx_seen,
                  "file_counter": self.file_counter,
                  "file_byte_counter": self.file_byte_counter,
                  "deletion_counter": self.deletion_counter,
@@ -290,8 +311,13 @@ class SortedFileNeedleMap(_SortedBase):
         else:
             self._overflow[nid] = _DELETED
 
+    def put(self, nid: int, offset: int, size: int):
+        super().put(nid, offset, size)
+        self._idx_seen += IDX_DTYPE.itemsize
+
     def delete(self, nid: int):
         super().delete(nid)
+        self._idx_seen += IDX_DTYPE.itemsize
         self._save_meta()  # advance the watermark past the tombstone
 
     def close(self):
@@ -363,6 +389,11 @@ def load_needle_map(idx_path: str, kind: str = "memory",
     the disk map was asked for: the numpy fast paths here are wired for
     the 16B layout, and the disk map is exactly the variant meant for
     volumes too big to hold an in-RAM index.
+
+    The kind is the caller's: a Volume asks for its configured kind,
+    except that a frozen (read-only) volume of a memory index reloads
+    into the compact map and gets the dict back when it is thawed
+    (storage/volume.Volume._reload_kind).
     """
     if kind == "disk":
         from .needle_map_disk import DiskNeedleMap

@@ -62,6 +62,8 @@ _SIZE_OF = operator.attrgetter("size")
 class NeedleMap:
     """Write-through needle map: in-memory dict + append-only .idx log."""
 
+    kind = "memory"
+
     def __init__(self, idx_path: Optional[str] = None,
                  offset_width: int = OFFSET_SIZE):
         self._m: dict = {}

@@ -49,7 +49,7 @@ class Volume:
         # needle-map variant (reference volume -index flag): memory |
         # compact (16B/needle sorted arrays) | sortedfile (mmap'd .sdx)
         self.index_kind = index_kind
-        self.readonly = False
+        self._readonly = False
         self.lock = make_rlock("volume.lock")
         self.last_modified = 0
         # write-lease delegate (server/native_plane.NativeWriter).
@@ -163,11 +163,20 @@ class Volume:
         poking the attribute) — the plane's accept gate cannot see a
         Python attribute on its own. Thawing does NOT re-open the
         gate here: re-qualification is the owning server's policy
-        (_fast_sync re-acquires the lease)."""
-        self._readonly = value
-        w = getattr(self, "fast_writer", None)
-        if value and w is not None:
-            w.set_accept_posts(False)
+        (_fast_sync re-acquires the lease).
+
+        Thawing puts the configured needle map back where a frozen
+        reload swapped the array map in (_reload_kind), under the lock
+        and so before the volume takes a write or a lease goes out."""
+        if value:
+            self._readonly = True
+            if self.fast_writer is not None:
+                self.fast_writer.set_accept_posts(False)
+            return
+        with self.lock:
+            self._readonly = False
+            if self.index_kind == "memory" and self.nm.kind != "memory":
+                self.reload_nm()
 
     def _writer_deltas(self):
         """(puts, put_bytes, deletes, deleted_bytes, max_key) appended
@@ -202,13 +211,32 @@ class Volume:
             return NeedleValue(hit[0], hit[1])
         return self.nm.get(nid)
 
+    def _reload_kind(self) -> str:
+        """The needle-map kind a reload takes: the map follows the
+        volume's writability. Nothing will ever `put` into a frozen
+        volume's map, so the dict of an in-memory index (29,000
+        NeedleValues a volume of 4 KB needles, built to be dropped by
+        the encode that froze it) stays the record array the .idx was
+        replayed into — the compact map. 5-byte offsets keep the dict
+        (load_needle_map's rule); compact, sortedfile and disk are what
+        the operator asked for."""
+        if self.readonly and self.index_kind == "memory" and \
+                self.offset_width == 4:
+            return "compact"
+        return self.index_kind
+
     def reload_nm(self):
         """Refresh the needle map from the .idx (call under self.lock,
         after the native writer's lease has been taken back — the .idx
-        it kept is authoritative)."""
+        it kept is authoritative). A frozen volume with an in-memory
+        index reloads into the array map (_reload_kind); the thaw
+        (readonly = False) reloads the configured kind."""
+        kind = self._reload_kind()
         self.nm.close()
-        self.nm = load_needle_map(self.idx_path, self.index_kind,
-                                  self.offset_width)
+        self.nm = load_needle_map(self.idx_path, kind, self.offset_width)
+        if kind != self.index_kind:
+            from ..ops import telemetry
+            telemetry.STATS.add("frozen_array_maps")
 
     def _demote_fast_writer(self, err):
         """The native writer failed with ambiguity (I/O error, poisoned

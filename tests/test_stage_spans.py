@@ -371,7 +371,10 @@ def test_counters_at_the_stage_boundaries(cycle):
         # PR 43: indexes loaded as arrays (tests/test_idx_array.py)
         "mirror_entries", "mirror_us", "mirror_loop_entries",
         # PR 45: decode apart from storage (tests/test_fanned_served.py)
-        "rebuild_delivered_bytes", "rebuild_local_bytes"}
+        "rebuild_delivered_bytes", "rebuild_local_bytes",
+        # PR 47: a frozen volume's map stays an array
+        # (tests/test_frozen_map.py)
+        "frozen_array_maps"}
     fetched = sum(s["tags"]["bytes"] for s in cycle["spans"]
                   if s["name"].startswith("ec.rebuild.fetch."))
     assert fetched == cycle["rebuild"]["survivor_bytes"] > 0
