@@ -4,6 +4,11 @@ RS(10,4) striping of volumes into 14 shard files with a two-level block
 layout (1GB large rows, 1MB small rows — reference
 weed/storage/erasure_coding/ec_encoder.go:17-23), with the GF(2^8) compute
 routed through ops.get_codec (numpy / native C++ / TPU MXU backends).
+
+One flat rebuild: `rebuild_ec_files_streaming` decodes from a striped
+gather of local files and remote holders (what a volume server runs);
+`rebuild_ec_files` is its entry for a directory that holds every
+survivor (reference RebuildEcFiles).
 """
 
 from .constants import (  # noqa: F401

@@ -164,7 +164,7 @@ def _copy_block(src, offset: int, length: int, dst, buf_size: int):
 # single-shard repair (ops/codec.repair_plan has the scheme math).
 # ---------------------------------------------------------------------------
 
-def _write_relaid(timer: StageTimer, out, span: str, w: int, relayout) -> int:
+def _write_relaid(timer: StageTimer, out, span: str, w: int, relayout):
     """One drained block of a single-shard repair: its host re-layout
     into ``w`` shard bytes, then the append. Under the timer's root (the
     stream's ``ec.rebuild.stream``) both leave as spans, ``span`` and
@@ -174,7 +174,6 @@ def _write_relaid(timer: StageTimer, out, span: str, w: int, relayout) -> int:
         st.nbytes = block.nbytes
     with timer.stage("shard_write", w, span="ec.rebuild.write"):
         out.write(block.data)
-    return w
 
 
 def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
@@ -200,15 +199,13 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
     back to the full streaming gather with a clean slate."""
     from ..ops import telemetry
     from ..ops.codec import combine_planes_to_bytes
-    from .encoder import volume_codec
-    codec = codec or volume_codec(base_name)
+    from . import encoder
+    codec = codec or encoder.volume_codec(base_name)
     if pipelined is None:
         pipelined = codec.pipelined
     if lost_sid != plan.lost:
         raise ValueError(f"plan repairs shard {plan.lost}, not {lost_sid}")
     before = telemetry.STATS.snapshot()
-    phases = {"gather": 0.0, "plan": 0.0, "dispatch": 0.0,
-              "drain": 0.0, "write": 0.0}
     # the source hands out blocks as tall as its row bucket (zero rows
     # below the symbol planes); the combine gets as many zero columns,
     # so the product is the plan's own
@@ -217,92 +214,40 @@ def rebuild_ec_file_repair(base_name: str, lost_sid: int, source, plan,
     if rows > plan.total_bits:
         combine = np.zeros((combine.shape[0], rows), dtype=np.uint8)
         combine[:, :plan.total_bits] = plan.combine
-    out_path = base_name + to_ext(lost_sid)
-    out = open(out_path, "wb")
-    rebuilt_bytes = 0
     # plane widths are byte strides: an 8 MB slab arrives as
     # total_bits x 1 MB planes, so the pipeline buckets on the stride
     stride_cap = (max(1, int(slab)) + 7) // 8
     timer = StageTimer(root=tracing.current_span())
-
-    def write_block(planes, w):
-        return _write_relaid(
-            timer, out, "ec.rebuild.trace_unpack", w,
-            lambda: combine_planes_to_bytes(planes, w))
-
     t_stream = time.perf_counter()
-    try:
-        if pipelined:
-            from ..ops.pipeline import PipelinedMatmul
-            pm = PipelinedMatmul(combine, max_width=stride_cap,
-                                 codec=codec, timer=timer)
-            for meta, block, planes in pm.stream(source.slabs()):
-                _give_slab(block)   # drained: the gather's again
-                rebuilt_bytes += write_block(planes, meta[2])
-            phases["gather"] = timer.totals.get("read_wait", 0.0)
-            phases["dispatch"] = timer.totals.get("h2d", 0.0)
-            phases["drain"] = timer.totals.get("drain_wait", 0.0)
-        else:
-            it = source.slabs()
-            while True:
-                t0 = time.perf_counter()
-                try:
-                    meta, planes = next(it)
-                except StopIteration:
-                    break
-                t1 = time.perf_counter()
-                combined = codec._matmul(combine, planes)
-                t2 = time.perf_counter()
-                _give_slab(planes)
-                rebuilt_bytes += write_block(combined, meta[2])
-                phases["gather"] += t1 - t0
-                phases["dispatch"] += t2 - t1
-        phases["write"] = timer.totals.get("relayout", 0.0) + \
-            timer.totals.get("shard_write", 0.0)
-    except BaseException:
-        out.close()
-        try:
-            os.remove(out_path)
-        except OSError:
-            pass
-        raise
-    finally:
-        if not out.closed:
-            out.close()
+    with encoder.rebuilt_outputs(base_name, [lost_sid]) as outs:
+        for meta, block, planes in encoder.matmul_stream(
+                codec, combine, source.slabs(), timer, pipelined,
+                stride_cap):
+            _give_slab(block)   # drained: the gather's again
+            _write_relaid(
+                timer, outs[lost_sid], "ec.rebuild.trace_unpack", meta[2],
+                lambda: combine_planes_to_bytes(planes, meta[2]))
     stream_s = time.perf_counter() - t_stream
-    residual = stream_s - (sum(phases.values()) - phases["plan"])
-    if residual > 0:
-        phases["dispatch"] += residual
-    for name, secs in phases.items():
-        if secs > 0:
-            tracing.record_span(name, secs, op="ec.rebuild",
-                                backend=codec.backend, repair="trace")
-    if stats is not None:
-        gs = source.stats
-        baseline = plan.k * source.shard_size
-        stats.update(telemetry.delta(before))
-        stats.update(gs.snapshot())
-        stats["rebuilt_bytes"] = rebuilt_bytes
-        stats["stream_s"] = round(stream_s, 3)
-        stats["backend"] = codec.backend
-        stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
-        stats["stage_max_s"] = {**timer.max_s(), **gs.timer.max_s()}
-        stats.update(gs.overlap(stream_s, phases["gather"]))
-        # the repair story: symbol bytes moved vs the k*shard baseline
-        # the full-RS gather would have pulled for the same rebuild
-        stats["repair_mode"] = "trace"
-        stats["operand"] = list(combine.shape)
-        stats["repair_helpers"] = len(plan.helpers)
-        stats["repair_total_bits"] = plan.total_bits
-        stats["repair_bits"] = {int(s): plan.bits_for(s)
-                                for s in plan.helpers}
-        stats["repair_bytes"] = gs.bytes
-        stats["repair_remote_bytes"] = gs.remote_bytes
-        stats["repair_baseline_bytes"] = baseline
-        stats["repair_bytes_frac"] = round(
-            gs.bytes / baseline, 4) if baseline else 0.0
-        stats["repair_mbps"] = round(gs.mbps(), 1)
+    # the repair story: symbol bytes moved vs the k*shard baseline the
+    # full-RS gather would have pulled for the same rebuild
+    encoder.close_rebuild(
+        stats, timer, stream_s, before, source, codec, combine, [lost_sid],
+        repair_mode="trace", repair_total_bits=plan.total_bits,
+        repair_bits={int(s): plan.bits_for(s) for s in plan.helpers},
+        **_single_shard_account(plan, source))
     return [lost_sid]
+
+
+def _single_shard_account(plan, source) -> dict:
+    """What the reply of a single-shard repair says beside the common
+    keys: how many helpers sent, and what they sent of the k whole
+    shards a full gather pulls, as a fraction and a rate."""
+    gs = source.stats
+    baseline = plan.k * source.shard_size
+    return {"repair_helpers": len(plan.helpers),
+            "repair_bytes_frac": round(gs.bytes / baseline, 4)
+            if baseline else 0.0,
+            "repair_mbps": round(gs.mbps(), 1)}
 
 
 def rebuild_ec_file_piggyback(base_name: str, lost_sid: int, source,
@@ -326,97 +271,32 @@ def rebuild_ec_file_piggyback(base_name: str, lost_sid: int, source,
     clean slate."""
     from ..ops import telemetry
     from ..ops.codec import pb_merge
-    from .encoder import volume_codec
-    codec = codec or volume_codec(base_name)
+    from . import encoder
+    codec = codec or encoder.volume_codec(base_name)
     if pipelined is None:
         pipelined = codec.pipelined
     if lost_sid != rplan.lost:
         raise ValueError(f"plan repairs shard {rplan.lost}, not {lost_sid}")
     alpha = rplan.alpha
     before = telemetry.STATS.snapshot()
-    phases = {"gather": 0.0, "plan": 0.0, "dispatch": 0.0,
-              "drain": 0.0, "write": 0.0}
-    out_path = base_name + to_ext(lost_sid)
-    out = open(out_path, "wb")
-    rebuilt_bytes = 0
     # stripe columns are w/alpha wide for a w-byte shard range
     stride_cap = max(1, int(slab)) // alpha + 1
     timer = StageTimer(root=tracing.current_span())
-
-    def write_block(sub, w):
-        return _write_relaid(
-            timer, out, "ec.rebuild.pb_merge", w,
-            lambda: pb_merge(np.asarray(sub, dtype=np.uint8),
-                             alpha, window)[0])
-
     t_stream = time.perf_counter()
-    try:
-        if pipelined:
-            from ..ops.pipeline import PipelinedMatmul
-            pm = PipelinedMatmul(rplan.matrix, max_width=stride_cap,
-                                 codec=codec, timer=timer)
-            for meta, block, sub in pm.stream(source.slabs()):
-                _give_slab(block)   # drained: the gather's again
-                rebuilt_bytes += write_block(sub, meta[2])
-            phases["gather"] = timer.totals.get("read_wait", 0.0)
-            phases["dispatch"] = timer.totals.get("h2d", 0.0)
-            phases["drain"] = timer.totals.get("drain_wait", 0.0)
-        else:
-            it = source.slabs()
-            while True:
-                t0 = time.perf_counter()
-                try:
-                    meta, stacked = next(it)
-                except StopIteration:
-                    break
-                t1 = time.perf_counter()
-                sub = codec._matmul(rplan.matrix, stacked)
-                t2 = time.perf_counter()
-                _give_slab(stacked)
-                rebuilt_bytes += write_block(sub, meta[2])
-                phases["gather"] += t1 - t0
-                phases["dispatch"] += t2 - t1
-        phases["write"] = timer.totals.get("relayout", 0.0) + \
-            timer.totals.get("shard_write", 0.0)
-    except BaseException:
-        out.close()
-        try:
-            os.remove(out_path)
-        except OSError:
-            pass
-        raise
-    finally:
-        if not out.closed:
-            out.close()
+    with encoder.rebuilt_outputs(base_name, [lost_sid]) as outs:
+        for meta, block, sub in encoder.matmul_stream(
+                codec, rplan.matrix, source.slabs(), timer, pipelined,
+                stride_cap):
+            _give_slab(block)   # drained: the gather's again
+            _write_relaid(
+                timer, outs[lost_sid], "ec.rebuild.pb_merge", meta[2],
+                lambda: pb_merge(np.asarray(sub, dtype=np.uint8),
+                                 alpha, window)[0])
     stream_s = time.perf_counter() - t_stream
-    residual = stream_s - (sum(phases.values()) - phases["plan"])
-    if residual > 0:
-        phases["dispatch"] += residual
-    for name, secs in phases.items():
-        if secs > 0:
-            tracing.record_span(name, secs, op="ec.rebuild",
-                                backend=codec.backend, repair="piggyback")
-    if stats is not None:
-        gs = source.stats
-        baseline = rplan.k * source.shard_size
-        stats.update(telemetry.delta(before))
-        stats.update(gs.snapshot())
-        stats["rebuilt_bytes"] = rebuilt_bytes
-        stats["stream_s"] = round(stream_s, 3)
-        stats["backend"] = codec.backend
-        stats["layout"] = "piggyback"
-        stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
-        stats["stage_max_s"] = {**timer.max_s(), **gs.timer.max_s()}
-        stats.update(gs.overlap(stream_s, phases["gather"]))
-        # the repair story: half-plane bytes moved vs the k*shard
-        # baseline the full-RS gather would have pulled
-        stats["repair_mode"] = "piggyback"
-        stats["operand"] = list(rplan.matrix.shape)
-        stats["repair_helpers"] = len(rplan.helpers)
-        stats["repair_bytes"] = gs.bytes
-        stats["repair_remote_bytes"] = gs.remote_bytes
-        stats["repair_baseline_bytes"] = baseline
-        stats["repair_bytes_frac"] = round(
-            gs.bytes / baseline, 4) if baseline else 0.0
-        stats["repair_mbps"] = round(gs.mbps(), 1)
+    # the repair story: half-plane bytes moved vs the k*shard baseline
+    # the full-RS gather would have pulled
+    encoder.close_rebuild(
+        stats, timer, stream_s, before, source, codec, rplan.matrix,
+        [lost_sid], repair_mode="piggyback", layout="piggyback",
+        **_single_shard_account(rplan, source))
     return [lost_sid]

@@ -7,7 +7,13 @@ Behavior-compatible with reference ec_encoder.go:
     (k x 1GB) remains, emit a large row; tail as small rows (k x 1MB),
     zero-padded [ec_encoder.go:192-229]
   * rebuild_ec_files: regenerate missing .ecNN from >=k survivors
-    [ec_encoder.go:61-116, 231-285]
+    [ec_encoder.go:61-116, 231-285] — the local entry of the two full
+    decodes, which are the served rebuild's too:
+    rebuild_ec_files_streaming (flat) and rebuild_ec_files_piggyback
+    (coupled), each over a gather of local files and remote holders.
+    With the two single-shard repairs of ec/decoder.py they share the
+    matmul stream (matmul_stream), the all-or-nothing outputs
+    (rebuilt_outputs) and the node's reply (close_rebuild)
 
 Geometry is taken from the codec (generic RS(k,m), default 10+4 — the
 reference hardcodes 10+4 at ec_encoder.go:17-20).
@@ -28,6 +34,7 @@ pass over every byte of the .dat, no seek, no intermediate copy.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from typing import Iterator, List, Optional, Tuple
@@ -521,10 +528,11 @@ def _phases_from_timer(timer: StageTimer, pipelined: bool) -> dict:
         "gather": t.get("read_wait" if pipelined else "disk_read", 0.0),
         "dispatch": t.get("h2d", 0.0),
         "drain": t.get("drain_wait", 0.0),
-        # piggyback: the consumer merges each drained parity block back
-        # into shard bytes before it writes them (as the plane repair's
-        # `write` holds its merge, ec/decoder.py)
-        "write": t.get("shard_write", 0.0) + t.get("pb_merge", 0.0),
+        # the consumer re-lays each drained block into shard bytes before
+        # it writes them: the coupled layout's merge, and a single-shard
+        # repair's (ec/decoder._write_relaid)
+        "write": (t.get("shard_write", 0.0) + t.get("pb_merge", 0.0) +
+                  t.get("relayout", 0.0)),
     }
 
 
@@ -534,160 +542,165 @@ def _record_phase_spans(timer: StageTimer, pipelined: bool, op: str):
             tracing.record_span(name, secs, op=op)
 
 
-def rebuild_ec_files(base_name: str,
-                     codec: Optional[ReedSolomonCodec] = None,
-                     slab: int = DEFAULT_SLAB,
-                     pipelined: Optional[bool] = None,
-                     stats: Optional[dict] = None,
-                     layout=None) -> List[int]:
-    """Regenerate missing shard files from survivors. Returns the list of
-    rebuilt shard ids. Raises if fewer than k survive.
+def matmul_stream(codec: ReedSolomonCodec, coeffs: np.ndarray, stripes,
+                  timer: StageTimer, pipelined: bool, max_width: int,
+                  pieces: bool = False):
+    """``coeffs @ stripe`` for each ``(meta, stripe)`` of a rebuild's
+    gather, as ``(meta, stripe, product)``: through PipelinedMatmul
+    where the codec pipelines; where it computes on the host, the gather
+    and then the matmul on this thread, timed under the pipeline's names
+    (``read_wait``, ``h2d``) so that one account of the phases serves
+    both. ``pieces``: the product as the ``[(column, block)]`` list the
+    pipeline drains a device's share into."""
+    if pipelined:
+        from ..ops.pipeline import PipelinedMatmul
+        yield from PipelinedMatmul(coeffs, max_width=max_width, codec=codec,
+                                   timer=timer, pieces=pieces
+                                   ).stream(stripes)
+        return
+    stripes = iter(stripes)
+    while True:
+        t0 = time.perf_counter()
+        item = next(stripes, None)
+        t1 = time.perf_counter()
+        timer.add("read_wait", t1 - t0)
+        if item is None:
+            return
+        out = np.ascontiguousarray(codec._matmul(coeffs, item[1]),
+                                   dtype=np.uint8)
+        timer.add("h2d", time.perf_counter() - t1)
+        yield item[0], item[1], [(0, out)] if pieces else out
 
-    Device-backed codecs (tpu AND mesh) stream survivor slabs through
-    PipelinedMatmul with the fused decode plan: one device dispatch per
-    slab regenerates every missing shard (data + parity rows stacked),
-    with bounded in-flight depth instead of a synchronous per-slab
-    round-trip. ``stats``, when given, is filled with the dispatch
-    telemetry of this rebuild (dispatches / bitmat_uploads /
-    device_bytes / host_fallbacks deltas, survivor_bytes, stream_s) —
-    the bench's regression counters.
 
-    ``layout``: an ec.layout.LayoutInfo (or None for flat). A piggyback
-    volume goes through rebuild_ec_files_piggyback, the body the
-    streaming rebuild runs too, with every survivor read from a local
-    file."""
-    codec = codec or volume_codec(base_name)
-    k, total = codec.k, codec.total
-    if pipelined is None:
-        pipelined = codec.pipelined
-    piggyback = layout is not None and getattr(layout, "piggyback", False)
-    present = [os.path.exists(base_name + to_ext(i)) for i in range(total)]
-    missing = [i for i, p in enumerate(present) if not p]
-    if not missing:
-        return []
-    if sum(present) < k:
-        raise ValueError(
-            f"cannot rebuild: only {sum(present)} of {total} shards")
-    shard_size = None
-    for i, p in enumerate(present):
-        if p:
-            sz = os.path.getsize(base_name + to_ext(i))
-            if shard_size is None:
-                shard_size = sz
-            elif shard_size != sz:
-                raise ValueError("surviving shards differ in size")
-    if piggyback:
-        from .gather import GatherStats, LocalShardReader, \
-            StripedGatherSource
-        gstats = GatherStats()
-        root = tracing.current_span()
-        return rebuild_ec_files_piggyback(
-            base_name, present, missing, layout,
-            lambda src: StripedGatherSource(
-                [LocalShardReader(base_name + to_ext(i), gstats)
-                 for i in src], shard_size,
-                slab=_pb_slab(slab, layout.window), stats=gstats,
-                parent_span=root),
-            codec=codec, pipelined=pipelined, stats=stats)
-    ins = [open(base_name + to_ext(i), "rb") if present[i] else None
-           for i in range(total)]
-    outs = {i: open(base_name + to_ext(i), "wb") for i in missing}
-    # only the first k survivors feed the decode plan; reading more would
-    # be dead I/O (their coefficient columns are zero by construction)
-    src = [i for i, p in enumerate(present) if p][:k]
-
-    def survivor_slabs():
-        for off in range(0, shard_size, slab):
-            n = min(slab, shard_size - off)
-            rows = []
-            for i in src:
-                ins[i].seek(off)
-                rows.append(np.frombuffer(ins[i].read(n), dtype=np.uint8))
-            yield None, np.stack(rows, axis=0)
-
-    from ..ops import telemetry
-    before = telemetry.STATS.snapshot()
-    phases = {"gather": 0.0, "plan": 0.0, "dispatch": 0.0,
-              "drain": 0.0, "write": 0.0}
-    ptimer = StageTimer(root=tracing.current_span())
-    t_stream = time.perf_counter()
+@contextlib.contextmanager
+def rebuilt_outputs(base_name: str, shard_ids: List[int], sink=None):
+    """The shard files a rebuild writes, open, by shard id (none where a
+    ``sink`` takes the rows). A rebuild is all-or-nothing: on any failure
+    they are closed and removed and the sink is aborted, so that neither
+    this node nor the sink's target keeps part of a shard — the next
+    rebuild would count it a survivor."""
+    outs = {} if sink is not None else \
+        {i: open(base_name + to_ext(i), "wb") for i in shard_ids}
     try:
-        if pipelined:
-            from ..ops.pipeline import PipelinedMatmul
-            t0 = time.perf_counter()
-            coeffs = _rebuild_coeffs(codec, present, missing)
-            phases["plan"] = time.perf_counter() - t0
-            # pieces: device-shard outputs drain and append to the
-            # missing-shard files per device, no full-slab host staging
-            pm = PipelinedMatmul(coeffs, max_width=slab, codec=codec,
-                                 timer=ptimer, pieces=True)
-            for _, _, parts in pm.stream(survivor_slabs()):
-                with ptimer.stage("shard_write",
-                                  span="ec.rebuild.write") as st:
-                    for _, piece in parts:
-                        for r, i in enumerate(missing):
-                            outs[i].write(piece[r])   # a view: no copy
-                            st.nbytes += piece[r].nbytes
-            phases["write"] = ptimer.totals.get("shard_write", 0.0)
-            # consumer-side accounting: the stream loop's time splits
-            # into waiting for survivor reads (gather), h2d puts
-            # (dispatch), waiting for device results (drain), and the
-            # writes above — overlapped worker-thread work (reader,
-            # drain pool) is deliberately NOT added on top, so the
-            # phases tile the wall instead of exceeding it
-            phases["gather"] = ptimer.totals.get("read_wait", 0.0)
-            phases["dispatch"] = ptimer.totals.get("h2d", 0.0)
-            phases["drain"] = ptimer.totals.get("drain_wait", 0.0)
-        else:
-            for off in range(0, shard_size, slab):
-                n = min(slab, shard_size - off)
-                t0 = time.perf_counter()
-                shards: List[Optional[np.ndarray]] = []
-                for i in range(total):
-                    if ins[i] is None:
-                        shards.append(None)
-                    else:
-                        ins[i].seek(off)
-                        shards.append(np.frombuffer(ins[i].read(n),
-                                                    dtype=np.uint8))
-                t1 = time.perf_counter()
-                rebuilt = codec.reconstruct(shards)
-                t2 = time.perf_counter()
-                for i in missing:
-                    outs[i].write(np.ascontiguousarray(rebuilt[i]))
-                t3 = time.perf_counter()
-                phases["gather"] += t1 - t0
-                phases["dispatch"] += t2 - t1
-                phases["write"] += t3 - t2
+        yield outs
+    except BaseException:
+        if sink is not None:
+            sink.abort()
+        for i, h in outs.items():
+            h.close()
+            try:
+                os.remove(base_name + to_ext(i))
+            except OSError:
+                pass
+        raise
     finally:
-        for h in ins:
-            if h is not None:
-                h.close()
         for h in outs.values():
             h.close()
-    stream_s = time.perf_counter() - t_stream
-    # pad/bucket copies and dispatch issuance are the only consumer-side
-    # work not bracketed above; attribute the remainder to dispatch so
-    # the phase breakdown sums to the operation wall
-    residual = stream_s - sum(phases.values())
+
+
+def close_rebuild(stats: Optional[dict], timer: StageTimer, stream_s: float,
+                  before: dict, source, codec: ReedSolomonCodec,
+                  coeffs: np.ndarray, lost: List[int], **route):
+    """The end of every rebuild body (the flat and the coupled full
+    decode here, the two single-shard repairs of ec/decoder.py), and the
+    one place that knows the node's reply.
+
+    The phases are the consumer thread's account of the stream: the
+    waits for stripes still in flight (``gather``: the gather's
+    UNOVERLAPPED remainder, not its busy time), the puts (``dispatch``),
+    the waits for results (``drain``) and the host re-layout and appends
+    (``write``, or ``deliver`` where a sink took the rows); the workers'
+    overlapped time is not added on top, and what the consumer did
+    between its stages (pads, dispatch issuance) goes to ``dispatch``, so
+    that they tile ``stream_s``. ``plan`` came before the stream. Each
+    leaves as a span. ``stats`` takes the telemetry since ``before``
+    (``telemetry.STATS.snapshot()``), the gather's own account
+    (``source.stats``), the decode's shape, and the byte account every
+    route gives: what the gather's readers received, of the k whole
+    shards a full gather pulls. ``route`` holds the keys that are one
+    route's own."""
+    from ..ops import telemetry
+    t = timer.totals
+    phases = {"plan": t.get("plan", 0.0), **_phases_from_timer(timer, True)}
+    if "deliver" in t:
+        phases["deliver"] = t["deliver"]
+    residual = stream_s - (sum(phases.values()) - phases["plan"])
     if residual > 0:
         phases["dispatch"] += residual
     for name, secs in phases.items():
         if secs > 0:
             tracing.record_span(name, secs, op="ec.rebuild",
                                 backend=codec.backend)
-    if stats is not None:
-        stats.update(telemetry.delta(before))
-        stats["survivor_bytes"] = shard_size * k
-        stats["rebuilt_bytes"] = shard_size * len(missing)
-        stats["stream_s"] = round(stream_s, 3)
-        stats["backend"] = codec.backend
-        stats["operand"] = [len(missing), k]
-        stats["k"], stats["m"] = codec.k, codec.m
-        stats["lost"] = list(missing)
-        stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
-        stats["stage_max_s"] = ptimer.max_s()
-    return missing
+    if stats is None:
+        return
+    gs = source.stats
+    stats.update(telemetry.delta(before))
+    stats.update(gs.snapshot())
+    stats.update(route)
+    stats["rebuilt_bytes"] = timer.bytes.get("shard_write", 0) + \
+        timer.bytes.get("deliver", 0)
+    stats["stream_s"] = round(stream_s, 3)
+    stats["backend"] = codec.backend
+    stats["operand"] = list(coeffs.shape)
+    stats["k"], stats["m"] = codec.k, codec.m
+    stats["lost"] = list(lost)
+    stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
+    stats["stage_max_s"] = {**timer.max_s(), **gs.timer.max_s()}
+    stats.update(gs.overlap(stream_s, phases["gather"]))
+    stats["repair_bytes"] = gs.bytes
+    stats["repair_remote_bytes"] = gs.remote_bytes
+    stats["repair_baseline_bytes"] = codec.k * source.shard_size
+
+
+def rebuild_ec_files(base_name: str,
+                     codec: Optional[ReedSolomonCodec] = None,
+                     slab: int = DEFAULT_SLAB,
+                     pipelined: Optional[bool] = None,
+                     stats: Optional[dict] = None,
+                     layout=None) -> List[int]:
+    """Regenerate missing shard files from survivors that are all local
+    files (reference RebuildEcFiles). Returns the list of rebuilt shard
+    ids. Raises if fewer than k survive.
+
+    The bodies are the served rebuild's — rebuild_ec_files_streaming,
+    and rebuild_ec_files_piggyback for a volume whose ``layout`` (an
+    ec.layout.LayoutInfo, None for flat) is piggyback — over a gather
+    whose every reader is a local file; ``stats``, when given, is the
+    reply they fill (close_rebuild)."""
+    from .gather import GatherStats, LocalShardReader, StripedGatherSource
+    codec = codec or volume_codec(base_name)
+    present = [os.path.exists(base_name + to_ext(i))
+               for i in range(codec.total)]
+    missing = [i for i, p in enumerate(present) if not p]
+    if not missing:
+        return []
+    if sum(present) < codec.k:
+        raise ValueError(
+            f"cannot rebuild: only {sum(present)} of {codec.total} shards")
+    sizes = {os.path.getsize(base_name + to_ext(i))
+             for i, p in enumerate(present) if p}
+    if len(sizes) > 1:
+        raise ValueError("surviving shards differ in size")
+    shard_size = sizes.pop()
+    gstats = GatherStats()
+
+    def local_source(src, slab):
+        return StripedGatherSource(
+            [LocalShardReader(base_name + to_ext(i), gstats) for i in src],
+            shard_size, slab=slab, stats=gstats,
+            parent_span=tracing.current_span())
+
+    if layout is not None and getattr(layout, "piggyback", False):
+        return rebuild_ec_files_piggyback(
+            base_name, present, missing, layout,
+            lambda src: local_source(src, _pb_slab(slab, layout.window)),
+            codec=codec, pipelined=pipelined, stats=stats)
+    # only the first k survivors feed the decode plan; reading more would
+    # be dead I/O (their coefficient columns are zero by construction)
+    src = [i for i, p in enumerate(present) if p][:codec.k]
+    return rebuild_ec_files_streaming(
+        base_name, present, missing, local_source(src, slab), codec=codec,
+        slab=slab, pipelined=pipelined, stats=stats)
 
 
 def _pb_slab(slab: int, window: int) -> int:
@@ -758,33 +771,10 @@ def rebuild_ec_files_piggyback(base_name: str, present: List[bool],
                 _give_slab(block)
             yield meta, sub
 
-    def decoded():
-        if pipelined:
-            from ..ops.pipeline import PipelinedMatmul
-            pm = PipelinedMatmul(coeffs, codec=codec,
-                                 max_width=source.slab // alpha,
-                                 timer=timer)
-            for _, _, out in pm.stream(split()):
-                yield out
-            return
-        # a host codec: gather + split, then the matmul, on this thread,
-        # timed under the pipeline's names so that one account serves both
-        stripes = split()
-        while True:
-            t0 = time.perf_counter()
-            item = next(stripes, None)
-            t1 = time.perf_counter()
-            timer.add("read_wait", t1 - t0)
-            if item is None:
-                return
-            out = codec._matmul(coeffs, item[1])
-            timer.add("h2d", time.perf_counter() - t1)
-            yield out
-
-    outs = {i: open(base_name + to_ext(i), "wb") for i in missing}
     t_stream = time.perf_counter()
-    try:
-        for out in decoded():
+    with rebuilt_outputs(base_name, missing) as outs:
+        for _, _, out in matmul_stream(codec, coeffs, split(), timer,
+                                       pipelined, source.slab // alpha):
             with timer.stage("pb_merge", span="ec.rebuild.pb_merge") as st:
                 merged = ops_codec.pb_merge(
                     np.asarray(out, dtype=np.uint8), alpha, window)
@@ -793,52 +783,11 @@ def rebuild_ec_files_piggyback(base_name: str, present: List[bool],
                              span="ec.rebuild.write"):
                 for row, i in zip(merged, missing):
                     outs[i].write(row)
-    except BaseException:
-        for i, h in outs.items():
-            h.close()
-            try:
-                os.remove(base_name + to_ext(i))
-            except OSError:
-                pass
-        raise
-    finally:
-        for h in outs.values():
-            h.close()
     stream_s = time.perf_counter() - t_stream
     telemetry.STATS.add("coupled_decodes")
-    t = timer.totals
-    phases = {"gather": t.get("read_wait", 0.0), "plan": t["plan"],
-              "dispatch": t.get("h2d", 0.0),
-              "drain": t.get("drain_wait", 0.0),
-              "write": t.get("pb_merge", 0.0) + t.get("shard_write", 0.0)}
-    # what the consumer did between its stages (pads, dispatch issuance)
-    # goes to dispatch, so that the phases tile the stream's wall
-    phases["dispatch"] += max(
-        stream_s - (sum(phases.values()) - phases["plan"]), 0.0)
-    for name, secs in phases.items():
-        if secs > 0:
-            tracing.record_span(name, secs, op="ec.rebuild",
-                                backend=codec.backend, layout="piggyback")
-    if stats is not None:
-        gs = source.stats
-        stats.update(telemetry.delta(before))
-        stats.update(gs.snapshot())
-        stats["survivor_bytes"] = source.shard_size * len(src)
-        stats["rebuilt_bytes"] = timer.bytes.get("shard_write", 0)
-        stats["stream_s"] = round(stream_s, 3)
-        stats["backend"] = codec.backend
-        stats["operand"] = list(coeffs.shape)
-        stats["k"], stats["m"] = codec.k, codec.m
-        stats["layout"] = "piggyback"
-        stats["lost"] = list(missing)
-        stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
-        stats["stage_max_s"] = {**timer.max_s(), **gs.timer.max_s()}
-        stats.update(gs.overlap(stream_s, phases["gather"]))
-        # the byte account the single-shard routes give: k whole shards
-        # gathered of the k a full gather pulls
-        stats["repair_bytes"] = gs.bytes
-        stats["repair_remote_bytes"] = gs.remote_bytes
-        stats["repair_baseline_bytes"] = codec.k * source.shard_size
+    close_rebuild(stats, timer, stream_s, before, source, codec, coeffs,
+                  missing, layout="piggyback",
+                  survivor_bytes=codec.k * source.shard_size)
     return list(missing)
 
 
@@ -851,10 +800,12 @@ def rebuild_ec_files_streaming(base_name: str,
                                pipelined: Optional[bool] = None,
                                stats: Optional[dict] = None,
                                sink=None) -> List[int]:
-    """Streaming variant of rebuild_ec_files: the survivor bytes arrive
-    from ``source`` (an ec.gather.StripedGatherSource — local files and
-    remote holders mixed) instead of whole shard files on local disk,
-    and each rebuilt slab is appended to the missing shard files as the
+    """The flat rebuild, served and local (rebuild_ec_files): the
+    survivor bytes arrive from ``source`` (an
+    ec.gather.StripedGatherSource over the first k survivors — local
+    files and remote holders mixed), one fused decode a stripe
+    regenerates every missing shard (data + parity rows stacked), and
+    each rebuilt slab is appended to the missing shard files as the
     decode drains. Rebuild wall approaches max(gather, compute) and the
     rebuilder never materializes a survivor copy.
 
@@ -882,124 +833,43 @@ def rebuild_ec_files_streaming(base_name: str,
             f"cannot rebuild: only {sum(present)} of {total} shards")
     from ..ops import telemetry
     before = telemetry.STATS.snapshot()
-    phases = {"gather": 0.0, "plan": 0.0, "dispatch": 0.0,
-              "drain": 0.0, "write": 0.0}
-    t0 = time.perf_counter()
-    coeffs = _rebuild_coeffs(codec, present, missing)
-    phases["plan"] = time.perf_counter() - t0
-    outs = {} if sink is not None else \
-        {i: open(base_name + to_ext(i), "wb") for i in missing}
+    # the stream's root span (ec.rebuild.stream, current here)
+    timer = StageTimer(root=tracing.current_span())
+    with timer.stage("plan"):
+        coeffs = _rebuild_coeffs(codec, present, missing)
     # where the rebuilt rows go: the local shard files, or the sink
     out_stage, out_span = ("shard_write", "ec.rebuild.write") \
         if sink is None else ("deliver", "ec.rebuild.deliver")
-    rebuilt_bytes = 0
-    # the stream's root span (ec.rebuild.stream, current here)
-    ptimer = StageTimer(root=tracing.current_span())
+    route = {"survivor_bytes": k * source.shard_size}
     t_stream = time.perf_counter()
-    try:
-        if pipelined:
-            from ..ops.pipeline import PipelinedMatmul
-            # pieces, same as rebuild_ec_files: the sharded decode's
-            # per-device outputs append as they land
-            pm = PipelinedMatmul(coeffs, max_width=slab, codec=codec,
-                                 timer=ptimer, pieces=True)
-            for _, data, parts in pm.stream(source.slabs()):
-                # its output is drained: the gather may fill it again
-                _give_slab(data)
-                with ptimer.stage(out_stage, span=out_span) as st:
-                    for _, piece in parts:
-                        if sink is not None:
-                            sink.write_rows(piece)    # views: no copy
-                            st.nbytes += piece.nbytes
-                            continue
-                        for r, i in enumerate(missing):
-                            outs[i].write(piece[r])   # a view: no copy
-                            st.nbytes += piece[r].nbytes
-            phases["write"] = ptimer.totals.get("shard_write", 0.0)
-            rebuilt_bytes = ptimer.bytes.get(out_stage, 0)
-            # consumer-side accounting, same discipline as
-            # rebuild_ec_files: read_wait is the time this thread spent
-            # blocked on stripes still in flight — the UNOVERLAPPED
-            # remainder of the gather, not its busy time
-            phases["gather"] = ptimer.totals.get("read_wait", 0.0)
-            phases["dispatch"] = ptimer.totals.get("h2d", 0.0)
-            phases["drain"] = ptimer.totals.get("drain_wait", 0.0)
-        else:
-            it = source.slabs()
-            while True:
-                t0 = time.perf_counter()
-                try:
-                    _, data = next(it)
-                except StopIteration:
-                    break
-                t1 = time.perf_counter()
-                out = codec._matmul(coeffs, data)
-                t2 = time.perf_counter()
-                _give_slab(data)
-                out = np.ascontiguousarray(out, dtype=np.uint8)
-                rebuilt_bytes += out.nbytes
-                if sink is not None:
-                    with ptimer.stage(out_stage, out.nbytes,
-                                      span=out_span):
-                        sink.write_rows(out)
-                else:
+    with rebuilt_outputs(base_name, missing, sink) as outs:
+        # pieces: the sharded decode's per-device outputs append as they
+        # land, no full-slab host staging
+        for _, data, parts in matmul_stream(codec, coeffs, source.slabs(),
+                                            timer, pipelined, slab,
+                                            pieces=True):
+            # its output is drained: the gather may fill it again
+            _give_slab(data)
+            with timer.stage(out_stage, span=out_span) as st:
+                for _, piece in parts:
+                    st.nbytes += piece.nbytes
+                    if sink is not None:
+                        sink.write_rows(piece)    # views: no copy
+                        continue
                     for r, i in enumerate(missing):
-                        outs[i].write(out[r])
-                    phases["write"] += time.perf_counter() - t2
-                phases["gather"] += t1 - t0
-                phases["dispatch"] += t2 - t1
+                        outs[i].write(piece[r])   # a view: no copy
         if sink is not None:
             # the lanes drained, every shard finalized on the target
-            with ptimer.stage(out_stage, span=out_span):
+            with timer.stage(out_stage, span=out_span):
                 sink.finish()
-    except BaseException:
-        if sink is not None:
-            sink.abort()
-        for i, h in outs.items():
-            h.close()
-            try:
-                os.remove(base_name + to_ext(i))
-            except OSError:
-                pass
-        raise
-    finally:
-        for h in outs.values():
-            h.close()
+            route["delivered_to"] = sink.target
+            route["deliver_blocked_s"] = round(sink.blocked_s, 6)
     stream_s = time.perf_counter() - t_stream
-    if sink is not None:
-        phases["deliver"] = ptimer.totals.get("deliver", 0.0)
-    residual = stream_s - (sum(phases.values()) - phases["plan"])
-    if residual > 0:
-        phases["dispatch"] += residual
-    for name, secs in phases.items():
-        if secs > 0:
-            tracing.record_span(name, secs, op="ec.rebuild",
-                                backend=codec.backend, streaming=True)
     telemetry.STATS.add("rebuild_local_bytes" if sink is None
-                        else "rebuild_delivered_bytes", rebuilt_bytes)
-    if stats is not None:
-        gs = source.stats
-        stats.update(telemetry.delta(before))
-        stats.update(gs.snapshot())
-        if sink is not None:
-            stats["delivered_to"] = sink.target
-            stats["deliver_blocked_s"] = round(sink.blocked_s, 6)
-        stats["survivor_bytes"] = source.shard_size * k
-        stats["rebuilt_bytes"] = rebuilt_bytes
-        stats["stream_s"] = round(stream_s, 3)
-        stats["backend"] = codec.backend
-        stats["operand"] = list(coeffs.shape)
-        stats["k"], stats["m"] = codec.k, codec.m
-        stats["lost"] = list(missing)
-        stats["phases"] = {n: round(s, 6) for n, s in phases.items()}
-        stats["stage_max_s"] = {**ptimer.max_s(), **gs.timer.max_s()}
-        stats.update(gs.overlap(stream_s, phases["gather"]))
-        # the byte account the single-shard routes give: what the
-        # gather's readers received, of the k whole shards it is meant
-        # to pull
-        stats["repair_bytes"] = gs.bytes
-        stats["repair_remote_bytes"] = gs.remote_bytes
-        stats["repair_baseline_bytes"] = k * source.shard_size
+                        else "rebuild_delivered_bytes",
+                        timer.bytes.get(out_stage, 0))
+    close_rebuild(stats, timer, stream_s, before, source, codec, coeffs,
+                  missing, **route)
     return list(missing)
 
 

@@ -167,7 +167,7 @@ class VolumeTierer:
         timings: Dict = {}
         t0 = time.perf_counter()
         try:
-            do_ec_encode(env, vid, mode="stream", timings=timings,
+            do_ec_encode(env, vid, timings=timings,
                          rate_mbps=self.rate_mbps)
         except Exception as e:  # noqa: BLE001 - recorded, retried next scan
             glog.V(0).infof("tier demotion of volume %s failed: %s",

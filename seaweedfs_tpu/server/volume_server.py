@@ -1115,10 +1115,12 @@ class VolumeServer:
         return {"volume": vid, "unmounted": out}
 
     def admin_ec_rebuild(self, req: Request):
-        """Local rebuild from whole shard files (legacy, query-only), or
-        — when the POST body carries ``sources`` ({shard: [holders]}) —
-        the streaming striped gather: survivor ranges are pulled and
-        decoded in overlapped slabs, never landing whole on disk.
+        """The streaming striped gather: the POST body's ``sources``
+        ({shard: [holders]}) names the survivors this server does not
+        hold; their ranges are pulled and decoded in overlapped slabs,
+        never landing whole on disk. The query-only form (no
+        ``sources``) is the same rebuild with every survivor a local
+        file, and always the full decode (``repair`` full).
         ``target`` in the body names the server that keeps the rebuilt
         shards where that is another one: this server decodes on its
         chip and the rows go to the target's ``/admin/ec/shard_write``
@@ -1135,23 +1137,21 @@ class VolumeServer:
             body = req.json()
         except ValueError:
             raise HttpError(400, "bad JSON body") from None
+        body = body if isinstance(body, dict) else {}
+        sources = body.get("sources") or None
+        hedge_ms = body.get("hedge_ms")
         stats: dict = {}
-        if isinstance(body, dict) and body.get("sources"):
-            hedge_ms = body.get("hedge_ms")
-            rebuilt = self.store.rebuild_ec_shards_streaming(
-                vid, collection, sources=body["sources"], stats=stats,
-                slab=int(body.get("slab") or 0) or None,
-                window=int(body.get("window") or 0) or None,
-                hedge_ms=float(hedge_ms) if hedge_ms is not None
-                else None,
-                repair=str(body.get("repair") or "auto"),
-                deliver_to=body.get("target") or None)
-            observe_gather(stats)
-            observe_repair(stats)
-            observe_mesh(stats)
-        else:
-            rebuilt = self.store.rebuild_ec_shards(
-                vid, collection, stats=stats)
+        rebuilt = self.store.rebuild_ec_shards_streaming(
+            vid, collection, sources=sources, stats=stats,
+            slab=int(body.get("slab") or 0) or None,
+            window=int(body.get("window") or 0) or None,
+            hedge_ms=float(hedge_ms) if hedge_ms is not None else None,
+            repair=str(body.get("repair") or "auto") if sources
+            else "full",
+            deliver_to=body.get("target") or None)
+        observe_gather(stats)
+        observe_repair(stats)
+        observe_mesh(stats)
         if rebuilt:
             # rebuilt shards serve from disk now; cached reconstructions
             # of them (engine LRU + plane slabs) are dead weight

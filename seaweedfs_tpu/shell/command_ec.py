@@ -194,13 +194,12 @@ def collect_volume_ids_for_ec_encode(env: CommandEnv, collection: str,
 
 @command("ec.encode",
          "-volumeId <id> | -collection <name> [-fullPercent 0.95] "
-         "[-mode stream|copy] [-geometry <data>,<parity>] : erasure-code "
-         "volumes and spread their shards across the cluster (geometry "
-         "= the RS code of the new EC volume, e.g. 6,3 for nine shards; "
-         "10,4 and 14 shards without the flag; it is stamped into the "
-         "volume's .vif and every later command reads it from there; "
-         "stream = push shard ranges to holders while later slabs "
-         "encode; copy = legacy generate-then-pull)")
+         "[-geometry <data>,<parity>] : erasure-code volumes and spread "
+         "their shards across the cluster, each shard's ranges pushed to "
+         "its holder while later slabs encode (geometry = the RS code of "
+         "the new EC volume, e.g. 6,3 for nine shards; 10,4 and 14 "
+         "shards without the flag; it is stamped into the volume's .vif "
+         "and every later command reads it from there)")
 def ec_encode(env: CommandEnv, args: List[str]):
     from ..util import tracing
     flags = parse_flags(args)
@@ -209,8 +208,7 @@ def ec_encode(env: CommandEnv, args: List[str]):
         from ..ec.layout import parse_geometry
         geometry = parse_geometry(flags["geometry"])
     if "volumeId" in flags:
-        do_ec_encode(env, int(flags["volumeId"]), mode=flags.get("mode"),
-                     geometry=geometry)
+        do_ec_encode(env, int(flags["volumeId"]), geometry=geometry)
         return
     if "collection" not in flags:
         env.write("usage: ec.encode -volumeId <id> | -collection <name>")
@@ -226,8 +224,7 @@ def ec_encode(env: CommandEnv, args: List[str]):
     counted = make_lock("command_ec.collection_span")
 
     def encode(vid, _placement):
-        do_ec_encode(env, vid, mode=flags.get("mode"),
-                     geometry=geometry, command=whole.trace_id)
+        do_ec_encode(env, vid, geometry=geometry, command=whole.trace_id)
         with counted:       # volumes in lanes finish on their threads
             whole.tags["volumes"] += 1
             whole.tags["bytes"] += found[vid][0]
@@ -248,18 +245,14 @@ def ec_encode(env: CommandEnv, args: List[str]):
         tracing.finish_span(whole)
 
 
-def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
+def do_ec_encode(env: CommandEnv, vid: int,
                  timings: Dict = None, rate_mbps: float = 0.0,
                  geometry: tuple = None, command: str = None):
     """Freeze -> encode+spread -> mount -> drop originals.
 
-    mode: "stream" (default; `SW_EC_SPREAD_MODE` overrides) sends the
-    shard assignment to the source, which pushes each shard's slab
-    ranges to its holder WHILE later slabs encode — remote-bound shards
-    never touch the source disk. "copy" is the legacy two-phase flow
-    (all 14 shards land on the source, then targets pull whole files);
-    stream mode also falls back to it when the source predates the
-    streaming endpoint or the spread dies mid-shard.
+    The shard assignment goes to the source, which pushes each shard's
+    slab ranges to its holder WHILE later slabs encode — remote-bound
+    shards never touch the source disk (`_encode_spread_streaming`).
 
     Any failure after the freeze unwinds: generated shard files (and
     ``.part`` stages) are deleted cluster-wide and each replica's
@@ -268,26 +261,21 @@ def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
 
     ``timings``, when given, records encode/spread busy seconds,
     ``overlap_frac``, and the spread counters for bench. ``rate_mbps``
-    > 0 paces the streaming spread (the tierer's background cap);
-    copy mode ignores it. ``geometry`` (k, m) is the new EC volume's RS
+    > 0 paces the spread (the tierer's background cap).
+    ``geometry`` (k, m) is the new EC volume's RS
     code, passed on to the source's ``/admin/ec/generate``; None leaves
     the node at its default, 10 + 4. ``command`` is the trace id of the
     `ec.encode -collection` span this volume is one of, kept as a tag."""
-    from ..util import config as _config
     from ..util import tracing
-    mode = (mode or _config.env_str("SW_EC_SPREAD_MODE") or
-            "stream").lower()
     replicas = _volume_replicas(env, vid)
     if not replicas:
         env.write(f"volume {vid} not found")
         return
     collection = replicas[0].get("collection", "")
     source = replicas[0]["url"]
-    root = tracing.start_span("ec.encode", volume=vid, mode=mode)
+    root = tracing.start_span("ec.encode", volume=vid)
     if command:
         root.tags["command"] = command
-    if timings is not None:
-        timings["mode"] = mode
     try:
         # 1. freeze every replica, recording each holder's OWN prior
         # state (not the master's heartbeat-delayed view) so a failure
@@ -301,28 +289,11 @@ def do_ec_encode(env: CommandEnv, vid: int, mode: str = None,
                     froze.append(r["url"])
         assignment = balanced_ec_distribution(
             _free_nodes(env), geometry or (DATA_SHARDS, PARITY_SHARDS))
-        by_node: Dict[str, List[int]] = {}
-        for sid, url in enumerate(assignment):
-            by_node.setdefault(url, []).append(sid)
         try:
             # 2+3. encode + spread + mount
-            if mode == "copy":
-                _encode_spread_copy(env, vid, collection, source,
-                                    by_node, timings, geometry)
-            else:
-                try:
-                    _encode_spread_streaming(env, vid, collection,
-                                             source, assignment,
-                                             timings, rate_mbps,
-                                             geometry)
-                except HttpError as e:
-                    env.write(f"volume {vid}: streaming encode failed "
-                              f"({e.status}); falling back to copy mode")
-                    root.tags["fallback"] = "copy"
-                    _cleanup_partial_encode(env, vid, collection,
-                                            set(assignment) | {source})
-                    _encode_spread_copy(env, vid, collection, source,
-                                        by_node, timings, geometry)
+            _encode_spread_streaming(env, vid, collection, source,
+                                     assignment, timings, rate_mbps,
+                                     geometry)
         except BaseException as e:
             _cleanup_partial_encode(env, vid, collection,
                                     set(assignment) | {source})
@@ -435,64 +406,10 @@ def _geometry_query(geometry) -> str:
     return f"&geometry={geometry[0]},{geometry[1]}" if geometry else ""
 
 
-def _encode_spread_copy(env: CommandEnv, vid: int, collection: str,
-                        source: str, by_node: Dict[str, List[int]],
-                        timings: Dict = None, geometry: tuple = None):
-    """Legacy two-phase flow: generate all k + m shards on the source,
-    then every target pulls + mounts its shards concurrently (reference
-    parallelCopyEcShardsFromSource, command_ec_encode.go:200-235:
-    goroutine per target server)."""
-    import time as _time
-    from ..util.fanout import fan_out_must_succeed
-    t0 = _time.perf_counter()
-    env.node_post(source, f"/admin/ec/generate?volume={vid}"
-                          f"&collection={collection}"
-                          f"{_geometry_query(geometry)}")
-    t1 = _time.perf_counter()
-    total = sum(len(held) for held in by_node.values())
-    env.write(f"volume {vid}: generated {total} shards on "
-              f"{source}")
-
-    def spread(target):
-        url, shards = target
-        s = ",".join(map(str, shards))
-        if url != source:
-            env.node_post(url, f"/admin/ec/copy?volume={vid}"
-                               f"&collection={collection}&source={source}"
-                               f"&shards={s}")
-        env.node_post(url, f"/admin/ec/mount?volume={vid}"
-                           f"&collection={collection}&shards={s}")
-        return s
-
-    for (url, _), s in zip(
-            by_node.items(),
-            fan_out_must_succeed(spread, list(by_node.items()),
-                                 what=f"ec shard spread for volume {vid}",
-                                 dedicated=True)):
-        env.write(f"volume {vid}: shards {s} -> {url}")
-    # 4. delete source's unassigned shard files
-    source_keeps = set(by_node.get(source, []))
-    extra = [s for s in range(total) if s not in source_keeps]
-    if extra:
-        env.node_post(source, f"/admin/ec/delete_shards?volume={vid}"
-                              f"&collection={collection}"
-                              f"&shards={','.join(map(str, extra))}")
-    t2 = _time.perf_counter()
-    if timings is not None:
-        timings["encode_busy_s"] = \
-            timings.get("encode_busy_s", 0) + (t1 - t0)
-        timings["spread_busy_s"] = \
-            timings.get("spread_busy_s", 0) + (t2 - t1)
-        timings["encode_wall_s"] = \
-            timings.get("encode_wall_s", 0) + (t2 - t0)
-        timings.setdefault("overlap_frac", 0.0)
-
-
 @command("ec.rebuild",
-         "[-collection <name>] [-mode stream|copy] "
-         "[-repair auto|trace|piggyback|full] : regenerate missing "
-         "shards (stream = ranged survivor gather overlapped with the "
-         "decode; copy = legacy whole-shard copies; repair = "
+         "[-collection <name>] [-repair auto|trace|piggyback|full] : "
+         "regenerate missing shards from ranged survivor reads "
+         "overlapped with the decode (repair = "
          "single-shard strategy — trace ships projected sub-shard "
          "symbols from all survivors on flat volumes, piggyback ships "
          "half-shard planes on piggyback-layout volumes, full pulls k "
@@ -511,8 +428,7 @@ def ec_rebuild(env: CommandEnv, args: List[str]):
         vid, collection, shards, missing = job
         timings: Dict = {}
         do_ec_rebuild(env, vid, collection, shards, missing,
-                      timings=timings, mode=flags.get("mode"),
-                      repair=flags.get("repair"),
+                      timings=timings, repair=flags.get("repair"),
                       command=whole.trace_id, placement=placement)
         with counted:       # volumes in lanes finish on their threads
             whole.tags["volumes"] += 1
@@ -607,19 +523,15 @@ def _merge_rebuild_stats(timings: Dict, out: dict):
 
 def do_ec_rebuild(env: CommandEnv, vid: int, collection: str,
                   shards: Dict[int, List[str]], missing: List[int],
-                  timings: Dict[str, float] = None, mode: str = None,
+                  timings: Dict[str, float] = None,
                   repair: str = None, command: str = None,
                   placement: tuple = None):
-    """`timings`, when given, records the phase walls plus the
-    rebuilder's stats (gather/compute busy time, overlap_frac, dispatch
-    telemetry) — the benchmark's overlap accounting.
-
-    mode: "stream" (default; `SW_EC_GATHER_MODE` overrides) pushes the
-    survivor holder map to the rebuilder, which pulls slab ranges and
-    decodes them overlapped — no whole-shard temp copies, no trailing
-    delete_shards pass. "copy" is the legacy copy-then-rebuild flow;
-    stream mode also falls back to it if the rebuilder predates the
-    streaming endpoint.
+    """The survivor holder map goes to the rebuilder, which pulls slab
+    ranges and decodes them overlapped — no whole-shard temp copies, no
+    trailing delete_shards pass (`_rebuild_streaming`). `timings`, when
+    given, records the phase walls plus the rebuilder's stats
+    (gather/compute busy time, overlap_frac, dispatch telemetry) — the
+    benchmark's overlap accounting.
 
     repair: "auto" (default; `SW_EC_REPAIR_MODE` overrides) lets the
     rebuilder pick the cheapest single-shard strategy for the volume's
@@ -627,7 +539,7 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str,
     survivors) on flat volumes, plane repair (half-shard planes from
     k+1 helpers) on piggyback volumes. "trace"/"piggyback" force the
     matching strategy and error on the other layout; "full" forces the
-    k-survivor gather on either. Stream mode only. ``command`` is the
+    k-survivor gather on either. ``command`` is the
     trace id of the `ec.rebuild` command's span, kept as a tag.
 
     ``placement`` is (computing node, target) where the command runs
@@ -640,53 +552,36 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str,
     chip the node that decoded names in its reply."""
     from ..util import config as _config
     from ..util import tracing
-    mode = (mode or _config.env_str("SW_EC_GATHER_MODE") or
-            "stream").lower()
     repair = (repair or _config.env_str("SW_EC_REPAIR_MODE") or
               "auto").lower()
     # shell-side trace root: every call below — survivor gathering, the
     # rebuild, mount — carries its traceparent: ONE trace per operation
-    root = tracing.start_span("ec.rebuild", volume=vid, mode=mode,
-                              repair=repair)
+    root = tracing.start_span("ec.rebuild", volume=vid, repair=repair)
     if command:
         root.tags["command"] = command
     try:
         node, rebuilder = placement or (None, None)
         if rebuilder is None:
             rebuilder = node = pick_rebuilder(env.cluster_nodes(), shards)
-        if mode == "copy":
-            node = rebuilder
         root.tags["target"], root.tags["computed_on"] = rebuilder, node
         root.tags["device"] = ""    # the node that decodes names it
-        if mode == "copy":
-            rebuilt = _rebuild_via_copy(env, vid, collection, shards,
-                                        rebuilder, root, timings)
-        else:
+        if node != rebuilder:
             try:
-                if node != rebuilder:
-                    try:
-                        rebuilt = _rebuild_streaming(
-                            env, vid, collection, shards, rebuilder, root,
-                            timings, repair=repair, node=node)
-                    except HttpError as e:
-                        env.write(f"volume {vid}: rebuild on {node} for "
-                                  f"{rebuilder} failed ({e}); rebuilding "
-                                  f"on {rebuilder}")
-                        root.tags["fallback"] = "target"
-                        root.tags["computed_on"] = node = rebuilder
-                        _cleanup_partial_rebuild(env, vid, collection,
-                                                 rebuilder, missing)
-                if node == rebuilder:
-                    rebuilt = _rebuild_streaming(env, vid, collection,
-                                                 shards, rebuilder, root,
-                                                 timings, repair=repair)
+                rebuilt = _rebuild_streaming(
+                    env, vid, collection, shards, rebuilder, root,
+                    timings, repair=repair, node=node)
             except HttpError as e:
-                env.write(f"volume {vid}: streaming rebuild failed "
-                          f"({e.status}); falling back to copy mode")
-                root.tags["fallback"] = "copy"
-                rebuilt = _rebuild_via_copy(env, vid, collection,
-                                            shards, rebuilder, root,
-                                            timings)
+                env.write(f"volume {vid}: rebuild on {node} for "
+                          f"{rebuilder} failed ({e}); rebuilding "
+                          f"on {rebuilder}")
+                root.tags["fallback"] = "target"
+                root.tags["computed_on"] = node = rebuilder
+                _cleanup_partial_rebuild(env, vid, collection,
+                                         rebuilder, missing)
+        if node == rebuilder:
+            rebuilt = _rebuild_streaming(env, vid, collection, shards,
+                                         rebuilder, root, timings,
+                                         repair=repair)
         if timings is not None:
             timings["trace_id"] = root.trace_id
     except BaseException as e:
@@ -774,72 +669,6 @@ def _rebuild_streaming(env: CommandEnv, vid: int, collection: str,
         if timings is not None:
             timings["mount_s"] = timings.get("mount_s", 0) + \
                 (_time.perf_counter() - t3)
-    return rebuilt
-
-
-def _rebuild_via_copy(env: CommandEnv, vid: int, collection: str,
-                      shards: Dict[int, List[str]], rebuilder: str,
-                      root, timings: Dict = None) -> List[int]:
-    """Legacy flow: copy every survivor whole, rebuild locally, delete
-    the temp copies."""
-    import time as _time
-    from ..util import tracing
-    from ..util.fanout import fan_out_must_succeed
-    local = {s for s, urls in shards.items() if rebuilder in urls}
-    # copy surviving shards the rebuilder lacks — pulls from distinct
-    # sources run concurrently (reference prepareDataToRecover +
-    # goroutine fan-out); the .ecx rides along with exactly one copy
-    to_copy = [(sid, urls[0]) for sid, urls in shards.items()
-               if sid not in local]
-    copied = [sid for sid, _ in to_copy]
-
-    def pull(job):
-        (sid, src), with_ecx = job
-        # fan-out worker threads don't inherit the contextvar —
-        # parent each per-source gather span on the root explicitly
-        with tracing.span("gather", parent=root, shard=sid,
-                          source=src):
-            env.node_post(
-                rebuilder,
-                f"/admin/ec/copy?volume={vid}&collection={collection}"
-                f"&source={src}&shards={sid}"
-                f"&copy_ecx={'true' if with_ecx else 'false'}")
-
-    jobs = [(item, (not local) and i == 0)
-            for i, item in enumerate(to_copy)]
-    t0 = _time.perf_counter()
-    fan_out_must_succeed(pull, jobs,
-                         what=f"survivor shard copy for volume {vid}",
-                         dedicated=True)
-    t1 = _time.perf_counter()
-    # rebuild + mount only the previously-missing shards
-    out = env.node_post(rebuilder,
-                        f"/admin/ec/rebuild?volume={vid}"
-                        f"&collection={collection}")
-    t2 = _time.perf_counter()
-    if timings is not None:
-        timings["gather_s"] = timings.get("gather_s", 0) + (t1 - t0)
-        timings["compute_s"] = timings.get("compute_s", 0) + (t2 - t1)
-        timings["wall_s"] = timings.get("wall_s", 0) + (t2 - t0)
-        timings["gathered_shards"] = \
-            timings.get("gathered_shards", 0) + len(to_copy)
-        _merge_rebuild_stats(timings, out)
-    rebuilt = out.get("rebuilt", [])
-    if rebuilt:
-        t3 = _time.perf_counter()
-        env.node_post(rebuilder,
-                      f"/admin/ec/mount?volume={vid}"
-                      f"&collection={collection}"
-                      f"&shards={','.join(map(str, rebuilt))}")
-        if timings is not None:
-            timings["mount_s"] = timings.get("mount_s", 0) + \
-                (_time.perf_counter() - t3)
-    # clean up temp survivor copies (not mounted here)
-    if copied:
-        env.node_post(rebuilder,
-                      f"/admin/ec/delete_shards?volume={vid}"
-                      f"&collection={collection}"
-                      f"&shards={','.join(map(str, copied))}")
     return rebuilt
 
 

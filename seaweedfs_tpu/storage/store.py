@@ -454,35 +454,6 @@ class Store:
             self._changed()
         return out
 
-    def rebuild_ec_shards(self, vid: int, collection: str = "",
-                          stats: dict = None) -> List[int]:
-        """``stats``, when given, receives the rebuild's dispatch
-        telemetry (rebuild_ec_files fills it) for the admin endpoint /
-        bench counters."""
-        import time as _time
-        from ..util import tracing
-        for loc in self.locations:
-            base = volume_file_prefix(loc.directory, collection, vid)
-            if os.path.exists(base + ".ecx"):
-                li = self._volume_layout(base)
-                codec = self.volume_codec(base)
-                with tracing.span("ec.rebuild.local", volume=vid,
-                                  layout=li.layout, k=codec.k, m=codec.m):
-                    rebuilt = ec_encoder.rebuild_ec_files(
-                        base, codec=codec, stats=stats,
-                        layout=(li if li.piggyback else None))
-                    from ..ec.decoder import read_ec_volume_superblock
-                    t0 = _time.perf_counter()
-                    rebuild_ecx_file(
-                        base, read_ec_volume_superblock(base).offset_width)
-                    ecx_s = _time.perf_counter() - t0
-                    tracing.record_span("write", ecx_s, op="ec.rebuild.ecx")
-                    if stats is not None and "phases" in stats:
-                        stats["phases"]["write"] = round(
-                            stats["phases"].get("write", 0.0) + ecx_s, 6)
-                return rebuilt
-        raise VolumeError(f"ec volume {vid} not found")
-
     def rebuild_ec_shards_streaming(self, vid: int, collection: str = "",
                                     sources: Dict[int, List[str]] = None,
                                     stats: dict = None,
@@ -496,8 +467,9 @@ class Store:
         survivors straight into the decode — no whole-shard copies on
         this server's disks, before, during, or after. ``sources`` maps
         shard id -> holder urls for survivors NOT local to this store;
-        shards already here are read from disk. Only the KB-scale index
-        sidecars (.ecx/.vif/.ecj) are copied whole.
+        shards already here are read from disk (with no ``sources`` every
+        survivor is: the query-only ``POST /admin/ec/rebuild``). Only the
+        KB-scale index sidecars (.ecx/.vif/.ecj) are copied whole.
 
         ``repair`` picks the single-shard repair strategy: ``trace``
         gathers per-survivor projected symbols over

@@ -108,16 +108,12 @@ _knob("SW_EC_MESH_WIDTH_DEVICES", "int", 0,
       "every visible device.")
 _knob("SW_EC_GATHER_WINDOW", "int", 4,
       "Bounded in-flight stripe prefetch window for streaming gathers.")
-_knob("SW_EC_GATHER_MODE", "str", "stream",
-      "ec.rebuild default transfer mode: stream or copy.")
 _knob("SW_EC_HEDGE_MS", "float", 0.0,
       "Hedge a duplicate survivor range read after this many ms; 0 "
       "disables hedging.")
 _knob("SW_EC_SPREAD_WINDOW", "int", 4,
       "Bounded per-lane send-queue window for streaming encode "
       "spread: stripes of the stream's slab width, counted in bytes.")
-_knob("SW_EC_SPREAD_MODE", "str", "stream",
-      "ec.encode default transfer mode: stream or copy.")
 _knob("SW_EC_REPAIR_MODE", "str", "auto",
       "Single-shard rebuild mode: auto (layout-routed: piggyback on "
       "coupled layouts, else trace, with fallback), trace, piggyback, "

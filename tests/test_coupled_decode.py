@@ -207,7 +207,10 @@ def test_one_lost_data_shard_still_takes_the_plane_route(encoded, tmp_path):
     stats = {}
     store = Store([str(tmp_path)], codec=NumpyCodec(K, M))
     assert store.rebuild_ec_shards_streaming(1, "", stats=stats) == [5]
-    assert stats["repair_mode"] == "piggyback" and "lost" not in stats
+    # (the plane repair's reply, not the full decode's: PR 48 gave every
+    # route `lost`; k whole survivors read stays the full decodes' own)
+    assert stats["repair_mode"] == "piggyback" and stats["lost"] == [5]
+    assert "survivor_bytes" not in stats and stats["coupled_decodes"] == 0
     assert _shas(base) == encoded[1]
 
 

@@ -104,7 +104,7 @@ def _sealed_volume(tmp_path, store, vid=3):
 
 @geometries
 def test_generate_stamps_the_geometry_and_rebuild_reads_it(tmp_path, k, m):
-    """The local (copy-mode) encode and rebuild of one store: k + m
+    """The local encode and rebuild of one store: k + m
     shard files, the .vif's keys, and a rebuild that takes the codec of
     the volume's geometry and not the store's default."""
     from seaweedfs_tpu.storage.store import Store
@@ -119,7 +119,7 @@ def test_generate_stamps_the_geometry_and_rebuild_reads_it(tmp_path, k, m):
     os.remove(base + to_ext(k))
     os.remove(base + to_ext(0))
     stats = {}
-    assert store.rebuild_ec_shards(3, stats=stats) == [0, k]
+    assert store.rebuild_ec_shards_streaming(3, stats=stats) == [0, k]
     assert (stats["k"], stats["m"], stats["lost"]) == (k, m, [0, k])
     assert open(base + to_ext(k), "rb").read() == want
     store.close()
@@ -184,7 +184,7 @@ def test_a_piggyback_volume_of_rs6_3_is_the_layouts_own(tmp_path,
     shards = [open(base + to_ext(i), "rb").read() for i in range(9)]
     for sid in (1, 7):
         os.remove(base + to_ext(sid))
-    assert store.rebuild_ec_shards(3) == [1, 7]
+    assert store.rebuild_ec_shards_streaming(3) == [1, 7]
     assert [open(base + to_ext(i), "rb").read() for i in range(9)] == shards
     store.close()
 

@@ -492,8 +492,8 @@ def test_cluster_flat_and_piggyback_coexist(cluster3):
     assert http_call("GET",
                      f"http://{servers[0].url}/{fid_b}") == data_b
     # forcing the flat-only strategy on a piggyback volume is a loud
-    # error, not silent wrong math (the shell would fall back to copy
-    # mode on it, so assert at the rebuilder's admin route)
+    # error, not silent wrong math (asserted at the rebuilder's admin
+    # route)
     from seaweedfs_tpu.server.http_util import HttpError, post_json
     rebuilder = next(vs.url for vs in servers if vs.url != victim.url)
     with pytest.raises(HttpError):

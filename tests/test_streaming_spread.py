@@ -4,7 +4,7 @@ holders while later slabs are still encoding): the chunked
 staging, atomic finalize), stream-vs-copy shard bit-identity across
 backends, the bounded per-target send window, all-or-nothing failure
 cleanup, dead-target failover to a spare, the end-to-end streaming
-`ec.encode -mode stream` over a live 3-server cluster, plus the
+`ec.encode` over a live 3-server cluster, plus the
 satellites: `/admin/ec/to_volume` roundtrip, SmallDispatchTuner opt-in
 auto-apply, and the bench device-init retry cap/backoff."""
 
@@ -464,10 +464,9 @@ def test_cluster_streaming_encode_end_to_end(cluster3, tmp_path):
     oracle = {sid: _digest(obase + to_ext(sid)) for sid in range(14)}
 
     timings = {}
-    do_ec_encode(env, vid, mode="stream", timings=timings)
+    do_ec_encode(env, vid, timings=timings)
     shell_log = env.out.getvalue()
     assert "streamed 14 shards" in shell_log
-    assert timings["mode"] == "stream"
     assert "overlap_frac" in timings
     assert timings["spread_stripes"] >= 1
     assert timings["spread_bytes"] > 0
