@@ -937,9 +937,13 @@ def build_parser() -> argparse.ArgumentParser:
     v.add_argument("-fileSizeLimitMB", type=int, default=256,
                    help="reject uploads above this size, 0 = no limit "
                         "(reference -fileSizeLimitMB)")
-    v.add_argument("-compactionMBps", type=int, default=0,
-                   help="throttle vacuum/compaction writes (MB/s, "
-                        "0 = unthrottled; reference compactionMBps)")
+    v.add_argument("-compactionMBps", type=int, default=None,
+                   help="limit background compaction or copying speed "
+                        "in mega bytes per second (reference "
+                        "-compactionMBps): vacuum's copy, every byte an "
+                        "ec.rebuild pulls from another server, "
+                        "volume.copy, ec.copy; 0 = unthrottled; not "
+                        "given: SW_COMPACTION_MBPS")
     v.add_argument("-index", default="memory",
                    choices=["memory", "compact", "sortedfile", "disk"],
                    help="needle map variant (reference -index flag): "

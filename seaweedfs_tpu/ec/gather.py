@@ -287,11 +287,12 @@ class LocalPlaneReader:
 
 
 def fetch_index_files(base_name: str, holders: Sequence[str],
-                      timeout: float = 300.0) -> List[str]:
+                      timeout: float = 300.0, budget=None) -> List[str]:
     """Pull the small index sidecars onto the rebuilder: .ecx required
     (the rebuilt .ecx tombstone replay and the mount need it), .vif and
     .ecj best-effort. These are KB-sized — the only whole files the
-    streaming rebuild copies."""
+    streaming rebuild copies; each is charged to ``budget`` (the
+    server's for background pulls) where one is given."""
     from ..server.http_util import HttpError, http_call
     name = os.path.basename(base_name)
     fetched: List[str] = []
@@ -318,6 +319,8 @@ def fetch_index_files(base_name: str, holders: Sequence[str],
         with open(base_name + ext, "wb") as f:
             f.write(data)
         fetched.append(ext)
+        if budget is not None:
+            budget.charge(len(data))
     return fetched
 
 

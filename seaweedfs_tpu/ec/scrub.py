@@ -424,7 +424,10 @@ class ScrubEngine:
     def _pace(self, t0: float, nbytes: int):
         """Sleep enough that the pass's gather bandwidth stays under
         the configured ceiling — this is the knob that bounds scrub's
-        tax on foreground p99."""
+        tax on foreground p99. A pass's own pacing, of everything it
+        reads (local shards too) at SW_EC_SCRUB_RATE_MBPS: not the
+        server's budget for what rebuilds and copies pull
+        (-compactionMBps), which a scrub read is never charged to."""
         rate = self.rate_mbps
         if rate <= 0:
             return

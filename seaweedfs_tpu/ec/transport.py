@@ -178,9 +178,16 @@ class TransportStats:
 
     stage = "transport"
 
-    def __init__(self):
+    def __init__(self, budget=None):
         self.timer = StageTimer()
         self._lock = make_lock("transport.TransportStats._lock")
+        # the server's budget for what it pulls in the background
+        # (util/throttler.ByteBudget, -compactionMBps) where this run is
+        # a rebuild's gather: its remote readers charge what they
+        # received and wait as told. None (a degraded read, a scrub
+        # pass, a server started with no budget): nobody is charged
+        self.budget = budget
+        self._pace_carry = [0, 0.0]
         self.fetches = 0
         self.sends = 0
         self.connects = 0
@@ -223,6 +230,41 @@ class TransportStats:
             if holder:
                 self.holder_fetches[holder] = \
                     self.holder_fetches.get(holder, 0) + 1
+
+    def add_pace(self, nbytes: int, t0: float, t1: float):
+        """One wait the budget imposed on a pull thread, after the
+        ``nbytes`` it had fetched. Returns the (bytes, seconds) a span
+        should carry — this wait and the shorter ones before it that
+        left none — or None while they sum to under ``PACE_SPAN_MIN_S``."""
+        self.timer.add("pace", t1 - t0, nbytes, interval=(t0, t1))
+        with self._lock:
+            carry = self._pace_carry
+            carry[0] += nbytes
+            carry[1] += t1 - t0
+            if carry[1] < PACE_SPAN_MIN_S:
+                return None
+            self._pace_carry = [0, 0.0]
+        return tuple(carry)
+
+    def pace_snapshot(self) -> Dict[str, float]:
+        """The budget's part in this run, as every rebuild replies it:
+        the rate (MiB/s as the flag gives it, 0 with no budget), the
+        waits summed over the pull threads and their union, and the most
+        the budget would have let through between the run's first fetch
+        and its last fetch or wait: the rate over that wall plus the one
+        refill window of credit a run may start with."""
+        if self.budget is None:
+            return {"pace_rate_mbps": 0, "paced_s": 0.0,
+                    "paced_wall_s": 0.0, "pace_budget_bytes": 0}
+        ivs = [iv for stage in (self.stage, "pace")
+               for iv in list(self.timer.intervals.get(stage, ()))]
+        wall = max(e for _, e in ivs) - min(s for s, _ in ivs) \
+            if ivs else 0.0
+        bps = self.budget.bps
+        return {"pace_rate_mbps": bps / (1 << 20),
+                "paced_s": round(self.timer.totals.get("pace", 0.0), 6),
+                "paced_wall_s": round(self.timer.busy_time("pace"), 6),
+                "pace_budget_bytes": int(bps * (wall + self.budget.WINDOW))}
 
     def add_connects(self, n: int):
         """Connections a remote writer opened: one a push lane and
@@ -298,8 +340,8 @@ class GatherStats(TransportStats):
 
     stage = "gather"
 
-    def __init__(self):
-        super().__init__()
+    def __init__(self, budget=None):
+        super().__init__(budget)
         self.rows_in_place = 0
         self.rows_copied = 0
 
@@ -314,6 +356,7 @@ class GatherStats(TransportStats):
         out = super().snapshot()
         out["rows_in_place"] = self.rows_in_place
         out["rows_copied"] = self.rows_copied
+        out.update(self.pace_snapshot())
         return out
 
     def overlap(self, stream_s: float, gather_wait_s: float) -> dict:
@@ -366,6 +409,11 @@ class SpreadStats(TransportStats):
 # `.trace.remote|local` for projected trace bits (ec/gather.py), so a
 # reader of one name never averages a full range with a part of one
 FETCH_SPAN = "ec.rebuild.fetch"
+# one span per wait the server's budget imposed on a pull thread (tags
+# `bytes`: what the thread had fetched, `thread`), under the rebuild's
+# root; a wait under a millisecond rides in the next one's
+PACE_SPAN = "ec.rebuild.pace"
+PACE_SPAN_MIN_S = 0.001
 
 
 class LocalShardReader:
@@ -494,7 +542,28 @@ class RemoteShardReader:
                              holder=holder)
         _health.BOARD.record_latency(holder, self._health_kind,
                                      st.t1 - st.t0)
+        if self.stats.budget is not None:
+            self._pace(got)
         return data
+
+    def _pace(self, nbytes: int):
+        """What crossed the socket is charged to the server's budget,
+        after it arrived, on the thread that fetched it (a hedged read:
+        both attempts), and the thread waits as long as it is told: a
+        paced fetch is a slow fetch, and the window, the decode and the
+        writer hide under it as they do under a slow holder. The wait is
+        no part of the fetch's span or of the holder's latency."""
+        wait = self.stats.budget.reserve(nbytes)
+        if wait <= 0:
+            return
+        t0 = time.perf_counter()
+        time.sleep(wait)
+        spanned = self.stats.add_pace(nbytes, t0, time.perf_counter())
+        if spanned is not None and self.span is not None:
+            tracing.record_span(
+                PACE_SPAN, spanned[1], parent=self.span,
+                bytes=spanned[0],
+                thread=threading.current_thread().name)
 
     def _read_failover(self, order: Sequence[str], off: int, n: int,
                        dest: Optional[np.ndarray] = None):
@@ -1319,7 +1388,10 @@ class StripedPush:
         cumulative pushed bytes fit the elapsed-time budget. Pacing the
         producer (not the workers) keeps the whole pipeline — encode
         compute included — at the cap, which is the point of running a
-        demotion under live traffic."""
+        demotion under live traffic. One producer's pacing of what it
+        SENDS, set by the caller of one push (the tierer's rate): not
+        the server's budget for what it pulls (-compactionMBps), which
+        sits in the puller's loop as upstream's throttle does."""
         if self.rate_mbps <= 0:
             return
         now = time.perf_counter()

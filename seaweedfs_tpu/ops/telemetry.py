@@ -76,6 +76,14 @@ holding nothing large enough): what these hosts charge for is memory a
 thread has not touched, and once a process has run its first volume
 the encode's reader and the rebuild's gather take every block from the
 pool, so the count stands still.
+
+`throttle` (`bytes`, `wait_us`) counts what the process's servers charged
+to their budget for background pulls (util/throttler.ByteBudget,
+`-compactionMBps`: a rebuild's remote survivor reads and sidecar
+fetches, `volume.copy`, `ec.copy`) and the microseconds the charging
+threads were told to wait, summed over the threads. A server started
+with no budget moves neither; degraded reads and scrub are never
+charged.
 """
 
 from __future__ import annotations
@@ -110,6 +118,7 @@ class DispatchStats:
         self._mesh_device_bytes: Dict[str, int] = {}
         self._repair_route = dict.fromkeys(self.REPAIR_ROUTES, 0)
         self._geometry_dispatches: Dict[str, int] = {}
+        self._throttle = {"bytes": 0, "wait_us": 0}
 
     def add(self, field: str, n: int = 1):
         with self._lock:
@@ -174,6 +183,12 @@ class DispatchStats:
         with self._lock:
             self._repair_route[route] += 1
 
+    def add_throttle(self, nbytes: int, wait_s: float):
+        """One charge to a server's budget for background pulls."""
+        with self._lock:
+            self._throttle["bytes"] += nbytes
+            self._throttle["wait_us"] += int(wait_s * 1e6)
+
     def add_mesh_device_bytes(self, device: str, n: int):
         """Payload bytes a sharded put landed on one device."""
         with self._lock:
@@ -186,6 +201,7 @@ class DispatchStats:
             snap["mesh_device_bytes"] = dict(self._mesh_device_bytes)
             snap["repair_route"] = dict(self._repair_route)
             snap["geometry_dispatches"] = dict(self._geometry_dispatches)
+            snap["throttle"] = dict(self._throttle)
             return snap
 
 

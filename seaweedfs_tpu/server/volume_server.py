@@ -31,13 +31,16 @@ from .http_util import (HttpError, HttpServer, Request, Response, Router,
 
 
 def _tag_holder_read(bytes_read: int, bytes_sent: int):
-    """On the server span of a holder-side repair read: the range read
-    off the disk against what leaves the holder — the byte reduction of
-    the trace and half-plane routes, per request."""
+    """On the server span of a holder-side survivor read (shard_read,
+    shard_repair_read, shard_plane_read): the range read off the disk
+    against what leaves the holder — the byte reduction of the trace
+    and half-plane routes, per request — and ``bytes``, what it sent,
+    under the one name every span that moves bytes carries: summed over
+    the holders it is what a rebuilder's budget has to have let in."""
     span = tracing.current_span()
     if span is not None:
         span.tags["bytes_read"] = int(bytes_read)
-        span.tags["bytes_sent"] = int(bytes_sent)
+        span.tags["bytes_sent"] = span.tags["bytes"] = int(bytes_sent)
 
 
 # the holder's end of the streaming spread moves a run from the socket to
@@ -71,7 +74,7 @@ class VolumeServer:
                  public_url: str = "", read_redirect: bool = True,
                  ec_backend: str = "auto", jwt_signing_key: str = "",
                  whitelist=(), index_kind: str = "memory",
-                 compaction_mbps: int = 0, fast_port: int = 0,
+                 compaction_mbps: int = None, fast_port: int = 0,
                  file_size_limit_mb: int = 256):
         router = Router()
         router.add("*", "/status", self.status)
@@ -159,13 +162,32 @@ class VolumeServer:
         self.pulse_seconds = _config.env_float("SW_PULSE_S") \
             if pulse_seconds is None else pulse_seconds
         self.read_redirect = read_redirect
+        # background copy throttle (reference -compactionMBps, "limit
+        # background compaction or copying speed"): vacuum's copy loop
+        # takes the rate, and everything this server pulls from another
+        # in the background is charged to ONE budget of that rate —
+        # every rebuild's survivor reads (the store's, whichever route
+        # and however many streams at once), volume.copy and ec.copy
+        # (upstream's doCopyFile, where its throttle sits). Reads a
+        # client waits for (degraded GETs) and scrub, which paces
+        # itself, are never charged. 0: no budget object at all
+        self.compaction_bps = int(
+            _config.env_int("SW_COMPACTION_MBPS")
+            if compaction_mbps is None else compaction_mbps) << 20
+        self.pull_budget = None
+        if self.compaction_bps > 0:
+            from ..util.throttler import ByteBudget
+            self.pull_budget = ByteBudget(
+                self.compaction_bps,
+                on_charge=telemetry.STATS.add_throttle)
         self.store = Store(
             directories or ["./data"],
             max_volume_counts=max_volume_counts,
             ip=host, port=self.port,
             public_url=public_url or f"{host}:{self.port}",
             data_center=data_center, rack=rack,
-            index_kind=index_kind, ec_backend=ec_backend)
+            index_kind=index_kind, ec_backend=ec_backend,
+            pull_budget=self.pull_budget)
         self.volume_size_limit = 30 * 1024 * 1024 * 1024
         # shard_write's piece buffers, kept between requests (a fresh
         # 8 MiB block is page faults on these hosts): a handler takes
@@ -174,8 +196,6 @@ class VolumeServer:
         # upload size cap (reference -fileSizeLimitMB: "limit file size
         # to avoid out of memory"); 0 (or negative) disables
         self.file_size_limit = max(0, int(file_size_limit_mb)) << 20
-        # compaction write throttle (reference -compactionMBps)
-        self.compaction_bps = int(compaction_mbps) << 20
         self.jwt_signing_key = jwt_signing_key
         from ..security.guard import Guard
         self.guard = Guard(whitelist)
@@ -726,8 +746,10 @@ class VolumeServer:
             # family (observe_mesh), not the flat kind counter
             if isinstance(total, (int, float)):
                 DEVICE_TELEMETRY_COUNTER.set_total(total, kind)
-            elif kind in ("repair_route", "geometry_dispatches"):
-                # repair_route.full, geometry_dispatches.6+3, ...
+            elif kind in ("repair_route", "geometry_dispatches",
+                          "throttle"):
+                # repair_route.full, geometry_dispatches.6+3,
+                # throttle.bytes, ...
                 for name, n in total.items():
                     DEVICE_TELEMETRY_COUNTER.set_total(
                         n, f"{kind}.{name}")
@@ -1268,6 +1290,10 @@ class VolumeServer:
             with open(base + ext, "wb") as f:
                 f.write(data)
             copied.append(ext)
+            if self.pull_budget is not None:
+                # upstream's doCopyFile: the throttle sits in the
+                # puller's write loop
+                self.pull_budget.charge(len(data))
         return {"volume": vid, "copied": copied}
 
 
@@ -1326,7 +1352,14 @@ class VolumeServer:
 
     def _pull_file(self, source: str, name: str, dest: str,
                    chunk: int = 64 << 20):
-        """Ranged streaming pull — never buffers whole volumes in RAM."""
+        """Ranged streaming pull — never buffers whole volumes in RAM.
+        Every range is charged to the server's budget for background
+        pulls once it is written (upstream's doCopyFile: the throttle
+        sits in the puller's write loop), and under a budget no range is
+        larger than a second of it."""
+        budget = self.pull_budget
+        if budget is not None:
+            chunk = min(chunk, max(1 << 20, budget.bps))
         stat = get_json(f"http://{source}/admin/file?name={name}&stat=true")
         total = stat["size"]
         with open(dest, "wb") as f:
@@ -1340,6 +1373,8 @@ class VolumeServer:
                 off += len(data)
                 if not data:
                     raise HttpError(502, f"short pull of {name} at {off}")
+                if budget is not None:
+                    budget.charge(len(data))
 
     def admin_volume_verify(self, req: Request):
         """Deep integrity check: walk the volume, CRC-verify every live
@@ -1412,13 +1447,16 @@ class VolumeServer:
         if rng is None:
             offset = int(req.query.get("offset", 0))
             size = int(req.query.get("size", 0))
-            return Response(shard.read_at(offset, size),
-                            headers={"Accept-Ranges": "bytes"})
+            data = shard.read_at(offset, size)
+            _tag_holder_read(len(data), len(data))
+            return Response(data, headers={"Accept-Ranges": "bytes"})
         offset, length = rng
         if length == 0:
             return Response(b"", headers={"Accept-Ranges": "bytes"})
+        data = shard.read_at(offset, length)
+        _tag_holder_read(len(data), len(data))
         return Response(
-            shard.read_at(offset, length), status=206,
+            data, status=206,
             headers={
                 "Accept-Ranges": "bytes",
                 "Content-Range":

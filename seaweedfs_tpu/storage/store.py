@@ -43,7 +43,8 @@ class Store:
                  ip: str = "127.0.0.1", port: int = 8080,
                  public_url: str = "", data_center: str = "",
                  rack: str = "", codec: Optional[ReedSolomonCodec] = None,
-                 index_kind: str = "memory", ec_backend: str = "auto"):
+                 index_kind: str = "memory", ec_backend: str = "auto",
+                 pull_budget=None):
         if isinstance(directories, str):
             directories = [directories]
         max_volume_counts = max_volume_counts or [7] * len(directories)
@@ -60,6 +61,11 @@ class Store:
         # its geometry's, and names the geometry of a volume whose
         # sidecars name none (10 + 4 unless a caller brought another)
         self.ec_backend = ec_backend
+        # the server's one budget for what it pulls in the background
+        # (util/throttler.ByteBudget, -compactionMBps): every rebuild
+        # here charges its remote survivor reads and sidecar fetches to
+        # it, whichever route it takes. None: unthrottled
+        self.pull_budget = pull_budget
         # a chip of its own among the process's (`tpu-own`); None: the
         # codecs compute wherever their backend does
         self.device_ordinal = next(_OWN_DEVICE_ORDINALS) \
@@ -497,7 +503,13 @@ class Store:
         (ec/spread.RebuiltShardSink; nothing of them is written here,
         and sidecars this server pulled for the decode alone are dropped
         again). The flat full gather only: a piggyback volume is refused
-        (VolumeError), and a single-shard loss is decoded in full."""
+        (VolumeError), and a single-shard loss is decoded in full.
+
+        Whatever the route, what this server receives from another
+        holder for the rebuild — survivor ranges, half-planes, trace
+        bits, the sidecars — is charged to ``pull_budget`` where the
+        server has one (-compactionMBps; the gather's stats carry it to
+        the remote readers); local shards are read unpaced."""
         import time as _time
         from ..ec import gather
         from ..util import tracing
@@ -530,7 +542,7 @@ class Store:
                 # so by the needle: 0.5 MB for a volume of 4 KB needles)
                 with tracing.Stage("ec.rebuild.index", root) as pulled:
                     pulled.tags["files"] = gather.fetch_index_files(
-                        base, holders)
+                        base, holders, budget=self.pull_budget)
                     pulled.nbytes = sum(os.path.getsize(base + ext)
                                         for ext in pulled.tags["files"])
                 if deliver_to and not any(
@@ -623,7 +635,7 @@ class Store:
                 # the gather of its plan's sources (surviving data,
                 # then just enough parities: not the first k), in
                 # stripes of whole sub-chunk windows
-                gstats = gather.GatherStats()
+                gstats = gather.GatherStats(self.pull_budget)
 
                 def coupled_source(src):
                     shard_size = sized(src)
@@ -649,7 +661,7 @@ class Store:
                 gather_present = self._health_survivor_mask(
                     present, local, sources, k, stats)
                 src = [i for i, p in enumerate(gather_present) if p][:k]
-                gstats = gather.GatherStats()
+                gstats = gather.GatherStats(self.pull_budget)
                 readers = []
                 for i in src:
                     if local[i]:
@@ -786,7 +798,7 @@ class Store:
             return bail(
                 f"shard size {shard_size} not aligned to sidecar "
                 f"window {li.window}")
-        gstats = gather.GatherStats()
+        gstats = gather.GatherStats(self.pull_budget)
         readers = []
         for i in rplan.helpers:
             if local[i]:
@@ -867,7 +879,7 @@ class Store:
         if mode == "auto" and plan.frac >= 1.0:
             return bail(f"no trace gain (frac={plan.frac:.3f})")
         shard_size = sized(plan.helpers)
-        gstats = gather.GatherStats()
+        gstats = gather.GatherStats(self.pull_budget)
         readers = []
         for i in plan.helpers:
             if local[i]:

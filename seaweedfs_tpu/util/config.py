@@ -72,6 +72,11 @@ def _knob(name: str, kind: str, default, doc: str) -> str:
 _knob("SW_PULSE_S", "float", 5.0,
       "Default heartbeat/prune pulse seconds for servers constructed "
       "without an explicit pulse_seconds.")
+_knob("SW_COMPACTION_MBPS", "int", 0,
+      "Default -compactionMBps (MiB/s, 0 = unthrottled) for volume "
+      "servers constructed without an explicit compaction_mbps: "
+      "vacuum's copy and every byte the server pulls in the background "
+      "(rebuild gathers, volume.copy, ec.copy).")
 _knob("SW_HTTP_POLL_S", "float", 0.5,
       "HTTP accept-loop poll interval; server shutdown latency is "
       "bounded by it.")
