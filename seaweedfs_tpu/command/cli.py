@@ -23,8 +23,9 @@ EC_BACKEND_HELP = (
     "tpu-own (one chip of the host's several: the process's volume "
     "servers take the local chips in turn, first server chip 0, second "
     "chip 1, ... modulo their count, and each reports its chip in "
-    "/status and its heartbeat so that ec.encode -collection and "
-    "ec.rebuild keep one volume in flight a distinct chip; a process "
+    "/status and its heartbeat so that ec.rebuild keeps one volume in "
+    "flight a distinct chip (ec.encode -collection keeps one in flight "
+    "a source server, whatever chips the servers have); a process "
     "a server with one visible chip gets index 0)")
 
 def _security_cfg(args):

@@ -64,6 +64,18 @@ that chose the array map for a frozen volume of an in-memory index
 (storage/volume.Volume._reload_kind): one a freeze whose lease came
 back, so an `ec.encode -collection` of 32 volumes moves it by 32.
 
+`collection_encode_inflight_us` and `collection_encode_us` count the
+`ec.encode -collection` commands this process's shell ran
+(shell/command_ec.ec_encode): the microseconds its volumes were in
+flight, summed over the volumes, and the microseconds of the commands.
+Their ratio is the time-weighted mean number of volumes in flight, which
+the command's `ec.encode.collection` span carries as
+`volumes_inflight_mean` beside `lanes` (the source servers that could
+run one: a collection's volumes run one at a time per server a `.dat`
+lies on). 1 where a command walks its volumes one by one; a collection
+on four servers reads between 3 and 4, the tail of a command, when
+fewer servers have volumes left, keeps it under the lanes.
+
 `rebuild_delivered_bytes` and `rebuild_local_bytes` count the bytes of
 rebuilt shards a full-gather rebuild produced, by where they went: sent
 to another node's disk through the spread's sink (the node that decoded
@@ -108,7 +120,8 @@ class DispatchStats:
                "lock_probe_stall_us",
                "mirror_entries", "mirror_us", "mirror_loop_entries",
                "rebuild_delivered_bytes", "rebuild_local_bytes",
-               "frozen_array_maps")
+               "frozen_array_maps",
+               "collection_encode_inflight_us", "collection_encode_us")
     REPAIR_ROUTES = ("piggyback", "trace", "full")
 
     def __init__(self):
@@ -177,6 +190,13 @@ class DispatchStats:
             if stalled:
                 self.lock_probe_stalls += 1
                 self.lock_probe_stall_us += int(late_s * 1e6)
+
+    def add_collection_encode(self, inflight_s: float, wall_s: float):
+        """One `ec.encode -collection` command ended: its volumes'
+        seconds in flight, summed, and its own."""
+        with self._lock:
+            self.collection_encode_inflight_us += int(inflight_s * 1e6)
+            self.collection_encode_us += int(wall_s * 1e6)
 
     def add_repair_route(self, route: str):
         """One streaming rebuild finished on this route."""

@@ -52,32 +52,36 @@ def chips_of(nodes: List[dict]) -> Dict[str, str]:
             for n in nodes}
 
 
-def lanes_of(chips: Dict[str, str]) -> int:
+def lanes_of(lane_of: Dict[str, str]) -> int:
     """How many volumes a collection command keeps in flight: the
-    distinct chips among the cluster's nodes."""
-    return len(set(chips.values()))
+    distinct lanes its nodes lie on (`run_in_lanes`)."""
+    return len(set(lane_of.values()))
 
 
-def run_in_lanes(jobs: list, chips: Dict[str, str], place, run):
-    """A collection's volumes over the cluster's chips: ONE volume in
-    flight per distinct chip, the jobs taken in order. ``place(job,
-    busy)`` is asked, with the volumes in flight by chip, where a job
-    would compute now: it returns (node url, whatever ``run`` needs
-    beside) or None while the job has to wait for a chip; the first job
-    of the order that has a place starts, on a thread of its own, and
-    ``run(job, placement)`` does the work. Where the cluster names one
-    chip (every deployment before `tpu-own`, and every server of one
-    process on `tpu`) nothing is placed and no thread is started:
+def run_in_lanes(jobs: list, lane_of: Dict[str, str], place, run):
+    """A collection's volumes, ONE in flight per lane, the jobs taken in
+    order. What a lane is the caller says: ``lane_of`` maps a node's url
+    to the lane a job placed on that node occupies — the chip the node
+    computes on for `ec.rebuild` (`chips_of`), the node itself for
+    `ec.encode -collection` (a volume is coded on the server its `.dat`
+    lies on, and what is scarce there is that server's). ``place(job,
+    busy)`` is asked, with the volumes in flight by lane, where a job
+    would run now: it returns (node url, whatever ``run`` needs beside)
+    or None while the job has to wait; the first job of the order that
+    has a place starts, on a thread of its own, and ``run(job,
+    placement)`` does the work. Where there is one lane (a single
+    server, a collection of one volume, a rebuild on a cluster that
+    names one chip) nothing is placed and no thread is started:
     ``run(job, None)`` in order on the caller's thread, which is what
     the commands did before there were lanes. A job that raises stops
     new ones from starting; those in flight finish, and the first
     error in job order is raised."""
     import threading
-    if lanes_of(chips) <= 1:
+    if lanes_of(lane_of) <= 1:
         for job in jobs:
             run(job, None)
         return
-    busy = dict.fromkeys(chips.values(), 0)
+    busy = dict.fromkeys(lane_of.values(), 0)
     freed = threading.Condition()
     errors: Dict[int, BaseException] = {}
     threads = []
@@ -90,7 +94,7 @@ def run_in_lanes(jobs: list, chips: Dict[str, str], place, run):
             errors[n] = e
         finally:
             with freed:
-                busy[chips[placement[0]]] -= 1
+                busy[lane_of[placement[0]]] -= 1
                 freed.notify()
 
     while pending and not errors:
@@ -98,7 +102,7 @@ def run_in_lanes(jobs: list, chips: Dict[str, str], place, run):
             snapshot = dict(busy)
         start = None
         # only this thread adds to `busy`: a job placed by the snapshot
-        # finds its chip no busier when it starts
+        # finds its lane no busier when it starts
         for n, job in pending:
             placement = place(job, snapshot)
             if placement is not None:
@@ -109,7 +113,7 @@ def run_in_lanes(jobs: list, chips: Dict[str, str], place, run):
                 if busy == snapshot:
                     freed.wait()
                 continue
-            busy[chips[start[2][0]]] += 1
+            busy[lane_of[start[2][0]]] += 1
         pending.remove(start[:2])
         t = threading.Thread(target=work, args=start, daemon=True,
                              name=f"ec-volume-{start[0]}")
@@ -170,7 +174,8 @@ def collect_volume_ids_for_ec_encode(env: CommandEnv, collection: str,
                                      size_limit: int = None
                                      ) -> Dict[int, tuple]:
     """Quiet & nearly-full volumes by volume id: (size, the url of the
-    server that holds it) (reference collectVolumeIdsForEcEncode
+    server that holds it, the urls of all its replicas' holders)
+    (reference collectVolumeIdsForEcEncode
     command_ec_encode.go:255-287)."""
     import time
     if size_limit is None:
@@ -188,19 +193,65 @@ def collect_volume_ids_for_ec_encode(env: CommandEnv, collection: str,
         modified = vi.get("modified_at", 0)
         if modified and now - modified < quiet_seconds:
             continue
-        out[int(vid_s)] = (int(vi.get("size", 0)), vi.get("url"))
+        out[int(vid_s)] = (int(vi.get("size", 0)), vi.get("url"),
+                           [r.get("url") for r in replicas])
     return out
+
+
+def plan_encode_placements(nodes: List[dict], jobs: list,
+                           geometry: tuple) -> tuple:
+    """vid -> (assignment, spares) of every volume of an `ec.encode
+    -collection` whose volumes run in lanes, decided before the first
+    starts, by one thread, in job order: the shards of volume n go
+    where they would have gone had the volumes been coded one after the
+    other. ``nodes`` is the master's list as the command begins
+    (`/cluster/status`), ``jobs`` the (vid, replica urls) in job order.
+    A volume coded one after the other reads the master's free counts
+    when it starts, and those hold every earlier volume whole: its
+    shards mounted (a shard takes 1/k of a slot) and its replicas
+    dropped (a slot back each). With several in flight the master is
+    between two states, so the counts are carried here instead, by the
+    master's own arithmetic (topology/node.DataNode.free_space: the
+    slots less the SUM of the volumes' shard shares, in the order the
+    volumes were coded), which keeps ties as the master would. Where a
+    volume finds no room the plan ends before it, as the serial order
+    would have coded the volumes before it and raised: returns (the
+    plan, that error or None)."""
+    k = geometry[0]
+    slots = {n["url"]: n.get("free", 0) for n in nodes}
+    coded: Dict[str, List[float]] = {n["url"]: [] for n in nodes}
+    out = {}
+    for vid, replicas in jobs:
+        view = sorted(({**n, "free": slots[n["url"]] - sum(coded[n["url"]])}
+                       for n in nodes), key=lambda n: -n["free"])
+        try:
+            assignment = balanced_ec_distribution(view, geometry)
+        except ValueError as e:
+            return out, e
+        out[vid] = (assignment, [n["url"] for n in view
+                                 if n["url"] not in assignment])
+        for url in set(assignment):
+            coded[url].append(assignment.count(url) / k)
+        for url in replicas:
+            if url in slots:
+                slots[url] += 1
+    return out, None
 
 
 @command("ec.encode",
          "-volumeId <id> | -collection <name> [-fullPercent 0.95] "
          "[-geometry <data>,<parity>] : erasure-code volumes and spread "
          "their shards across the cluster, each shard's ranges pushed to "
-         "its holder while later slabs encode (geometry = the RS code of "
+         "its holder while later slabs encode; a collection's volumes run "
+         "one at a time per source server (the server a volume's .dat "
+         "lies on), so a collection on four servers has four in flight "
+         "(geometry = the RS code of "
          "the new EC volume, e.g. 6,3 for nine shards; 10,4 and 14 "
          "shards without the flag; it is stamped into the volume's .vif "
          "and every later command reads it from there)")
 def ec_encode(env: CommandEnv, args: List[str]):
+    import time
+    from ..ops import telemetry
     from ..util import tracing
     flags = parse_flags(args)
     geometry = None
@@ -216,38 +267,64 @@ def ec_encode(env: CommandEnv, args: List[str]):
     found = collect_volume_ids_for_ec_encode(
         env, flags["collection"], float(flags.get("fullPercent", 0.95)),
         quiet_seconds=float(flags.get("quietFor", 3600)))
+    # a volume is coded on the server its .dat lies on: that server's
+    # freeze, index, read and spread lanes are what a volume occupies,
+    # so the command keeps one volume in flight per source server
+    homes = {home: home for _, home, _ in found.values()}
+    lanes = lanes_of(homes)
+    jobs = sorted(found) if lanes > 1 else list(found)
     # the whole command under one span of its own trace; each volume's
     # ec.encode stays the root of its own and names this one
     whole = tracing.Span("ec.encode.collection", tags={
-        "collection": flags["collection"], "volumes": 0, "bytes": 0})
-
+        "collection": flags["collection"], "volumes": 0, "bytes": 0,
+        "lanes": lanes, "volumes_inflight_mean": 0.0})
     counted = make_lock("command_ec.collection_span")
+    inflight_s = 0.0
 
-    def encode(vid, _placement):
-        do_ec_encode(env, vid, geometry=geometry, command=whole.trace_id)
-        with counted:       # volumes in lanes finish on their threads
+    def encode(vid, placement):
+        nonlocal inflight_s
+        t0 = time.perf_counter()
+        try:
+            do_ec_encode(env, vid, geometry=geometry,
+                         command=whole.trace_id,
+                         placement=placement and placement[1:])
+        finally:
+            with counted:   # volumes in lanes finish on their threads
+                inflight_s += time.perf_counter() - t0
+        with counted:
             whole.tags["volumes"] += 1
             whole.tags["bytes"] += found[vid][0]
 
-    chips = chips_of(env.cluster_nodes())
+    # asked only where there are lanes: where every volume of the
+    # command will put its shards, as the serial order would have
+    planned, no_room = plan_encode_placements(
+        env.cluster_nodes(), [(vid, found[vid][2]) for vid in jobs],
+        geometry or (DATA_SHARDS, PARITY_SHARDS)) if lanes > 1 \
+        else ({}, None)
+    if no_room:
+        jobs = jobs[:len(planned)]
 
     def home_if_free(vid, busy):
-        # a volume is coded where its .dat lies: it starts when that
-        # node's chip has nothing in flight (asked only where there are
-        # lanes; a volume whose holder the cluster does not list raises)
         home = found[vid][1]
-        return None if busy[chips[home]] else (home,)
+        return None if busy[home] else (home, *planned[vid])
 
+    t0 = time.perf_counter()
     try:
-        run_in_lanes(sorted(found) if lanes_of(chips) > 1 else list(found),
-                     chips, home_if_free, encode)
+        run_in_lanes(jobs, homes, home_if_free, encode)
+        if no_room:
+            raise no_room
     finally:
+        wall = time.perf_counter() - t0
+        whole.tags["volumes_inflight_mean"] = \
+            round(inflight_s / wall, 3) if wall > 0 else 0.0
+        telemetry.STATS.add_collection_encode(inflight_s, wall)
         tracing.finish_span(whole)
 
 
 def do_ec_encode(env: CommandEnv, vid: int,
                  timings: Dict = None, rate_mbps: float = 0.0,
-                 geometry: tuple = None, command: str = None):
+                 geometry: tuple = None, command: str = None,
+                 placement: tuple = None):
     """Freeze -> encode+spread -> mount -> drop originals.
 
     The shard assignment goes to the source, which pushes each shard's
@@ -265,7 +342,11 @@ def do_ec_encode(env: CommandEnv, vid: int,
     ``geometry`` (k, m) is the new EC volume's RS
     code, passed on to the source's ``/admin/ec/generate``; None leaves
     the node at its default, 10 + 4. ``command`` is the trace id of the
-    `ec.encode -collection` span this volume is one of, kept as a tag."""
+    `ec.encode -collection` span this volume is one of, kept as a tag.
+    ``placement`` is (assignment, spares) where the command runs its
+    volumes in lanes and has decided every volume's holders before the
+    first started (`plan_encode_placements`); None: the master is asked
+    here, now, as it always was."""
     from ..util import tracing
     replicas = _volume_replicas(env, vid)
     if not replicas:
@@ -287,13 +368,15 @@ def do_ec_encode(env: CommandEnv, vid: int,
                     r["url"], f"/admin/volume/readonly?volume={vid}")
                 if not (out or {}).get("was_readonly"):
                     froze.append(r["url"])
-        assignment = balanced_ec_distribution(
-            _free_nodes(env), geometry or (DATA_SHARDS, PARITY_SHARDS))
+        assignment, spares = placement or (None, None)
+        if assignment is None:
+            assignment = balanced_ec_distribution(
+                _free_nodes(env), geometry or (DATA_SHARDS, PARITY_SHARDS))
         try:
             # 2+3. encode + spread + mount
             _encode_spread_streaming(env, vid, collection, source,
                                      assignment, timings, rate_mbps,
-                                     geometry)
+                                     geometry, spares)
         except BaseException as e:
             _cleanup_partial_encode(env, vid, collection,
                                     set(assignment) | {source})
@@ -337,15 +420,19 @@ def _encode_spread_streaming(env: CommandEnv, vid: int, collection: str,
                              source: str, assignment: List[str],
                              timings: Dict = None,
                              rate_mbps: float = 0.0,
-                             geometry: tuple = None):
+                             geometry: tuple = None,
+                             spares: List[str] = None):
     """One POST: the source encodes and pushes each shard's slab ranges
     to its assigned holder while later slabs encode. Afterwards only
     the KB-scale index sidecars (.ecx/.vif) are copied to remote
-    holders, then every holder mounts its shards."""
+    holders, then every holder mounts its shards. ``spares`` (nodes a
+    dead target's shards may move to) come with a planned placement;
+    None: the master is asked."""
     import time as _time
     from ..util.fanout import fan_out_must_succeed
-    spares = [n["url"] for n in _free_nodes(env)
-              if n["url"] not in assignment]
+    if spares is None:
+        spares = [n["url"] for n in _free_nodes(env)
+                  if n["url"] not in assignment]
     t0 = _time.perf_counter()
     out = env.node_post(
         source, f"/admin/ec/generate?volume={vid}"

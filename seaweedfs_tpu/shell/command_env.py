@@ -52,7 +52,10 @@ class CommandEnv:
         return posixpath.normpath(path)
 
     def write(self, *args):
-        print(*args, file=self.out)
+        # one call a line: the volumes of a collection command write
+        # from their own threads, and print() hands a stream the text
+        # and the newline apart
+        self.out.write(" ".join(map(str, args)) + "\n")
 
     # -- cluster state helpers --------------------------------------------
     def master_get(self, path: str) -> dict:

@@ -961,8 +961,8 @@ class Store:
         }
         if self.device_ordinal is not None:
             # the chip of a `tpu-own` server: the master passes it on
-            # (/cluster/status), the shell keeps one volume in flight a
-            # distinct chip
+            # (/cluster/status), the shell's ec.rebuild keeps one volume
+            # in flight a distinct chip
             hb["device"] = self.device()
         return hb
 

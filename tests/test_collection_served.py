@@ -177,7 +177,9 @@ def served(tmp_path_factory):
         assert wait_until(lambda: all(
             any(r.get("size") == dat_bytes for r in
                 env.all_volumes().get(str(v), [])) for v in vids))
-        out.update(vids=vids, kept=kept, dat_bytes=dat_bytes)
+        out.update(vids=vids, kept=kept, dat_bytes=dat_bytes,
+                   homes={int(v): r[0]["url"]
+                          for v, r in env.all_volumes().items()})
 
         tracing.add_finish_hook(spans.append)
         out["encode"] = shell("ec.encode", "-collection", COLLECTION,
@@ -349,11 +351,19 @@ def test_the_index_build_is_a_stage_a_phase_and_two_counters(served):
         assert s["tags"]["tombstones"] == NEEDLES // 16
         assert s["tags"]["bytes"] == 16 * live
     replies = _replies(served["encode"], "/admin/ec/generate")
-    assert sum(r["index_entries"] for r in replies) == \
-        served["encode"]["counters"]["index_entries"] == VOLUMES * live
-    assert sum(r["index_us"] for r in replies) == \
-        served["encode"]["counters"]["index_us"] > 0
-    for r, s in zip(replies, built):
+    assert served["encode"]["counters"]["index_entries"] == VOLUMES * live
+    assert served["encode"]["counters"]["index_us"] > 0
+    # a reply's counters are the PROCESS's movement while its volume ran
+    # (ops/telemetry.delta), and since PR 50 the four servers of this
+    # process code a volume each at once: its own build and those of the
+    # volumes beside it, never less
+    for r in replies:
+        assert r["index_entries"] >= live and \
+            r["index_entries"] % live == 0 and r["index_us"] > 0
+    assert sum(r["index_entries"] for r in replies) >= VOLUMES * live
+    # what a reply says of its own stream is its own: the stage
+    for r, s in zip(sorted(replies, key=lambda r: r["phases"]["index"]),
+                    sorted(built, key=lambda s: s["duration_s"])):
         assert r["phases"]["index"] == r["stage_max_s"]["index"] == \
             pytest.approx(s["duration_s"], abs=2e-6)
     # the rebuilds build no index, they pull it: no count moves
@@ -382,17 +392,22 @@ def test_each_command_has_a_span_that_counts_its_volumes(served):
     spans = served["spans"]
     whole, = [s for s in spans if s["name"] == "ec.encode.collection"]
     assert whole["parent_id"] is None
-    assert whole["tags"] == {"collection": COLLECTION, "volumes": VOLUMES,
-                             "bytes": VOLUMES * served["dat_bytes"]}
     roots = [s for s in spans if s["name"] == "ec.encode"]
     assert len(roots) == VOLUMES
+    # PR 50: the servers that could code a volume at once (the .dat files
+    # lie on all four) and how many did, over the command
+    inflight = whole["tags"].pop("volumes_inflight_mean")
+    assert whole["tags"] == {"collection": COLLECTION, "volumes": VOLUMES,
+                             "bytes": VOLUMES * served["dat_bytes"],
+                             "lanes": 4}
+    assert 1.0 < inflight <= 4.0 and inflight == pytest.approx(
+        sum(s["duration_s"] for s in roots) / whole["duration_s"], rel=0.1)
     # one trace an operation, as before: each volume's root is a root,
     # of a trace of its own, and names the command's
     assert len({s["trace_id"] for s in roots} | {whole["trace_id"]}) == \
         VOLUMES + 1
     assert all(s["parent_id"] is None and
                s["tags"]["command"] == whole["trace_id"] for s in roots)
-    assert sum(s["duration_s"] for s in roots) <= whole["duration_s"]
     rebuilds = [s for s in spans if s["name"] == "ec.rebuild.collection"]
     assert len(rebuilds) == 4
     for whole, loss in zip(rebuilds, served["losses"]):
@@ -413,7 +428,8 @@ def test_a_single_volume_encode_leaves_no_command_span(served):
                 if s["name"] == "ec.encode.collection"]) == 1
 
 
-# -- servers that share a chip: one volume in flight (PR 45) -----------------
+# -- servers that share a chip: a rebuild has one volume in flight (PR 45), ----
+# -- an encode one per server a .dat lies on (PR 50) --------------------------
 
 def _volumes_of(served, name: str) -> list:
     commands = {s["trace_id"] for s in served["spans"]
@@ -426,13 +442,21 @@ def _volumes_of(served, name: str) -> list:
 @pytest.mark.parametrize("name", ["ec.encode", "ec.rebuild"])
 def test_on_one_chip_a_volume_starts_when_the_last_has_ended(served, name):
     """The four servers are one process on `-ec.backend tpu`: none names a
-    chip, the shell reads one lane from the cluster, and a collection's
-    volumes follow each other as they always did."""
+    chip, an `ec.rebuild` reads one lane from the cluster, and its
+    volumes follow each other as they always did. An `ec.encode`'s lanes
+    are the servers the `.dat` files lie on: the volumes of ONE server
+    follow each other (tests/test_encode_lanes.py has the rest)."""
     volumes = _volumes_of(served, name)
     assert len(volumes) == VOLUMES * (1 if name == "ec.encode" else 4)
-    for before, after in zip(volumes, volumes[1:]):
-        assert before["start"] + before["duration_s"] <= \
-            after["start"] + 1e-6
+    lanes = {}
+    for span in volumes:
+        lanes.setdefault(served["homes"][span["tags"]["volume"]]
+                         if name == "ec.encode" else "", []).append(span)
+    assert len(lanes) == (4 if name == "ec.encode" else 1)
+    for walked in lanes.values():
+        for before, after in zip(walked, walked[1:]):
+            assert before["start"] + before["duration_s"] <= \
+                after["start"] + 1e-6
 
 
 def test_on_one_chip_the_target_decodes_for_itself(served):

@@ -563,33 +563,38 @@ def one_chip(request, tmp_path):
         cluster.stop()
 
 
-def test_on_one_chip_the_calls_are_todays_in_todays_order(one_chip):
+def test_on_one_chip_a_rebuild_walks_its_volumes_an_encode_its_servers(
+        one_chip):
     cluster, vids, kept = one_chip
     nodes = cluster.env.cluster_nodes()
     assert all("device" not in n for n in nodes)
     assert set(command_ec.chips_of(nodes).values()) == {""}
-    order = [int(v) for v in cluster.env.all_volumes()]
+    home = {int(v): r[0]["url"]
+            for v, r in cluster.env.all_volumes().items()}
     enc = cluster.shell("ec.encode", "-collection", "one",
                         "-fullPercent", "0.45", "-quietFor", "0")
     assert wait_until(lambda: cluster.whole(vids))
     me = threading.get_ident()
-    # a volume after the other, each whole before the next begins, on
-    # the caller's thread but for the mounts' fan-out
-    assert {c["thread"] for c in enc["calls"]
-            if c["route"] != "/admin/ec/mount"
-            and c["route"] != "/admin/ec/copy"} == {me}
+    # an encode's lanes are the servers the .dat files lie on, whatever
+    # chip they share (tests/test_encode_lanes.py has the single server,
+    # on the caller's thread): each volume whole before the next of ITS
+    # server begins, each by today's calls in today's order
+    assert len({home[v] for v in vids}) > 1
+    assert me not in {c["thread"] for c in enc["calls"]}
     generated = [int(c["path"].split("volume=")[1].split("&")[0])
                  for c in enc["calls"]
                  if c["route"] == "/admin/ec/generate"]
-    assert generated == [v for v in order if v in vids]
-    per_volume = []
+    assert sorted(generated) == sorted(vids)
+    per_volume, per_server = {}, {}
     for c in enc["calls"]:
         vid = int(c["path"].split("volume=")[1].split("&")[0])
-        if not per_volume or per_volume[-1][0] != vid:
-            per_volume.append((vid, []))
-        per_volume[-1][1].append(c["route"])
-    assert [v for v, _ in per_volume] == generated    # never interleaved
-    for _, routes in per_volume:
+        per_volume.setdefault(vid, []).append(c["route"])
+        walked = per_server.setdefault(home[vid], [])
+        if not walked or walked[-1] != vid:
+            walked.append(vid)
+    for walked in per_server.values():      # never interleaved
+        assert len(walked) == len(set(walked))
+    for routes in per_volume.values():
         assert routes[0] == "/admin/volume/readonly"
         assert routes[1] == "/admin/ec/generate"
         assert routes[-1] == "/admin/delete_volume"

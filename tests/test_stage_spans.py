@@ -374,7 +374,10 @@ def test_counters_at_the_stage_boundaries(cycle):
         "rebuild_delivered_bytes", "rebuild_local_bytes",
         # PR 47: a frozen volume's map stays an array
         # (tests/test_frozen_map.py)
-        "frozen_array_maps"}
+        "frozen_array_maps",
+        # PR 50: a collection's volumes in flight, one a source server
+        # (tests/test_encode_lanes.py)
+        "collection_encode_inflight_us", "collection_encode_us"}
     fetched = sum(s["tags"]["bytes"] for s in cycle["spans"]
                   if s["name"].startswith("ec.rebuild.fetch."))
     assert fetched == cycle["rebuild"]["survivor_bytes"] > 0
